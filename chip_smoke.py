@@ -1,0 +1,714 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — does the system still start on the chip?
+
+Drives the main path once, through the entry points a user calls, on
+real TPU devices:
+
+  this process (an IPython shell that never touches the chip)
+    -> %dist_init            -> one runtime.worker process per chip
+       -> kernels, a few optimizer steps, generate
+    -> %dist_shutdown
+    -> %dist_pool start      -> gateway daemon -> workers
+       -> %dist_attach, %dist_serve: paged KV + chunked prefill,
+          every stream the greedy `generate` stream (exactly, in
+          float32; to a near-tie margin in bf16 — see TIE_MARGIN)
+    -> %dist_pool stop
+
+The model is `mistral_7b_config` at its published widths (d_model 4096,
+32 query / 8 KV heads of 128, d_ff 14336, vocab 32000, window 4096,
+bf16, use_flash) with n_layers cut to what the chip's memory holds; the
+weights are random, from a seed.  Every phase is checked here — a
+caught exception or an `error` field in a reply is a failed phase.
+
+    python chip_smoke.py               # one worker, one chip
+    python chip_smoke.py --workers 4   # the same path on a 4-chip host
+
+Exit 0 only if every phase passed on TPU devices.  Then (and whenever
+the fleet came up on TPU devices) the last line of stdout is the
+verdict, one JSON object of exactly two keys,
+{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}},
+and the line before it is `SUMMARY {json}`: chosen depth, per-phase
+pass/fail and seconds, compile seconds, versions, transport.
+With no accelerator the fleet phase fails, nothing is printed as a
+result and the exit code is 2.  Anything printed besides pass/fail
+(compile seconds, step times, tokens) is a log line, never a metric.
+"""
+
+from __future__ import annotations
+
+import argparse
+import contextlib
+import io
+import json
+import os
+import re
+import signal
+import sys
+import tempfile
+import time
+import uuid
+
+REPO = os.path.dirname(os.path.abspath(__file__))
+DEADLINE_S = 1100           # the driver allows 1200 s, teardown included
+
+# Serving geometry: prompts longer than one prefill chunk stream in
+# 128-token chunks; short ones pad to the same 128 so admission
+# compiles one shape.
+SERVE = {"max_batch": 4, "max_len": 512, "pad_to": 128,
+         "kv_block_tokens": 64, "prefill_chunk": 128}
+# (prompt length, new tokens): mixed lengths, two longer than a chunk.
+REQUESTS = [(24, 24), (96, 16), (200, 24), (57, 32), (130, 16)]
+VOCAB = 32000               # prompts are drawn here, off the chip
+
+# A served stream must equal the greedy `generate` reference.  In
+# float32 at `highest` matmul precision it does, on the chip, through
+# paged KV + chunked prefill + the row-masked batch step (checked on
+# every pool rank for the requests F32_EXACT names).  In bf16 the
+# server's step over max_batch rows and generate's one-row step are
+# different programs that round differently, and a near-tie then flips
+# the argmax and everything after it (isolated on the chip, PERF.md
+# finding 7: every flip sat where the top two logits were within
+# 0.008 sigma).  So a bf16 stream that differs must still be greedy
+# under the model: every served token's logit within this many standard
+# deviations of the best at its position (teacher-forced; a wrong token
+# sits several sigma down — the best of 32000 is about four above the
+# mean).
+TIE_MARGIN = 0.05
+F32_EXACT = (0, 2, 4)       # 24, 200 (two chunks) and 130 tokens
+
+# Depth: bytes one more Mistral-7B layer / the rest of the train step
+# needs at S=4096, batch 1/chip, bf16 params + adamw (XLA's buffer
+# assignment for v5e: 13.8 GB at 3 layers, 17.2 GB at 4).  The largest
+# depth whose step fits in 85% of the device's reported limit.
+_STEP_FIXED_GB, _STEP_PER_LAYER_GB, _HBM_SHARE = 3.8, 3.34, 0.85
+
+
+PHASES = ("fleet", "kernels", "train", "generate", "teardown", "serve",
+          "parent_off_chip")
+
+
+class PhaseFailed(Exception):
+    pass
+
+
+class _Tee(io.TextIOBase):
+    """stdout that also keeps what passed through it (the magics
+    report by printing)."""
+
+    def __init__(self, real):
+        self.real, self.buf = real, io.StringIO()
+
+    def write(self, s):
+        self.real.write(s)
+        self.buf.write(s)
+        return len(s)
+
+    def flush(self):
+        self.real.flush()
+
+
+def _captured(fn, *a):
+    tee = _Tee(sys.stdout)
+    with contextlib.redirect_stdout(tee):
+        fn(*a)
+    return tee.buf.getvalue()
+
+
+def _sentinels(text: str) -> list[dict]:
+    """The `SMOKE {json}` lines worker cells print, one per rank."""
+    return [json.loads(m) for m in re.findall(r"SMOKE (\{.*\})", text)]
+
+
+def _marked_processes(marker: str) -> dict[int, str]:
+    """Live (non-zombie) processes started under this run, found by
+    the marker every child inherits in its environment — pid -> argv."""
+    out = {}
+    for pid in filter(str.isdigit, os.listdir("/proc")):
+        if int(pid) == os.getpid():
+            continue
+        try:
+            with open(f"/proc/{pid}/environ", "rb") as f:
+                if marker.encode() not in f.read():
+                    continue
+            with open(f"/proc/{pid}/stat") as f:
+                state = f.read().rsplit(")", 1)[1].split()[0]
+            with open(f"/proc/{pid}/cmdline", "rb") as f:
+                argv = f.read().replace(b"\0", b" ").decode().strip()
+        except OSError:
+            continue
+        if state != "Z":
+            out[int(pid)] = argv
+    return out
+
+
+def _maps_libtpu(pid: int) -> bool:
+    """A process that initialised the TPU backend has libtpu mapped."""
+    with open(f"/proc/{pid}/maps") as f:
+        return "libtpu" in f.read()
+
+
+class Smoke:
+    def __init__(self, workers: int):
+        self.n = workers
+        # Exported before anything starts, so every process of this
+        # run carries it and a leftover can be told from a stranger.
+        self.marker = f"NBD_SMOKE_RUN={uuid.uuid4().hex}"
+        os.environ.update([self.marker.split("=")])
+        self.phases: dict[str, dict] = {}
+        self.facts: dict = {}
+        self.device: dict | None = None
+        self.layers: int | None = None
+        self.pool_dir: str | None = None
+        self.ip = None
+        self.DM = None
+
+    # -- plumbing ------------------------------------------------------
+
+    def shell(self):
+        from IPython.testing.globalipapp import get_ipython, start_ipython
+        self.ip = start_ipython() or get_ipython()
+        os.chdir(REPO)      # start_ipython may move the cwd
+        self.ip.run_line_magic("load_ext", "nbdistributed_tpu")
+        from nbdistributed_tpu.magics.magic import DistributedMagics
+        self.DM = DistributedMagics
+
+    def magic(self, name: str, line: str = "") -> str:
+        return _captured(self.ip.run_line_magic, name, line)
+
+    def cell(self, code: str, ranks: str | None = None) -> list[dict]:
+        """Run a cell on the fleet (or the attached pool) and return
+        one sentinel per rank that ran it; a rank without one failed."""
+        if ranks is None:
+            out = _captured(self.ip.run_cell_magic, "distributed", "",
+                            code)
+            want = self.n
+        else:
+            out = _captured(self.ip.run_cell_magic, "rank", ranks, code)
+            want = 1
+        got = _sentinels(out)
+        if len(got) != want or any("error" in g for g in got):
+            raise PhaseFailed(
+                f"cell reported {len(got)}/{want} ranks: "
+                + out.strip()[-1500:])
+        return sorted(got, key=lambda g: g.get("rank", 0))
+
+    def phase(self, name: str, fn) -> bool:
+        print(f"\n=== phase {name}", flush=True)
+        t0 = time.time()
+        try:
+            fn()
+            rec = {"ok": True}
+        except PhaseFailed as e:
+            rec = {"ok": False, "error": str(e)}
+        except Exception as e:     # boundary: every failure is a verdict
+            rec = {"ok": False, "error": f"{type(e).__name__}: {e}"}
+        rec["seconds"] = round(time.time() - t0, 1)
+        self.phases[name] = rec
+        print(f"=== phase {name}: {'PASS' if rec['ok'] else 'FAIL'} "
+              f"({rec['seconds']}s)"
+              + ("" if rec["ok"] else f"\n{rec['error']}"), flush=True)
+        return rec["ok"]
+
+    def status(self) -> dict[int, dict]:
+        resp = self.DM._comm.send_to_all("get_status", None, timeout=120)
+        return {r: m.data or {} for r, m in resp.items()}
+
+    # -- phases --------------------------------------------------------
+
+    def fleet(self):
+        out = self.magic("dist_init", f"-n {self.n} --backend tpu "
+                                      f"--attach-timeout 240")
+        if self.DM._comm is None:
+            raise PhaseFailed("no fleet: " + out.strip()[-1500:])
+        st = self.status()
+        seen = set()
+        for r in range(self.n):
+            d = st.get(r) or {}
+            devs = d.get("devices") or []
+            if (d.get("backend") != "tpu" or len(devs) != 1
+                    or devs[0].get("platform") != "tpu"
+                    or not devs[0].get("kind")
+                    or d.get("global_device_count") != self.n):
+                raise PhaseFailed(f"rank {r} is not one TPU device of "
+                                  f"{self.n}: {d}")
+            seen.add(devs[0]["id"])
+        if len(seen) != self.n:
+            raise PhaseFailed(f"ranks share devices: ids {sorted(seen)}")
+        dev0 = st[0]["devices"][0]
+        self.device = {"platform": str(dev0["platform"]),
+                       "kind": str(dev0["kind"]),
+                       "count": int(st[0]["global_device_count"])}
+        limit_gb = min(st[r]["devices"][0]["memory_gb"]["limit"]
+                       for r in range(self.n))
+        self.layers = int((_HBM_SHARE * limit_gb - _STEP_FIXED_GB)
+                          // _STEP_PER_LAYER_GB)
+        if self.layers < 1:
+            raise PhaseFailed(f"{limit_gb} GB of device memory holds no "
+                              f"full-width layer")
+        facts = self.cell(_FACTS_CELL)
+        if self.n > 1:
+            want = self.n * (self.n + 1) / 2
+            if any(f["all_reduce"] != want for f in facts):
+                raise PhaseFailed(f"all_reduce(rank+1) != {want}: {facts}")
+            if len({tuple(f["coords"]) for f in facts}) != self.n:
+                raise PhaseFailed(f"ranks share chip coords: {facts}")
+        f0 = facts[0]
+        self.facts = {"versions": f0["versions"],
+                      "transport": {"fleet": self.DM._comm.transport},
+                      "tuned_blocks": f0["tuned_blocks"],
+                      "compile_cache": f0["compile_cache"],
+                      "hbm_limit_gb": limit_gb}
+        print(f"device {self.device} · {f0['versions']} · transport "
+              f"{self.DM._comm.transport} · tuned block table "
+              f"{'loaded' if f0['tuned_blocks'] else 'absent'} · compile "
+              f"cache {f0['compile_cache']} · depth {self.layers}")
+
+    def kernels(self):
+        k = self.cell(_KERNELS_CELL, ranks="[0]")[0]
+        self.facts["kernels"] = k["checks"]
+        bad = [c for c in k["checks"]
+               if not (c["mosaic"] >= 1 and c["err"] <= c["tol"])]
+        if bad:
+            raise PhaseFailed(f"kernel checks failed: {bad}")
+
+    def train(self):
+        res = self.cell(_HEADER.format(layers=self.layers) + _TRAIN_CELL)
+        t0 = res[0]
+        self.facts["train"] = {k: t0[k] for k in
+                               ("params_m", "losses", "mosaic",
+                                "compile_s", "step_s")}
+        print(f"depth {self.layers} · {t0['params_m']} M parameters · "
+              f"losses {t0['losses']}")
+        for t in res:
+            ls = t["losses"]
+            if not all(l == l and abs(l) != float("inf") for l in ls):
+                raise PhaseFailed(f"rank {t['rank']}: loss not finite {ls}")
+            if not ls[-1] < ls[0]:
+                raise PhaseFailed(f"rank {t['rank']}: loss did not fall "
+                                  f"on a repeated batch {ls}")
+            if t["mosaic"] < 3:
+                raise PhaseFailed(f"train step holds {t['mosaic']} Mosaic "
+                                  f"calls, expected flash fwd + 2 bwd")
+            if t["losses"] != t0["losses"]:
+                raise PhaseFailed(f"losses differ across ranks: {res}")
+        in_use = {r: d["devices"][0]["memory_gb"]["in_use"]
+                  for r, d in self.status().items()}
+        self.facts["train"]["in_use_gb"] = in_use
+        idle = [r for r, gb in in_use.items() if not gb or gb < 1.0]
+        if idle:
+            raise PhaseFailed(f"chips of ranks {idle} hold under 1 GB "
+                              f"after training — not in use: {in_use}")
+
+    def generate(self):
+        res = self.cell(_GENERATE_CELL)
+        self.facts["generate"] = {k: res[0][k] for k in
+                                  ("mosaic", "compile_s", "tokens")}
+        for g in res:
+            if not (g["reproducible"] and g["mosaic"]["bf16"] >= 1
+                    and g["mosaic"]["int8"] >= 1 and g["in_vocab"]):
+                raise PhaseFailed(f"generate check failed: {g}")
+
+    def teardown_fleet(self):
+        self.magic("dist_shutdown")
+        self._no_survivors()
+
+    def _survivors(self, wait_s: float = 20) -> dict[int, str]:
+        """Our processes still alive once ``wait_s`` have passed."""
+        deadline = time.time() + wait_s
+        left = _marked_processes(self.marker)
+        while left and time.time() < deadline:
+            time.sleep(0.5)
+            left = _marked_processes(self.marker)
+        return left
+
+    def _no_survivors(self):
+        left = self._survivors()
+        if left:
+            raise PhaseFailed(f"processes survived teardown: {left}")
+
+    def serve(self):
+        from nbdistributed_tpu.gateway.daemon import read_gateway_manifest
+        self.pool_dir = tempfile.mkdtemp(prefix="nbd_smoke_pool_")
+        out = self.magic("dist_pool", f"start -n {self.n} --backend tpu "
+                                      f"--run-dir {self.pool_dir}")
+        manifest = read_gateway_manifest(self.pool_dir)
+        if "pool up" not in out or not manifest:
+            raise PhaseFailed("pool did not start: " + out.strip()[-1500:])
+        if manifest.get("backend") != "tpu":
+            raise PhaseFailed(f"pool backend is {manifest.get('backend')}")
+        self.facts["transport"]["pool"] = manifest.get("transport")
+        self.facts["daemon_loaded_libtpu"] = _maps_libtpu(manifest["pid"])
+        if self.facts["daemon_loaded_libtpu"]:
+            raise PhaseFailed("the gateway daemon mapped libtpu — only "
+                              "workers may touch the chip")
+        self.magic("dist_attach", f"--tenant smoke {self.pool_dir}")
+        client = self.DM._tenant
+        if client is None:
+            raise PhaseFailed("tenant attach failed")
+        head = _HEADER.format(layers=self.layers)
+        self.ip.user_ns["smoke_spec"] = head + _SPEC_CELL
+        flags = " ".join(f"--{k.replace('_', '-')} {v}"
+                         for k, v in SERVE.items())
+        if self.n > 1:
+            flags += f" --decode-ranks {self.n}"
+        out = self.magic("dist_serve", f"start --spec smoke_spec {flags}")
+        if "serving as tenant" not in out:
+            raise PhaseFailed("serve start failed: " + out.strip()[-1500:])
+        # One request set per decode rank, so every rank gets traffic.
+        import numpy as np
+        rng = np.random.default_rng(11)
+        reqs = [(rng.integers(0, VOCAB, n).tolist(), n_new)
+                for n, n_new in REQUESTS * self.n]
+        rids = []
+        for prompt, n_new in reqs:
+            out = self.magic("dist_serve", "submit --prompt "
+                             + ",".join(map(str, prompt))
+                             + f" --max-new {n_new}")
+            m = re.search(r"accepted (\S+)", out)
+            if not m:
+                raise PhaseFailed("submit refused: " + out.strip()[-800:])
+            rids.append(m.group(1))
+        deadline = time.time() + 420
+        results = {}
+        while len(results) < len(rids):
+            if time.time() > deadline:
+                raise PhaseFailed(
+                    f"requests unfinished after 420s: "
+                    f"{sorted(set(rids) - set(results))} · "
+                    f"{client.serve_status().get('last_error')}")
+            for rid in rids:
+                if rid not in results:
+                    r = client.serve_result(rid)
+                    if r.get("error"):
+                        raise PhaseFailed(f"{rid}: {r['error']}")
+                    if r.get("done"):
+                        results[rid] = r
+            time.sleep(0.3)
+        served = []
+        for rid, (prompt, n_new) in zip(rids, reqs):
+            toks = [int(t) for t in results[rid].get("tokens") or []]
+            if results[rid].get("status") != "completed" \
+                    or len(toks) != n_new:
+                raise PhaseFailed(f"{rid}: {results[rid].get('status')} "
+                                  f"with {len(toks)}/{n_new} tokens")
+            served.append(toks)
+        server_kw = dict(SERVE, interleave_prefill=True)
+        ver = self.cell(head + _SPEC_CELL + _VERIFY_CELL.format(
+            prompts=[p for p, _ in reqs], served=served,
+            max_len=SERVE["max_len"], server_kw=server_kw,
+            f32_exact=F32_EXACT))
+        bad = [v for v in ver if v["platform"] != "tpu"
+               or not all(v["exact_f32"])]
+        if bad:
+            raise PhaseFailed(f"float32 serving does not reproduce "
+                              f"generate on TPU: {bad}")
+        v0 = ver[0]
+        for rid, exact, deficit in zip(rids, v0["exact"], v0["deficit"]):
+            if not exact and deficit > TIE_MARGIN:
+                raise PhaseFailed(
+                    f"{rid}: stream differs from generate and is not "
+                    f"greedy under the model (a served token sits "
+                    f"{deficit:.3f} logit-sigmas under the best)")
+        self.magic("dist_serve", "status")
+        st = client.serve_status()
+        n_tok = sum(n for _, n in reqs)
+        if st.get("completed") != len(reqs) \
+                or st.get("tokens_total") != n_tok or st.get("last_error"):
+            raise PhaseFailed(
+                f"serve status: completed {st.get('completed')}/"
+                f"{len(reqs)}, tokens {st.get('tokens_total')}/{n_tok}, "
+                f"last_error {st.get('last_error')}")
+        served_by = sorted({r.get("rank") for r in
+                            (st.get("lat") or {}).get("records") or []})
+        if self.n > 1 and served_by != list(range(self.n)):
+            raise PhaseFailed(f"decode ranks that served: {served_by}, "
+                              f"expected all of 0..{self.n - 1}")
+        # What each decode rank's own server reported at serve_open:
+        # Mosaic calls in the program its step() runs (paged gather,
+        # row-masked step, scatter), lowered at the live pool's shapes.
+        step_kernels = {int(r): v.get("step_kernels")
+                        for r, v in (st.get("ranks") or {}).items()}
+        if sorted(step_kernels) != served_by \
+                or not all(step_kernels.values()):
+            raise PhaseFailed(f"decode steps without a compiled Pallas "
+                              f"kernel (rank: Mosaic calls): "
+                              f"{step_kernels}")
+        self.facts["serve"] = {
+            "requests": len(reqs), "tokens": n_tok,
+            "decode_ranks": served_by,
+            "equal_to_generate": sum(v0["exact"]),
+            "worst_deficit": max(v0["deficit"]),
+            "equal_to_generate_f32": [sum(v["exact_f32"]) for v in ver],
+            "step_kernels": step_kernels,
+            "verify_s": v0["verify_s"]}
+        self.stop_pool()
+        self._no_survivors()
+
+    def stop_pool(self):
+        if self.pool_dir is None:
+            return
+        d, self.pool_dir = self.pool_dir, None
+        self.magic("dist_pool", f"stop --run-dir {d}")
+
+    def parent_off_chip(self):
+        from jax._src import xla_bridge
+        if xla_bridge.backends_are_initialized():
+            raise PhaseFailed("this process initialised a JAX backend")
+        if _maps_libtpu(os.getpid()):
+            raise PhaseFailed("this process mapped libtpu")
+
+    # -- run -----------------------------------------------------------
+
+    def run(self) -> int:
+        try:
+            self.shell()
+            if self.phase("fleet", self.fleet):
+                for name in ("kernels", "train", "generate"):
+                    self.phase(name, getattr(self, name))
+            self.phase("teardown", self.teardown_fleet)
+            if self.phases["fleet"]["ok"]:
+                self.phase("serve", self.serve)
+            self.phase("parent_off_chip", self.parent_off_chip)
+        finally:
+            self.cleanup()
+        return self.report()
+
+    def cleanup(self):
+        """Always: stop the pool and the fleet, then kill whatever of
+        ours is still alive (a survivor holds the chip)."""
+        with contextlib.suppress(Exception):
+            self.stop_pool()
+        with contextlib.suppress(Exception):
+            if self.DM is not None and (self.DM._comm is not None
+                                        or self.DM._tenant is not None):
+                self.magic("dist_shutdown")
+        for pid, argv in self._survivors().items():
+            print(f"killing survivor {pid}: {argv}", file=sys.stderr)
+            with contextlib.suppress(OSError):
+                os.kill(pid, signal.SIGKILL)
+
+    def report(self) -> int:
+        ok = all(self.phases.get(p, {}).get("ok") for p in PHASES)
+        sys.stdout.flush()
+        if self.device is None:
+            print("chip_smoke: no fleet on TPU devices — "
+                  + self.phases.get("fleet", {}).get("error", "not run"),
+                  file=sys.stderr)
+            return 2
+        print("SUMMARY " + json.dumps({
+            "ok": ok, "device": self.device, "workers": self.n,
+            "model": "mistral_7b_config", "n_layers": self.layers,
+            "phases": self.phases, **self.facts, "claim": None}))
+        # The verdict: these two keys and nothing else, last on stdout.
+        print(json.dumps({"ok": ok, "device": self.device}), flush=True)
+        return 0 if ok else 1
+
+
+# ----------------------------------------------------------------------
+# worker cells.  Each ends by printing one `SMOKE {json}` line per rank.
+
+_EMIT = '''
+def _emit(**kw):
+    import json, sys
+    sys.stdout.write("SMOKE " + json.dumps(dict(rank=rank, **kw)) + "\\n")
+'''
+
+_HEADER = _EMIT + '''
+import time
+from nbdistributed_tpu.models import mistral_7b_config, init_params
+cfg = mistral_7b_config(n_layers={layers})
+'''
+
+_FACTS_CELL = _EMIT + '''
+import jaxlib, libtpu
+from nbdistributed_tpu.ops import attention as _att, decode as _dec
+_d = jax.local_devices()[0]
+_emit(versions=dict(jax=jax.__version__, jaxlib=jaxlib.__version__,
+                    libtpu=libtpu.__version__),
+      coords=list(_d.coords),
+      all_reduce=float(all_reduce(jnp.float32(rank + 1))),
+      tuned_blocks=bool(_att.TUNED_BLOCKS or _dec.DECODE_TUNED_BLOCKS),
+      compile_cache=jax.config.jax_compilation_cache_dir)
+'''
+
+# bf16 tolerance: the largest error allowed is 2% of the reference's
+# largest magnitude (about five bf16 ulps there).
+_KERNELS_CELL = _EMIT + '''
+from nbdistributed_tpu.ops import (attention_reference, flash_attention,
+                                   flash_decode_attention)
+from nbdistributed_tpu.models.generate import _dequantize_kv, _quantize_kv
+B, S, H, Hkv, D, W = 1, 4096, 32, 8, 128, 4096
+_ks = jax.random.split(jax.random.PRNGKey(7), 8)
+q, do = (jax.random.normal(_ks[i], (B, S, H, D), jnp.bfloat16) for i in (0, 1))
+k, v = (jax.random.normal(_ks[i], (B, S, Hkv, D), jnp.bfloat16) for i in (2, 3))
+checks = []
+def _check(name, fn, ref, *args):
+    jf = jax.jit(fn)
+    mosaic = jf.lower(*args).as_text().count("tpu_custom_call")
+    got, want = jax.tree.leaves(jf(*args)), jax.tree.leaves(jax.jit(ref)(*args))
+    err = max(float(jnp.max(jnp.abs(g.astype(jnp.float32) - w.astype(jnp.float32))))
+              for g, w in zip(got, want))
+    top = max(float(jnp.max(jnp.abs(w.astype(jnp.float32)))) for w in want)
+    checks.append(dict(name=name, mosaic=mosaic, err=err, tol=0.02 * top))
+_fl = lambda q, k, v: flash_attention(q, k, v, True, None, None, None, W, None)
+_rf = lambda q, k, v: attention_reference(q, k, v, causal=True, window=W)
+_check("flash_fwd", _fl, _rf, q, k, v)
+_g = lambda f: jax.grad(lambda q, k, v: (f(q, k, v).astype(jnp.float32)
+                                         * do.astype(jnp.float32)).sum(), argnums=(0, 1, 2))
+_check("flash_bwd", _g(_fl), _g(_rf), q, k, v)
+Bd, T = 2, 4096
+pos = jnp.asarray([1000, T - 1], jnp.int32)
+qd = jax.random.normal(_ks[4], (Bd, H, D), jnp.bfloat16)
+kc, vc = (jax.random.normal(_ks[i], (Bd, Hkv, T, D), jnp.bfloat16) for i in (5, 6))
+def _dref(qd, kc, vc, pos):
+    # one query per row against keys [0, pos]: mask by position
+    kk, vv = kc.transpose(0, 2, 1, 3), vc.transpose(0, 2, 1, 3)
+    seg_q = jnp.zeros((Bd, 1), jnp.int32)
+    seg_k = (jnp.arange(T)[None, :] > pos[:, None]).astype(jnp.int32)
+    return attention_reference(qd[:, None], kk, vv, causal=False,
+                               segment_ids=seg_q, kv_segment_ids=seg_k)[:, 0]
+_check("decode_bf16", lambda *a: flash_decode_attention(*a, window=W), _dref, qd, kc, vc, pos)
+k8, k_s = _quantize_kv(kc); v8, v_s = _quantize_kv(vc)
+_check("decode_int8",
+       lambda qd, k8, v8, pos, k_s, v_s: flash_decode_attention(
+           qd, k8, v8, pos, window=W, k_s=k_s, v_s=v_s),
+       lambda qd, k8, v8, pos, k_s, v_s: _dref(
+           qd, _dequantize_kv(k8, k_s).astype(jnp.bfloat16),
+           _dequantize_kv(v8, v_s).astype(jnp.bfloat16), pos),
+       qd, k8, v8, pos, k_s, v_s)
+del q, k, v, do, qd, kc, vc, k8, v8, k_s, v_s
+_emit(checks=checks)
+'''
+
+_TRAIN_CELL = '''
+import optax
+from nbdistributed_tpu.models import loss_fn, make_train_step
+from nbdistributed_tpu.parallel.data_parallel import ddp_init, make_ddp_step
+S = 4096
+params = init_params(jax.random.PRNGKey(0), cfg)
+opt = optax.adamw(3e-4)
+tokens = np.random.default_rng(1000 + rank).integers(
+    0, cfg.vocab_size, (1, S), dtype=np.int32)
+if world_size == 1:
+    opt_state = opt.init(params)
+    step = jax.jit(make_train_step(cfg, opt), donate_argnums=(0, 1))
+    batch = {"tokens": jnp.asarray(tokens)}
+else:
+    # DDP over one device per rank, batch sharded over the ranks.
+    mesh = make_mesh({"dp": world_size})
+    params, _ = ddp_init(params, (), mesh)
+    opt_state = opt.init(params)
+    step = make_ddp_step(lambda p, b: loss_fn(p, b, cfg), opt, mesh)
+    batch = shard_batch({"tokens": tokens}, mesh)
+lowered = step.lower(params, opt_state, batch)
+mosaic = lowered.as_text().count("tpu_custom_call")
+t0 = time.time()
+compiled = lowered.compile()
+compile_s = round(time.time() - t0, 1)
+losses, step_s = [], []
+for _ in range(4):
+    t0 = time.time()
+    params, opt_state, loss = compiled(params, opt_state, batch)
+    losses.append(float(loss))
+    step_s.append(round(time.time() - t0, 3))
+del opt_state, batch, compiled, lowered
+_emit(params_m=round(cfg.num_params() / 1e6, 1), losses=losses,
+      mosaic=mosaic, compile_s=compile_s, step_s=step_s)
+'''
+
+# Greedy decode of the trained parameters, bf16 and int8 caches; the
+# prompt (160) is longer than one serving prefill chunk (128).
+_GENERATE_CELL = _EMIT + '''
+import time
+from nbdistributed_tpu.models import make_generate_fn
+local = jax.tree.map(lambda x: x.addressable_data(0), params)
+prompt = jnp.asarray(np.random.default_rng(5).integers(
+    0, cfg.vocab_size, (1, 160), dtype=np.int32))
+mosaic, compile_s, runs = {}, {}, {}
+for name, q8 in (("bf16", False), ("int8", True)):
+    fn = make_generate_fn(cfg, 32, max_len=512, kv_quantized=q8)
+    mosaic[name] = fn.lower(local, prompt).as_text().count("tpu_custom_call")
+    t0 = time.time()
+    first = np.asarray(fn(local, prompt))[0, 160:]
+    compile_s[name] = round(time.time() - t0, 1)
+    runs[name] = (first, np.asarray(fn(local, prompt))[0, 160:])
+same = all(len(a) == 32 and np.array_equal(a, b) for a, b in runs.values())
+in_vocab = all(bool(((a >= 0) & (a < cfg.vocab_size)).all())
+               for a, _ in runs.values())
+tokens = dict((name, a.tolist()) for name, (a, _) in runs.items())
+del local
+_emit(mosaic=mosaic, compile_s=compile_s, tokens=tokens,
+      reproducible=same, in_vocab=in_vocab)
+'''
+
+_SPEC_CELL = '''
+params = init_params(jax.random.PRNGKey(0), cfg)
+'''
+
+# After serving, on every pool rank: the greedy reference for every
+# request and each served stream scored under the model (teacher-forced
+# logits); then the float32 run — a server of the served geometry
+# against `generate`, both on float32 copies of the same weights.
+_VERIFY_CELL = '''
+import dataclasses
+from nbdistributed_tpu.models import DecodeServer, forward, make_generate_fn
+prompts, served = {prompts}, {served}
+t0 = time.time()
+def _reference(params, cfg, prompts, budgets):
+    gen, out = dict(), []
+    for p, n in zip(prompts, budgets):
+        if n not in gen:          # one jit per budget, one trace per shape
+            gen[n] = make_generate_fn(cfg, n, max_len={max_len})
+        toks = gen[n](params, jnp.asarray(p, jnp.int32)[None])
+        out.append([int(t) for t in np.asarray(toks)[0, len(p):]])
+    return out
+ref = _reference(params, cfg, prompts, [len(o) for o in served])
+exact = [r == o for r, o in zip(ref, served)]
+deficit = []
+_fwd = jax.jit(lambda params, toks: forward(params, toks, cfg))
+for p, out in zip(prompts, served):
+    logits = _fwd(params, jnp.asarray(p + out, jnp.int32)[None])[0]
+    at = logits[len(p) - 1:len(p) + len(out) - 1]       # predicts out[i]
+    gap = at.max(-1) - at[jnp.arange(len(out)), jnp.asarray(out)]
+    deficit.append(float((gap / at.std(-1)).max()))
+cfg32 = dataclasses.replace(cfg, dtype=jnp.float32)
+params32 = jax.tree.map(lambda x: x.astype(jnp.float32), params)
+p32 = [prompts[i] for i in {f32_exact}]
+n32 = [len(served[i]) for i in {f32_exact}]
+with jax.default_matmul_precision("highest"):
+    srv = DecodeServer(params32, cfg32, **{server_kw})
+    rids = [srv.submit(p, n) for p, n in zip(p32, n32)]
+    srv.run_until_done()
+    exact_f32 = [list(srv.outputs[r]) == want for r, want in
+                 zip(rids, _reference(params32, cfg32, p32, n32))]
+del params32, srv
+_emit(platform=jax.default_backend(), exact=exact, deficit=deficit,
+      exact_f32=exact_f32, verify_s=round(time.time() - t0, 1))
+'''
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    ap.add_argument("--workers", type=int, default=1,
+                    help="fleet size = chips used (1, or 4 on a "
+                         "four-chip host)")
+    args = ap.parse_args(argv)
+    sys.path.insert(0, REPO)
+    try:
+        import nbdistributed_tpu  # noqa: F401
+    except ImportError as e:
+        print(f"chip_smoke: the nbdistributed_tpu package is not beside "
+              f"this script ({e})", file=sys.stderr)
+        return 2
+
+    def _bail(signum, _frame):
+        raise SystemExit(f"chip_smoke: stopped by signal {signum}")
+
+    signal.signal(signal.SIGTERM, _bail)
+    signal.signal(signal.SIGALRM, _bail)
+    signal.alarm(DEADLINE_S)
+    return Smoke(args.workers).run()
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -4,7 +4,7 @@ checkpoint, tokenize a dataset, and fine-tune it interactively,
 cell-by-cell, data-parallel across workers.
 
 This build environment has zero network egress (no HF hub, no
-datasets downloads — recorded in BASELINE.md), so the checkpoint is
+datasets downloads), so the checkpoint is
 constructed LOCALLY at the real SmolLM2-135M architecture and saved
 with ``save_pretrained``; the load -> convert -> fine-tune path the
 notebook exercises is byte-identical to pulling the same files from
@@ -44,8 +44,7 @@ tuned weights.
 > architecture (`LlamaForCausalLM`, 576 hidden / 30 layers / 9 heads /
 > 3 KV heads, tied embeddings) and saved with `save_pretrained` — the
 > directory the loader consumes is indistinguishable from a hub
-> download of the same files.  See BASELINE.md for the limitation
-> note.""")
+> download of the same files.""")
 
 code("%load_ext nbdistributed_tpu")
 
@@ -121,7 +120,7 @@ import numpy as _np
 import nbdistributed_tpu as _pkg
 repo = os.path.dirname(os.path.dirname(os.path.abspath(_pkg.__file__)))
 corpus = ""
-for f in ("README.md", "PARITY.md", "SURVEY.md"):
+for f in ("README.md", "ROADMAP.md", "SURVEY.md"):
     p = os.path.join(repo, f)
     if os.path.exists(p):
         corpus += open(p, encoding="utf-8").read() + "\\n\\n"

@@ -258,17 +258,6 @@ class GatewayDaemon:
         self._resize_lock = threading.Lock()   # one resize at a time
         self._backend = backend
         self._attach_timeout = attach_timeout
-        # Warm starts: a persistent per-pool XLA compilation cache,
-        # shipped to every worker (including resized-in ones), so the
-        # first cell after a grow — or a migrated tenant's first cell —
-        # doesn't pay the cold compile.  Default lives under the run
-        # dir; NBD_COMPILE_CACHE_DIR overrides; "0"/"off" disables.
-        cache = knobs.get_str("NBD_COMPILE_CACHE_DIR")
-        if cache is None:
-            cache = os.path.join(self.run_dir, "xla-cache")
-        if cache.strip().lower() in ("", "0", "off", "none"):
-            cache = ""
-        self.compile_cache_dir = cache
         # Template namespaces: admin-registered cells re-run on every
         # epoch's fresh fleet so resized-in workers start warm.
         self._templates: dict[str, str] = {}
@@ -410,6 +399,8 @@ class GatewayDaemon:
             "kind": "gateway",
             "pid": os.getpid(),
             "world_size": self.world_size,
+            "backend": self.pm.backend,
+            "transport": self.comm.transport,
             # Elastic pools: the epoch fences stale frames after a
             # resize, the generation stamps the membership view, and
             # gc_runs keeps a recently-bumped manifest even when the
@@ -452,11 +443,8 @@ class GatewayDaemon:
     # elastic pools (ISSUE 16): resize, templates, autoscale
 
     def _worker_env(self, epoch: int) -> dict:
-        env = {"NBD_SESSION_TOKEN": self._session_token,
-               "NBD_SESSION_EPOCH": str(epoch)}
-        if self.compile_cache_dir:
-            env["NBD_COMPILE_CACHE_DIR"] = self.compile_cache_dir
-        return env
+        return {"NBD_SESSION_TOKEN": self._session_token,
+                "NBD_SESSION_EPOCH": str(epoch)}
 
     def resize(self, target: int, *, reason: str = "manual") -> dict:
         """Change the pool's world size: a two-phase drain barrier

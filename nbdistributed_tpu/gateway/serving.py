@@ -447,6 +447,9 @@ class ServingManager:
         # DecodeServer keeps an over-admitted request pending until
         # blocks free, so the skew self-heals without a verdict.
         self._open: dict[int, BlockAllocator] = {}
+        # rank -> compiled Pallas kernels in its decode step, as its
+        # serve_open reported (0: interpreted, or the einsum path).
+        self._step_kernels: dict[int, int] = {}
         # rank -> monotonic deadline to avoid it: a rank whose
         # serve_open failed (missing namespace after a reconnect,
         # OOM building the server) must not be retried forever while
@@ -966,7 +969,9 @@ class ServingManager:
                 ranks[str(rank)] = {"placed": placed,
                                     "kv_used": alloc.used_blocks,
                                     "kv_free": alloc.free_blocks,
-                                    "frag": alloc.largest_free_run()}
+                                    "frag": alloc.largest_free_run(),
+                                    "step_kernels":
+                                        self._step_kernels.get(rank, 0)}
             d["ranks"] = ranks
             # Per-SUBMITTING-tenant block counts (%dist_serve status).
             by_tenant: dict[str, int] = {}
@@ -1168,6 +1173,8 @@ class ServingManager:
             self._unbind_rank_locked(rank)
             self._open[rank] = BlockAllocator(self.kv_blocks_per_rank,
                                               self.kv_block_tokens)
+            self._step_kernels[rank] = int(
+                (resp[rank].data or {}).get("step_kernels") or 0)
             self._avoid.pop(rank, None)
         self._record("serve_open", rank=rank)
 

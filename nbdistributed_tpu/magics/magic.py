@@ -39,7 +39,8 @@ _COLLECTIVE_TOKENS = re.compile(
     r"sync_global_devices|shard_map|dist\.(?:scatter|gather|reduce))\b")
 
 _BANNER = """\
-✅ {n} workers ready (backend={backend}, attach {secs:.1f}s).
+✅ {n} workers ready (backend={backend}, transport={transport}, \
+attach {secs:.1f}s).
 
 Every cell now runs on ALL workers. Namespace on each worker:
   rank, world_size     — this worker's rank / total workers
@@ -741,6 +742,7 @@ class DistributedMagics(Magics):
         self._maybe_start_metrics_httpd()
         print(_BANNER.format(n=num_workers,
                              backend=pm.backend,
+                             transport=comm.transport,
                              secs=time.time() - t0))
 
     def _maybe_start_metrics_httpd(self) -> None:
@@ -1279,6 +1281,7 @@ class DistributedMagics(Magics):
             plane = m.get("tenant_plane") or {}
             print(f"✅ pool up: pid {m.get('pid')} · tenant plane "
                   f"{plane.get('host')}:{plane.get('port')} · "
+                  f"transport {m.get('transport')} · "
                   f"policy {m.get('policy')} · run dir {run_dir}")
             met = m.get("metrics") or {}
             if met:
@@ -1829,6 +1832,8 @@ class DistributedMagics(Magics):
             per_rank = " · ".join(
                 f"r{r}: {v.get('placed', 0)} req, "
                 f"{v.get('kv_used', 0)} blk"
+                + (f", {v['step_kernels']} Pallas/step"
+                   if v.get("step_kernels") else "")
                 for r, v in sorted((st.get("ranks") or {}).items(),
                                    key=lambda kv_: int(kv_[0])))
             print(f"   KV blocks {kv.get('used', 0)}/"

@@ -72,11 +72,24 @@ def wait_until_ready(comm, pm, timeout_s: float, *, poll_s: float = 2.0,
                 on_wait()
 
 
+def find_free_ports(n: int) -> list[int]:
+    """``n`` distinct free ports by bind-to-zero discovery (reference:
+    process_manager.py:154-175).  All sockets are held until every port
+    is known, so one call can never hand out the same port twice."""
+    socks = [socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+             for _ in range(n)]
+    try:
+        for s in socks:
+            s.bind(("127.0.0.1", 0))
+        return [s.getsockname()[1] for s in socks]
+    finally:
+        for s in socks:
+            s.close()
+
+
 def find_free_port() -> int:
-    """Bind-to-zero port discovery (reference: process_manager.py:154-175)."""
-    with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
+    """One free port (see :func:`find_free_ports`)."""
+    return find_free_ports(1)[0]
 
 
 class _ChildIO:
@@ -213,7 +226,7 @@ class ProcessManager:
             raise RuntimeError("workers already running; shutdown first")
         if backend == "auto":
             backend = topology.detect_backend()
-        host_chips = None
+        host_chips = tpu_ports = None
         if backend == "tpu":
             # Fail fast, before any child exists, when the topology
             # can't fit this host's chips (reference validates GPU ids
@@ -222,6 +235,10 @@ class ProcessManager:
             # construction share one host geometry (one probe).
             host_chips = topology.validate_tpu_request(
                 num_workers, chips_per_worker, chips=chips)
+            # One TPU-runtime port per rank, picked fresh for every
+            # fleet: a fixed base would collide with the previous
+            # fleet's lingering sockets or a leaked worker.
+            tpu_ports = find_free_ports(num_workers)
         self.backend = backend
         self.world_size = num_workers
         self.dist_port = find_free_port() if num_workers > 1 else None
@@ -229,7 +246,8 @@ class ProcessManager:
         for rank in range(num_workers):
             env = topology.worker_env(rank, num_workers, backend,
                                       chips_per_worker=chips_per_worker,
-                                      chips=chips, host_chips=host_chips)
+                                      chips=chips, host_chips=host_chips,
+                                      tpu_ports=tpu_ports)
             if extra_env:
                 env.update(extra_env)
             cmd = [sys.executable, "-m", "nbdistributed_tpu.runtime.worker",
@@ -300,9 +318,8 @@ class ProcessManager:
             for launch in plan:
                 self.hosts[launch.rank] = launch.host
                 if launch.host == "local":
-                    # Direct spawn: local base env (incl. the cpu
-                    # backend's sitecustomize neutralization) + the
-                    # plan's overrides.
+                    # Direct spawn: local base env + the plan's
+                    # overrides.
                     env = topology.cpu_worker_env() if backend == "cpu" \
                         else dict(os.environ)
                     env.update(dict(launch.env))

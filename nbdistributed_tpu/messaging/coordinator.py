@@ -435,8 +435,12 @@ class CommunicationManager:
                                        allow_pickle=allow_pickle,
                                        auth_token=auth_token)
         self.port = self._listener.port
+        # Which control-plane transport is live ("native" / "python"):
+        # stated in the fleet banner and the gateway manifest.
+        self.transport = self._listener.transport
         self.flight.record("coordinator_start",
-                           num_workers=num_workers, port=self.port)
+                           num_workers=num_workers, port=self.port,
+                           transport=self.transport)
         self._lock = threading.Lock()
         self._pending: dict[str, _Pending] = {}
         self._connected: set[int] = set()

@@ -1,13 +1,22 @@
 """ctypes bindings for the native (C++) control-plane listener.
 
-Loads ``native/libnbdtransport.so`` (built by ``native/build.sh``) and
-wraps it in :class:`NativeCoordinatorListener`, interface-compatible with
-the pure-Python :class:`~nbdistributed_tpu.messaging.transport.
-CoordinatorListener`.  Selection:
+Loads ``native/libnbdtransport.so`` and wraps it in
+:class:`NativeCoordinatorListener`, interface-compatible with the
+pure-Python :class:`~nbdistributed_tpu.messaging.transport.
+CoordinatorListener`.  The ``.so`` is a build product, never
+committed: it is built from ``native/nbd_transport.cpp`` by
+``native/build.sh`` on first use, and again whenever the source is
+newer than the library, so what runs is what the checkout holds.
+Selection:
 
 * ``NBD_NATIVE=0`` forces pure Python;
-* ``NBD_NATIVE=1`` requires the native lib (raises if unbuilt);
-* unset: native if the library is present, else Python.
+* ``NBD_NATIVE=1`` requires the native lib (raises if it cannot be
+  built or loaded);
+* unset: native if it builds and loads, else Python — and a build that
+  was attempted and failed says so on stderr, once.
+
+Every listener carries ``transport`` (``"native"`` / ``"python"``) so
+the fleet banner and the gateway manifest can state which one is live.
 
 The C side owns sockets, epoll, framing, and identity routing; a single
 Python dispatch thread pops whole events (connect / disconnect /
@@ -20,6 +29,8 @@ from __future__ import annotations
 
 import ctypes
 import os
+import subprocess
+import sys
 import threading
 
 from ..utils import knobs
@@ -32,33 +43,44 @@ _LIB_PATH = os.path.join(os.path.dirname(os.path.dirname(
 _EVENT_MESSAGE, _EVENT_CONNECT, _EVENT_DISCONNECT = 0, 1, 2
 
 _lib = None
+_build_attempted = False  # one build attempt (and one report) per process
+
+
+def _stale() -> bool:
+    """True when the library is absent or older than its source."""
+    src = os.path.join(os.path.dirname(_LIB_PATH), "nbd_transport.cpp")
+    try:
+        return os.path.getmtime(_LIB_PATH) < os.path.getmtime(src)
+    except OSError:
+        return not os.path.exists(_LIB_PATH)
 
 
 def _build_library() -> None:
-    """Compile the native listener on first use in a fresh checkout.
-
-    The .so is a build artifact (not committed); build.sh is a one-file
-    g++ invocation, so building lazily keeps `pip install -e . && pytest`
-    working without a separate build step.
-    """
-    src_dir = os.path.dirname(_LIB_PATH)
-    script = os.path.join(src_dir, "build.sh")
+    """Compile the native listener (build.sh is a one-file g++
+    invocation, so building lazily keeps `pip install -e . && pytest`
+    working without a separate build step).  A failed build is
+    reported, not swallowed: the caller falls back to the Python
+    transport knowingly."""
+    script = os.path.join(os.path.dirname(_LIB_PATH), "build.sh")
     if not os.path.exists(script):
         return
-    import subprocess
-    subprocess.run(["sh", script], check=True, capture_output=True,
-                   timeout=120)
+    try:
+        subprocess.run(["sh", script], check=True, capture_output=True,
+                       timeout=120)
+    except (OSError, subprocess.SubprocessError) as e:
+        detail = (getattr(e, "stderr", None) or b"").decode(
+            "utf-8", "replace").strip()[-400:]
+        print(f"[nbd] native transport build failed ({e})"
+              + (f":\n{detail}" if detail else ""), file=sys.stderr)
 
 
 def load_library():
-    global _lib
+    global _lib, _build_attempted
     if _lib is not None:
         return _lib
-    if not os.path.exists(_LIB_PATH):
-        try:
-            _build_library()
-        except Exception:
-            pass
+    if _stale() and not _build_attempted:
+        _build_attempted = True
+        _build_library()
     lib = ctypes.CDLL(_LIB_PATH)
     lib.nbd_listener_create.restype = ctypes.c_void_p
     lib.nbd_listener_create.argtypes = [ctypes.c_char_p, ctypes.c_int,
@@ -104,6 +126,8 @@ def available() -> bool:
 class NativeCoordinatorListener:
     """Drop-in replacement for the Python CoordinatorListener backed by
     the C++ epoll listener."""
+
+    transport = "native"
 
     def __init__(self, host: str = "127.0.0.1", port: int = 0, *,
                  allow_pickle: bool = True, auth_token: str | None = None):
@@ -272,7 +296,6 @@ def make_listener(host: str = "127.0.0.1", port: int = 0, *,
             raise OSError(
                 "NBD_NATIVE=1 but libnbdtransport.so predates the "
                 "authenticated preamble; rebuild with native/build.sh")
-        import sys
         print("[nbd] native listener predates the authenticated "
               "preamble; using the Python listener (rebuild with "
               "native/build.sh)", file=sys.stderr)

@@ -176,6 +176,8 @@ class CoordinatorListener:
     they must not block.
     """
 
+    transport = "python"
+
     def __init__(self, host: str = "127.0.0.1", port: int = 0, *,
                  allow_pickle: bool = True, auth_token: str | None = None):
         self._allow_pickle = allow_pickle

@@ -26,7 +26,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..utils.compat import shard_map
 from .transformer import (TransformerConfig, _mlp_block, _rms_norm,
                           _rope, qlinear)
 
@@ -214,7 +213,7 @@ def _flash_decode_on_mesh(q, kc, vc, pos, mesh, scale, window=None,
     in_specs = ((qspec, cspec, cspec, P(dp))
                 + ((sspec, sspec) if quant else ()))
     args = (q, kc, vc, pos) + ((k_s, v_s) if quant else ())
-    return shard_map(inner, mesh=mesh, in_specs=in_specs,
+    return jax.shard_map(inner, mesh=mesh, in_specs=in_specs,
                          out_specs=qspec, check_vma=False)(*args)
 
 

@@ -39,9 +39,12 @@ attention: positions ``> cache_len`` are masked, and a slot's
 ``cache_len`` never passes its allocated token count.
 
 Exactness: gather ∘ scatter is the identity on the blocks a slot owns,
-so a paged greedy decode is bit-identical to the dense server's (and
-to a solo :func:`~.generate.generate`) — asserted by the paged-decode
-unit tests, including the quantized round-trip tolerance.
+so a paged greedy decode computes what the dense server (and a solo
+:func:`~.generate.generate`) computes.  In float32 the tokens are
+bit-identical — asserted by the paged-decode unit tests on the CPU
+(including the quantized round-trip tolerance) and by ``chip_smoke.py``
+on the TPU at ``highest`` matmul precision; in bf16 on the TPU see the
+rounding note in :mod:`.serving`.
 """
 
 from __future__ import annotations

@@ -386,6 +386,41 @@ class SeqParallel:
                 self.tp_axis if self.tp_axis in names else None)
 
 
+def _flash_on_mesh(q, k, v, window, segment_ids):
+    """``flash_attention`` inside a program that may span devices.
+
+    GSPMD cannot split a Mosaic kernel (jax refuses at lowering: "Mosaic
+    kernels cannot be automatically partitioned"), so under an active
+    mesh — the mesh step builders trace under theirs, a user's
+    ``jax.set_mesh`` does the same — the kernel runs in a shard_map:
+    batch over ``dp`` and heads over ``tp`` where the mesh has those
+    axes and they divide (the naming :class:`SeqParallel` and
+    ``generate._flash_decode_on_mesh`` use; whole GQA groups per shard,
+    see ``ring_attention``), every other axis replicated.  Axes an
+    enclosing shard_map already made manual are local as they are.
+    """
+    # block sizes None -> TUNED_BLOCKS table (tune_flash.py) with the
+    # 128x128 fallback.
+    def local(q, k, v, seg=None):
+        return flash_attention(q, k, v, True, None, None, None, window,
+                               seg)
+
+    mesh = jax.sharding.get_abstract_mesh()
+    free = frozenset(mesh.axis_names) - frozenset(mesh.manual_axes)
+    if not free:
+        return local(q, k, v, segment_ids)
+    dp = "dp" if "dp" in free and q.shape[0] % mesh.shape["dp"] == 0 \
+        else None
+    tp = "tp" if "tp" in free and k.shape[2] % mesh.shape["tp"] == 0 \
+        else None
+    spec = P(dp, None, tp, None)
+    args, in_specs = (q, k, v), (spec, spec, spec)
+    if segment_ids is not None:
+        args, in_specs = args + (segment_ids,), in_specs + (P(dp, None),)
+    return jax.shard_map(local, in_specs=in_specs, out_specs=spec,
+                         axis_names=free, check_vma=False)(*args)
+
+
 def _attention_block(x, layer, cfg: TransformerConfig, positions,
                      sp: SeqParallel | None = None, segment_ids=None):
     B, S, D = x.shape
@@ -416,10 +451,7 @@ def _attention_block(x, layer, cfg: TransformerConfig, positions,
                                window=cfg.sliding_window,
                                segment_ids=segment_ids)
     elif cfg.use_flash:
-        # block sizes None -> TUNED_BLOCKS table (tune_flash.py) with
-        # the 128x128 fallback.
-        o = flash_attention(q, k, v, True, None, None, None,
-                            cfg.sliding_window, segment_ids)
+        o = _flash_on_mesh(q, k, v, cfg.sliding_window, segment_ids)
     else:
         from ..ops import attention_reference
         o = attention_reference(q, k, v, causal=True,
@@ -618,7 +650,7 @@ def loss_fn(params, batch, cfg: TransformerConfig,
         and dict(getattr(sp.mesh, "shape", {})).get(sp.tp_axis, 1) > 1)
     if (not tp_sharded_head and cfg.ce_chunk is not None
             and _head_vocab_sharded(params["lm_head"])):
-        # Plain-TP trap (ADVICE r5): a vocab-sharded head reached the
+        # Plain-TP trap: a vocab-sharded head reached the
         # chunked path without an sp object — slicing it chunk-wise
         # would make GSPMD re-gather the whole head every scan step,
         # silently destroying the memory win.  Fall back loudly.

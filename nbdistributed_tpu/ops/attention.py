@@ -811,28 +811,48 @@ def _resolved_scale(scale, D):
 
 
 # (Sq, Sk, head_dim, gqa_group) -> (block_q, block_k), measured on a
-# live v5e by tune_flash.py's chained-timing sweep (see BASELINE.md for
-# the sweep protocol and numbers).  The group (H // Hkv) is part of the
-# key because it sets the q-block's batch extent inside the kernel —
+# chip by tune_flash.py's chained-timing sweep.  The group (H // Hkv)
+# is part of the key because it sets the q-block's batch extent inside
+# the kernel —
 # MHA (group 1) and GQA (group > 1) tune differently at the same S/D.
 # Consulted only when the caller passes no explicit block sizes; empty
 # entries fall back to 128x128.  Seeded from ops/tuned_blocks.json
-# (written by tune_flash.py on a live chip — see ops/_tuned.py).
+# (written by tune_flash.py, absent until then — see ops/_tuned.py).
 from ._tuned import load as _load_tuned
 
 TUNED_BLOCKS: dict = _load_tuned()[0]
 _DEFAULT_BLOCK = 128
 
 
-def _block_sizes(block_q, block_k, Sq, Sk, D=None, group=None):
+def _mosaic_block(block: int) -> int:
+    """The nearest block size Mosaic accepts: a multiple of 128.
+
+    These kernels put block_q on the 128 lanes of the logsumexp output
+    and slice K/V, segment ids and lse/delta planes by block inside the
+    kernel, so on hardware every block is a whole number of 128-row
+    tiles and a shorter sequence is padded up to one (the MXU works in
+    128-wide tiles anyway; the wrappers mask padded keys and drop
+    padded query rows).  Interpret mode checks none of this: a 64-row
+    block over 256 rows, or an 89-row sequence as its own block, passes
+    every CPU test and is refused by the compiler on the chip."""
+    return -(-block // 128) * 128
+
+
+def _block_sizes(block_q, block_k, Sq, Sk, D=None, group=None, *,
+                 interpret: bool):
     """Resolve block sizes: explicit args win; None consults the tuned
-    per-shape table, then the 128 default; both clamp to the array."""
+    per-shape table, then the 128 default.  Compiled kernels get the
+    nearest sizes Mosaic accepts (:func:`_mosaic_block`); interpret
+    mode takes them as given, clamped to the array, so CPU tests can
+    drive the multi-block logic with small blocks."""
     if block_q is None or block_k is None:
         tq, tk = TUNED_BLOCKS.get((Sq, Sk, D, group),
                                   (_DEFAULT_BLOCK, _DEFAULT_BLOCK))
         block_q = tq if block_q is None else block_q
         block_k = tk if block_k is None else block_k
-    return min(block_q, Sq), min(block_k, Sk)
+    if interpret:
+        return min(block_q, Sq), min(block_k, Sk)
+    return _mosaic_block(block_q), _mosaic_block(block_k)
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None,
@@ -842,12 +862,13 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None,
         raise ValueError("segment_ids requires Sq == Sk (packed "
                          "self-attention)")
     D = q.shape[-1]
+    interpret = _use_interpret()
     bq, bk = _block_sizes(block_q, block_k, q.shape[1], k.shape[1], D,
-                          q.shape[2] // k.shape[2])
+                          q.shape[2] // k.shape[2], interpret=interpret)
     out, lse = _flash_forward(q, k, v, causal=causal,
                               scale=_resolved_scale(scale, D),
                               block_q=bq, block_k=bk,
-                              interpret=_use_interpret(),
+                              interpret=interpret,
                               window=window, segment_ids=segment_ids,
                               kv_segment_ids=segment_ids)
     return out, (q, k, v, out, lse, segment_ids)
@@ -858,13 +879,15 @@ def _flash_bwd(causal, scale, block_q, block_k, window, residuals, g):
     the saved logsumexp, so no O(S^2) tensor exists in the backward
     either."""
     q, k, v, out, lse, segment_ids = residuals
+    interpret = _use_interpret()
     bq, bk = _block_sizes(block_q, block_k, q.shape[1], k.shape[1],
-                          q.shape[-1], q.shape[2] // k.shape[2])
+                          q.shape[-1], q.shape[2] // k.shape[2],
+                          interpret=interpret)
     dq, dk, dv = _flash_backward(
         q, k, v, out, lse, g, causal=causal,
         scale=_resolved_scale(scale, q.shape[-1]),
         block_q=bq, block_k=bk,
-        interpret=_use_interpret(), window=window,
+        interpret=interpret, window=window,
         segment_ids=segment_ids, kv_segment_ids=segment_ids)
     if segment_ids is None:
         return dq, dk, dv, None

@@ -36,7 +36,6 @@ from ..observability import metrics as _obs_metrics
 from ..observability.spans import maybe_span as _maybe_span
 from ..runtime.collective_guard import check as _guard_check
 from ..runtime.collective_guard import done as _guard_done
-from ..utils.compat import shard_map as _shard_map
 
 
 def _jax():
@@ -80,10 +79,22 @@ def _instrumented(name: str):
 
 @functools.lru_cache(maxsize=None)
 def _proc_mesh():
-    """1-D mesh over every global device, axis name ``proc``."""
+    """1-D mesh over every global device, axis name ``proc``.
+
+    The eager collectives undo per-process duplication by dividing by /
+    striding over ``local_device_count``, which is only right when every
+    process owns the same number of devices (one per worker in the
+    default one-worker-per-chip layout)."""
     jax = _jax()
     from jax.sharding import Mesh
-    return Mesh(np.asarray(jax.devices()), ("proc",))
+    devices = jax.devices()
+    if len(devices) != jax.process_count() * jax.local_device_count():
+        raise RuntimeError(
+            f"eager collectives need the same device count on every "
+            f"process; this world has {len(devices)} devices over "
+            f"{jax.process_count()} process(es) but this process owns "
+            f"{jax.local_device_count()}")
+    return Mesh(np.asarray(devices), ("proc",))
 
 
 def world_size() -> int:
@@ -142,7 +153,7 @@ def _reduce_fn(mesh, prim_name: str):
     prim = getattr(jax.lax, prim_name)
 
     @jax.jit
-    @functools.partial(_shard_map, mesh=mesh, in_specs=P("proc"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("proc"),
                        out_specs=P())
     def f(a):
         # Each device holds one copy on the leading axis; drop it, then
@@ -161,7 +172,7 @@ def _gather_fn(mesh):
     # check_vma off: all_gather's output is replicated over "proc" but the
     # static varying-axes analysis cannot prove it.
     @jax.jit
-    @functools.partial(_shard_map, mesh=mesh, in_specs=P("proc"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("proc"),
                        out_specs=P(), check_vma=False)
     def f(a):
         return jax.lax.all_gather(a[0], "proc")
@@ -265,7 +276,7 @@ def _reduce_scatter_fn(mesh):
     from jax.sharding import PartitionSpec as P
 
     @jax.jit
-    @functools.partial(_shard_map, mesh=mesh, in_specs=P("proc"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("proc"),
                        out_specs=P("proc"))
     def f(a):
         return jax.lax.psum_scatter(a[0], "proc", scatter_dimension=0,
@@ -317,7 +328,7 @@ def _quantized_all_reduce_fn(mesh, block: int):
     from jax.sharding import PartitionSpec as P
 
     @jax.jit
-    @functools.partial(_shard_map, mesh=mesh, in_specs=P("proc"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("proc"),
                        out_specs=P(), check_vma=False)
     def f(a):
         shard = jax.lax.psum_scatter(a[0], "proc", scatter_dimension=0,

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..utils.compat import shard_map
 
 
 def init_moe_params(key, d_model: int, d_ff: int, n_experts: int,
@@ -337,7 +336,7 @@ def _dropless_ffn_ep(xt, params, logits, top_k: int, E: int, mesh,
             rows * wgt[:, None])
         return jax.lax.psum(y, ep_axis)                   # combine
 
-    return shard_map(
+    return jax.shard_map(
         local_ffn, mesh=mesh,
         in_specs=(P(tok_entry, None), P(tok_entry, None),
                   P(tok_entry),

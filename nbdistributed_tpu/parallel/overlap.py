@@ -36,7 +36,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..utils.compat import axis_size
 
 
 def allgather_matmul(x, w, axis_name: str):
@@ -52,7 +51,7 @@ def allgather_matmul(x, w, axis_name: str):
     ``ppermute`` and the GEMM at each step share no dataflow edge, so
     XLA schedules them concurrently (DMA vs MXU).
     """
-    t = axis_size(axis_name)
+    t = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     m = x.shape[0]
     fwd = [(i, (i + 1) % t) for i in range(t)]
@@ -85,7 +84,7 @@ def matmul_reducescatter(x, w, axis_name: str):
     rows ``[d*M/t, (d+1)*M/t)``), and terminates at ``d`` — so each
     hop's transfer overlaps the next chunk's GEMM.
     """
-    t = axis_size(axis_name)
+    t = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     M = x.shape[0]
     if M % t:

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..utils.compat import shard_map
 
 
 def shard_stage_params(stage_params, mesh, axis: str = "pp"):
@@ -92,7 +91,7 @@ def pipeline_forward(stage_fn, stage_params, x, mesh, *, axis: str = "pp",
         # so every stage returns the full result.
         return jax.lax.psum(outs, axis)
 
-    outs = shard_map(
+    outs = jax.shard_map(
         spmd, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
         check_vma=False)(stage_params, xs)
     # Microbatch m exits the last stage at step m + n_stages - 1.
@@ -312,7 +311,7 @@ def make_pipeline_1f1b_full(stage_fn, tail_fn, mesh, *,
         # the dp sharding when batch_axis is set.
         data_spec = (P(None, batch_axis) if batch_axis is not None
                      else P())
-        loss, stage_grads, tail_grads, dxa = shard_map(
+        loss, stage_grads, tail_grads, dxa = jax.shard_map(
             spmd, mesh=mesh,
             in_specs=(P(), P(axis), data_spec, data_spec),
             out_specs=(P(), P(axis), P(), P()), check_vma=False)(

@@ -40,7 +40,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from ..utils.compat import shard_map
 
 _NEG_INF = -1e30
 
@@ -76,7 +75,7 @@ def _ring_fn(mesh, axis: str, causal: bool, scale: float,
         # Segment ids are per (batch, position): sequence-sharded like
         # q, replicated over heads.
         in_specs = in_specs + (P(batch_axis, axis),)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         inner, mesh=mesh, in_specs=in_specs, out_specs=spec,
         check_vma=False))
 
@@ -432,8 +431,9 @@ def _make_ring_flash(axis: str, n: int, causal: bool, scale: float,
     def _rf_fwd(q, k, v, seg=None):
         B, Sq, H, D = q.shape
         Sk, Hkv = k.shape[1], k.shape[2]
-        bq, bk = _block_sizes(block_q, block_k, Sq, Sk, D, H // Hkv)
         interp = _use_interpret()
+        bq, bk = _block_sizes(block_q, block_k, Sq, Sk, D, H // Hkv,
+                              interpret=interp)
         my = jax.lax.axis_index(axis)
         Sq_pad = -(-Sq // bq) * bq
         O = jnp.zeros((B, Sq, H, D), jnp.float32)
@@ -467,8 +467,9 @@ def _make_ring_flash(axis: str, n: int, causal: bool, scale: float,
         q, k, v, out, L, seg = res
         B, Sq, H, D = q.shape
         Sk, Hkv = k.shape[1], k.shape[2]
-        bq, bk = _block_sizes(block_q, block_k, Sq, Sk, D, H // Hkv)
         interp = _use_interpret()
+        bq, bk = _block_sizes(block_q, block_k, Sq, Sk, D, H // Hkv,
+                              interpret=interp)
         my = jax.lax.axis_index(axis)
         # Hop-invariant work — the q/dO folds and the delta reduction —
         # happens once, not n times (only k/v change per hop).
@@ -540,8 +541,9 @@ def _make_ring_flash_zigzag(axis: str, n: int, scale: float,
         Hkv = k.shape[2]
         C = Sq // 2
         G = H // Hkv
-        bq, bk = _block_sizes(block_q, block_k, C, C, D, H // Hkv)
         interp = _use_interpret()
+        bq, bk = _block_sizes(block_q, block_k, C, C, D, H // Hkv,
+                              interpret=interp)
         my = jax.lax.axis_index(axis)
         C_pad = -(-C // bq) * bq
         q_offs = _offs(my, C)
@@ -597,8 +599,9 @@ def _make_ring_flash_zigzag(axis: str, n: int, scale: float,
         B, Sq, H, D = q.shape
         Hkv = k.shape[2]
         C = Sq // 2
-        bq, bk = _block_sizes(block_q, block_k, C, C, D, H // Hkv)
         interp = _use_interpret()
+        bq, bk = _block_sizes(block_q, block_k, C, C, D, H // Hkv,
+                              interpret=interp)
         my = jax.lax.axis_index(axis)
         q_offs = _offs(my, C)
         Ls = (La, Lb)

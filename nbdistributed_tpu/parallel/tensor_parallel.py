@@ -169,10 +169,17 @@ def make_tp_train_step(loss_fn, optimizer, mesh, param_rules, *,
                                jnp.sqrt(gn_sq)])}
         return out_params, out_state, loss, aux
 
+    def step_on_mesh(params, opt_state, batch):
+        # Traced with the mesh active: code GSPMD cannot partition (a
+        # Mosaic kernel — models/transformer._flash_on_mesh) reads it
+        # there and wraps itself in a shard_map.
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return step(params, opt_state, batch)
+
     out_sh = ((param_sh, opt_state_sh, repl, repl) if guard
               else (param_sh, opt_state_sh, repl))
     return jax.jit(
-        step,
+        step_on_mesh,
         in_shardings=(param_sh, opt_state_sh, batch_sh),
         out_shardings=out_sh,
         donate_argnums=(0, 1) if donate else ())

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from ..utils.compat import shard_map
 
 
 @functools.lru_cache(maxsize=None)
@@ -49,7 +48,7 @@ def _ulysses_fn(mesh, axis: str, causal: bool, scale: float,
     in_specs = (spec, spec, spec)
     if with_segments:
         in_specs = in_specs + (P(batch_axis, axis),)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         inner, mesh=mesh, in_specs=in_specs, out_specs=spec,
         check_vma=False))
 

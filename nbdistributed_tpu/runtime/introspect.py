@@ -110,7 +110,7 @@ def device_status(rank: int, world_size: int) -> dict:
         entry: dict[str, Any] = {
             "id": d.id,
             "platform": d.platform,
-            "kind": getattr(d, "device_kind", "unknown"),
+            "kind": d.device_kind,
         }
         mem = device_memory(d)
         entry["memory_gb"] = None if mem is None else {

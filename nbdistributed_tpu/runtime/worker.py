@@ -39,8 +39,8 @@ from ..observability import telemetry as obs_telemetry
 from ..resilience import faults as faults_mod
 from ..resilience.dedup import _READ_ONLY, ReplayCache, ResultMailbox
 from ..resilience.faults import FaultPlan
-from ..utils import knobs
-from . import collective_guard, executor, introspect
+from ..utils import PLATFORMS, knobs
+from . import collective_guard, compile_cache, executor, introspect
 from .interrupt import InterruptGate
 
 
@@ -51,6 +51,29 @@ def _load_hf_pretrained_lazy(name_or_path, **kw):
     return load_hf_pretrained(name_or_path, **kw)
 
 HEARTBEAT_INTERVAL_S = 2.0
+
+
+def _require_backend(rank: int, backend: str | None) -> None:
+    """Refuse to start on any backend but the one this worker was
+    launched for.  Without this a failed accelerator bring-up turns
+    into a fleet of CPU workers running every Pallas kernel in
+    interpret mode while the banner says "workers ready".  Exits
+    (SystemExit, code 1) so the spawner's startup-failure path shows
+    this message with the rest of the worker's stdio."""
+    if backend is None:
+        return
+    import jax
+    try:
+        actual = jax.default_backend()
+    except RuntimeError as e:
+        raise SystemExit(
+            f"[worker {rank}] launched with --backend {backend} but "
+            f"JAX cannot initialise it: {e}") from None
+    if actual != backend:
+        raise SystemExit(
+            f"[worker {rank}] launched with --backend {backend} but "
+            f"JAX initialised {actual!r} — refusing to attach on the "
+            f"wrong device")
 
 # Documented exemptions for the lifecycle self-lint
 # (analysis/lifecycle.py): "Class:attr" → reason.
@@ -264,47 +287,36 @@ class DistributedWorker:
         dist_host = dist_host or coordinator_host
 
         # --- data plane: JAX runtime init (reference: worker.py:145-151) --
-        if backend == "cpu":
-            import jax
-            jax.config.update("jax_platforms", "cpu")
-            if world_size > 1:
-                jax.config.update("jax_cpu_collectives_implementation",
-                                  "gloo")
+        import jax
+        if backend is not None:
+            # The platform is the launcher's decision, not the
+            # environment's: an inherited JAX_PLATFORMS must not turn
+            # a worker asked for a chip into a CPU one (or the
+            # reverse), and a listed platform that cannot initialise
+            # raises instead of falling back.
+            jax.config.update("jax_platforms", PLATFORMS[backend])
+        if backend == "cpu" and world_size > 1:
+            jax.config.update("jax_cpu_collectives_implementation",
+                              "gloo")
         if world_size > 1 and dist_port is not None:
-            import jax
             print(f"[worker {rank}] joining jax.distributed world "
                   f"({world_size} processes)...", flush=True)
             jax.distributed.initialize(
                 coordinator_address=f"{dist_host}:{dist_port}",
                 num_processes=world_size,
                 process_id=rank)
-        import jax  # noqa: F811 — backend resolves here
         self._jax = jax
-        # Warm starts (ISSUE 16): the gateway ships a persistent
-        # per-pool XLA compilation cache dir so a resized-in worker's
-        # (or a migrated tenant's) first cell replays a compiled
-        # executable instead of paying the cold compile.  Gated: old
-        # jaxlibs without the option, or an unwritable dir, degrade
-        # to the ordinary in-memory cache.
-        cache_dir = knobs.get_str("NBD_COMPILE_CACHE_DIR") or ""
-        if cache_dir and cache_dir.strip().lower() not in (
-                "0", "off", "none"):
-            try:
-                os.makedirs(cache_dir, exist_ok=True)
-                jax.config.update("jax_compilation_cache_dir",
-                                  cache_dir)
-                # Cache every compile, however fast: the 1 B-param
-                # first-cell compile is the target, but resize tests
-                # ride tiny graphs.
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.0)
-                print(f"[worker {rank}] compile cache: {cache_dir}",
-                      flush=True)
-            except Exception as e:
-                print(f"[worker {rank}] compile cache disabled "
-                      f"({type(e).__name__}: {e})", flush=True)
+        _require_backend(rank, backend)
+        # One persistent compile cache for every worker of every fleet
+        # (runtime/compile_cache.py says where, and when to leave the
+        # choice to JAX_COMPILATION_CACHE_DIR).
+        cache_dir = compile_cache.resolve()
+        if cache_dir is not None:
+            os.makedirs(cache_dir, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
         n_local = jax.local_device_count()
         print(f"[worker {rank}] backend={jax.default_backend()} "
+              f"kind={jax.local_devices()[0].device_kind!r} "
               f"local_devices={n_local} global_devices={jax.device_count()}",
               flush=True)
 
@@ -353,7 +365,6 @@ class DistributedWorker:
                                      zigzag_unshard)
         from ..parallel.ulysses import ulysses_attention
         from ..utils import data as data_mod
-        from ..utils.compat import shard_map as _compat_shard_map
 
         dist = collectives.DistNamespace()
         ns = {
@@ -370,7 +381,7 @@ class DistributedWorker:
             "NamedSharding": NamedSharding,
             "P": PartitionSpec,
             "PartitionSpec": PartitionSpec,
-            "shard_map": getattr(jax, "shard_map", _compat_shard_map),
+            "shard_map": jax.shard_map,
             "dist": dist,
             "all_reduce": collectives.all_reduce,
             "all_gather": collectives.all_gather,
@@ -1218,17 +1229,15 @@ class DistributedWorker:
             kw["kv_quantized"] = True
         # Shard the decode across this rank's addressable devices via
         # NamedSharding when the KV heads divide evenly (a local
-        # tensor-parallel mesh; CPU CI has one device -> no mesh).
-        try:
-            import jax
-            local = jax.local_devices()
-            n_kv = int(getattr(ns[cname], "n_kv_heads", 0) or 0)
-            if len(local) > 1 and n_kv and n_kv % len(local) == 0:
-                from ..parallel.mesh import make_mesh
-                kw["mesh"] = make_mesh({"tp": len(local)},
-                                       devices=local)
-        except Exception:
-            pass
+        # tensor-parallel mesh; one device -> no mesh).  A failure
+        # building the mesh propagates to an error reply — never a
+        # silent single-device server on a multi-device rank.
+        import jax
+        local = jax.local_devices()
+        n_kv = int(getattr(ns[cname], "n_kv_heads", 0) or 0)
+        if len(local) > 1 and n_kv and n_kv % len(local) == 0:
+            from ..parallel.mesh import make_mesh
+            kw["mesh"] = make_mesh({"tp": len(local)}, devices=local)
         try:
             server = DecodeServer(
                 ns[pname], ns[cname],
@@ -1238,6 +1247,7 @@ class DistributedWorker:
                 eos_id=data.get("eos_id"),
                 temperature=float(data.get("temperature") or 0.0),
                 **kw)
+            step_kernels = server.step_kernels()
         except Exception as e:
             return msg.reply(data={"error": f"DecodeServer build "
                                             f"failed: {e}"},
@@ -1246,7 +1256,8 @@ class DistributedWorker:
         self._publish_serve_snap()
         self._flight.record("serve_open", tenant=tenant,
                             max_batch=server._B, max_len=server._T)
-        return msg.reply(data={"status": "open", "slots": server._B},
+        return msg.reply(data={"status": "open", "slots": server._B,
+                               "step_kernels": step_kernels},
                          rank=self.rank)
 
     def _handle_serve_step(self, msg: Message) -> Message:

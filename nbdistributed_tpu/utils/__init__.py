@@ -12,7 +12,15 @@ from __future__ import annotations
 _DATA_EXPORTS = ("batch_iterator", "interleave_shards",
                  "prefetch_to_device", "rank_slice", "shard_arrays")
 
-__all__ = ["fan_in_normal", *_DATA_EXPORTS]
+__all__ = ["PLATFORMS", "fan_in_normal", *_DATA_EXPORTS]
+
+# --backend -> JAX platform list: what the spawner writes into a
+# worker's environment (manager/topology.py) and what the worker pins
+# ``jax_platforms`` to from its own flag (runtime/worker.py).  A TPU
+# worker keeps the host CPU backend as its second platform (host-side
+# staging with ``jax.devices("cpu")``); the first entry is the default
+# backend, and every listed platform must initialise or JAX raises.
+PLATFORMS = {"cpu": "cpu", "tpu": "tpu,cpu"}
 
 
 def fan_in_normal(key, shape, fan_in, dtype):

@@ -185,12 +185,6 @@ _ALL = (
        "Resize drain-barrier bound: seconds to wait for in-flight "
        "cells and decode ticks to finish before the resize is "
        "aborted and the pool resumed at its old size.", "elastic"),
-    _k("NBD_COMPILE_CACHE_DIR", None, "path",
-       "Persistent XLA compilation-cache directory workers enable at "
-       "spawn (jax_compilation_cache_dir) so resized-in workers and "
-       "new tenants skip the cold compile.  The gateway daemon "
-       "defaults it to <run_dir>/xla-cache for its fleet; set 0/off "
-       "to disable entirely.", "elastic"),
     # --- serving plane (%dist_serve) --------------------------------------
     _k("NBD_SERVE_MAX_BATCH", "8", "int",
        "Default KV-slot count (continuous-batching width) of the "
@@ -387,8 +381,6 @@ _ALL = (
        "harness"),
     _k("NBD_BENCH_FAMILY_BUDGET_S", None, "float",
        "bench.py: per-family wall-clock budget.", "harness"),
-    _k("NBD_PROBE_CPU_SMOKE", None, "bool",
-       "tools/probe_timing.py: run the CPU smoke variant.", "harness"),
 )
 
 KNOBS: dict[str, Knob] = {k.name: k for k in _ALL}

@@ -153,7 +153,7 @@ def executed_finetune_nb(tmp_path_factory):
     SmolLM2-135M-architecture checkpoint -> load_hf_pretrained ->
     packed local-text dataset -> cell-by-cell DDP fine-tune ->
     generation.  (Checkpoint is locally constructed: zero-egress
-    environment, see BASELINE.md.)  Per-run temp dirs: no /tmp litter
+    environment.)  Per-run temp dirs: no /tmp litter
     or cross-run races on the ~0.5G checkpoint."""
     tmp = tmp_path_factory.mktemp("finetune_nb")
     return _execute_notebook(

@@ -157,6 +157,10 @@ def test_loadgen_smoke_two_ranks(pool):
         st = t.serve_status()
         assert len(st["decode_ranks"]) == 2, st
         assert st["kv"]["block_tokens"] == 8
+        # Each rank's server reports the compiled Pallas kernels in
+        # its decode step; the CPU interprets them, so none.
+        assert [v["step_kernels"] for v in st["ranks"].values()] \
+            == [0, 0], st
         assert t.serve_stop()["status"] == "stopped"
     finally:
         try:

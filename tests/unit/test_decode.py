@@ -280,7 +280,7 @@ def test_decode_tuned_block_table_consulted():
 # shards combined by log-sum-exp (the flash inter-block combine run
 # across chips)
 
-def test_decode_lse_matches_reference():
+def test_decode_lse_matches_reference(chip_tol):
     """return_lse must equal log-sum-exp of the masked scores, and an
     all-masked query must report NEG_INF with a zero output row."""
     from nbdistributed_tpu.ops.decode import flash_decode_attention
@@ -304,7 +304,8 @@ def test_decode_lse_matches_reference():
             s = (np.asarray(q[b, h]) * scale) @ np.asarray(kc[b, kv]).T
             s = s[: int(pos[b]) + 1]
             ref = float(np.log(np.exp(s - s.max()).sum()) + s.max())
-            np.testing.assert_allclose(float(lse[b, h]), ref, rtol=1e-5)
+            np.testing.assert_allclose(float(lse[b, h]), ref,
+                                       rtol=chip_tol(1e-5, 1e-4))
     o3, lse3 = flash_decode_attention(
         q, kc, vc, jnp.asarray([-1, -1], jnp.int32), block_k=32,
         return_lse=True)

@@ -227,7 +227,7 @@ def test_generate_validates_sampler_args(setup):
         generate(params, prompt, cfg, 2, temperature=1.0, top_p=0.0,
                  key=jax.random.PRNGKey(0))
     # top_k above the vocabulary must fail at the argument, not as an
-    # opaque lax.top_k trace error (ADVICE r2).
+    # opaque lax.top_k trace error.
     with pytest.raises(ValueError, match="vocab_size"):
         generate(params, prompt, cfg, 2, temperature=1.0,
                  top_k=cfg.vocab_size + 1, key=jax.random.PRNGKey(0))
@@ -235,7 +235,7 @@ def test_generate_validates_sampler_args(setup):
 
 def test_empty_prompt_prefill_raises(setup):
     """prefill_chunked(S=0) must not silently return the zero init
-    logits (which would seed decode with token 0) — ADVICE r2."""
+    logits (which would seed decode with token 0)."""
     from nbdistributed_tpu.models import init_kv_cache, prefill_chunked
     cfg, params = setup
     cache = init_kv_cache(cfg, 1, 8)
@@ -248,8 +248,7 @@ def test_empty_prompt_prefill_raises(setup):
 
 def test_quantized_cache_with_stale_rules_raises(setup):
     """A caller-supplied rules dict that predates quantization (only
-    k/v specs) must fail with a named error, not a KeyError — ADVICE
-    r2."""
+    k/v specs) must fail with a named error, not a KeyError."""
     from nbdistributed_tpu.parallel.mesh import make_mesh
     cfg, _ = setup
     mesh = make_mesh({"tp": 2}, devices=jax.devices()[:2])

@@ -12,7 +12,6 @@ from nbdistributed_tpu.parallel import mesh as mesh_mod
 from nbdistributed_tpu.parallel.overlap import (allgather_matmul,
                                                 matmul_reducescatter,
                                                 megatron_sp_block)
-from nbdistributed_tpu.utils.compat import shard_map
 
 T = 4
 
@@ -28,7 +27,7 @@ def test_allgather_matmul_exact(mesh):
     x = jax.random.normal(ks[0], (S, D), jnp.float32)
     w = jax.random.normal(ks[1], (D, F), jnp.float32)
 
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         lambda xs, ws: allgather_matmul(xs, ws, "tp"),
         mesh=mesh, in_specs=(P("tp", None), P(None, "tp")),
         out_specs=P(None, "tp")))(x, w)
@@ -42,7 +41,7 @@ def test_matmul_reducescatter_exact(mesh):
     h = jax.random.normal(ks[0], (S, F), jnp.float32)
     w = jax.random.normal(ks[1], (F, D), jnp.float32)
 
-    got = jax.jit(shard_map(
+    got = jax.jit(jax.shard_map(
         lambda hs, ws: matmul_reducescatter(hs, ws, "tp"),
         mesh=mesh, in_specs=(P(None, "tp"), P("tp", None)),
         out_specs=P("tp", None)))(h, w)
@@ -60,7 +59,7 @@ def test_megatron_sp_block_exact_and_grads(mesh):
     wd = jax.random.normal(ks[2], (F, D), jnp.float32) / np.sqrt(F)
 
     def sharded(x, wu, wd):
-        return shard_map(
+        return jax.shard_map(
             lambda a, b, c: megatron_sp_block(a, b, c, "tp"),
             mesh=mesh,
             in_specs=(P("tp", None), P(None, "tp"), P("tp", None)),
@@ -87,7 +86,7 @@ def test_ring_structure(mesh):
     S, D, F = 8, 4, 8
     x = jnp.ones((S, D))
     w = jnp.ones((D, F))
-    jaxpr = str(jax.make_jaxpr(shard_map(
+    jaxpr = str(jax.make_jaxpr(jax.shard_map(
         lambda xs, ws: allgather_matmul(xs, ws, "tp"),
         mesh=mesh, in_specs=(P("tp", None), P(None, "tp")),
         out_specs=P(None, "tp")))(x, w))
@@ -96,7 +95,7 @@ def test_ring_structure(mesh):
 
     h = jnp.ones((S, F))
     wd = jnp.ones((F, D))
-    jaxpr = str(jax.make_jaxpr(shard_map(
+    jaxpr = str(jax.make_jaxpr(jax.shard_map(
         lambda hs, ws: matmul_reducescatter(hs, ws, "tp"),
         mesh=mesh, in_specs=(P(None, "tp"), P("tp", None)),
         out_specs=P("tp", None)))(h, wd))
@@ -106,7 +105,7 @@ def test_ring_structure(mesh):
 
 def test_reducescatter_rejects_indivisible(mesh):
     with pytest.raises(ValueError, match="not divisible"):
-        shard_map(
+        jax.shard_map(
             lambda hs, ws: matmul_reducescatter(hs, ws, "tp"),
             mesh=mesh, in_specs=(P(None, "tp"), P("tp", None)),
             out_specs=P("tp", None))(jnp.ones((6, 8)), jnp.ones((8, 4)))

@@ -142,6 +142,21 @@ def test_kv_snapshot_surface(setup):
         or snap["owners"] == {rid: 2}
 
 
+def test_step_kernels_probe_only_traces(setup):
+    """What serve_open reports: compiled Pallas kernels in the step
+    program the server runs, dense or paged.  The CPU interprets
+    kernels, so none here; and lowering must not consume the donated
+    pool — the server serves afterwards as if never asked."""
+    cfg, params = setup
+    for kw in ({}, {"kv_block_tokens": 8}):
+        srv = DecodeServer(params, cfg, max_batch=2, max_len=32,
+                           pad_to=4, **kw)
+        assert srv.step_kernels() == 0
+        rid = srv.submit([5, 9, 2], 6)
+        srv.run_until_done(max_steps=50)
+        assert srv.outputs[rid] == solo(params, cfg, [5, 9, 2], 6)
+
+
 def test_paged_validation(setup):
     cfg, params = setup
     with pytest.raises(ValueError, match="kv_block_tokens"):

@@ -344,7 +344,7 @@ def test_hop_plan_shapes_and_coverage():
 
 
 def test_windowed_ring_skips_hops():
-    """The VERDICT item: SWA x SP must not pay all n hops.  Count
+    """SWA x SP must not pay all n hops.  Count
     ppermute equations in the traced program — windowed rings must
     issue strictly fewer collectives than the full causal ring, for
     forward and backward, einsum, flash, and zigzag paths."""

@@ -78,7 +78,7 @@ class FakeComm:
         if msg_type == "serve_open":
             self._srv[rank] = {}
             return {rank: types.SimpleNamespace(
-                data={"status": "open"})}
+                data={"status": "open", "step_kernels": rank})}
         if msg_type == "serve_close":
             self._srv.pop(rank, None)
             return {rank: types.SimpleNamespace(data={"status": "ok"})}
@@ -188,6 +188,9 @@ def test_multi_rank_decode_uses_both_ranks_exactly(tmp_path):
         assert d["decode_ranks"] == [1, 2]
         assert d["decode_rank"] == 2          # legacy headline rank
         assert set(d["ranks"]) == {"1", "2"}
+        # what each rank's own server reported at serve_open
+        assert {r: v["step_kernels"] for r, v in d["ranks"].items()} \
+            == {"1": 1, "2": 2}
         assert d["failovers"] == 0 and d["dup_dropped"] == 0
         done_rids = [m.data["rid"] for _t, m in delivered
                      if m.msg_type == "serve_done"]
