@@ -1459,21 +1459,6 @@ def measure_serving() -> dict | None:
         if kv:
             out["kv_block_tokens"] = kv.get("block_tokens")
             out["kv_blocks_per_rank"] = kv.get("blocks_per_rank")
-        # Score the sustained phase against the checked-in perf
-        # baseline (ISSUE 18) so a BENCH run carries the same
-        # regression verdict CI's perfwatch gate would give —
-        # reported, not enforced (the CI job owns the exit code).
-        try:
-            from nbdistributed_tpu.observability import perfbase
-            doc = perfbase.load_baselines("BENCH_BASELINES.json")
-            base = (doc.get("baselines") or {}).get("serving_smoke")
-            if base:
-                res = perfbase.score(base, perfbase.extract_metrics(
-                    rep, (st.get("lat") or {}).get("summary")))
-                out["perfwatch"] = {"pass": res["pass"],
-                                    "regressions": res["regressions"]}
-        except Exception:
-            pass
         return out
     finally:
         if client is not None:

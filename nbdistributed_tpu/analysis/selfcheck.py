@@ -98,11 +98,9 @@ _THREAD_CHECKED_FILES = (
     os.path.join("nbdistributed_tpu", "gateway", "router.py"),
     os.path.join("nbdistributed_tpu", "resilience", "autoscaler.py"),
     # Serving observatory (ISSUE 18): the request table and util ring
-    # are shared between the gateway listener, per-request serve
-    # threads, and the decode driver; perfbase is pure functions but
-    # rides the list so any future cache/memo grows a lock.
+    # (and, ISSUE 25, the tick ring) are shared between the gateway
+    # listener, per-request serve threads, and the decode driver.
     os.path.join("nbdistributed_tpu", "observability", "servingobs.py"),
-    os.path.join("nbdistributed_tpu", "observability", "perfbase.py"),
     # Training integrity guard (ISSUE 19): TrainGuard's counters and
     # snapshot ring are mutated on the train-loop thread while the
     # heartbeat thread reads the published snapshot.

@@ -518,6 +518,16 @@ def pool_resize(host: str, port: int, pool_token: str | None,
                            "reason": reason}, timeout=timeout)
 
 
+def pool_trace(host: str, port: int, pool_token: str | None,
+               action: str, *, timeout: float = 150.0) -> dict:
+    """``%dist_trace`` for a pool: start / stop / status / save the
+    span trace of the gateway daemon and its workers (``save`` brings
+    the merged Chrome trace back under ``merged``)."""
+    return _admin_request(host, port, pool_token, "pool_trace",
+                          {"token": pool_token, "action": action},
+                          timeout=timeout)
+
+
 def pool_template(host: str, port: int, pool_token: str | None,
                   code: str | None = None, *, name: str = "default",
                   timeout: float = 600.0) -> dict:

@@ -122,7 +122,7 @@ _LINT_CALLBACK_OK = {
 # elastic-pool controls; tenant_export/import/release are the router's
 # migration plane (ISSUE 16).
 _PRE_HELLO = frozenset({"tenant_hello", "pool_status", "pool_shutdown",
-                        "pool_resize", "pool_template",
+                        "pool_resize", "pool_template", "pool_trace",
                         "tenant_export", "tenant_import",
                         "tenant_release"})
 
@@ -895,6 +895,27 @@ class GatewayDaemon:
 
             threading.Thread(target=_do_template,
                              name="nbd-gw-template",
+                             daemon=True).start()
+        elif mt == "pool_trace":
+            data = msg.data or {}
+            if data.get("token") != self.pool_token:
+                self._send_to_client(client_id, msg.reply(
+                    data={"error": "pool token mismatch"}))
+                return
+
+            def _do_trace():
+                # The pool's %dist_trace: this process holds the
+                # fleet's comm, its tracer and the serving driver.
+                from ..observability.export import fleet_trace
+                try:
+                    out = fleet_trace(self.comm,
+                                      str(data.get("action") or "status"))
+                except Exception as e:
+                    out = {"error": f"{type(e).__name__}: {e}"}
+                self._send_to_client(client_id, msg.reply(data=out))
+
+            # Off the listener thread: it waits for every worker.
+            threading.Thread(target=_do_trace, name="nbd-gw-trace",
                              daemon=True).start()
         elif mt == "tenant_export":
             data = msg.data or {}

@@ -77,6 +77,7 @@ from collections import deque
 from ..messaging.codec import Message
 from ..observability import latency as obs_latency
 from ..observability import metrics as obs_metrics
+from ..observability import spans as obs_spans
 from ..observability.servingobs import ServingObservatory
 from ..serving_fast.paging import BlockAllocator, blocks_needed
 from ..utils import knobs
@@ -498,6 +499,17 @@ class ServingManager:
         # Ranks whose KV gauges were last published (driver thread
         # only): a retired rank's series is zeroed the next tick.
         self._gauged_ranks: set[int] = set()
+        # The tick's account (ISSUE 25), written by the driver thread:
+        # the sequence number every tick's spans and the worker's
+        # reply carry; whether the driver waited for work since the
+        # last tick (that tick's period is then no decode period); and
+        # the seconds spent writing the journal and delivering tokens,
+        # which split ``apply`` without spans of their own (a shed's
+        # ``_finish`` on a submitter's thread adds to them too: a lost
+        # update there costs a tick a few microseconds of account).
+        self._seq = 0
+        self._idled = True
+        self._apply_s = {"journal": 0.0, "notify": 0.0}
 
     def _slo_hist(self, name: str, help: str, tenant: str):
         """Per-SUBMITTING-tenant SLO histogram, resolved through the
@@ -1050,12 +1062,14 @@ class ServingManager:
         while not self._stop.is_set():
             if self._pause.is_set():
                 # Drained: no tick starts until resume_after_resize.
+                self._idled = True
                 self._wake.wait(timeout=0.2)
                 self._wake.clear()
                 continue
             with self._lock:
                 work = self._has_work_locked()
             if not work:
+                self._idled = True
                 self._wake.wait(timeout=1.0)
                 self._wake.clear()
                 continue
@@ -1176,6 +1190,8 @@ class ServingManager:
             self._step_kernels[rank] = int(
                 (resp[rank].data or {}).get("step_kernels") or 0)
             self._avoid.pop(rank, None)
+        self.obs.kv_view_bytes = int(
+            (resp[rank].data or {}).get("kv_view_bytes") or 0)
         self._record("serve_open", rank=rank)
 
     def _place_admits_locked(self) -> tuple[dict, dict, list]:
@@ -1283,62 +1299,98 @@ class ServingManager:
         return admits, release, qwaits, events
 
     def _tick(self) -> None:
-        target = self._pick_ranks()
-        if not target:
-            # Whole pool dead/unreachable: keep the journal and WAIT
-            # for a heal — accepted requests survive by contract.  A
-            # wait state, not a failover: any prior placement was
-            # already un-placed by the rank-lost path.
-            self._stop.wait(1.0)
-            return
-        with self._lock:
-            stale = [r for r in self._open if r not in target]
-        for rank in stale:
-            self._retire_rank(rank)
-        for rank in target:
+        """One serving tick, under its sequence number: the whole of
+        it is the span ``serve/tick``; its phases (place, roundtrip,
+        apply, util) are child spans and, always, seconds on
+        perf_counter that telescope, handed to the observatory with
+        the worker's own account of the same tick."""
+        seq = self._seq = self._seq + 1     # driver thread only
+        with obs_spans.phase("serve/tick", seq):
+            self._tick_phases(seq)
+
+    def _tick_phases(self, seq: int) -> None:
+        idled, self._idled = self._idled, False
+        t0 = time.perf_counter()
+        with obs_spans.phase("serve/tick/place", seq):
+            target = self._pick_ranks()
+            if not target:
+                # Whole pool dead/unreachable: keep the journal and
+                # WAIT for a heal — accepted requests survive by
+                # contract.  A wait state, not a failover: any prior
+                # placement was already un-placed by the rank-lost
+                # path.
+                self._idled = True
+                self._stop.wait(1.0)
+                return
             with self._lock:
-                if rank in self._open:
-                    continue
-            self._open_on(rank)
-        with self._lock:
-            admits, release, qwaits, events = \
-                self._place_admits_locked()
-            busy = {r.rank for r in self._reqs.values()
-                    if r.state == ACCEPTED and r.placed
-                    and r.rank is not None}
-            ticks = sorted((set(admits) | set(release) | busy)
-                           & set(self._open))
-        for ev in events:
-            self._record(**ev)
-        for tenant_name, wait in qwaits:
-            self._slo_hist(
-                "nbd_serve_queue_wait_seconds",
-                "serving queue wait: submit → first KV-slot placement",
-                tenant_name).observe(wait)
+                stale = [r for r in self._open if r not in target]
+            for rank in stale:
+                self._retire_rank(rank)
+            for rank in target:
+                with self._lock:
+                    if rank in self._open:
+                        continue
+                self._open_on(rank)
+            with self._lock:
+                admits, release, qwaits, events = \
+                    self._place_admits_locked()
+                busy = {r.rank for r in self._reqs.values()
+                        if r.state == ACCEPTED and r.placed
+                        and r.rank is not None}
+                ticks = sorted((set(admits) | set(release) | busy)
+                               & set(self._open))
+            for ev in events:
+                self._record(**ev)
+            for tenant_name, wait in qwaits:
+                self._slo_hist(
+                    "nbd_serve_queue_wait_seconds",
+                    "serving queue wait: submit → first KV-slot "
+                    "placement", tenant_name).observe(wait)
         if not ticks:
             self._update_kv_gauges()
             return
         payloads = {rank: {"tenant": self.tenant,
                            "admit": admits.get(rank, []),
                            "release": release.get(rank, []),
-                           "steps": self.steps}
+                           "steps": self.steps, "seq": seq}
                     for rank in ticks}
-        replies, lost = self._step_all(payloads)
+        t1 = time.perf_counter()
+        with obs_spans.phase("serve/tick/roundtrip", seq):
+            replies, lost = self._step_all(payloads)
+        t2 = time.perf_counter()
+        apply0 = dict(self._apply_s)
+        with obs_spans.phase("serve/tick/apply", seq):
+            for rank in ticks:
+                data = replies.get(rank)
+                if data is None:
+                    continue
+                if data.get("error"):
+                    # Whole-step refusal (e.g. the rank lost its
+                    # serving state): treat like a dead rank — re-open
+                    # and re-admit from the journal instead of
+                    # spinning.
+                    self._record("serve_step_refused", rank=rank,
+                                 error=str(data["error"])[:200])
+                    lost.append((rank, str(data["error"])))
+                    continue
+                self._apply_reply(data, rank=rank)
+        t3 = time.perf_counter()
+        with obs_spans.phase("serve/tick/util", seq):
+            self._note_tick_util(ticks, replies)
+            self._update_kv_gauges()
+        t4 = time.perf_counter()
+        gw = {"place": t1 - t0, "roundtrip": t2 - t1, "apply": t3 - t2,
+              "util": t4 - t3,
+              **{k: v - apply0[k] for k, v in self._apply_s.items()}}
         for rank in ticks:
-            data = replies.get(rank)
-            if data is None:
-                continue
-            if data.get("error"):
-                # Whole-step refusal (e.g. the rank lost its serving
-                # state): treat like a dead rank — re-open and
-                # re-admit from the journal instead of spinning.
-                self._record("serve_step_refused", rank=rank,
-                             error=str(data["error"])[:200])
-                lost.append((rank, str(data["error"])))
-                continue
-            self._apply_reply(data, rank=rank)
-        self._note_tick_util(ticks, replies)
-        self._update_kv_gauges()
+            tk = (replies.get(rank) or {}).get("tick") or {}
+            if tk.get("seq") != seq:
+                continue        # refused, or a worker without the account
+            slow = self.obs.note_tick(
+                seq, rank, gw, tk.get("ph") or {}, tk.get("cmp"),
+                turnaround=tk.get("turnaround"), idled=idled)
+            if slow is not None:
+                self._record("serve_slow_tick", **slow)
         if lost:
             # Every received reply above is already applied, so the
             # failover surgery is scoped to the lost rank alone.  With
@@ -1580,7 +1632,9 @@ class ServingManager:
                     "receipt", {"tenant": self.tenant}).inc(dup)
             if not new:
                 continue
+            t_j0 = time.perf_counter()
             self.journal.emit(rid, have, new)
+            self._apply_s["journal"] += time.perf_counter() - t_j0
             now = time.time()
             with self._lock:
                 req.tokens.extend(new)
@@ -1627,7 +1681,9 @@ class ServingManager:
             if done:
                 self._finish(req, COMPLETED)
             else:
+                t_n0 = time.perf_counter()
                 self._notify_tokens(req, offset, new)
+                self._apply_s["notify"] += time.perf_counter() - t_n0
 
     def _finish(self, req: _Req, status: str,
                 error: str | None = None) -> None:
@@ -1687,7 +1743,9 @@ class ServingManager:
                 "nbd_serve_e2e_seconds",
                 "serving end-to-end latency (submit → completed)",
                 req.tenant).observe(slo["e2e"])
+        t_j0 = time.perf_counter()
         self.journal.done(req.rid, status)
+        self._apply_s["journal"] += time.perf_counter() - t_j0
         self.sched.complete(req.rid)
         self._wake.set()
         obs_metrics.registry().counter(
@@ -1705,10 +1763,12 @@ class ServingManager:
             data={"status": status, "rid": req.rid,
                   "tokens": list(req.tokens),
                   **({"error": error} if error else {})})
+        t_n0 = time.perf_counter()
         try:
             self._deliver(req.tenant, reply)
         except Exception:
             pass
+        self._apply_s["notify"] += time.perf_counter() - t_n0
 
     def _notify_tokens(self, req: _Req, offset: int,
                        toks: list[int]) -> None:

@@ -1860,6 +1860,21 @@ class DistributedMagics(Magics):
                   f"{util.get('prefill_share', 0):.0%} of "
                   f"{util.get('prefill_toks', 0) + util.get('decode_toks', 0)}"
                   f" tok" + (f" · {frag}" if frag else ""))
+        # Tick line (ISSUE 25): the last ticks' period as the chip's
+        # owner saw it, and where a tick's time went.
+        tk = ((st.get("lat") or {}).get("summary") or {}).get("ticks") \
+            or {}
+        if tk.get("count"):
+            def _p50(key: str) -> str:
+                return f"{(tk.get(key) or {}).get('p50', 0):g}"
+
+            print(f"   ticks: period p50/p99 "
+                  f"{_p50('period_ms')}/"
+                  f"{(tk.get('period_ms') or {}).get('p99', 0):g} ms · "
+                  f"sync {_p50('sync')} · host {_p50('host')} · "
+                  f"turnaround {_p50('turnaround')} ms (p50 of "
+                  f"{tk['count']}) · compiles {tk.get('compiles', 0)}"
+                  f" · slow {len(tk.get('slow') or ())}")
         print(f"   accepted {st.get('accepted', 0)} · completed "
               f"{st.get('completed', 0)} · shed {st.get('shed', 0)} · "
               f"rejected {st.get('rejected', 0)} · replayed "
@@ -3909,86 +3924,69 @@ class DistributedMagics(Magics):
         coordinator's timebase (per-rank clock offsets estimated from
         request RTTs) into one Perfetto-loadable file, with any active
         fault plan's decisions folded in as instant events.  Off by
-        default with near-zero overhead."""
-        if not self._require_cluster():
-            return
+        default with near-zero overhead.  Attached to a pool
+        (``%dist_attach``), the same four actions go to the gateway
+        daemon, whose process drives the fleet and the serving ticks
+        (``serve/tick/*`` there, ``serve/step/*`` on the workers)."""
+        from ..observability import export as obs_export
         args = parse_argstring(self.dist_trace, line)
-        comm = self._comm
-        tr = comm.tracer
-        if args.action == "start":
-            import uuid
-            tid = uuid.uuid4().hex[:16]
-            try:
-                # Workers first (adopting the shared trace id), so the
-                # coordinator never stamps a request that lands on a
-                # not-yet-tracing worker.
-                comm.send_to_all("trace", {"action": "start",
-                                           "trace_id": tid}, timeout=30)
-            except Exception as e:
-                print(f"❌ starting worker tracers failed: {e}")
+        try:
+            if DistributedMagics._tenant is not None \
+                    and not self._running():
+                # Attached to a pool: the fleet, and the serving
+                # driver, are the gateway daemon's.
+                manifest, _d = self._pool_endpoint()
+                if manifest is None:
+                    print("❌ no gateway pool to trace")
+                    return
+                from ..gateway.client import pool_trace
+                plane = manifest.get("tenant_plane") or {}
+                res = pool_trace(plane.get("host") or "127.0.0.1",
+                                 int(plane.get("port") or 0),
+                                 manifest.get("pool_token"), args.action)
+                if res.get("error"):
+                    raise RuntimeError(res["error"])
+            elif not self._require_cluster():
                 return
-            tr.start(trace_id=tid)
-            print(f"📡 tracing ON (trace {tid}) — run cells, then "
-                  f"%dist_trace save <path>")
+            else:
+                res = obs_export.fleet_trace(self._comm, args.action)
+        except Exception as e:
+            print(f"❌ %dist_trace {args.action} failed: {e}")
             return
+        if args.action == "start":
+            print(f"📡 tracing ON (trace {res['trace_id']}) — run "
+                  f"cells, then %dist_trace save <path>")
+            return
+        ranks = res.get("ranks") or {}
         if args.action == "stop":
-            n = tr.stop()
-            try:
-                resps = comm.send_to_all("trace", {"action": "stop"},
-                                         timeout=30)
-                per_rank = {r: resps[r].data.get("spans")
-                            for r in sorted(resps)}
-            except Exception as e:
-                per_rank = f"<worker stop failed: {e}>"
-            print(f"📡 tracing OFF — buffered spans: coordinator {n}, "
-                  f"workers {per_rank} (%dist_trace save still works)")
+            per_rank = ({r: d["spans"] for r, d in ranks.items()}
+                        if "ranks_error" not in res else
+                        f"<worker stop failed: {res['ranks_error']}>")
+            print(f"📡 tracing OFF — buffered spans: coordinator "
+                  f"{res['spans']}, workers {per_rank} (%dist_trace "
+                  f"save still works)")
             return
         if args.action == "status":
-            state = "ON" if tr.enabled else "off"
-            print(f"coordinator: tracing {state}, {len(tr)} spans "
+            state = "ON" if res.get("enabled") else "off"
+            print(f"coordinator: tracing {state}, {res['spans']} spans "
                   f"buffered"
-                  + (f", trace {tr.trace_id}" if tr.trace_id else ""))
-            try:
-                resps = comm.send_to_all("trace", {"action": "status"},
-                                         timeout=30)
-                for r in sorted(resps):
-                    d = resps[r].data
-                    print(f"🔹 rank {r}: {d.get('status')} "
-                          f"({d.get('spans', 0)} spans)")
-            except Exception as e:
-                print(f"⚠️ worker-side status failed: {e}")
+                  + (f", trace {res['trace_id']}"
+                     if res.get("trace_id") else ""))
+            for r, d in ranks.items():
+                print(f"🔹 rank {r}: {d['status']} ({d['spans']} spans)")
+            if "ranks_error" in res:
+                print(f"⚠️ worker-side status failed: "
+                      f"{res['ranks_error']}")
             return
-        # save: collect per-rank dumps + fault events, merge on the
-        # coordinator's timebase, write one Chrome-trace JSON.
-        from ..observability import export as obs_export
         try:
-            resps = comm.send_to_all("trace", {"action": "dump"},
-                                     timeout=120)
-        except Exception as e:
-            print(f"❌ collecting worker traces failed: {e}")
-            return
-        rank_dumps = {r: m.data.get("trace") or {}
-                      for r, m in resps.items()}
-        rank_faults = {r: m.data.get("fault_events") or []
-                       for r, m in resps.items()}
-        plan = comm.fault_plan()
-        cdump = tr.dump()
-        offsets = comm.clock.offsets()
-        merged = obs_export.merge_trace(
-            cdump, rank_dumps, offsets,
-            coordinator_faults=plan.events() if plan is not None else [],
-            rank_faults=rank_faults)
-        try:
-            n = obs_export.save_trace(args.path, merged)
+            n = obs_export.save_trace(args.path, res["merged"])
         except OSError as e:
             print(f"❌ could not write {args.path}: {e}")
             return
-        n_spans = {r: len(d.get("spans", [])) for r, d in
-                   sorted(rank_dumps.items())}
-        offs = {r: round(o * 1e3, 3) for r, o in sorted(offsets.items())}
         print(f"✅ {n} events → {args.path} (coordinator "
-              f"{len(cdump['spans'])} spans, ranks {n_spans}, "
-              f"clock offsets {offs} ms) — load in ui.perfetto.dev")
+              f"{res['spans']} spans, ranks {ranks}, "
+              f"clock offsets {res['offsets_ms']} ms) — load in "
+              f"ui.perfetto.dev")
 
     @magic_arguments()
     @argument("--prom", action="store_true",

@@ -331,37 +331,41 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
         else:
             kc = write_kv(kc, kT.astype(kc.dtype))
             vc = write_kv(vc, vT.astype(vc.dtype))
-        window = getattr(cfg, "sliding_window", None)
-        if S == 1 and cfg.use_flash and mesh is None:
-            # Decode hot path: fused Pallas kernel streams the cache
-            # once with the masked online softmax (ops/decode.py); an
-            # int8 cache streams at half width with its scales
-            # commuted through the matmuls.
-            from ..ops.decode import flash_decode_attention
-            o = flash_decode_attention(
-                q[:, 0], kc, vc, positions[:, 0], scale=scale,
-                window=window, k_s=ks, v_s=vs).reshape(B, 1, H * Dh)
-        elif (S == 1 and cfg.use_flash and mesh is not None
-              and _can_flash_decode_on_mesh(mesh, B, H, Hkv,
-                                            kc.shape[2])):
-            # Same kernel under GSPMD: shard_map carves the batch over
-            # dp and the (already tp-sharded) heads over tp, so the
-            # kernel runs on local shards instead of forcing GSPMD to
-            # replicate a raw pallas_call.
-            o = _flash_decode_on_mesh(
-                q[:, 0], kc, vc, positions[:, 0], mesh,
-                scale, window, ks, vs).reshape(B, 1, H * Dh)
-        else:
-            if kv_quantized:
-                # Compat/prefill path: dequantize for the einsum.
-                kc_a = _dequantize_kv(kc, ks)
-                vc_a = _dequantize_kv(vc, vs)
+        # Named scopes (trace-time metadata): attention, mlp and, in
+        # the serving step, sample can be told apart in a profile.
+        with jax.named_scope("attention"):
+            window = getattr(cfg, "sliding_window", None)
+            if S == 1 and cfg.use_flash and mesh is None:
+                # Decode hot path: fused Pallas kernel streams the cache
+                # once with the masked online softmax (ops/decode.py); an
+                # int8 cache streams at half width with its scales
+                # commuted through the matmuls.
+                from ..ops.decode import flash_decode_attention
+                o = flash_decode_attention(
+                    q[:, 0], kc, vc, positions[:, 0], scale=scale,
+                    window=window, k_s=ks, v_s=vs).reshape(B, 1, H * Dh)
+            elif (S == 1 and cfg.use_flash and mesh is not None
+                  and _can_flash_decode_on_mesh(mesh, B, H, Hkv,
+                                                kc.shape[2])):
+                # Same kernel under GSPMD: shard_map carves the batch over
+                # dp and the (already tp-sharded) heads over tp, so the
+                # kernel runs on local shards instead of forcing GSPMD to
+                # replicate a raw pallas_call.
+                o = _flash_decode_on_mesh(
+                    q[:, 0], kc, vc, positions[:, 0], mesh,
+                    scale, window, ks, vs).reshape(B, 1, H * Dh)
             else:
-                kc_a, vc_a = kc, vc
-            o = _cached_attention(q, kc_a, vc_a, positions, scale,
-                                  window=window)
-        x = x + qlinear(o, layer["wo"])
-        x = mlp(x, layer)
+                if kv_quantized:
+                    # Compat/prefill path: dequantize for the einsum.
+                    kc_a = _dequantize_kv(kc, ks)
+                    vc_a = _dequantize_kv(vc, vs)
+                else:
+                    kc_a, vc_a = kc, vc
+                o = _cached_attention(q, kc_a, vc_a, positions, scale,
+                                      window=window)
+            x = x + qlinear(o, layer["wo"])
+        with jax.named_scope("mlp"):
+            x = mlp(x, layer)
         new_cache = ((kc, vc, ks, vs) if kv_quantized else (kc, vc))
         return x, new_cache
 

@@ -74,6 +74,12 @@ def make_paged_pool(cfg, n_blocks: int, block_tokens: int, *,
                          mesh=mesh, rules=rules, quantized=quantized)
 
 
+# The gathers and scatters below carry a ``jax.named_scope`` each, so
+# their ops can be told from the model's in a profile (trace-time
+# metadata only: the compiled program is the same).
+
+
+@jax.named_scope("gather_dense")
 def gather_dense(pool, table):
     """Table-select every slot's blocks into a dense cache view.
 
@@ -89,6 +95,7 @@ def gather_dense(pool, table):
     return jax.tree_util.tree_map(one, pool)
 
 
+@jax.named_scope("gather_row")
 def gather_row(pool, row_ids):
     """One slot's blocks as a dense ``(L, 1, Hkv, MB*bt, D)`` row —
     the prefill working view."""
@@ -101,6 +108,7 @@ def gather_row(pool, row_ids):
     return jax.tree_util.tree_map(one, pool)
 
 
+@jax.named_scope("scatter_row")
 def scatter_row(pool, row, row_ids):
     """Write a slot's whole dense row back to its physical blocks.
     Trash-mapped ids receive the row's pad garbage — harmless by
@@ -114,6 +122,7 @@ def scatter_row(pool, row, row_ids):
     return jax.tree_util.tree_map(one, pool, row)
 
 
+@jax.named_scope("scatter_step")
 def scatter_step(pool, dense, table, pos, active, trash: int,
                  block_tokens: int):
     """Write back the ONE block per slot that a decode step touched.

@@ -67,12 +67,21 @@ caveat as batched speculative decoding.
 
 from __future__ import annotations
 
+import time
+
 import jax
 import jax.numpy as jnp
 
+from ..observability import spans as obs_spans
 from .generate import (_sample, forward_with_cache, init_kv_cache,
                        kv_cache_shardings)
 from .transformer import TransformerConfig
+
+# The phases of one :meth:`DecodeServer.step`, in order.  Adjacent
+# phases share their boundary instant, so a step's phases sum to its
+# wall time; the same names are the ``serve/step/*`` spans and
+# profiler annotations (observability/spans.py::phase).
+STEP_PHASES = ("prefill", "dispatch", "sync", "emit")
 
 
 class DecodeServer:
@@ -211,8 +220,16 @@ class DecodeServer:
             self._cache = make_paged_pool(
                 cfg, kv_blocks, kv_block_tokens, mesh=mesh,
                 quantized=kv_quantized)
+            # Bytes of the dense view one decode step gathers from
+            # the pool (every slot's whole block table, all layers):
+            # a count from shapes, the paged layer's cost per step.
+            self.kv_view_bytes = sum(
+                c.nbytes // c.shape[1] * self._paged.max_blocks
+                * max_batch
+                for c in jax.tree_util.tree_leaves(self._cache))
         else:
             self._paged = None
+            self.kv_view_bytes = 0
             self._cache = init_kv_cache(cfg, max_batch, max_len,
                                         mesh=mesh,
                                         quantized=kv_quantized)
@@ -270,6 +287,14 @@ class DecodeServer:
         # prefill/decode token split to the serving observatory.
         self.prefill_tokens_total = 0
         self.decode_tokens_total = 0
+        # Cumulative seconds per phase of step() (and of submit()'s
+        # admission, which is prefill), on this process's
+        # perf_counter; the worker's serve_step handler reports each
+        # tick's deltas.  ``tick`` is the gateway's sequence number of
+        # the tick being served: it rides the phases' spans and
+        # profiler annotations and changes nothing else.
+        self.phase_s = dict.fromkeys(STEP_PHASES, 0.0)
+        self.tick: int | None = None
 
         if self._paged is not None:
             self._prefill_fn = self._make_prefill_paged()
@@ -286,7 +311,7 @@ class DecodeServer:
         cfg = cfg if cfg is not None else self._cfg
         mesh, ep_axis = self._mesh, self._ep_axis
 
-        def fn(params, cache, prompt, slot, start, length):
+        def nbd_prefill(params, cache, prompt, slot, start, length):
             """prompt (1, s_pad) right-padded; writes the slot's cache
             rows at offset ``start`` and returns (updated cache,
             logits at the segment's last REAL token).  ``start`` is 0
@@ -317,23 +342,28 @@ class DecodeServer:
         # One jit serves every prompt bucket — jax.jit retraces (and
         # caches) per input shape, so padding to pad_to multiples
         # bounds the compile count.
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(nbd_prefill, donate_argnums=(1,))
 
     def _make_step(self):
         cfg, mesh, ep_axis = self._cfg, self._mesh, self._ep_axis
         temperature, top_k, top_p = (self._temperature, self._top_k,
                                      self._top_p)
 
-        def fn(params, cache, lens, last, active, key):
+        # Every jitted serving program carries a name that says what
+        # it is (``jit_nbd_decode_step*`` / ``jit_nbd_prefill*``): the
+        # profile's "XLA Modules" line splits device time by it.
+        def nbd_decode_step(params, cache, lens, last, active, key):
             logits, cache = forward_with_cache(
                 params, last[:, None], cache, lens, cfg, mesh=mesh,
                 ep_axis=ep_axis, row_mask=active)
-            nxt = _sample(logits[:, -1], temperature, key, top_k, top_p)
+            with jax.named_scope("sample"):
+                nxt = _sample(logits[:, -1], temperature, key, top_k,
+                              top_p)
             nxt = jnp.where(active, nxt, last)
             lens = lens + active.astype(lens.dtype)
             return cache, lens, nxt
 
-        return fn
+        return nbd_decode_step
 
     def _jit_step(self):
         # Donated cache: the decode step rewrites the pool in place.
@@ -351,7 +381,8 @@ class DecodeServer:
 
         cfg, mesh, ep_axis = self._cfg, self._mesh, self._ep_axis
 
-        def fn(params, pool, row_ids, prompt, start, length):
+        def nbd_prefill_paged(params, pool, row_ids, prompt, start,
+                              length):
             row = gather_row(pool, row_ids)
             s_pad = prompt.shape[1]
             mask = (jnp.arange(s_pad)[None, :] < length)
@@ -362,7 +393,7 @@ class DecodeServer:
             pool = scatter_row(pool, row, row_ids)
             return pool, logits[0, 0]                  # (V,)
 
-        jit_fn = jax.jit(fn, donate_argnums=(1,))
+        jit_fn = jax.jit(nbd_prefill_paged, donate_argnums=(1,))
 
         def wrapper(params, pool, prompt, slot, start, length):
             return jit_fn(params, pool,
@@ -383,7 +414,8 @@ class DecodeServer:
         bt = self._paged.block_tokens
         trash = self._paged.trash
 
-        def fn(params, pool, table, lens, last, active, key):
+        def nbd_decode_step_paged(params, pool, table, lens, last,
+                                  active, key):
             dense = gather_dense(pool, table)
             pos = lens                    # position this step writes
             dense, new_lens, nxt = step(params, dense, lens, last,
@@ -392,12 +424,13 @@ class DecodeServer:
                                 trash, bt)
             return pool, new_lens, nxt
 
-        return jax.jit(fn, donate_argnums=(1,))
+        return jax.jit(nbd_decode_step_paged, donate_argnums=(1,))
 
     def _jit_step_many(self):
         step = self._make_step()
 
-        def many(params, cache, lens, last, active, keys):
+        def nbd_decode_step_many(params, cache, lens, last, active,
+                                 keys):
             def body(carry, k):
                 cache, lens, last = carry
                 cache, lens, nxt = step(params, cache, lens, last,
@@ -408,7 +441,7 @@ class DecodeServer:
                 body, (cache, lens, last), keys)
             return cache, lens, last, toks        # toks (n, B)
 
-        return jax.jit(many, donate_argnums=(1,))
+        return jax.jit(nbd_decode_step_many, donate_argnums=(1,))
 
     def _jit_spec_many(self):
         from .speculative import spec_round
@@ -419,8 +452,9 @@ class DecodeServer:
         top_k, top_p = self._top_k, self._top_p
         T = self._T
 
-        def fn(params, draft_params, cache_t, lens_t, cache_d, lens_d,
-               last, active, keys):
+        def nbd_decode_step_spec_many(params, draft_params, cache_t,
+                                      lens_t, cache_d, lens_d, last,
+                                      active, keys):
             def body(carry, key):
                 cache_t, lens_t, cache_d, lens_d, last = carry
                 # Self-freeze before the cache could overflow: a round
@@ -446,7 +480,8 @@ class DecodeServer:
             return (cache_t, lens_t, cache_d, lens_d, last, cands,
                     n_accs, acts)
 
-        return jax.jit(fn, donate_argnums=(2, 4))
+        return jax.jit(nbd_decode_step_spec_many,
+                       donate_argnums=(2, 4))
 
     def _jit_spec_step(self):
         from .speculative import spec_round
@@ -456,8 +491,8 @@ class DecodeServer:
         mesh, ep_axis = self._mesh, self._ep_axis
         top_k, top_p = self._top_k, self._top_p
 
-        def fn(params, draft_params, cache_t, lens_t, cache_d, lens_d,
-               last, active, key):
+        def nbd_decode_step_spec(params, draft_params, cache_t, lens_t,
+                                 cache_d, lens_d, last, active, key):
             (cache_t, lens_t, cache_d, lens_d, key, cand, n_acc,
              new_last) = spec_round(
                 params, draft_params, cfg, dcfg, gamma=gamma,
@@ -469,7 +504,7 @@ class DecodeServer:
                 new_last
 
         # Both cache pools donated (updated in place each round).
-        return jax.jit(fn, donate_argnums=(2, 4))
+        return jax.jit(nbd_decode_step_spec, donate_argnums=(2, 4))
 
     def step_kernels(self) -> int:
         """Compiled Pallas (Mosaic) kernels in the decode-step program
@@ -512,8 +547,19 @@ class DecodeServer:
         self.prompts[rid] = prompt
         self.outputs[rid] = []
         self._pending.append((rid, prompt, max_new_tokens))
-        self._admit_pending()
+        self._admit_as_prefill(time.perf_counter())
         return rid
+
+    def _admit_as_prefill(self, t0: float) -> float:
+        """:meth:`_admit_pending` under the ``prefill`` phase (an
+        admission runs the prefill program and waits for its first
+        token), from the instant ``t0``; returns the instant it
+        ended."""
+        with obs_spans.phase("serve/step/prefill", self.tick):
+            self._admit_pending()
+        t1 = time.perf_counter()
+        self.phase_s["prefill"] += t1 - t0
+        return t1
 
     def _bucket(self, n: int) -> int:
         return -(-n // self._pad_to) * self._pad_to
@@ -838,51 +884,69 @@ class DecodeServer:
         {request_id: tokens emitted this step} — one token per step in
         plain mode, 1..gamma+1 in speculative mode.  Admits pending
         requests first, then advances at most one mid-prefill chunk
-        (interleave mode)."""
-        self._admit_pending()
-        self._advance_prefill()
+        (interleave mode).  Each phase (:data:`STEP_PHASES`) adds its
+        seconds to :attr:`phase_s`; the step's phases telescope."""
+        ph, tick = self.phase_s, self.tick
+        t0 = time.perf_counter()
+        with obs_spans.phase("serve/step/prefill", tick):
+            self._admit_pending()
+            self._advance_prefill()
+        t1 = time.perf_counter()
+        ph["prefill"] += t1 - t0
         if not self._slot_req:
             return {}
-        if self._draft_cfg is not None:
-            return self._spec_step()
-        if self._paged is not None:
-            self._cache, self._lens, nxt = self._step_fn(
-                self._params, self._cache,
-                self._paged.device_table(), self._lens, self._last,
-                self._active, self._sample_key())
-        else:
-            self._cache, self._lens, nxt = self._step_fn(
-                self._params, self._cache, self._lens, self._last,
-                self._active, self._sample_key())
-        self._last = nxt
-        toks = jax.device_get(nxt)
-        emitted: dict[int, list[int]] = {}
-        for slot, rid in list(self._slot_req.items()):
-            emitted[rid] = self._emit(slot, rid, [int(toks[slot])])
-        self._admit_pending()
+        with obs_spans.phase("serve/step/dispatch", tick):
+            # Returns before the chip is done.
+            out = self._dispatch_step()
+        t2 = time.perf_counter()
+        ph["dispatch"] += t2 - t1
+        with obs_spans.phase("serve/step/sync", tick):
+            # The host blocked on the chip: one fetch per step.
+            out = jax.device_get(out)
+        t3 = time.perf_counter()
+        ph["sync"] += t3 - t2
+        with obs_spans.phase("serve/step/emit", tick):
+            emitted: dict[int, list[int]] = {}
+            for slot, rid in list(self._slot_req.items()):
+                emitted[rid] = self._emit(slot, rid,
+                                          self._step_tokens(out, slot))
+        t4 = time.perf_counter()
+        ph["emit"] += t4 - t3
+        if self._pending:
+            self._admit_as_prefill(t4)
         return emitted
 
-    def _spec_step(self) -> dict[int, list[int]]:
-        """One speculative round: draft proposes gamma tokens per
-        slot, ONE target forward verifies all slots' candidates.
-        Per-slot acceptance lengths diverge freely; budget/EOS cut a
-        stream mid-round by truncating its emission and finishing the
-        slot (its device-side cache state beyond the cut is stale but
-        dies with the slot — re-admission prefills from 0)."""
-        (self._cache, self._lens, self._cache_d, self._lens_d,
-         cand, n_acc, new_last) = self._spec_fn(
-            self._params, self._draft_params, self._cache, self._lens,
-            self._cache_d, self._lens_d, self._last, self._active,
-            self._sample_key())
-        self._last = new_last
-        cand_h, acc_h = jax.device_get((cand, n_acc))
-        emitted: dict[int, list[int]] = {}
-        for slot, rid in list(self._slot_req.items()):
-            emitted[rid] = self._emit(
-                slot, rid,
-                [int(t) for t in cand_h[slot][: int(acc_h[slot]) + 1]])
-        self._admit_pending()
-        return emitted
+    def _dispatch_step(self):
+        """Enqueue one decode step (plain, paged or one speculative
+        round) and return the device arrays the host has to fetch."""
+        if self._draft_cfg is not None:
+            # Draft proposes gamma tokens per slot, ONE target forward
+            # verifies all slots' candidates.  Per-slot acceptance
+            # lengths diverge freely; budget/EOS cut a stream
+            # mid-round by truncating its emission and finishing the
+            # slot (its device-side cache state beyond the cut is
+            # stale but dies with the slot — re-admission prefills
+            # from 0).
+            (self._cache, self._lens, self._cache_d, self._lens_d,
+             cand, n_acc, self._last) = self._spec_fn(
+                self._params, self._draft_params, self._cache,
+                self._lens, self._cache_d, self._lens_d, self._last,
+                self._active, self._sample_key())
+            return cand, n_acc
+        table = (() if self._paged is None
+                 else (self._paged.device_table(),))
+        self._cache, self._lens, self._last = self._step_fn(
+            self._params, self._cache, *table, self._lens, self._last,
+            self._active, self._sample_key())
+        return self._last
+
+    def _step_tokens(self, out, slot: int) -> list[int]:
+        """One slot's tokens of a fetched step (see
+        :meth:`_dispatch_step`)."""
+        if self._draft_cfg is not None:
+            cand, n_acc = out
+            return [int(t) for t in cand[slot][: int(n_acc[slot]) + 1]]
+        return [int(out[slot])]
 
     def _emit(self, slot: int, rid: int, toks: list[int]) -> list[int]:
         """Budget-then-EOS truncation + bookkeeping for a multi-token

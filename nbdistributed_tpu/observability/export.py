@@ -19,6 +19,7 @@ the coordinator send span that caused it.
 from __future__ import annotations
 
 import json
+import uuid
 from typing import Any
 
 COORDINATOR_PID = -1
@@ -269,3 +270,52 @@ def save_trace(path: str, merged: dict) -> int:
     with open(path, "w") as f:
         json.dump(merged, f)
     return sum(1 for e in merged["traceEvents"] if e.get("ph") != "M")
+
+
+def fleet_trace(comm, action: str) -> dict:
+    """``%dist_trace <action>`` against one fleet, through its
+    coordinator-side ``comm``: the kernel's own after ``%dist_init``,
+    the gateway daemon's for a pool (whose serving driver, and so the
+    ``serve/tick/*`` spans, live in that process).  Returns what the
+    magic prints; ``save`` returns the merged Chrome trace under
+    ``merged``.  Keys are strings so the result crosses the wire."""
+    tr = comm.tracer
+    if action == "start":
+        tid = uuid.uuid4().hex[:16]
+        # Workers first (adopting the shared trace id), so the
+        # coordinator never stamps a request that lands on a
+        # not-yet-tracing worker.
+        comm.send_to_all("trace", {"action": "start", "trace_id": tid},
+                         timeout=30)
+        tr.start(trace_id=tid)
+        return {"trace_id": tid}
+    if action in ("stop", "status"):
+        out: dict = {"spans": tr.stop() if action == "stop"
+                     else len(tr),
+                     "enabled": tr.enabled, "trace_id": tr.trace_id}
+        try:
+            resps = comm.send_to_all("trace", {"action": action},
+                                     timeout=30)
+            out["ranks"] = {str(r): {"status": m.data.get("status"),
+                                     "spans": m.data.get("spans", 0)}
+                            for r, m in sorted(resps.items())}
+        except Exception as e:
+            out["ranks_error"] = str(e)
+        return out
+    # save: collect per-rank dumps + fault events, merge on the
+    # coordinator's timebase.
+    resps = comm.send_to_all("trace", {"action": "dump"}, timeout=120)
+    rank_dumps = {r: m.data.get("trace") or {} for r, m in resps.items()}
+    plan = comm.fault_plan()
+    cdump = tr.dump()
+    offsets = comm.clock.offsets()
+    merged = merge_trace(
+        cdump, rank_dumps, offsets,
+        coordinator_faults=plan.events() if plan is not None else [],
+        rank_faults={r: m.data.get("fault_events") or []
+                     for r, m in resps.items()})
+    return {"merged": merged, "spans": len(cdump["spans"]),
+            "ranks": {str(r): len(d.get("spans", []))
+                      for r, d in sorted(rank_dumps.items())},
+            "offsets_ms": {str(r): round(o * 1e3, 3)
+                           for r, o in sorted(offsets.items())}}

@@ -44,6 +44,17 @@ sample per decode tick (batch fill ratio, prefill-vs-decode token
 split, per-rank KV block occupancy / fragmentation / defer depth) into
 a time-series ring rendered by ``%dist_serve status`` and
 ``/latency.json``, and mirrored into gauges for scrapes.
+
+The third (ISSUE 25) is the tick's own account: for each of the last
+:data:`TICK_RING` ticks, the gateway's phases (place, roundtrip,
+apply, util) and the worker's (admit, prefill, dispatch, sync, emit,
+collect, and the turnaround it waited between two ticks), each side
+on its own ``perf_counter`` and each telescoping, joined by the
+tick's sequence number.  No cross-clock subtraction enters it:
+``wire`` is one gateway duration minus the sum of one worker's.
+``summary()["ticks"]`` reads it; a tick far slower than its
+neighbours is kept with every phase (``slow``), so a silence in a
+stream of tokens arrives with what both processes were doing.
 """
 
 from __future__ import annotations
@@ -59,6 +70,21 @@ SERVE_STAGES = ("admit", "queue", "kv_alloc", "prefill",
                 "decode_wait", "decode", "emit", "deliver")
 
 DEFAULT_RING = 256
+
+# The tick account: the last 64 ticks (at two ticks a second, the last
+# half minute), the last 8 slow ones, and what makes a tick slow: a
+# period over 3x the ring's median (once it holds 8) or over 1 s.
+TICK_RING = 64
+SLOW_TICKS = 8
+SLOW_FACTOR = 3.0
+SLOW_ABS_S = 1.0
+SLOW_MIN_RING = 8
+GATEWAY_PHASES = ("place", "roundtrip", "apply", "util")
+WORKER_PHASES = ("admit", "prefill", "dispatch", "sync", "emit",
+                 "collect")
+# Worker phases that are the host's alone.  ``prefill`` stays apart:
+# host work and a wait for the chip in one.
+HOST_PHASES = ("admit", "dispatch", "emit", "collect")
 
 
 def largest_free_run(free_ids) -> int:
@@ -124,6 +150,11 @@ class ServingObservatory:
         self._pending: dict[str, _PendingServe] = {}
         self._ring: deque = deque(maxlen=max(8, ring))
         self._util: deque = deque(maxlen=max(8, ring))
+        self._ticks: deque = deque(maxlen=TICK_RING)
+        self._slow: deque = deque(maxlen=SLOW_TICKS)
+        # Bytes of the dense KV view one decode step gathers on a
+        # rank (the worker's serve_open reports it; 0 = dense pool).
+        self.kv_view_bytes = 0
         self.completed = 0
         self.dropped = 0
 
@@ -385,6 +416,112 @@ class ServingObservatory:
                           rl).set(int(v["pending"]))
 
     # ------------------------------------------------------------------
+    # the tick's account (per serve_step round trip and rank)
+
+    def note_tick(self, seq: int, rank: int, gateway: dict,
+                  worker: dict, cmp=None, *,
+                  turnaround: float | None = None,
+                  idled: bool = False,
+                  t_wall: float | None = None) -> dict | None:
+        """One tick of one rank: the gateway's phase seconds, the
+        worker's (its ``tick["ph"]``), the worker's compile delta
+        ``[count, seconds]`` and the ``turnaround`` it waited since
+        its last reply (None on a server's first tick).  ``idled``
+        marks a tick that followed a wait for work: its turnaround is
+        no part of a decode period.  Returns the tick's record when
+        it was slow (kept under ``slow``; the caller writes it to the
+        flight recorder, once), else None."""
+        wk = {k: max(0.0, float(worker.get(k) or 0.0))
+              for k in WORKER_PHASES}
+        gw = {k: max(0.0, float(v)) for k, v in gateway.items()}
+        handler = sum(wk.values())
+        waited = (None if turnaround is None or idled
+                  else max(0.0, float(turnaround)))
+        n_cmp, s_cmp = cmp or (0, 0.0)
+        cmp = [int(n_cmp), float(s_cmp)]
+        rec = {
+            "seq": int(seq), "rank": int(rank),
+            "t_wall": round(self._now() if t_wall is None else t_wall,
+                            3),
+            "gw": gw, "wk": wk, "cmp": cmp, "idled": bool(idled),
+            "turnaround": (None if turnaround is None
+                           else max(0.0, float(turnaround))),
+            "handler": handler,
+            # What the wire and the two processes' queues took: one
+            # gateway duration minus one worker's, clamped like every
+            # stage here.
+            "wire": max(0.0, gw.get("roundtrip", 0.0) - handler),
+            # The decode period as the chip's owner sees it; None
+            # where this tick followed a wait for work or a fresh
+            # open.
+            "period": None if waited is None else waited + handler,
+        }
+        with self._lock:
+            periods = sorted(t["period"] for t in self._ticks
+                             if t["period"] is not None)
+            self._ticks.append(rec)
+            span = rec["period"] if rec["period"] is not None \
+                else handler
+            slow = span > SLOW_ABS_S or (
+                len(periods) >= SLOW_MIN_RING
+                and span > SLOW_FACTOR * percentile(periods, 0.50))
+            if not slow:
+                return None
+            view = {
+                "seq": rec["seq"], "rank": rec["rank"],
+                "t_wall": rec["t_wall"], "idled": rec["idled"],
+                "period_ms": _ms(span), "wire_ms": _ms(rec["wire"]),
+                "turnaround_ms": (None if rec["turnaround"] is None
+                                  else _ms(rec["turnaround"])),
+                "gateway_ms": {k: _ms(v) for k, v in gw.items()},
+                "worker_ms": {k: _ms(v) for k, v in wk.items()},
+                "cmp": cmp}
+            self._slow.append(view)
+        return view
+
+    def ticks_summary(self) -> dict:
+        """The ``ticks`` block of :meth:`summary`, over the ring: per
+        phase the p50 / p99 / mean of its per-tick sum, milliseconds."""
+        with self._lock:
+            ticks = list(self._ticks)
+            slow = list(self._slow)
+
+        def _stats(vals: list[float]) -> dict:
+            sv = sorted(vals)
+            return {"p50": _ms(percentile(sv, 0.50)),
+                    "p99": _ms(percentile(sv, 0.99)),
+                    "mean": _ms(sum(sv) / len(sv))}
+
+        out: dict = {"count": len(ticks),
+                     "compiles": sum(t["cmp"][0] for t in ticks),
+                     "compile_ms": _ms(sum(t["cmp"][1] for t in ticks)),
+                     "kv_view_bytes": self.kv_view_bytes,
+                     "slow": slow}
+        if not ticks:
+            return out
+        periods = [t["period"] for t in ticks
+                   if t["period"] is not None]
+        if periods:
+            st = _stats(periods)
+            out["period_ms"] = {"p50": st["p50"], "p99": st["p99"]}
+        for k in WORKER_PHASES:
+            out[k] = _stats([t["wk"][k] for t in ticks])
+        for k in GATEWAY_PHASES + ("journal", "notify"):
+            out[k] = _stats([t["gw"].get(k, 0.0) for t in ticks])
+        out["host"] = _stats([sum(t["wk"][k] for k in HOST_PHASES)
+                              for t in ticks])
+        out["gateway_self"] = _stats(
+            [sum(t["gw"].get(k, 0.0) for k in ("place", "apply",
+                                                "util"))
+             for t in ticks])
+        out["wire"] = _stats([t["wire"] for t in ticks])
+        waits = [t["turnaround"] for t in ticks
+                 if t["turnaround"] is not None and not t["idled"]]
+        if waits:
+            out["turnaround"] = _stats(waits)
+        return out
+
+    # ------------------------------------------------------------------
     # readers
 
     def records(self, last: int | None = None) -> list[dict]:
@@ -401,9 +538,11 @@ class ServingObservatory:
         """Percentile table over the ring, milliseconds:
         ``{"count", "dropped", "e2e_ms": {...}, "ttft_ms": {...},
         "tpot_ms": {...}, "stages": {stage: {p50,p95,p99,mean,
-        share}}}``."""
+        share}}, "ticks": ...}`` — ``ticks`` (:meth:`ticks_summary`)
+        is there with no finished request too."""
         recs = self.records()
-        out: dict = {"count": len(recs), "dropped": self.dropped}
+        out: dict = {"count": len(recs), "dropped": self.dropped,
+                     "ticks": self.ticks_summary()}
         if not recs:
             return out
 

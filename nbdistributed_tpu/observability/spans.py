@@ -25,6 +25,7 @@ tracks instead of producing an invalid stack.
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 import uuid
@@ -311,3 +312,69 @@ def maybe_span(name: str, kind: str = "", attrs: dict | None = None):
     if not t.enabled:
         return _NULL_CTX
     return t.span(name, kind, attrs=attrs)
+
+
+# ----------------------------------------------------------------------
+# named phases: one set of names for the tracer and the device profiler
+
+_ANNOTATION = None   # jax.profiler.TraceAnnotation, once jax is imported
+
+
+def _annotation():
+    """``jax.profiler.TraceAnnotation`` on a process that has imported
+    jax (the workers), else None: this module imports no jax, so the
+    coordinator and the gateway stay off it."""
+    global _ANNOTATION
+    if _ANNOTATION is None and sys.modules.get("jax") is not None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+class _PhaseCtx:
+    """A tracer span and a profiler annotation entered as one."""
+
+    __slots__ = ("_span", "_ann")
+
+    def __init__(self, span, ann):
+        self._span = span
+        self._ann = ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
+
+
+def phase(name: str, tick: int | None = None, wall: float | None = None):
+    """``with phase("serve/step/sync", tick=seq):`` — a named phase of
+    the serving tick, under the one name in every place it can be
+    read: a span in this process's tracer while ``%dist_trace`` is on
+    (child of the thread's current span), and, where jax is imported,
+    a ``jax.profiler.TraceAnnotation`` — a flag check in C++ until a
+    profile runs, then an event on the host plane of the ``.xplane.pb``
+    beside the device's own lines.  ``tick`` (the gateway's sequence
+    number) joins the two processes' spans and the device trace;
+    ``wall`` (``time.time()``, on a tick's first annotation) lays the
+    gateway's wall-clock spans beside the profile.  Tracer off and no
+    jax: the shared null context."""
+    ann = _ANNOTATION or _annotation()
+    t = _TRACER
+    if not t.enabled:
+        # The hot path (every phase of every serving tick): no dict.
+        if ann is None:
+            return _NULL_CTX
+        if wall is not None:
+            return ann(name, tick=tick, wall=wall)
+        return ann(name, tick=tick) if tick is not None else ann(name)
+    attrs = {}
+    if tick is not None:
+        attrs["tick"] = tick
+    if wall is not None:
+        attrs["wall"] = wall
+    span = t.span(name, "serve", attrs=attrs)
+    return span if ann is None else _PhaseCtx(span, ann(name, **attrs))

@@ -93,6 +93,15 @@ class _CompileWatch:
             return cls.count, round(cls.seconds, 3)
 
 
+def compile_snapshot() -> tuple[int, float]:
+    """``(backend compiles, their seconds)`` so far in this process
+    (installs the listener on first use): the serve_step handler
+    takes the delta across a tick, so a tick that compiled, or loaded
+    a program from the persistent cache, says so."""
+    _CompileWatch.install()
+    return _CompileWatch.snapshot()
+
+
 def compile_seconds() -> float:
     """Cumulative XLA backend-compile seconds observed in this process
     (0.0 until the listener is installed).  The latency observatory's

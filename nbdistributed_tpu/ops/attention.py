@@ -420,6 +420,7 @@ def _flash_forward(q, k, v, *, causal: bool, scale: float,
             ],
         ),
         interpret=interpret,
+        name="nbd_flash_fwd",
     )(_offsets_array(offsets), *args)
     return _unfold_q_gqa(out, B, Hkv, Sq), lse
 
@@ -697,6 +698,7 @@ def _flash_backward_folded(qt, got, delta, lse, k, v, *, B: int, Sq: int,
                                    lambda bh, qb, offs: (bh, 0, qb, 0)),
         ),
         interpret=interpret,
+        name="nbd_flash_bwd_dq",
     )(offs, *dq_args)
 
     dkv_base = functools.partial(
@@ -769,6 +771,7 @@ def _flash_backward_folded(qt, got, delta, lse, k, v, *, B: int, Sq: int,
             ],
         ),
         interpret=interpret,
+        name="nbd_flash_bwd_dkv",
     )(offs, *dkv_args)
 
     dq = _unfold_q_gqa(dq, B, Hkv, Sq)

@@ -226,6 +226,7 @@ def _decode_call(q, kc, vc, pos, *, block_k: int, scale: float,
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name="nbd_flash_decode",
     )(*args)
 
 

@@ -99,7 +99,7 @@ class _WorkerServe:
     """
 
     __slots__ = ("server", "rids", "sent", "tokens_total", "window",
-                 "pf_seen", "dc_seen")
+                 "pf_seen", "dc_seen", "t_reply")
 
     def __init__(self, server):
         self.server = server
@@ -112,6 +112,11 @@ class _WorkerServe:
         # observatory's prefill-vs-decode split, ISSUE 18).
         self.pf_seen = 0
         self.dc_seen = 0
+        # perf_counter at which the last serve_step reply was built:
+        # the next handler's entry minus this is the tick's
+        # ``turnaround``, the time the chip's owner waited for the
+        # gateway and the wire (None before the first tick).
+        self.t_reply: float | None = None
 
     def note_rate(self) -> None:
         now = time.monotonic()
@@ -1257,7 +1262,8 @@ class DistributedWorker:
         self._flight.record("serve_open", tenant=tenant,
                             max_batch=server._B, max_len=server._T)
         return msg.reply(data={"status": "open", "slots": server._B,
-                               "step_kernels": step_kernels},
+                               "step_kernels": step_kernels,
+                               "kv_view_bytes": server.kv_view_bytes},
                          rank=self.rank)
 
     def _handle_serve_step(self, msg: Message) -> Message:
@@ -1274,73 +1280,105 @@ class DistributedWorker:
                 data={"error": "no serving loop open on this rank "
                                "(serve_open first)"},
                 rank=self.rank)
+        # The tick's account (ISSUE 25): phases that telescope from
+        # this entry to the reply being built, on perf_counter, under
+        # the gateway's sequence number.  ``admit`` and ``collect``
+        # are this handler's own; prefill / dispatch / sync / emit are
+        # the server's, wherever in the handler they ran (an
+        # admission's prefill runs inside ``submit``).
+        t_in = time.perf_counter()
+        srv = st.server
+        seq = data.get("seq")
+        srv.tick = seq
+        ph0 = dict(srv.phase_s)
+        cmp0 = obs_telemetry.compile_snapshot()
         errors: dict[str, str] = {}
-        for a in data.get("admit") or ():
-            rid = a.get("rid")
-            try:
-                local = st.server.submit([int(t) for t in a["prompt"]],
-                                         int(a["max_new"]))
-            except Exception as e:
-                errors[rid] = f"{type(e).__name__}: {e}"
-                continue
-            st.rids[rid] = local
-            st.sent[rid] = 0
-        for rid in data.get("release") or ():
-            local = st.rids.pop(rid, None)
-            st.sent.pop(rid, None)
-            if local is not None:
+        with obs_spans.phase("serve/step/admit", seq, wall=time.time()):
+            for a in data.get("admit") or ():
+                rid = a.get("rid")
                 try:
-                    st.server.release(local)
-                except (KeyError, ValueError):
-                    # Still pending or mid-(chunked-)prefill: cancel
-                    # instead — frees its queue entry and KV blocks.
+                    local = srv.submit([int(t) for t in a["prompt"]],
+                                       int(a["max_new"]))
+                except Exception as e:
+                    errors[rid] = f"{type(e).__name__}: {e}"
+                    continue
+                st.rids[rid] = local
+                st.sent[rid] = 0
+            for rid in data.get("release") or ():
+                local = st.rids.pop(rid, None)
+                st.sent.pop(rid, None)
+                if local is not None:
                     try:
-                        st.server.cancel(local)
-                    except Exception:
-                        pass
+                        srv.release(local)
+                    except (KeyError, ValueError):
+                        # Still pending or mid-(chunked-)prefill:
+                        # cancel instead — frees its queue entry and
+                        # KV blocks.
+                        try:
+                            srv.cancel(local)
+                        except Exception:
+                            pass
         steps = max(0, int(data.get("steps") or 0))
         t_step0 = time.perf_counter()
+        admit_prefill_s = srv.phase_s["prefill"] - ph0["prefill"]
         for _ in range(steps):
-            if st.server.done():
+            if srv.done():
                 break
-            st.server.step()
+            srv.step()
         step_s = time.perf_counter() - t_step0
-        emitted: dict[str, dict] = {}
-        finished: list[str] = []
-        for rid, local in st.rids.items():
-            out = st.server.outputs.get(local, [])
-            o = st.sent.get(rid, 0)
-            if len(out) > o:
-                emitted[rid] = {"o": o, "t": [int(t) for t in out[o:]]}
-                st.tokens_total += len(out) - o
-                st.sent[rid] = len(out)
-            if local in st.server.finished:
-                finished.append(rid)
-        st.note_rate()
-        self._publish_serve_snap()
-        # Tick telemetry (ISSUE 18): compute seconds, the tick's
-        # prefill/decode token split (deltas of the server's
-        # cumulative counters), and per-request prefill progress —
-        # the gateway's serving observatory clock-corrects the wall
-        # stamp and attributes the compute to active requests.
-        pf_tot = getattr(st.server, "prefill_tokens_total", 0)
-        dc_tot = getattr(st.server, "decode_tokens_total", 0)
-        pf_d, dc_d = pf_tot - st.pf_seen, dc_tot - st.dc_seen
-        st.pf_seen, st.dc_seen = pf_tot, dc_tot
-        local_rids = {v: k for k, v in st.rids.items()}
-        pfp = {local_rids[lid]: [int(w), int(n)]
-               for lid, (w, n) in st.server.prefill_progress().items()
-               if lid in local_rids}
+        with obs_spans.phase("serve/step/collect", seq):
+            emitted: dict[str, dict] = {}
+            finished: list[str] = []
+            for rid, local in st.rids.items():
+                out = srv.outputs.get(local, [])
+                o = st.sent.get(rid, 0)
+                if len(out) > o:
+                    emitted[rid] = {"o": o,
+                                    "t": [int(t) for t in out[o:]]}
+                    st.tokens_total += len(out) - o
+                    st.sent[rid] = len(out)
+                if local in srv.finished:
+                    finished.append(rid)
+            st.note_rate()
+            self._publish_serve_snap()
+            # Tick telemetry (ISSUE 18): compute seconds, the tick's
+            # prefill/decode token split (deltas of the server's
+            # cumulative counters), and per-request prefill progress
+            # — the gateway's serving observatory clock-corrects the
+            # wall stamp and attributes the compute to active
+            # requests.
+            pf_tot = getattr(srv, "prefill_tokens_total", 0)
+            dc_tot = getattr(srv, "decode_tokens_total", 0)
+            pf_d, dc_d = pf_tot - st.pf_seen, dc_tot - st.dc_seen
+            st.pf_seen, st.dc_seen = pf_tot, dc_tot
+            local_rids = {v: k for k, v in st.rids.items()}
+            pfp = {local_rids[lid]: [int(w), int(n)]
+                   for lid, (w, n) in srv.prefill_progress().items()
+                   if lid in local_rids}
+        cmp1 = obs_telemetry.compile_snapshot()
+        t_out = time.perf_counter()
+        ph = {k: v - ph0[k] for k, v in srv.phase_s.items()}
+        ph["admit"] = (t_step0 - t_in) - admit_prefill_s
+        # What of the handler after the admissions lies in no phase
+        # of the server: building the reply, and the step loop's own
+        # few microseconds.  So the phases sum to t_out - t_in.
+        ph["collect"] = (t_out - t_step0) - (
+            sum(ph[k] for k in srv.phase_s) - admit_prefill_s)
+        tick = {"now": time.time(), "step_s": round(step_s, 6),
+                "pf": int(pf_d), "dc": int(dc_d), "seq": seq,
+                "ph": {k: round(v, 6) for k, v in ph.items()},
+                "cmp": [cmp1[0] - cmp0[0],
+                        round(cmp1[1] - cmp0[1], 3)]}
+        if st.t_reply is not None:
+            tick["turnaround"] = round(t_in - st.t_reply, 6)
+        st.t_reply = t_out
         return msg.reply(
             data={"status": "ok", "emitted": emitted,
                   "finished": finished, "errors": errors,
-                  "active": st.server.n_active,
-                  "slots": st.server._B,
-                  "pending": len(st.server._pending),
-                  "tick": {"now": time.time(),
-                           "step_s": round(step_s, 6),
-                           "pf": int(pf_d), "dc": int(dc_d)},
-                  "pfp": pfp},
+                  "active": srv.n_active,
+                  "slots": srv._B,
+                  "pending": len(srv._pending),
+                  "tick": tick, "pfp": pfp},
             rank=self.rank)
 
     def _handle_serve_close(self, msg: Message) -> Message:

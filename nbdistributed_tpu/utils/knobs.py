@@ -276,14 +276,6 @@ _ALL = (
        "Recent per-request serving stage records (and utilization "
        "samples) kept for %dist_serve lat and /latency.json.",
        "observability"),
-    _k("NBD_PERFWATCH_BASELINE", "BENCH_BASELINES.json", "str",
-       "nbd-perfwatch: baseline file the perf-regression sentinel "
-       "scores loadgen reports against (repo-root relative or "
-       "absolute).", "observability"),
-    _k("NBD_PERFWATCH_BAND_SCALE", "1", "float",
-       "nbd-perfwatch: uniform multiplier on every baseline noise "
-       "band (e.g. 2.0 on a noisy shared runner; bands themselves "
-       "are pinned in the baseline file).", "observability"),
     _k("NBD_METRICS_PORT", "0", "int",
        "Live scrape endpoint port (GET /metrics Prometheus text, "
        "/healthz, /latency.json) served by the coordinator or "

@@ -381,3 +381,204 @@ def test_fault_plan_records_timestamped_events():
     assert evs[0]["actions"] == ["drop"]
     assert evs[0]["kind"] == "execute"
     assert evs[0]["ts"] > 0
+
+
+# ---------------------------------------------------------------------
+# named phases of the serving tick (ISSUE 25)
+
+
+def test_phase_is_the_null_context_with_tracer_off_and_no_jax(
+        monkeypatch):
+    import sys
+
+    from nbdistributed_tpu.observability import spans
+    assert not spans.tracer().enabled
+    # a process that never imported jax: the gateway, the kernel
+    monkeypatch.setattr(spans, "_ANNOTATION", None)
+    monkeypatch.setitem(sys.modules, "jax", None)
+    assert spans.phase("serve/tick/place", 3) is spans._NULL_CTX
+    assert spans.phase("serve/tick", 3, wall=1.5) is spans._NULL_CTX
+    assert spans._ANNOTATION is None        # and it imported nothing
+
+
+def test_phase_is_the_bare_annotation_where_jax_is_imported():
+    import jax.profiler
+
+    from nbdistributed_tpu.observability import spans
+    assert not spans.tracer().enabled
+    for args in (("serve/step/sync", 7), ("serve/step/sync",),
+                 ("serve/step/admit", 7, 12.5)):
+        ctx = spans.phase(*args)
+        assert type(ctx) is jax.profiler.TraceAnnotation
+        with ctx:                   # no profile runs: a flag check
+            pass
+
+
+def test_phase_records_tick_wall_and_parent_when_the_tracer_is_on(
+        monkeypatch):
+    from nbdistributed_tpu.observability import spans
+    tr = Tracer()
+    tr.start()
+    monkeypatch.setattr(spans, "_TRACER", tr)
+    with spans.phase("serve/tick", 9) as outer:
+        with spans.phase("serve/tick/place", 9, wall=5.0) as inner:
+            assert inner.parent_id == outer.span_id
+    by_name = {d["name"]: d for d in tr.dump()["spans"]}
+    assert by_name["serve/tick"]["attrs"] == {"tick": 9}
+    assert by_name["serve/tick/place"]["attrs"] == {"tick": 9,
+                                                    "wall": 5.0}
+    assert by_name["serve/tick/place"]["kind"] == "serve"
+    assert "parent_id" not in by_name["serve/tick"]
+
+
+def test_tick_spans_chain_from_the_gateway_into_the_worker(
+        tmp_path, monkeypatch):
+    """gateway ``serve/tick/roundtrip`` -> the worker's
+    ``handle/serve_step`` (through the ``tr`` header, as the worker's
+    loop opens it) -> ``serve/step/*``, all under one ``tick``."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from nbdistributed_tpu.gateway.serving import ServingManager
+    from nbdistributed_tpu.messaging import Message
+    from nbdistributed_tpu.models import init_params, tiny_config
+    from nbdistributed_tpu.models.serving import DecodeServer
+    from nbdistributed_tpu.observability import spans
+    from nbdistributed_tpu.runtime import worker as worker_mod
+
+    tr = Tracer()
+    tr.start()
+    monkeypatch.setattr(spans, "_TRACER", tr)
+    cfg = tiny_config(dtype=jnp.float32, use_flash=False, n_layers=1)
+    server = DecodeServer(init_params(jax.random.PRNGKey(0), cfg), cfg,
+                          max_batch=2, max_len=32, pad_to=4,
+                          kv_block_tokens=8)
+    w = object.__new__(worker_mod.DistributedWorker)
+    w.rank, w._serve_snap = 0, None
+    w._serve = {"serve": worker_mod._WorkerServe(server)}
+
+    class BridgeComm:
+        """One in-process worker behind the comm's surface; does what
+        the coordinator's transmit and the worker's loop do to a
+        request's trace context."""
+        num_workers = 1
+        tracer = tr
+
+        def dead_ranks(self):
+            return set()
+
+        def post(self, ranks, msg_type, data=None):
+            pass
+
+        def send_to_ranks(self, ranks, msg_type, data=None, **kw):
+            if msg_type != "serve_step":
+                return {0: Message(msg_type="response",
+                                   data={"status": "open"})}
+            msg = Message(msg_type=msg_type, data=data)
+            msg.trace = tr.context()
+            span = tr.begin("handle/serve_step", kind="worker",
+                            trace_id=msg.trace["tid"],
+                            parent_id=msg.trace["sid"])
+            with tr.activate(span):
+                reply = w._handle_serve_step(msg)
+            tr.end(span)
+            return {0: reply}
+
+    mgr = ServingManager(BridgeComm(), str(tmp_path), world_size=1,
+                         max_batch=2, max_len=32, pad_to=4, steps=2,
+                         kv_block_tokens=8, step_timeout=30.0)
+    mgr.start()
+    try:
+        rid = mgr.submit("t1", [5, 9, 2], 6)["rid"]
+        deadline = time.monotonic() + 60
+        while not mgr.result(rid)["done"]:
+            assert time.monotonic() < deadline
+            time.sleep(0.02)
+    finally:
+        mgr.stop()
+    sp = tr.dump()["spans"]
+    by_id = {d["span_id"]: d for d in sp}
+    handles = [d for d in sp if d["name"] == "handle/serve_step"]
+    assert len(handles) >= 2
+    for h in handles:
+        rt = by_id[h["parent_id"]]
+        assert rt["name"] == "serve/tick/roundtrip"
+        tick = rt["attrs"]["tick"]
+        assert by_id[rt["parent_id"]]["name"] == "serve/tick"
+        assert by_id[rt["parent_id"]]["attrs"]["tick"] == tick
+        kids = [d for d in sp if d.get("parent_id") == h["span_id"]]
+        assert {d["name"] for d in kids} >= {
+            "serve/step/admit", "serve/step/collect",
+            "serve/step/prefill"}
+        assert all(d["name"].startswith("serve/step/") for d in kids)
+        assert {d["attrs"]["tick"] for d in kids} == {tick}
+        assert sum("wall" in d["attrs"] for d in kids) == 1
+    step_names = {d["name"] for d in sp
+                  if d["name"].startswith("serve/step/")}
+    assert step_names == {"serve/step/" + p for p in (
+        "admit", "prefill", "dispatch", "sync", "emit", "collect")}
+    tick_names = {d["name"] for d in sp
+                  if d["name"].startswith("serve/tick")}
+    assert tick_names == {"serve/tick"} | {"serve/tick/" + p for p in (
+        "place", "roundtrip", "apply", "util")}
+    # one set of names, three ways: the same ticks are in the ring
+    tk = mgr.describe()["lat"]["summary"]["ticks"]
+    assert tk["count"] == len(handles) and tk["sync"]["mean"] > 0
+
+
+def test_fleet_trace_drives_the_tracer_and_every_rank():
+    """``%dist_trace`` for a fleet or a pool is one function over the
+    coordinator-side comm; its results cross the wire (string keys)."""
+    import types
+
+    from nbdistributed_tpu.observability.export import fleet_trace
+
+    class Comm:
+        def __init__(self):
+            self.tracer = Tracer()
+            self.sent = []
+            self.clock = types.SimpleNamespace(
+                offsets=lambda: {0: 0.001, 1: -0.002})
+
+        def fault_plan(self):
+            return None
+
+        def send_to_all(self, msg_type, data, timeout=None):
+            self.sent.append((msg_type, dict(data)))
+            reply = {"status": "tracing", "spans": 2}
+            if data["action"] == "dump":
+                reply = {"trace": {"trace_id": "t", "spans": [
+                    {"name": "serve/step/sync", "kind": "serve",
+                     "tid": 0, "trace_id": "t", "span_id": "s",
+                     "t0": 10.0, "dur": 0.5,
+                     "attrs": {"tick": 3}}],
+                    "instants": [], "dropped": 0}}
+            return {r: types.SimpleNamespace(data=reply)
+                    for r in (0, 1)}
+
+    comm = Comm()
+    tid = fleet_trace(comm, "start")["trace_id"]
+    assert comm.tracer.enabled and comm.tracer.trace_id == tid
+    assert comm.sent[0] == ("trace", {"action": "start",
+                                      "trace_id": tid})
+    with comm.tracer.span("serve/tick/roundtrip", attrs={"tick": 3}):
+        pass
+    st = fleet_trace(comm, "status")
+    assert st["enabled"] and st["spans"] == 1
+    assert st["ranks"] == {"0": {"status": "tracing", "spans": 2},
+                           "1": {"status": "tracing", "spans": 2}}
+    saved = fleet_trace(comm, "save")
+    assert saved["spans"] == 1 and saved["ranks"] == {"0": 1, "1": 1}
+    assert saved["offsets_ms"] == {"0": 1.0, "1": -2.0}
+    json.dumps(saved)                           # crosses the wire
+    names = [e["name"] for e in saved["merged"]["traceEvents"]
+             if e["ph"] == "X"]
+    assert names.count("serve/step/sync") == 2
+    assert "serve/tick/roundtrip" in names
+    ticks = [e["args"].get("tick") for e in
+             saved["merged"]["traceEvents"] if e["ph"] == "X"]
+    assert ticks == [3, 3, 3]
+    out = fleet_trace(comm, "stop")
+    assert not comm.tracer.enabled and out["spans"] == 1

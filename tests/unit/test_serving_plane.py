@@ -124,6 +124,14 @@ class FakeComm:
         self.overlap_next_reply = 0   # test hook: re-send n tokens
         self.fail_next = 0            # test hook: raise TimeoutError
         self.steps_seen: list[dict] = []
+        # ISSUE 25: a worker that speaks the tick account echoes the
+        # payload's seq with its phases; seqs in slow_seqs report a
+        # sync of slow_sync_s (a stalled chip), serve_open the view.
+        self.tick_ph: dict | None = None
+        self.slow_seqs: set[int] = set()
+        self.slow_sync_s = 5.0
+        self.kv_view_bytes = 0
+        self._ticked: set[int] = set()
 
     # --- the surface ServingManager uses ------------------------------
 
@@ -152,8 +160,10 @@ class FakeComm:
                 return {rank: types.SimpleNamespace(
                     data={"error": "injected serve_open failure"})}
             self._srv[rank] = {}
+            self._ticked.discard(rank)
             return {rank: types.SimpleNamespace(
-                data={"status": "open"})}
+                data={"status": "open",
+                      "kv_view_bytes": self.kv_view_bytes})}
         if msg_type == "serve_close":
             self._srv.pop(rank, None)
             return {rank: types.SimpleNamespace(data={"status": "ok"})}
@@ -205,6 +215,16 @@ class FakeComm:
         reply = {"status": "ok", "emitted": emitted,
                  "finished": finished, "errors": {},
                  "active": len(srv), "slots": 8, "pending": 0}
+        if self.tick_ph is not None:
+            seq = data.get("seq")
+            ph = dict(self.tick_ph)
+            if seq in self.slow_seqs:
+                ph["sync"] = self.slow_sync_s
+            reply["tick"] = {"seq": seq, "ph": ph, "cmp": [0, 0.0],
+                             "pf": 0, "dc": len(emitted)}
+            if rank in self._ticked:
+                reply["tick"]["turnaround"] = 0.001
+            self._ticked.add(rank)
         if msg_id is not None:
             self._replay[msg_id] = reply
         return {rank: types.SimpleNamespace(data=reply)}
@@ -553,3 +573,108 @@ def test_slo_queue_wait_counts_first_placement_only(tmp_path):
     finally:
         mgr.stop()
     assert qcount() - base == 1
+
+
+# ----------------------------------------------------------------------
+# the tick's account, gateway side (ISSUE 25)
+
+WORKER_PH = {"admit": 0.0001, "prefill": 0.0, "dispatch": 0.0002,
+             "sync": 0.0004, "emit": 0.0001, "collect": 0.0001}
+
+
+class FakeFlight:
+    def __init__(self):
+        self.events: list[tuple[str, dict]] = []
+
+    def record(self, event, **kw):
+        self.events.append((event, kw))
+
+
+def test_tick_seq_is_monotone_rides_the_payload_and_comes_back(tmp_path):
+    comm = FakeComm(per_tick=1)
+    comm.tick_ph = dict(WORKER_PH)
+    comm.kv_view_bytes = 4096
+    mgr, _d, _n = make_mgr(tmp_path, comm)
+    mgr.start()
+    try:
+        rids = [mgr.submit("t1", [5, 9, 2], 6)["rid"],
+                mgr.submit("t1", [7, 1], 4)["rid"]]
+        wait_done(mgr, rids)
+        st = mgr.describe()
+    finally:
+        mgr.stop()
+    seqs = [p["seq"] for p in comm.steps_seen]
+    assert seqs == sorted(set(seqs)) and seqs[0] >= 1, seqs
+    assert all(p["steps"] == 2 for p in comm.steps_seen)
+    tk = st["lat"]["summary"]["ticks"]
+    # every step that came back under its seq is in the ring
+    assert tk["count"] == min(len(seqs), 64)
+    assert tk["kv_view_bytes"] == 4096 and tk["compiles"] == 0
+    assert tk["sync"]["p50"] == 0.4 and tk["host"]["p50"] == 0.5
+    # the gateway's own phases were timed, and the wire is what the
+    # round trip holds beyond the worker's handler: never negative
+    for k in ("place", "roundtrip", "apply", "util", "journal",
+              "notify", "gateway_self", "wire"):
+        assert tk[k]["p50"] >= 0 and tk[k]["p99"] >= tk[k]["p50"], k
+    assert tk["roundtrip"]["mean"] > 0 and tk["apply"]["mean"] > 0
+    assert tk["journal"]["mean"] > 0
+    assert tk["apply"]["mean"] >= tk["journal"]["mean"]
+    # the first tick followed a wait for work and a fresh open: it is
+    # in the ring and out of the period
+    assert "period_ms" in tk and tk["slow"] == []
+
+
+def test_a_worker_without_the_account_leaves_the_ring_empty(tmp_path):
+    comm = FakeComm()                   # replies carry no tick block
+    mgr, _d, _n = make_mgr(tmp_path, comm)
+    mgr.start()
+    try:
+        wait_done(mgr, [mgr.submit("t1", [5, 9, 2], 5)["rid"]])
+        tk = mgr.describe()["lat"]["summary"]["ticks"]
+    finally:
+        mgr.stop()
+    assert tk["count"] == 0 and tk["slow"] == []
+    assert all("seq" in p for p in comm.steps_seen)
+
+
+def test_forced_slow_tick_is_kept_and_written_to_the_flight_once(
+        tmp_path):
+    comm = FakeComm(per_tick=1)
+    comm.tick_ph = dict(WORKER_PH)
+    flight = FakeFlight()
+    mgr, _d, _n = make_mgr(tmp_path, comm, flight=flight)
+    mgr.start()
+    try:
+        wait_done(mgr, [mgr.submit("t1", [5, 9, 2], 24)["rid"]])
+        nxt = mgr._seq + 2
+        comm.slow_seqs = {nxt}          # a tick 3x over any median
+        wait_done(mgr, [mgr.submit("t1", [7, 1], 8)["rid"]])
+        st = mgr.describe()
+        mgr.describe()                  # reading twice writes nothing
+    finally:
+        mgr.stop()
+    slow = st["lat"]["summary"]["ticks"]["slow"]
+    assert [t["seq"] for t in slow] == [nxt]
+    (t,) = slow
+    assert t["worker_ms"]["sync"] == 5000.0 and t["period_ms"] > 5000.0
+    assert set(t["worker_ms"]) == set(WORKER_PH)
+    assert {"place", "roundtrip", "apply", "util"} <= set(t["gateway_ms"])
+    written = [kw for ev, kw in flight.events if ev == "serve_slow_tick"]
+    assert len(written) == 1 and written[0] == t
+
+
+def test_serve_status_renders_the_tick_line(tmp_path, capsys):
+    from nbdistributed_tpu.magics.magic import DistributedMagics
+    comm = FakeComm(per_tick=1)
+    comm.tick_ph = dict(WORKER_PH)
+    mgr, _d, _n = make_mgr(tmp_path, comm)
+    mgr.start()
+    try:
+        wait_done(mgr, [mgr.submit("t1", [5, 9, 2], 6)["rid"]])
+        st = mgr.describe()
+    finally:
+        mgr.stop()
+    DistributedMagics._render_serve_status(st)
+    out = capsys.readouterr().out
+    assert "ticks: period p50/p99" in out and "sync 0.4" in out
+    assert "compiles 0" in out and "slow 0" in out

@@ -1,16 +1,17 @@
-"""Unit tests for the serving observatory + perf-regression sentinel
-(ISSUE 18): the telescoping stage decomposition (sum == e2e and TTFT
-== admit+queue+kv_alloc+prefill EXACTLY, by construction), the
-clock-corrected TPOT clamp, the KV fragmentation scan, the utilization
-ring/gauges, the {tenant,rank} series-retirement pin, the autoscaler
-audit record shape, and perfbase's band scoring."""
+"""Unit tests for the serving observatory (ISSUE 18): the telescoping
+stage decomposition (sum == e2e and TTFT == admit+queue+kv_alloc+prefill
+EXACTLY, by construction), the clock-corrected TPOT clamp, the KV
+fragmentation scan, the utilization ring/gauges, the {tenant,rank}
+series-retirement pin, the autoscaler audit record shape; and the
+tick's account (ISSUE 25): the ring of 64, phases that sum to the
+handler's time, the clamped wire, the slow-tick rule."""
 
 import math
 
 import pytest
 
 from nbdistributed_tpu.observability import metrics as obs_metrics
-from nbdistributed_tpu.observability import perfbase
+from nbdistributed_tpu.observability import servingobs
 from nbdistributed_tpu.observability.servingobs import (
     SERVE_STAGES, ServingObservatory, format_serve_stage_table,
     format_serve_waterfall, largest_free_run)
@@ -255,102 +256,120 @@ def test_tenant_eviction_retires_rank_labeled_series():
 
 
 # ---------------------------------------------------------------------
-# perfbase: the regression-scoring contract
+# the tick's account (ISSUE 25): ring, telescoping, wire, slow ticks
+
+GW = {"place": 0.001, "roundtrip": 0.5, "apply": 0.002, "util": 0.001,
+      "journal": 0.0005, "notify": 0.0003}
+WK = {"admit": 0.001, "prefill": 0.0, "dispatch": 0.02, "sync": 0.42,
+      "emit": 0.01, "collect": 0.002}
 
 
-REPORT = {
-    "offered": 20, "completed": 18, "shed_rate": 0.1,
-    "tokens_per_s": 10.0,
-    "client": {"ttft_ms": {"p50": 100.0, "p99": 300.0},
-               "tpot_ms": {"p50": 20.0, "p99": 50.0},
-               "e2e_ms": {"p50": 400.0, "p99": 900.0}},
-}
-STAGES = {"stages": {"decode": {"p95": 30.0}, "queue": {"p95": 80.0}}}
+def _tick(obs, seq, *, gw=None, wk=None, cmp=(0, 0.0), turnaround=0.01,
+          idled=False, rank=0):
+    return obs.note_tick(seq, rank, dict(GW, **(gw or {})),
+                         dict(WK, **(wk or {})), list(cmp),
+                         turnaround=turnaround, idled=idled)
 
 
-def _baseline():
-    return perfbase.make_baseline(
-        perfbase.extract_metrics(REPORT, STAGES), source="test")
+def test_ticks_block_present_with_no_finished_request():
+    obs = ServingObservatory(now=FakeClock())
+    tk = obs.summary()["ticks"]
+    assert tk == {"count": 0, "compiles": 0, "compile_ms": 0.0,
+                  "kv_view_bytes": 0, "slow": []}
+    _tick(obs, 1)
+    s = obs.summary()
+    assert s["count"] == 0 and "stages" not in s
+    assert s["ticks"]["count"] == 1
+    assert s["ticks"]["sync"]["p50"] == 420.0
 
 
-def test_extract_and_seed_roundtrip(tmp_path):
-    m = perfbase.extract_metrics(REPORT, STAGES)
-    assert m["tokens_per_s"] == 10.0
-    assert m["stage_queue_ms_p95"] == 80.0
-    doc = {"baselines": {"serving_smoke": _baseline()}}
-    path = str(tmp_path / "b.json")
-    perfbase.save_baselines(path, doc)
-    back = perfbase.load_baselines(path)
-    assert back["schema"] == perfbase.BASELINE_SCHEMA_VERSION
-    entry = back["baselines"]["serving_smoke"]
-    assert entry["metrics"]["tokens_per_s"]["direction"] == "higher"
+def test_tick_ring_keeps_64():
+    obs = ServingObservatory(now=FakeClock())
+    for seq in range(1, 101):
+        _tick(obs, seq, cmp=(1, 0.5) if seq <= 36 else (0, 0.0))
+    tk = obs.ticks_summary()
+    assert servingobs.TICK_RING == 64 and tk["count"] == 64
+    # ticks 37..100 remain: the compiles of the first 36 have left
+    assert tk["compiles"] == 0 and tk["compile_ms"] == 0.0
+    _tick(obs, 101, cmp=(2, 0.25))
+    tk = obs.ticks_summary()
+    assert tk["compiles"] == 2 and tk["compile_ms"] == 250.0
 
 
-def test_score_clean_run_passes():
-    res = perfbase.score(_baseline(),
-                         perfbase.extract_metrics(REPORT, STAGES))
-    assert res["pass"] and res["regressions"] == []
+def test_worker_phases_sum_to_the_handler_time_exactly():
+    """The handler's time IS the sum of its phases (they telescope on
+    the worker's clock); the period adds the turnaround, host leaves
+    prefill and sync out, gateway_self leaves the round trip out."""
+    obs = ServingObservatory(now=FakeClock())
+    wk = {"admit": 0.25, "prefill": 0.5, "dispatch": 0.125,
+          "sync": 1.0, "emit": 0.0625, "collect": 0.03125}
+    _tick(obs, 1, wk=wk, gw={"roundtrip": 2.0}, turnaround=0.5)
+    (rec,) = obs._ticks
+    assert rec["handler"] == sum(wk.values()) == 1.96875
+    assert rec["period"] == 0.5 + 1.96875
+    assert rec["wire"] == 2.0 - 1.96875
+    tk = obs.ticks_summary()
+    assert tk["period_ms"]["p50"] == 2468.75
+    assert tk["host"]["p50"] == 468.75          # admit+dispatch+emit+collect
+    assert tk["gateway_self"]["p50"] == 4.0     # place+apply+util
+    for k in servingobs.WORKER_PHASES + servingobs.GATEWAY_PHASES:
+        assert set(tk[k]) == {"p50", "p99", "mean"}
 
 
-def test_score_catches_the_acceptance_regressions():
-    """The ISSUE 18 pins: tokens/s -30% and p99 TTFT +3x must trip."""
-    import copy
-    bad = copy.deepcopy(REPORT)
-    bad["tokens_per_s"] = 7.0                      # -30%
-    bad["client"]["ttft_ms"]["p99"] = 900.0        # 3x
-    res = perfbase.score(_baseline(),
-                         perfbase.extract_metrics(bad, STAGES))
-    assert not res["pass"]
-    assert set(res["regressions"]) == {"tokens_per_s", "ttft_ms_p99"}
-    assert res["metrics"]["tokens_per_s"]["verdict"] == "regressed"
-    # Improvements in the good direction never fail.
-    good = copy.deepcopy(REPORT)
-    good["tokens_per_s"] = 30.0
-    good["client"]["ttft_ms"]["p99"] = 10.0
-    res = perfbase.score(_baseline(),
-                         perfbase.extract_metrics(good, STAGES))
-    assert res["pass"]
-    assert res["metrics"]["tokens_per_s"]["verdict"] == "improved"
+def test_wire_is_clamped_at_zero():
+    obs = ServingObservatory(now=FakeClock())
+    # a handler that (by rounding, or a redelivered reply) reads
+    # longer than the round trip around it
+    _tick(obs, 1, gw={"roundtrip": 0.4})
+    assert obs._ticks[0]["wire"] == 0.0
+    assert obs.ticks_summary()["wire"]["p50"] == 0.0
 
 
-def test_score_missing_metric_fails():
-    m = perfbase.extract_metrics(REPORT, STAGES)
-    del m["tokens_per_s"]
-    res = perfbase.score(_baseline(), m)
-    assert not res["pass"]
-    assert res["metrics"]["tokens_per_s"]["verdict"] == "missing"
+def test_idled_and_first_ticks_stay_out_of_the_period():
+    obs = ServingObservatory(now=FakeClock())
+    _tick(obs, 1, turnaround=None)              # a server's first tick
+    _tick(obs, 2, turnaround=30.0, idled=True)  # waited for work
+    assert "period_ms" not in obs.ticks_summary()
+    assert "turnaround" not in obs.ticks_summary()
+    _tick(obs, 3, turnaround=0.047)
+    tk = obs.ticks_summary()
+    assert tk["period_ms"] == {"p50": 500.0, "p99": 500.0}
+    assert tk["turnaround"]["p50"] == 47.0
+    assert tk["count"] == 3 and tk["slow"] == []
 
 
-def test_band_scale_widens_uniformly():
-    import copy
-    bad = copy.deepcopy(REPORT)
-    bad["tokens_per_s"] = 7.0                      # -30%, band 25%
-    m = perfbase.extract_metrics(bad, STAGES)
-    assert not perfbase.score(_baseline(), m)["pass"]
-    assert perfbase.score(_baseline(), m, band_scale=2.0)["pass"]
+@pytest.mark.parametrize("base_sync, sync_s, warm, slow", [
+    (0.1, 0.35, 20, False),   # 0.393 s against 3 x 0.143 s = 0.429 s
+    (0.1, 0.40, 20, True),    # 0.443 s: over 3x the median
+    (0.42, 0.90, 20, False),  # 0.943 s: under 1 s and 3 x 0.463 s
+    (0.42, 0.98, 20, True),   # 1.023 s: over 1 s, under 3x the median
+    (0.1, 0.40, 3, False),    # too few ticks for a median
+    (0.1, 0.98, 3, True),     # the 1 s rule needs no ring
+])
+def test_slow_tick_rule(base_sync, sync_s, warm, slow):
+    obs = ServingObservatory(now=FakeClock())
+    for seq in range(warm):
+        assert _tick(obs, seq, wk={"sync": base_sync}) is None
+    got = _tick(obs, 99, wk={"sync": sync_s}, cmp=(1, 0.3))
+    assert (got is not None) == slow
+    kept = obs.ticks_summary()["slow"]
+    assert len(kept) == (1 if slow else 0)
+    if slow:
+        assert got == kept[0]
+        assert got["seq"] == 99 and got["cmp"] == [1, 0.3]
+        assert got["worker_ms"]["sync"] == sync_s * 1e3
+        assert set(got["worker_ms"]) == set(servingobs.WORKER_PHASES)
+        assert set(got["gateway_ms"]) >= set(servingobs.GATEWAY_PHASES)
+        assert got["period_ms"] == pytest.approx(
+            (0.01 + sum(dict(WK, sync=sync_s).values())) * 1e3)
 
 
-def test_shed_rate_band_is_absolute():
-    import copy
-    bad = copy.deepcopy(REPORT)
-    bad["shed_rate"] = 0.35                        # +0.25 absolute
-    res = perfbase.score(_baseline(),
-                         perfbase.extract_metrics(bad, STAGES))
-    assert "shed_rate" in res["regressions"]
-    ok = copy.deepcopy(REPORT)
-    ok["shed_rate"] = 0.15                         # +0.05 < 0.10 band
-    assert perfbase.score(
-        _baseline(), perfbase.extract_metrics(ok, STAGES))["pass"]
-
-
-def test_format_diff_names_regressions():
-    import copy
-    bad = copy.deepcopy(REPORT)
-    bad["tokens_per_s"] = 1.0
-    res = perfbase.score(_baseline(),
-                         perfbase.extract_metrics(bad, STAGES))
-    txt = perfbase.format_diff(res)
-    assert "REGRESSION" in txt and "tokens_per_s" in txt
-    assert "PASS" in perfbase.format_diff(
-        perfbase.score(_baseline(),
-                       perfbase.extract_metrics(REPORT, STAGES)))
+def test_slow_ticks_kept_to_eight_under_a_fake_clock():
+    clk = FakeClock(1000.0)
+    obs = ServingObservatory(now=clk)
+    for seq in range(12):
+        clk.advance(2.0)
+        assert _tick(obs, seq, wk={"sync": 1.5}) is not None
+    slow = obs.ticks_summary()["slow"]
+    assert [t["seq"] for t in slow] == list(range(4, 12))
+    assert slow[-1]["t_wall"] == 1024.0 and slow[0]["t_wall"] == 1010.0
