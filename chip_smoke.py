@@ -424,8 +424,8 @@ class Smoke:
             raise PhaseFailed(f"decode ranks that served: {served_by}, "
                               f"expected all of 0..{self.n - 1}")
         # What each decode rank's own server reported at serve_open:
-        # Mosaic calls in the program its step() runs (paged gather,
-        # row-masked step, scatter), lowered at the live pool's shapes.
+        # Mosaic calls in the program its step() runs (the row-masked
+        # step over the paged pool), lowered at the live pool's shapes.
         step_kernels = {int(r): v.get("step_kernels")
                         for r, v in (st.get("ranks") or {}).items()}
         if sorted(step_kernels) != served_by \

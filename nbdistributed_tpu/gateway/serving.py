@@ -1388,7 +1388,8 @@ class ServingManager:
                 continue        # refused, or a worker without the account
             slow = self.obs.note_tick(
                 seq, rank, gw, tk.get("ph") or {}, tk.get("cmp"),
-                turnaround=tk.get("turnaround"), idled=idled)
+                turnaround=tk.get("turnaround"), idled=idled,
+                kv_read=tk.get("kvr"))
             if slow is not None:
                 self._record("serve_slow_tick", **slow)
         if lost:

@@ -227,6 +227,82 @@ def _can_flash_decode_on_mesh(mesh, B, H, Hkv, T=None):
             and (T is None or T % sp_n == 0))
 
 
+def _attend(q, kc, vc, ks, vs, positions, scale, cfg, mesh):
+    """Attention of the new tokens' queries over ONE layer's dense
+    ``(B, Hkv, T, Dh)`` K/V (``ks``/``vs``: the scales of an int8
+    cache, else None), by the path the configuration and the mesh
+    allow.  q: (B, S, H, Dh) -> (B, S, H*Dh)."""
+    B, S, H, Dh = q.shape
+    window = getattr(cfg, "sliding_window", None)
+    if S == 1 and cfg.use_flash and mesh is None:
+        # Decode hot path: fused Pallas kernel streams the cache
+        # once with the masked online softmax (ops/decode.py); an
+        # int8 cache streams at half width with its scales
+        # commuted through the matmuls.
+        from ..ops.decode import flash_decode_attention
+        return flash_decode_attention(
+            q[:, 0], kc, vc, positions[:, 0], scale=scale,
+            window=window, k_s=ks, v_s=vs).reshape(B, 1, H * Dh)
+    if (S == 1 and cfg.use_flash and mesh is not None
+            and _can_flash_decode_on_mesh(mesh, B, H, kc.shape[1],
+                                          kc.shape[2])):
+        # Same kernel under GSPMD: shard_map carves the batch over
+        # dp and the (already tp-sharded) heads over tp, so the
+        # kernel runs on local shards instead of forcing GSPMD to
+        # replicate a raw pallas_call.
+        return _flash_decode_on_mesh(
+            q[:, 0], kc, vc, positions[:, 0], mesh,
+            scale, window, ks, vs).reshape(B, 1, H * Dh)
+    if ks is not None:
+        # Compat/prefill path: dequantize for the einsum.
+        kc, vc = _dequantize_kv(kc, ks), _dequantize_kv(vc, vs)
+    return _cached_attention(q, kc, vc, positions, scale, window=window)
+
+
+class DenseKV:
+    """The dense cache's side of :func:`forward_with_cache`'s one
+    seam: how a layer writes its new K/V and how it attends.  (The
+    paged pool's side is :class:`~.paged_kv.PagedKV`.)  An
+    implementation gives the layer scan what it ``held`` across layers
+    (carry) and what it takes ``per_layer`` (xs); its :meth:`layer`
+    writes ``new`` (name -> (B, Hkv, S, Dh | 1), the cache's leaves for
+    the S new tokens), attends, and hands back the attention output,
+    what is held, and the layer's ys; :meth:`result` makes the updated
+    cache of the scan's two results.
+
+    Here the ``(L, B, Hkv, T, Dh)`` cache rides the scan as xs and
+    comes back as ys, one layer's buffers at a time, and nothing is
+    held."""
+
+    def __init__(self, cache: dict, cache_len, scale, cfg, mesh):
+        self.held = ()
+        self.per_layer = cache
+        self._cache_len = cache_len
+        self._env = (scale, cfg, mesh)
+
+    def _write(self, buf, new):
+        """Insert S new entries at the cache pointer: one slice update
+        for a shared scalar pointer, a per-row (vmapped, scatter-
+        lowered) update for per-stream pointers.  K/V buffers and int8
+        scales share the heads-major layout — the token axis sits at
+        -2 for both (D or the singleton scale at -1)."""
+        at = self._cache_len
+        if at.ndim == 1:
+            return jax.vmap(lambda c, u, s: jax.lax.dynamic_update_slice(
+                c, u, (0, s, 0)))(buf, new, at)
+        return jax.lax.dynamic_update_slice(buf, new, (0, 0, at, 0))
+
+    def layer(self, held, bufs, q, new, positions):
+        bufs = {name: self._write(buf, new[name].astype(buf.dtype))
+                for name, buf in bufs.items()}
+        o = _attend(q, bufs["k"], bufs["v"], bufs.get("k_s"),
+                    bufs.get("v_s"), positions, *self._env)
+        return o, held, bufs
+
+    def result(self, held, per_layer):
+        return per_layer
+
+
 def _make_mlp_fn(cfg: TransformerConfig, mesh, ep_axis: str,
                  token_mask=None):
     """The per-layer feed-forward branch: dense SwiGLU, or the MoE
@@ -250,7 +326,7 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
                        cfg: TransformerConfig, *,
                        last_only: bool = False, last_index=None,
                        mesh=None, ep_axis: str = "ep", row_mask=None,
-                       token_mask=None):
+                       token_mask=None, block_table=None):
     """Run ``tokens`` (B, S) through the model, reading/writing the KV
     cache at offset ``cache_len`` (traced scalar ok, or a per-row
     ``(B,)`` vector when the streams in the batch sit at different
@@ -277,6 +353,14 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
     is per-token, so the mask only reaches the expert router).
     ``row_mask`` (B,) is the whole-row shorthand the decode step uses
     for inactive streams; passing both ANDs them.
+
+    ``block_table`` (B, MB) makes ``cache`` the *paged* physical pool
+    (:mod:`.paged_kv`) and this a decode step over it (S = 1, per-row
+    ``cache_len``): each layer writes its one new token into the
+    row's page and attends over the pool where it lies
+    (:class:`~.paged_kv.PagedKV`); rows outside ``row_mask`` write to
+    the trash block.  The layer's mathematics is the same code either
+    way; only where K/V are kept differs (:class:`DenseKV`).
     """
     B, S = tokens.shape
     H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -294,24 +378,15 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
         token_mask = rows if token_mask is None else token_mask & rows
     mlp = _make_mlp_fn(cfg, mesh, ep_axis, token_mask=token_mask)
     kv_quantized = "k_s" in cache
+    if block_table is not None:
+        from .paged_kv import PagedKV
+        kv = PagedKV(cache, block_table, row_mask, scale, cfg, mesh)
+    else:
+        kv = DenseKV(cache, cache_len, scale, cfg, mesh)
 
-    def write_kv(buf, new):
-        """Insert S new entries at the cache pointer: one slice update
-        for a shared scalar pointer, a per-row (vmapped, scatter-
-        lowered) update for per-stream pointers.  K/V buffers and int8
-        scales share the heads-major layout — the token axis sits at
-        -2 for both (D or the singleton scale at -1)."""
-        if per_row:
-            return jax.vmap(lambda c, u, s: jax.lax.dynamic_update_slice(
-                c, u, (0, s, 0)))(buf, new, cache_len)
-        return jax.lax.dynamic_update_slice(buf, new,
-                                            (0, 0, cache_len, 0))
-
-    def layer_step(x, inputs):
-        if kv_quantized:
-            layer, kc, vc, ks, vs = inputs
-        else:
-            (layer, kc, vc), ks, vs = inputs, None, None
+    def layer_step(carry, inputs):
+        x, held = carry
+        layer, per_layer = inputs
         h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
         q = _rope(qlinear(h, layer["wq"]).reshape(B, S, H, Dh),
                   positions, cfg.rope_theta)
@@ -319,66 +394,23 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
                   positions, cfg.rope_theta)
         v = qlinear(h, layer["wv"]).reshape(B, S, Hkv, Dh)
         # Heads-major for the cache: (B, S, Hkv, Dh) -> (B, Hkv, S, Dh).
-        kT = k.transpose(0, 2, 1, 3)
-        vT = v.transpose(0, 2, 1, 3)
+        new = {"k": k.transpose(0, 2, 1, 3), "v": v.transpose(0, 2, 1, 3)}
         if kv_quantized:
-            k8, k_sc = _quantize_kv(kT)
-            v8, v_sc = _quantize_kv(vT)
-            kc = write_kv(kc, k8)
-            vc = write_kv(vc, v8)
-            ks = write_kv(ks, k_sc)
-            vs = write_kv(vs, v_sc)
-        else:
-            kc = write_kv(kc, kT.astype(kc.dtype))
-            vc = write_kv(vc, vT.astype(vc.dtype))
+            new["k"], new["k_s"] = _quantize_kv(new["k"])
+            new["v"], new["v_s"] = _quantize_kv(new["v"])
         # Named scopes (trace-time metadata): attention, mlp and, in
         # the serving step, sample can be told apart in a profile.
         with jax.named_scope("attention"):
-            window = getattr(cfg, "sliding_window", None)
-            if S == 1 and cfg.use_flash and mesh is None:
-                # Decode hot path: fused Pallas kernel streams the cache
-                # once with the masked online softmax (ops/decode.py); an
-                # int8 cache streams at half width with its scales
-                # commuted through the matmuls.
-                from ..ops.decode import flash_decode_attention
-                o = flash_decode_attention(
-                    q[:, 0], kc, vc, positions[:, 0], scale=scale,
-                    window=window, k_s=ks, v_s=vs).reshape(B, 1, H * Dh)
-            elif (S == 1 and cfg.use_flash and mesh is not None
-                  and _can_flash_decode_on_mesh(mesh, B, H, Hkv,
-                                                kc.shape[2])):
-                # Same kernel under GSPMD: shard_map carves the batch over
-                # dp and the (already tp-sharded) heads over tp, so the
-                # kernel runs on local shards instead of forcing GSPMD to
-                # replicate a raw pallas_call.
-                o = _flash_decode_on_mesh(
-                    q[:, 0], kc, vc, positions[:, 0], mesh,
-                    scale, window, ks, vs).reshape(B, 1, H * Dh)
-            else:
-                if kv_quantized:
-                    # Compat/prefill path: dequantize for the einsum.
-                    kc_a = _dequantize_kv(kc, ks)
-                    vc_a = _dequantize_kv(vc, vs)
-                else:
-                    kc_a, vc_a = kc, vc
-                o = _cached_attention(q, kc_a, vc_a, positions, scale,
-                                      window=window)
+            o, held, per_layer = kv.layer(held, per_layer, q, new,
+                                          positions)
             x = x + qlinear(o, layer["wo"])
         with jax.named_scope("mlp"):
             x = mlp(x, layer)
-        new_cache = ((kc, vc, ks, vs) if kv_quantized else (kc, vc))
-        return x, new_cache
+        return (x, held), per_layer
 
-    if kv_quantized:
-        xs = (params["layers"], cache["k"], cache["v"],
-              cache["k_s"], cache["v_s"])
-        x, (k_new, v_new, ks_new, vs_new) = jax.lax.scan(
-            layer_step, x, xs)
-        new = {"k": k_new, "v": v_new, "k_s": ks_new, "v_s": vs_new}
-    else:
-        x, (k_new, v_new) = jax.lax.scan(
-            layer_step, x, (params["layers"], cache["k"], cache["v"]))
-        new = {"k": k_new, "v": v_new}
+    (x, held), per_layer = jax.lax.scan(
+        layer_step, (x, kv.held), (params["layers"], kv.per_layer))
+    new = kv.result(held, per_layer)
     if last_index is not None:
         idx = jnp.asarray(last_index, jnp.int32).reshape(B, 1, 1)
         x = jnp.take_along_axis(x, jnp.broadcast_to(

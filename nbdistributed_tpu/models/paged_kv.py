@@ -1,4 +1,4 @@
-"""Paged KV storage: fixed-size blocks under the dense decode path.
+"""Paged KV storage: fixed-size blocks, read by the decode step in place.
 
 The dense serving cache is one ``(L, max_batch, Hkv, max_len, D)``
 pool — every slot reserves ``max_len`` tokens of KV for its whole
@@ -16,20 +16,28 @@ by the same :func:`~.generate.init_kv_cache` (values + per-token
 scales), and every helper here tree-maps over the cache dict, so
 paged + quantized compose without new code.
 
-**Compute path (stated honestly).**  The attention kernels are
-unchanged: each step *gathers* the table-selected blocks into a dense
-``(L, S, Hkv, T', D)`` view, runs the existing
-:func:`~.generate.forward_with_cache`, and *scatters* back only what
-changed (decode: the one block containing the written position per
-active slot; prefill: the slot's whole row).  The gather is one
-``jnp.take`` per cache leaf — XLA fuses it, but the dense view is
-materialized per step, so paging here buys *capacity accounting and
-admission semantics*, not peak-HBM-per-step; a fused paged-attention
-kernel (block tables consumed inside the Pallas decode kernel,
-ops/decode.py) is the stated next step on the roadmap.
+**Compute path.**  The decode step consumes the physical pool where
+it lies (:class:`PagedKV`, the pool's side of
+:func:`~.generate.forward_with_cache`'s one seam).  The pool rides the
+layer scan as carry, updated in place under the step's donation, and
+each layer does two things to it: it *writes* the step's one new token
+per slot straight into its page (physical block ``table[b, pos //
+bt]``, offset ``pos % bt``, all heads: ``S x Hkv x D`` values a leaf),
+and it *attends*.  With ``cfg.use_flash`` on one device, attention is
+:func:`~..ops.decode.paged_decode_attention`: block table and positions
+are scalar-prefetch operands of the Pallas kernel, whose index maps
+pick each live page out of the pool, so a step reads the tokens its
+slots hold and never ``max_len``, and no dense view of the pool exists
+(``DecodeServer.kv_view_bytes`` reads 0).  Otherwise (``use_flash``
+off, or under a mesh) :func:`gather_layer` takes *that layer's* blocks
+to a ``(S, Hkv, T', D)`` view for the dense attention paths of
+:mod:`.generate`; the view lives for one layer, and ``kv_view_bytes``
+counts what a step gathers that way.  Prefill still works on one
+slot's dense row (:func:`gather_row` / :func:`scatter_row`), a whole
+row per chunk program.
 
 **The trash block.**  Physical block ``n_blocks`` is never allocated.
-Unallocated table entries point at it, and the decode scatter
+Unallocated table entries point at it, and the decode step's write
 redirects *inactive* slots there, so a freed-and-reallocated block can
 never be corrupted by a stale slot's frozen-position write (the dense
 pool tolerates those because admission re-prefills the whole row;
@@ -38,9 +46,9 @@ trash block — or in allocated-but-unwritten blocks — is unreachable by
 attention: positions ``> cache_len`` are masked, and a slot's
 ``cache_len`` never passes its allocated token count.
 
-Exactness: gather ∘ scatter is the identity on the blocks a slot owns,
-so a paged greedy decode computes what the dense server (and a solo
-:func:`~.generate.generate`) computes.  In float32 the tokens are
+Exactness: a slot's pages hold, token for token, what its dense row
+would, so a paged greedy decode computes what the dense server (and a
+solo :func:`~.generate.generate`) computes.  In float32 the tokens are
 bit-identical — asserted by the paged-decode unit tests on the CPU
 (including the quantized round-trip tolerance) and by ``chip_smoke.py``
 on the TPU at ``highest`` matmul precision; in bf16 on the TPU see the
@@ -54,7 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..serving_fast.paging import BlockAllocator, blocks_needed
-from .generate import init_kv_cache, kv_cache_shardings
+from .generate import _attend, init_kv_cache, kv_cache_shardings
 
 
 def make_paged_pool(cfg, n_blocks: int, block_tokens: int, *,
@@ -74,24 +82,24 @@ def make_paged_pool(cfg, n_blocks: int, block_tokens: int, *,
                          mesh=mesh, rules=rules, quantized=quantized)
 
 
-# The gathers and scatters below carry a ``jax.named_scope`` each, so
-# their ops can be told from the model's in a profile (trace-time
-# metadata only: the compiled program is the same).
+# The gathers, scatters and the write below carry a ``jax.named_scope``
+# each, so their ops can be told from the model's in a profile
+# (trace-time metadata only: the compiled program is the same).
 
 
-@jax.named_scope("gather_dense")
-def gather_dense(pool, table):
-    """Table-select every slot's blocks into a dense cache view.
-
-    pool leaves ``(L, NB+1, Hkv, bt, D)``, table ``(S, MB)`` physical
-    ids -> dense leaves ``(L, S, Hkv, MB*bt, D)`` — the exact layout
-    ``forward_with_cache`` expects, with ``T' = MB*bt``.
-    """
+@jax.named_scope("gather_layer")
+def gather_layer(pool, layer, table):
+    """Table-select every slot's blocks of ONE layer into a dense
+    view: pool leaves ``(L, NB+1, Hkv, bt, D)``, ``layer`` a traced
+    scalar, table ``(S, MB)`` physical ids -> leaves ``(S, Hkv, MB*bt,
+    D)``, what the dense attention paths expect with ``T' = MB*bt``.
+    Only for a step that cannot read the pool in place (see
+    :func:`reads_in_place`)."""
     def one(c):
-        g = jnp.take(c, table, axis=1)        # (L, S, MB, Hkv, bt, D)
-        g = jnp.transpose(g, (0, 1, 3, 2, 4, 5))
+        g = jnp.take(c[layer], table, axis=0)  # (S, MB, Hkv, bt, D)
+        g = jnp.transpose(g, (0, 2, 1, 3, 4))
         sh = g.shape
-        return g.reshape(sh[0], sh[1], sh[2], sh[3] * sh[4], sh[5])
+        return g.reshape(sh[0], sh[1], sh[2] * sh[3], sh[4])
     return jax.tree_util.tree_map(one, pool)
 
 
@@ -122,30 +130,84 @@ def scatter_row(pool, row, row_ids):
     return jax.tree_util.tree_map(one, pool, row)
 
 
-@jax.named_scope("scatter_step")
-def scatter_step(pool, dense, table, pos, active, trash: int,
-                 block_tokens: int):
-    """Write back the ONE block per slot that a decode step touched.
+@jax.named_scope("write_token")
+def write_token(pool, layer, new, table, pos, active):
+    """Write each slot's ONE new token of one layer into its page.
 
-    ``pos`` is the position the step wrote (pre-increment ``lens``).
-    Inactive slots are redirected to the trash block — their frozen-
-    position write must never land in a block that may have been
-    reallocated to another request.
-    """
-    blk_log = pos // block_tokens                       # (S,)
-    phys = jnp.take_along_axis(table, blk_log[:, None],
-                               axis=1)[:, 0]            # (S,)
-    phys = jnp.where(active, phys, trash)
+    ``new`` leaves ``(S, Hkv, 1, D)`` (the pool's leaves for the
+    step's token), ``pos`` (S,) the position written.  Physical block
+    ``table[b, pos // bt]``, offset ``pos % bt``, all heads.  Inactive
+    slots are redirected to the trash block — their frozen-position
+    write must never land in a block that may have been reallocated to
+    another request."""
+    first = jax.tree_util.tree_leaves(pool)[0]
+    trash, bt = first.shape[1] - 1, first.shape[3]
+    blk = jnp.minimum(pos // bt, table.shape[1] - 1)
+    phys = jnp.take_along_axis(table, blk[:, None], axis=1)[:, 0]
+    if active is not None:
+        phys = jnp.where(active, phys, trash)
+    off = pos % bt
 
-    def one(c, d):
-        sh = c.shape                          # (L, NB+1, Hkv, bt, D)
-        d = d.reshape(d.shape[0], d.shape[1], d.shape[2], -1,
-                      block_tokens, d.shape[-1])
-        blk = jnp.take_along_axis(
-            d, blk_log[None, :, None, None, None, None],
-            axis=3)[:, :, :, 0]               # (L, S, Hkv, bt, D)
-        return c.at[:, phys].set(blk)
-    return jax.tree_util.tree_map(one, pool, dense)
+    def one(c, n):
+        # One slice update a slot, not one scatter: a slice update
+        # takes the pool in whatever layout it has and rewrites it in
+        # place, where a scatter over the token axis makes XLA re-lay
+        # the whole pool out around it, twice a layer.
+        n = n.astype(c.dtype)                 # (S, Hkv, 1, D)
+        for b in range(n.shape[0]):
+            c = jax.lax.dynamic_update_slice(
+                c, n[b][None, None], (layer, phys[b], 0, off[b], 0))
+        return c
+    return jax.tree_util.tree_map(one, pool, new)
+
+
+def reads_in_place(cfg, mesh) -> bool:
+    """Whether a decode step over a paged pool attends inside the
+    Pallas kernel, block tables and all — what ``cfg.use_flash``
+    chooses on one device, as everywhere else in
+    :func:`~.generate.forward_with_cache` — or gathers a per-layer
+    view for the dense attention paths."""
+    return bool(cfg.use_flash) and mesh is None
+
+
+class PagedKV:
+    """The paged pool's side of :func:`~.generate.forward_with_cache`'s
+    seam (see :class:`~.generate.DenseKV` for the contract): the whole
+    ``(L, NB+1, Hkv, bt, D)`` pool is held across the layer scan and
+    updated in place, and a layer takes only its index."""
+
+    def __init__(self, pool: dict, table, active, scale, cfg, mesh):
+        self.held = pool
+        n_layers = jax.tree_util.tree_leaves(pool)[0].shape[0]
+        self.per_layer = jnp.arange(n_layers, dtype=jnp.int32)
+        self._table, self._active = table, active
+        self._env = (scale, cfg, mesh)
+
+    def layer(self, pool, layer, q, new, positions):
+        if q.shape[1] != 1:
+            raise ValueError("a paged pool takes decode steps only "
+                             f"(one new token a row, got {q.shape[1]})")
+        scale, cfg, mesh = self._env
+        pos = positions[:, 0]
+        # Write first, attend second: the kernel sees position pos.
+        pool = write_token(pool, layer, new, self._table, pos,
+                           self._active)
+        if reads_in_place(cfg, mesh):
+            from ..ops.decode import paged_decode_attention
+            o = paged_decode_attention(
+                q[:, 0], pool["k"], pool["v"], layer, self._table, pos,
+                active=self._active, scale=scale,
+                window=getattr(cfg, "sliding_window", None),
+                k_s=pool.get("k_s"), v_s=pool.get("v_s"))
+            o = o.reshape(q.shape[0], 1, -1)
+        else:
+            view = gather_layer(pool, layer, self._table)
+            o = _attend(q, view["k"], view["v"], view.get("k_s"),
+                        view.get("v_s"), positions, *self._env)
+        return o, pool, None
+
+    def result(self, pool, per_layer):
+        return pool
 
 
 def apply_moves(pool, moves: dict[int, int]):

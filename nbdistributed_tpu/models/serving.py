@@ -155,9 +155,10 @@ class DecodeServer:
                              "mode is enabled by the block size)")
         if kv_block_tokens is not None and draft_cfg is not None:
             # A speculative round writes gamma+1 positions per step;
-            # the paged scatter writes back exactly one block per slot.
-            # Compose them when the fused paged kernel lands, not by
-            # silently corrupting cross-block rounds.
+            # the paged step writes exactly one token per slot and
+            # attends one query.  Compose them with a multi-token
+            # paged write and verify, not by silently corrupting
+            # cross-block rounds.
             raise ValueError("paged KV serving does not compose with "
                              "speculative decoding yet")
         if interleave_prefill and prefill_chunk is None:
@@ -207,7 +208,8 @@ class DecodeServer:
         # max_len rows; self._cache holds the pool either way (it is
         # donated through the same jitted programs).
         if kv_block_tokens is not None:
-            from .paged_kv import PagedKVCache, make_paged_pool
+            from .paged_kv import (PagedKVCache, make_paged_pool,
+                                   reads_in_place)
             if kv_blocks is None:
                 # Derived default: exactly the dense pool's capacity,
                 # so paging with no explicit budget never refuses a
@@ -220,13 +222,19 @@ class DecodeServer:
             self._cache = make_paged_pool(
                 cfg, kv_blocks, kv_block_tokens, mesh=mesh,
                 quantized=kv_quantized)
-            # Bytes of the dense view one decode step gathers from
-            # the pool (every slot's whole block table, all layers):
-            # a count from shapes, the paged layer's cost per step.
-            self.kv_view_bytes = sum(
-                c.nbytes // c.shape[1] * self._paged.max_blocks
-                * max_batch
+            # One page of K and V over all layers, in bytes: what a
+            # step's attention fetches per live page of a slot
+            # (``step`` sums them into ``kv_read_bytes_total``).
+            self._page_bytes = sum(
+                c.nbytes // c.shape[1]
                 for c in jax.tree_util.tree_leaves(self._cache))
+            # Bytes a decode step gathers from the pool into dense
+            # views, all layers: 0 where the kernel reads the pool in
+            # place, else every slot's whole block table once a layer
+            # (the fallback's cost per step; a count from shapes).
+            self.kv_view_bytes = (
+                0 if reads_in_place(cfg, mesh) else
+                self._page_bytes * self._paged.max_blocks * max_batch)
         else:
             self._paged = None
             self.kv_view_bytes = 0
@@ -287,6 +295,12 @@ class DecodeServer:
         # prefill/decode token split to the serving observatory.
         self.prefill_tokens_total = 0
         self.decode_tokens_total = 0
+        # Paged pool: cumulative bytes of K and V pages the decode
+        # steps' attention fetched and the steps that ran, counted on
+        # the host from the active slots' lengths (the worker reports
+        # each tick's deltas, as for the token counters).
+        self.kv_read_bytes_total = 0
+        self.decode_steps_total = 0
         # Cumulative seconds per phase of step() (and of submit()'s
         # admission, which is prefill), on this process's
         # perf_counter; the worker's serve_step handler reports each
@@ -352,10 +366,11 @@ class DecodeServer:
         # Every jitted serving program carries a name that says what
         # it is (``jit_nbd_decode_step*`` / ``jit_nbd_prefill*``): the
         # profile's "XLA Modules" line splits device time by it.
-        def nbd_decode_step(params, cache, lens, last, active, key):
+        def nbd_decode_step(params, cache, lens, last, active, key,
+                            table=None):
             logits, cache = forward_with_cache(
                 params, last[:, None], cache, lens, cfg, mesh=mesh,
-                ep_axis=ep_axis, row_mask=active)
+                ep_axis=ep_axis, row_mask=active, block_table=table)
             with jax.named_scope("sample"):
                 nxt = _sample(logits[:, -1], temperature, key, top_k,
                               top_p)
@@ -403,26 +418,17 @@ class DecodeServer:
         return wrapper
 
     def _jit_step_paged(self):
-        """The paged decode step: gather table-selected blocks to a
-        dense view, run the SAME step computation, scatter back only
-        the one block per active slot the step wrote (inactive slots
-        redirect to the trash block — their frozen-position write must
-        never land in a block reallocated to another request)."""
-        from .paged_kv import gather_dense, scatter_step
-
+        """The paged decode step: the SAME step computation over the
+        physical pool, which it consumes where it lies (each layer
+        writes its one new token per slot into its page — inactive
+        slots into the trash block — and attends through the block
+        table; see :class:`~.paged_kv.PagedKV`).  The pool is donated
+        and updated in place."""
         step = self._make_step()
-        bt = self._paged.block_tokens
-        trash = self._paged.trash
 
         def nbd_decode_step_paged(params, pool, table, lens, last,
                                   active, key):
-            dense = gather_dense(pool, table)
-            pos = lens                    # position this step writes
-            dense, new_lens, nxt = step(params, dense, lens, last,
-                                        active, key)
-            pool = scatter_step(pool, dense, table, pos, active,
-                                trash, bt)
-            return pool, new_lens, nxt
+            return step(params, pool, lens, last, active, key, table)
 
         return jax.jit(nbd_decode_step_paged, donate_argnums=(1,))
 
@@ -509,7 +515,7 @@ class DecodeServer:
     def step_kernels(self) -> int:
         """Compiled Pallas (Mosaic) kernels in the decode-step program
         :meth:`step` runs, lowered at the live pool's shapes (paged:
-        gather, row-masked step, scatter) — 0 where kernels are
+        the row-masked step over the pool) — 0 where kernels are
         interpreted (the CPU) or the step fell back to the einsum
         path.  Lowering only traces, so the donated pool is untouched;
         a speculative server's rounds are not counted."""
@@ -736,7 +742,7 @@ class DecodeServer:
                 # chunk; lens tracks the written offset so the decode
                 # step's frozen-position write for this inactive row
                 # always lands exactly where the NEXT chunk will
-                # write (dense pool; the paged scatter redirects
+                # write (dense pool; the paged step's write redirects
                 # inactive rows to trash anyway).
                 self._prefilling[slot] = [rid, prompt, budget, 0]
                 self._lens = self._lens.at[slot].set(0)
@@ -933,12 +939,28 @@ class DecodeServer:
                 self._lens, self._cache_d, self._lens_d, self._last,
                 self._active, self._sample_key())
             return cand, n_acc
-        table = (() if self._paged is None
-                 else (self._paged.device_table(),))
+        table = ()
+        if self._paged is not None:
+            table = (self._paged.device_table(),)
+            self.kv_read_bytes_total += self._step_kv_read_bytes()
+        self.decode_steps_total += 1
         self._cache, self._lens, self._last = self._step_fn(
             self._params, self._cache, *table, self._lens, self._last,
             self._active, self._sample_key())
         return self._last
+
+    def _step_kv_read_bytes(self) -> int:
+        """Bytes of K and V pages the next decode step's attention
+        fetches, all layers: for every active slot the pages from the
+        window's first to the one its new token lands in."""
+        bt = self._paged.block_tokens
+        window = getattr(self._cfg, "sliding_window", None)
+        pages = 0
+        for rid in self._slot_req.values():
+            pos = len(self.prompts[rid]) + len(self.outputs[rid]) - 1
+            first = max(0, pos + 1 - window) // bt if window else 0
+            pages += pos // bt - first + 1
+        return pages * self._page_bytes
 
     def _step_tokens(self, out, slot: int) -> list[int]:
         """One slot's tokens of a fetched step (see

@@ -152,8 +152,9 @@ class ServingObservatory:
         self._util: deque = deque(maxlen=max(8, ring))
         self._ticks: deque = deque(maxlen=TICK_RING)
         self._slow: deque = deque(maxlen=SLOW_TICKS)
-        # Bytes of the dense KV view one decode step gathers on a
-        # rank (the worker's serve_open reports it; 0 = dense pool).
+        # Bytes one decode step gathers from a paged pool into dense
+        # views on a rank (the worker's serve_open reports it; 0 = a
+        # dense pool, or a kernel that reads the paged pool in place).
         self.kv_view_bytes = 0
         self.completed = 0
         self.dropped = 0
@@ -422,13 +423,16 @@ class ServingObservatory:
                   worker: dict, cmp=None, *,
                   turnaround: float | None = None,
                   idled: bool = False,
-                  t_wall: float | None = None) -> dict | None:
+                  t_wall: float | None = None,
+                  kv_read=None) -> dict | None:
         """One tick of one rank: the gateway's phase seconds, the
         worker's (its ``tick["ph"]``), the worker's compile delta
         ``[count, seconds]`` and the ``turnaround`` it waited since
         its last reply (None on a server's first tick).  ``idled``
         marks a tick that followed a wait for work: its turnaround is
-        no part of a decode period.  Returns the tick's record when
+        no part of a decode period.  ``kv_read`` is the worker's
+        ``[bytes, steps]``: K and V pages its decode steps fetched
+        from a paged pool in this tick.  Returns the tick's record when
         it was slow (kept under ``slow``; the caller writes it to the
         flight recorder, once), else None."""
         wk = {k: max(0.0, float(worker.get(k) or 0.0))
@@ -439,11 +443,13 @@ class ServingObservatory:
                   else max(0.0, float(turnaround)))
         n_cmp, s_cmp = cmp or (0, 0.0)
         cmp = [int(n_cmp), float(s_cmp)]
+        kv_bytes, kv_steps = kv_read or (0, 0)
         rec = {
             "seq": int(seq), "rank": int(rank),
             "t_wall": round(self._now() if t_wall is None else t_wall,
                             3),
             "gw": gw, "wk": wk, "cmp": cmp, "idled": bool(idled),
+            "kvr": [int(kv_bytes), int(kv_steps)],
             "turnaround": (None if turnaround is None
                            else max(0.0, float(turnaround))),
             "handler": handler,
@@ -496,6 +502,11 @@ class ServingObservatory:
                      "compiles": sum(t["cmp"][0] for t in ticks),
                      "compile_ms": _ms(sum(t["cmp"][1] for t in ticks)),
                      "kv_view_bytes": self.kv_view_bytes,
+                     # mean bytes of K and V pages a decode step
+                     # fetched (0 with no step, or a dense pool)
+                     "kv_read_bytes": round(
+                         sum(t["kvr"][0] for t in ticks)
+                         / max(1, sum(t["kvr"][1] for t in ticks))),
                      "slow": slow}
         if not ticks:
             return out

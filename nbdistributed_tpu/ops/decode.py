@@ -230,6 +230,138 @@ def _decode_call(q, kc, vc, pos, *, block_k: int, scale: float,
     )(*args)
 
 
+def _paged_decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref,
+                         v_ref, o_ref, acc_s, m_s, l_s, *,
+                         block_tokens: int, scale: float, num_kb: int,
+                         window: int | None = None,
+                         ks_ref=None, vs_ref=None):
+    """One grid step = one (row, logical page), all KV heads of the
+    page at once: ``k_ref``/``v_ref`` hold the ``(Hkv, bt, D)`` page
+    the index map picked out of the physical pool (see
+    :func:`_paged_decode_call`), so the scores are one dot batched over
+    ``Hkv``.  Same recurrence, masks and float32 state as
+    :func:`_decode_kernel`; pages are whole blocks of the pool, so
+    there is no padded tail to zero.  A row whose ``pos`` is negative
+    takes no part: no page of it is live, and it writes zeros."""
+    from jax.experimental import pallas as pl
+
+    del layer_ref, table_ref            # consumed by the index maps
+    b = pl.program_id(0)
+    kb = pl.program_id(1)
+    valid = pos_ref[b] + 1                              # keys [0, valid)
+    lo = jnp.maximum(valid - window, 0) if window is not None else 0
+
+    @pl.when(kb == 0)
+    def _init():
+        acc_s[...] = jnp.zeros_like(acc_s)
+        m_s[...] = jnp.full_like(m_s, _NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+
+    @pl.when((kb * block_tokens < valid)
+             & ((kb + 1) * block_tokens > lo))
+    def _page():
+        q = q_ref[0].astype(jnp.float32) * scale        # (Hkv, group, D)
+        k_pg = k_ref[...].astype(jnp.float32)           # (Hkv, bt, D)
+        v_pg = v_ref[...].astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k_pg, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)         # (Hkv, group, bt)
+        if ks_ref is not None:
+            s = s * ks_ref[...][:, :, 0][:, None, :]
+        ki = (kb * block_tokens
+              + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2))
+        s = jnp.where((ki < valid) & (ki >= lo), s, _NEG_INF)
+        m_prev, l_prev = m_s[...], l_s[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_s[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if vs_ref is not None:
+            p = p * vs_ref[...][:, :, 0][:, None, :]
+        acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
+            p, v_pg, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)         # (Hkv, group, D)
+        m_s[...] = m_new
+
+    @pl.when(kb == num_kb - 1)
+    def _finalize():
+        o_ref[0] = (acc_s[...]
+                    / jnp.maximum(l_s[...], 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "window"))
+def _paged_decode_call(q, k_pool, v_pool, layer, table, pos, *,
+                       scale: float, interpret: bool,
+                       window: int | None = None, k_s=None, v_s=None):
+    """The pool stays where it lies: ``layer``, ``table`` and ``pos``
+    are scalar-prefetch operands, and the K/V index map turns grid step
+    ``(b, kb)`` into physical block ``table[b, j]`` of layer ``layer``,
+    with ``j`` the logical page ``kb`` clamped to the row's live range
+    (the window's first page to the page of ``pos``).  Past that range
+    the block index repeats, so Pallas issues no new copy; a row that
+    takes no part (``pos < 0``) points at the trash block, and a run of
+    such rows costs one copy of it."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, Hkv, group, D = q.shape
+    bt = k_pool.shape[3]
+    trash = k_pool.shape[1] - 1
+    num_kb = table.shape[1]
+    quantized = k_s is not None
+
+    def page(b, kb, layer, table, pos):
+        p = pos[b]
+        last = jnp.maximum(p, 0) // bt
+        first = (jnp.maximum(p + 1 - window, 0) // bt
+                 if window is not None else 0)
+        j = jnp.minimum(jnp.maximum(kb, first), last)
+        return (layer[0], jnp.where(p < 0, trash, table[b, j]), 0, 0, 0)
+
+    def row(b, kb, layer, table, pos):
+        return (b, 0, 0, 0)
+
+    def _kernel(layer_ref, table_ref, pos_ref, *refs):
+        *refs, o_ref, a, m, l = refs
+        if quantized:
+            q_ref, k_ref, v_ref, ks_ref, vs_ref = refs
+        else:
+            (q_ref, k_ref, v_ref), ks_ref, vs_ref = refs, None, None
+        _paged_decode_kernel(layer_ref, table_ref, pos_ref, q_ref,
+                             k_ref, v_ref, o_ref, a, m, l,
+                             block_tokens=bt, scale=scale,
+                             num_kb=num_kb, window=window,
+                             ks_ref=ks_ref, vs_ref=vs_ref)
+
+    in_specs = [
+        pl.BlockSpec((1, Hkv, group, D), row),                # q
+        pl.BlockSpec((None, None, Hkv, bt, D), page),         # k
+        pl.BlockSpec((None, None, Hkv, bt, D), page),         # v
+    ]
+    args = [layer.reshape(1), table, pos, q, k_pool, v_pool]
+    if quantized:
+        in_specs += [pl.BlockSpec((None, None, Hkv, bt, 1), page)] * 2
+        args += [k_s, v_s]
+    return pl.pallas_call(
+        _kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(S, num_kb),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, Hkv, group, D), row),
+            scratch_shapes=[
+                pltpu.VMEM((Hkv, group, D), jnp.float32),   # acc
+                pltpu.VMEM((Hkv, group, 1), jnp.float32),   # running max
+                pltpu.VMEM((Hkv, group, 1), jnp.float32),   # normalizer
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, Hkv, group, D), q.dtype),
+        interpret=interpret,
+        name="nbd_flash_decode_paged",
+    )(*args)
+
+
 # (T, head_dim, gqa_group) -> block_k, measured on a live chip by
 # tune_flash.py's decode sweep.  Consulted when the caller passes no
 # explicit block_k; empty entries fall back to 128.  Decode is
@@ -297,3 +429,45 @@ def flash_decode_attention(q, kc, vc, pos, *, scale: float | None = None,
         o, lse = out
         return o.reshape(B, H, D), lse.reshape(B, H)
     return out.reshape(B, H, D)
+
+
+def paged_decode_attention(q, k_pool, v_pool, layer, table, pos, *,
+                           active=None, scale: float | None = None,
+                           window: int | None = None,
+                           k_s=None, v_s=None):
+    """Decode attention that reads the paged KV pool in place: the
+    same mathematics as :func:`flash_decode_attention`, over keys that
+    lie in table-selected physical blocks instead of a dense row.
+
+    q: (S, H, D) — this step's queries, one per slot;
+    k_pool/v_pool: (L, NB+1, Hkv, bt, D) — the whole physical pool
+    (:func:`~..models.paged_kv.make_paged_pool`), of which only layer
+    ``layer`` (a traced int32 scalar) is read;
+    table: (S, MB) int32 physical block ids per slot;
+    pos: (S,) int32 — the position of the new token per slot: keys
+    ``[max(0, pos + 1 - window), pos]`` attend, so its own K/V must
+    already be in the pool;
+    active: (S,) bool — slots that take part; the others fetch and
+    compute nothing and come back as zeros;
+    ``k_s``/``v_s``: (L, NB+1, Hkv, bt, 1) fp32 scales of an int8 pool.
+    Returns (S, H, D).  Only the pages of a slot's live range are
+    copied out of the pool: the step's traffic goes with the tokens
+    held, not with ``max_len``."""
+    S, H, D = q.shape
+    Hkv = k_pool.shape[2]
+    if H % Hkv:
+        raise ValueError(f"n_heads {H} not divisible by n_kv_heads {Hkv}")
+    if (k_s is None) != (v_s is None):
+        raise ValueError("pass both k_s and v_s, or neither")
+    if window is not None and window < 1:
+        raise ValueError(f"window must be >= 1, got {window}")
+    scale = scale if scale is not None else float(1.0 / np.sqrt(D))
+    pos = jnp.asarray(pos, jnp.int32)
+    if active is not None:
+        pos = jnp.where(active, pos, -1)
+    out = _paged_decode_call(
+        q.reshape(S, Hkv, H // Hkv, D), k_pool, v_pool,
+        jnp.asarray(layer, jnp.int32), jnp.asarray(table, jnp.int32),
+        pos, scale=float(scale), interpret=_use_interpret(),
+        window=window, k_s=k_s, v_s=v_s)
+    return out.reshape(S, H, D)

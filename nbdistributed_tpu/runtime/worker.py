@@ -1321,6 +1321,8 @@ class DistributedWorker:
         steps = max(0, int(data.get("steps") or 0))
         t_step0 = time.perf_counter()
         admit_prefill_s = srv.phase_s["prefill"] - ph0["prefill"]
+        kvr0 = (getattr(srv, "kv_read_bytes_total", 0),
+                getattr(srv, "decode_steps_total", 0))
         for _ in range(steps):
             if srv.done():
                 break
@@ -1368,7 +1370,11 @@ class DistributedWorker:
                 "pf": int(pf_d), "dc": int(dc_d), "seq": seq,
                 "ph": {k: round(v, 6) for k, v in ph.items()},
                 "cmp": [cmp1[0] - cmp0[0],
-                        round(cmp1[1] - cmp0[1], 3)]}
+                        round(cmp1[1] - cmp0[1], 3)],
+                # Bytes of K and V pages the tick's decode steps
+                # fetched from a paged pool, and how many steps ran.
+                "kvr": [getattr(srv, "kv_read_bytes_total", 0) - kvr0[0],
+                        getattr(srv, "decode_steps_total", 0) - kvr0[1]]}
         if st.t_reply is not None:
             tick["turnaround"] = round(t_in - st.t_reply, 6)
         st.t_reply = t_out

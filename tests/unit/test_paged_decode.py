@@ -1,6 +1,6 @@
 """Paged KV-cache decode (ISSUE 17): the device half of the block
-allocator.  gather∘scatter over table-selected blocks is an identity
-on live rows, so paged greedy serving must be BIT-IDENTICAL to solo
+allocator.  A slot's table-selected blocks hold, token for token, what
+its dense row would, so paged greedy serving must be BIT-IDENTICAL to solo
 ``generate()`` — with dense admission order, quantized caches, and
 chunked/interleaved prefill all invisible to the numerics — while the
 allocator-backed pool recycles blocks across requests.
@@ -72,8 +72,8 @@ def test_paged_block_starved_pool_recycles(setup):
 
 
 def test_paged_int8_kv_matches_int8_generate(setup):
-    """Paged + int8-quantized KV: gather/scatter moves the quantized
-    payload and its scales together, so the stream equals the dense
+    """Paged + int8-quantized KV: the quantized payload and its scales
+    are written and read together, so the stream equals the dense
     int8 reference token for token (the quantized round-trip adds no
     further error)."""
     cfg, params = setup

@@ -6,6 +6,7 @@ serving programs, the one counter of the paged layer.  In-process on the
 CPU, no fleet; kept out of the ``slow`` tier (a tiny model, a few
 steps), so it counts where the driver counts."""
 
+import dataclasses
 import types
 
 import jax
@@ -109,7 +110,10 @@ def test_trailing_admission_of_a_step_counts_as_prefill(setup, monkeypatch):
     assert sum(delta.values()) == clk.n - n0 - 1
 
 
-def test_kv_view_bytes_is_the_dense_view_of_one_step(setup):
+def test_kv_view_bytes_is_what_a_step_gathers_into_dense_views(setup):
+    """The einsum fallback gathers every slot's whole table once a
+    layer; a step whose kernel reads the pool in place gathers
+    nothing, and that 0 is the counter that says it engaged."""
     cfg, params = setup
     srv = _paged(setup, max_batch=2, max_len=32)
     # layers x rows x max_len x KV heads x head dim x (K and V) x itemsize
@@ -117,6 +121,46 @@ def test_kv_view_bytes_is_the_dense_view_of_one_step(setup):
     assert srv.kv_view_bytes == want
     dense = DecodeServer(params, cfg, max_batch=2, max_len=32, pad_to=4)
     assert dense.kv_view_bytes == 0
+    flash = dataclasses.replace(cfg, use_flash=True)
+    in_place = DecodeServer(params, flash, max_batch=2, max_len=32,
+                            pad_to=4, kv_block_tokens=8)
+    assert in_place.kv_view_bytes == 0
+
+
+def test_kv_read_bytes_counts_the_live_pages_of_the_active_slots(setup):
+    """Each decode step adds, for every active slot, the pages from
+    its window's first to the one its new token lands in, all layers,
+    K and V; the handler reports the tick's delta with its steps."""
+    cfg, _ = setup
+    srv = _paged(setup, max_batch=2, max_len=32)
+    # one page: block tokens x KV heads x head dim x (K, V) x itemsize
+    page = cfg.n_layers * 8 * cfg.n_kv_heads * cfg.head_dim * 2 * 4
+    assert srv._page_bytes == page
+    srv.submit([5, 9, 2, 7, 1, 3, 4], 6)       # pos 7 is the page edge
+    srv.submit([5, 9], 6)
+    srv.step()                                  # writes pos 7 and pos 2
+    assert (srv.kv_read_bytes_total, srv.decode_steps_total) == (
+        page * (1 + 1), 1)
+    srv.step()                                  # pos 8: a second page
+    assert srv.kv_read_bytes_total == page * (2 + 2 + 1)
+    w = _worker(srv)
+    tick = _step(w, 1, steps=2)["tick"]
+    assert tick["kvr"] == [page * 2 * (2 + 1), 2]
+    dense = DecodeServer(setup[1], cfg, max_batch=2, max_len=32, pad_to=4)
+    dense.submit([5, 9], 3)
+    dense.step()
+    assert (dense.kv_read_bytes_total, dense.decode_steps_total) == (0, 1)
+
+
+def test_kv_read_bytes_leaves_out_pages_below_the_window(setup):
+    cfg, params = setup
+    win = dataclasses.replace(cfg, sliding_window=8)
+    srv = DecodeServer(params, win, max_batch=1, max_len=32, pad_to=4,
+                       kv_block_tokens=8)
+    srv.submit(list(range(1, 18)), 4)           # first step writes pos 17
+    srv.step()
+    # keys [10, 17]: pages 1 and 2 of three
+    assert srv.kv_read_bytes_total == 2 * srv._page_bytes
 
 
 @pytest.mark.parametrize("paged", [True, False])

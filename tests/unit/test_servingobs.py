@@ -275,7 +275,7 @@ def test_ticks_block_present_with_no_finished_request():
     obs = ServingObservatory(now=FakeClock())
     tk = obs.summary()["ticks"]
     assert tk == {"count": 0, "compiles": 0, "compile_ms": 0.0,
-                  "kv_view_bytes": 0, "slow": []}
+                  "kv_view_bytes": 0, "kv_read_bytes": 0, "slow": []}
     _tick(obs, 1)
     s = obs.summary()
     assert s["count"] == 0 and "stages" not in s
@@ -294,6 +294,16 @@ def test_tick_ring_keeps_64():
     _tick(obs, 101, cmp=(2, 0.25))
     tk = obs.ticks_summary()
     assert tk["compiles"] == 2 and tk["compile_ms"] == 250.0
+
+
+def test_kv_read_bytes_is_the_mean_over_the_rings_decode_steps():
+    """``kvr`` = [bytes, steps] a tick: the summary divides the ring's
+    bytes by the ring's steps (a tick with no step adds nothing)."""
+    obs = ServingObservatory(now=FakeClock())
+    for seq, kvr in enumerate([(800, 8), (0, 0), (1000, 2), None], 1):
+        obs.note_tick(seq, 0, dict(GW), dict(WK), [0, 0.0],
+                      turnaround=0.01, kv_read=kvr)
+    assert obs.ticks_summary()["kv_read_bytes"] == 180
 
 
 def test_worker_phases_sum_to_the_handler_time_exactly():
