@@ -5,9 +5,13 @@ configs)."""
 from .generate import (forward_with_cache, generate, init_kv_cache,
                        kv_cache_shardings, make_generate_fn,
                        prefill_chunked)
-from .hf import (config_from_hf, load_hf_pretrained,
+from .hf import (config_from_hf, config_from_hf_json,
+                 latent_moe_config_from_hf, load_hf_pretrained,
                  moe_config_from_hf, moe_params_from_hf,
                  params_from_hf)
+from .mla import (LatentMoEConfig, init_latent_moe_model,
+                  joyai_flash_config, latent_moe_forward,
+                  latent_moe_shardings, tiny_latent_moe_config)
 from .lora import (ALL_TARGETS, ATTN_TARGETS, lora_init, lora_merge,
                    lora_num_params, lora_shardings,
                    make_lora_train_step)
@@ -48,7 +52,12 @@ __all__ = ["SeqParallel", "TransformerConfig", "forward",
            "tiny_moe_config",
            "forward_with_cache", "generate", "init_kv_cache",
            "kv_cache_shardings", "make_generate_fn", "prefill_chunked",
-           "config_from_hf", "load_hf_pretrained", "params_from_hf",
+           "config_from_hf", "config_from_hf_json",
+           "latent_moe_config_from_hf", "load_hf_pretrained",
+           "params_from_hf",
+           "LatentMoEConfig", "init_latent_moe_model",
+           "joyai_flash_config", "latent_moe_forward",
+           "latent_moe_shardings", "tiny_latent_moe_config",
            "moe_config_from_hf", "moe_params_from_hf",
            "ALL_TARGETS", "ATTN_TARGETS", "lora_init", "lora_merge",
            "lora_num_params", "lora_shardings", "make_lora_train_step",

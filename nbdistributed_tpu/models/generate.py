@@ -58,6 +58,19 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int, *,
     context the cache — not the weights — dominates decode HBM traffic,
     and the scales commute through both attention matmuls (see
     ops/decode.py), so the kernel streams half the bytes."""
+    if getattr(cfg, "kv_lora_rank", None):
+        # A latent (MLA) cache: one leaf, one head, ``[c_kv | k_rope]``
+        # a token (models/mla.py).
+        if quantized:
+            raise ValueError("a latent cache has no int8 layout yet")
+        cache = {"ckv": jnp.zeros(
+            (cfg.n_layers, batch, 1, max_len, cfg.cache_width),
+            cfg.dtype)}
+        if mesh is not None:
+            spec = P(None, "dp" if "dp" in mesh.shape else None)
+            cache = {"ckv": jax.device_put(
+                cache["ckv"], NamedSharding(mesh, spec))}
+        return cache
     shape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, cfg.head_dim)
     if quantized:
         sshape = (cfg.n_layers, batch, cfg.n_kv_heads, max_len, 1)
@@ -259,45 +272,94 @@ def _attend(q, kc, vc, ks, vs, positions, scale, cfg, mesh):
     return _cached_attention(q, kc, vc, positions, scale, window=window)
 
 
+class GQAMixer:
+    """How a layer of the dense family mixes tokens: wq/wk/wv/wo with
+    rotary grouped-query attention over ``(B, Hkv, T, Dh)`` K and V.
+    A mixer is the model's half of :func:`forward_with_cache`'s seam
+    (the cache's half is :class:`DenseKV` / :class:`~.paged_kv.PagedKV`):
+    :meth:`project` makes the queries and the cache's leaves for the S
+    new tokens, :meth:`attend` reads one layer's dense buffers,
+    :meth:`attend_paged` the paged pool where it lies, :meth:`out`
+    projects back to the residual stream.  The latent-attention mixer
+    is :class:`~.mla.MLAMixer`."""
+
+    def __init__(self, cfg, mesh, kv_quantized: bool = False):
+        self.cfg, self.mesh = cfg, mesh
+        self.scale = 1.0 / float(cfg.head_dim) ** 0.5
+        self._quantized = kv_quantized
+
+    def project(self, h, layer, positions):
+        cfg = self.cfg
+        B, S = h.shape[:2]
+        H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        q = _rope(qlinear(h, layer["wq"]).reshape(B, S, H, Dh),
+                  positions, cfg.rope_theta)
+        k = _rope(qlinear(h, layer["wk"]).reshape(B, S, Hkv, Dh),
+                  positions, cfg.rope_theta)
+        v = qlinear(h, layer["wv"]).reshape(B, S, Hkv, Dh)
+        # Heads-major for the cache: (B, S, Hkv, Dh) -> (B, Hkv, S, Dh).
+        new = {"k": k.transpose(0, 2, 1, 3), "v": v.transpose(0, 2, 1, 3)}
+        if self._quantized:
+            new["k"], new["k_s"] = _quantize_kv(new["k"])
+            new["v"], new["v_s"] = _quantize_kv(new["v"])
+        return q, new
+
+    def attend(self, q, bufs, positions, layer):
+        return _attend(q, bufs["k"], bufs["v"], bufs.get("k_s"),
+                       bufs.get("v_s"), positions, self.scale, self.cfg,
+                       self.mesh)
+
+    def attend_paged(self, q, pool, layer_idx, table, pos, active,
+                     layer):
+        from ..ops.decode import paged_decode_attention
+        o = paged_decode_attention(
+            q[:, 0], pool["k"], pool["v"], layer_idx, table, pos,
+            active=active, scale=self.scale,
+            window=getattr(self.cfg, "sliding_window", None),
+            k_s=pool.get("k_s"), v_s=pool.get("v_s"))
+        return o.reshape(q.shape[0], 1, -1)
+
+    def out(self, o, layer):
+        return qlinear(o, layer["wo"])
+
+
 class DenseKV:
     """The dense cache's side of :func:`forward_with_cache`'s one
-    seam: how a layer writes its new K/V and how it attends.  (The
-    paged pool's side is :class:`~.paged_kv.PagedKV`.)  An
+    seam: how a layer writes its new cache entries and how it attends.
+    (The paged pool's side is :class:`~.paged_kv.PagedKV`.)  An
     implementation gives the layer scan what it ``held`` across layers
     (carry) and what it takes ``per_layer`` (xs); its :meth:`layer`
-    writes ``new`` (name -> (B, Hkv, S, Dh | 1), the cache's leaves for
-    the S new tokens), attends, and hands back the attention output,
-    what is held, and the layer's ys; :meth:`result` makes the updated
-    cache of the scan's two results.
+    writes ``new`` (name -> (B, Hkv, S, width), the cache's leaves for
+    the S new tokens), attends through the model's mixer, and hands
+    back the attention output, what is held, and the layer's ys;
+    :meth:`result` makes the updated cache of the scan's two results.
 
-    Here the ``(L, B, Hkv, T, Dh)`` cache rides the scan as xs and
+    Here the ``(L, B, Hkv, T, width)`` cache rides the scan as xs and
     comes back as ys, one layer's buffers at a time, and nothing is
     held."""
 
-    def __init__(self, cache: dict, cache_len, scale, cfg, mesh):
+    def __init__(self, cache: dict, cache_len, mixer):
         self.held = ()
         self.per_layer = cache
         self._cache_len = cache_len
-        self._env = (scale, cfg, mesh)
+        self._mixer = mixer
 
     def _write(self, buf, new):
         """Insert S new entries at the cache pointer: one slice update
         for a shared scalar pointer, a per-row (vmapped, scatter-
-        lowered) update for per-stream pointers.  K/V buffers and int8
-        scales share the heads-major layout — the token axis sits at
-        -2 for both (D or the singleton scale at -1)."""
+        lowered) update for per-stream pointers.  K/V buffers, latent
+        rows and int8 scales share the heads-major layout — the token
+        axis sits at -2 for all."""
         at = self._cache_len
         if at.ndim == 1:
             return jax.vmap(lambda c, u, s: jax.lax.dynamic_update_slice(
                 c, u, (0, s, 0)))(buf, new, at)
         return jax.lax.dynamic_update_slice(buf, new, (0, 0, at, 0))
 
-    def layer(self, held, bufs, q, new, positions):
+    def layer(self, held, bufs, q, new, positions, layer):
         bufs = {name: self._write(buf, new[name].astype(buf.dtype))
                 for name, buf in bufs.items()}
-        o = _attend(q, bufs["k"], bufs["v"], bufs.get("k_s"),
-                    bufs.get("v_s"), positions, *self._env)
-        return o, held, bufs
+        return self._mixer.attend(q, bufs, positions, layer), held, bufs
 
     def result(self, held, per_layer):
         return per_layer
@@ -305,28 +367,42 @@ class DenseKV:
 
 def _make_mlp_fn(cfg: TransformerConfig, mesh, ep_axis: str,
                  token_mask=None):
-    """The per-layer feed-forward branch: dense SwiGLU, or the MoE
-    layer when the config is a :class:`~.moe.MoEConfig` (sharing
-    ``moe._moe_mlp_block`` so the two paths can never diverge).
-    ``token_mask`` reaches only the MoE dispatch (dense SwiGLU is
-    per-token, so inactive tokens cannot couple anything there)."""
+    """The per-layer feed-forward branch, ``(x, layer) -> (x, load)``:
+    dense SwiGLU, the capacity-dispatched MoE layer when the config is
+    a :class:`~.moe.MoEConfig` (sharing ``moe._moe_mlp_block`` so the
+    two paths can never diverge), or shared + routed fine-grained
+    experts for a layer that holds ``moe`` under a
+    :class:`~.mla.LatentMoEConfig`.  ``token_mask`` reaches only the
+    expert dispatch (dense SwiGLU is per-token, so inactive tokens
+    cannot couple anything there).  ``load`` is the layer's
+    :func:`~..parallel.expert.routing_load` where the last kind
+    routed, else None."""
+    from .mla import LatentMoEConfig, latent_moe_mlp_block
     from .moe import MoEConfig, _moe_mlp_block
 
     if isinstance(cfg, MoEConfig):
         def mlp(x, layer):
             x, _aux = _moe_mlp_block(x, layer, cfg, mesh, ep_axis,
                                      token_mask=token_mask)
-            return x
+            return x, None
 
         return mlp
-    return lambda x, layer: _mlp_block(x, layer, cfg)
+    if isinstance(cfg, LatentMoEConfig):
+        def mlp(x, layer):
+            if "moe" in layer:
+                return latent_moe_mlp_block(x, layer, cfg, token_mask)
+            return _mlp_block(x, layer, cfg), None
+
+        return mlp
+    return lambda x, layer: (_mlp_block(x, layer, cfg), None)
 
 
 def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
                        cfg: TransformerConfig, *,
                        last_only: bool = False, last_index=None,
                        mesh=None, ep_axis: str = "ep", row_mask=None,
-                       token_mask=None, block_table=None):
+                       token_mask=None, block_table=None,
+                       with_moe_load: bool = False):
     """Run ``tokens`` (B, S) through the model, reading/writing the KV
     cache at offset ``cache_len`` (traced scalar ok, or a per-row
     ``(B,)`` vector when the streams in the batch sit at different
@@ -361,15 +437,27 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
     (:class:`~.paged_kv.PagedKV`); rows outside ``row_mask`` write to
     the trash block.  The layer's mathematics is the same code either
     way; only where K/V are kept differs (:class:`DenseKV`).
+
+    The stack need not be one homogeneous scan: a
+    :class:`~.mla.LatentMoEConfig` holds its leading dense layers under
+    ``params["dense_layers"]`` (stacked, scanned) and its expert layers
+    under ``params["layers"]`` (one tree a layer, unrolled), run one
+    after the other over the one cache, and mixes tokens by latent
+    attention
+    (:class:`~.mla.MLAMixer`) where the dense family uses
+    :class:`GQAMixer`.  ``with_moe_load`` adds a third result, the
+    expert layers' :func:`~..parallel.expert.routing_load` as
+    ``[experts touched (mean over layers), most rows on one expert,
+    rows routed a layer]`` (zeros for a config that routes nothing
+    that way).
     """
+    from .mla import LatentMoEConfig, MLAMixer
     B, S = tokens.shape
-    H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     cache_len = jnp.asarray(cache_len)
     per_row = cache_len.ndim == 1  # per-stream cache pointers
     offs = cache_len[:, None] if per_row else cache_len
     positions = offs + jnp.broadcast_to(jnp.arange(S), (B, S))
     x = params["embed"][tokens].astype(cfg.dtype)
-    scale = 1.0 / float(cfg.head_dim) ** 0.5
     # row_mask (B,) bool: inactive batch rows (finished speculative
     # streams) must not couple to live rows — only MoE capacity
     # dispatch can couple rows, so the mask feeds the expert router.
@@ -377,39 +465,61 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
         rows = jnp.broadcast_to(row_mask[:, None], (B, S))
         token_mask = rows if token_mask is None else token_mask & rows
     mlp = _make_mlp_fn(cfg, mesh, ep_axis, token_mask=token_mask)
-    kv_quantized = "k_s" in cache
+    if isinstance(cfg, LatentMoEConfig):
+        mixer = MLAMixer(cfg, mesh)
+    else:
+        mixer = GQAMixer(cfg, mesh, kv_quantized="k_s" in cache)
     if block_table is not None:
         from .paged_kv import PagedKV
-        kv = PagedKV(cache, block_table, row_mask, scale, cfg, mesh)
+        kv = PagedKV(cache, block_table, row_mask, mixer, cfg, mesh)
     else:
-        kv = DenseKV(cache, cache_len, scale, cfg, mesh)
+        kv = DenseKV(cache, cache_len, mixer)
 
     def layer_step(carry, inputs):
         x, held = carry
         layer, per_layer = inputs
         h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q = _rope(qlinear(h, layer["wq"]).reshape(B, S, H, Dh),
-                  positions, cfg.rope_theta)
-        k = _rope(qlinear(h, layer["wk"]).reshape(B, S, Hkv, Dh),
-                  positions, cfg.rope_theta)
-        v = qlinear(h, layer["wv"]).reshape(B, S, Hkv, Dh)
-        # Heads-major for the cache: (B, S, Hkv, Dh) -> (B, Hkv, S, Dh).
-        new = {"k": k.transpose(0, 2, 1, 3), "v": v.transpose(0, 2, 1, 3)}
-        if kv_quantized:
-            new["k"], new["k_s"] = _quantize_kv(new["k"])
-            new["v"], new["v_s"] = _quantize_kv(new["v"])
+        q, new = mixer.project(h, layer, positions)
         # Named scopes (trace-time metadata): attention, mlp and, in
         # the serving step, sample can be told apart in a profile.
         with jax.named_scope("attention"):
             o, held, per_layer = kv.layer(held, per_layer, q, new,
-                                          positions)
-            x = x + qlinear(o, layer["wo"])
+                                          positions, layer)
+            x = x + mixer.out(o, layer)
         with jax.named_scope("mlp"):
-            x = mlp(x, layer)
-        return (x, held), per_layer
+            x, load = mlp(x, layer)
+        return (x, held), (per_layer, load)
 
-    (x, held), per_layer = jax.lax.scan(
-        layer_step, (x, kv.held), (params["layers"], kv.per_layer))
+    # One stack a kind of layer, in the model's order, over the one
+    # cache, whose per-layer part is cut where the stacks meet.  A
+    # stack is a dict of leaves stacked on a leading layer axis, which
+    # is scanned, or a tuple of one dict a layer, which is unrolled:
+    # weights that a layer hands to a kernel whole (grouped expert
+    # matmuls) must not be slices of a stack, or every layer copies
+    # them out of it first (models/mla.py).
+    stacks = [params[name] for name in ("dense_layers", "layers")
+              if name in params]
+    held, at, outs, loads = kv.held, 0, [], []
+    for stack in stacks:
+        if isinstance(stack, dict):
+            n = jax.tree_util.tree_leaves(stack)[0].shape[0]
+            xs = (kv.per_layer if len(stacks) == 1
+                  else jax.tree_util.tree_map(lambda c: c[at:at + n],
+                                              kv.per_layer))
+            (x, held), (ys, load) = jax.lax.scan(
+                layer_step, (x, held), (stack, xs))
+            outs.append(ys)
+            loads.append(load)
+            at += n
+            continue
+        for layer in stack:
+            xs = jax.tree_util.tree_map(lambda c: c[at], kv.per_layer)
+            (x, held), (ys, load) = layer_step((x, held), (layer, xs))
+            outs.append(jax.tree_util.tree_map(lambda y: y[None], ys))
+            loads.append(None if load is None else load[None])
+            at += 1
+    per_layer = (outs[0] if len(outs) == 1 else jax.tree_util.tree_map(
+        lambda *ys: jnp.concatenate(ys), *outs))
     new = kv.result(held, per_layer)
     if last_index is not None:
         idx = jnp.asarray(last_index, jnp.int32).reshape(B, 1, 1)
@@ -419,6 +529,14 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
         x = x[:, -1:]
     x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = qlinear(x, params["lm_head"]).astype(jnp.float32)
+    if with_moe_load:
+        routed = [l for l in loads if l is not None]
+        if not routed:
+            return logits, new, jnp.zeros((3,), jnp.float32)
+        routed = jnp.concatenate(routed)                # (layers, 3)
+        return logits, new, jnp.stack([jnp.mean(routed[:, 0]),
+                                       jnp.max(routed[:, 1]),
+                                       jnp.mean(routed[:, 2])])
     return logits, new
 
 

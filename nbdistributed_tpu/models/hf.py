@@ -217,6 +217,84 @@ def moe_params_from_hf(model, *, dtype: Any = jnp.bfloat16,
     return params, cfg
 
 
+def latent_moe_config_from_hf(hf_config, **overrides):
+    """Map a ``model_type: joyai_llm_flash`` config (DeepSeek-V3's
+    keys: MLA ranks and head sizes, ``first_k_dense_replace``,
+    ``n_routed_experts`` ...) onto
+    :class:`~nbdistributed_tpu.models.mla.LatentMoEConfig`.
+    ``overrides`` are fields of that class (``dtype``, ``use_flash``).
+
+    Refused rather than mis-served: rope scaling (this forward applies
+    no mscale), a group-limited choice (``n_group`` > 1), scoring other
+    than sigmoid with ``norm_topk_prob``, expert layers that do not
+    follow every dense one (``moe_layer_freq`` != 1).
+    ``num_nextn_predict_layers`` is read and dropped: multi-token
+    prediction is not served, and next-token logits do not depend on
+    it.
+
+    A real checkpoint would also need its weights permuted on the way
+    in: ``rope_interleave`` pairs rotary dimension ``2j`` with
+    ``2j + 1``, :func:`~.transformer._rope` pairs ``j`` with
+    ``j + half``, so the rotary columns of ``q_b_proj`` (per head) and
+    of ``kv_a_proj_with_mqa`` go from ``[0, 1, 2, ...]`` to
+    ``[0, 2, 4, ..., 1, 3, 5, ...]``; no weight converter for this
+    family exists yet."""
+    from .mla import LatentMoEConfig
+
+    get = lambda k, d=None: getattr(hf_config, k, d)
+    if get("rope_scaling"):
+        raise ValueError("rope_scaling is not supported for latent "
+                         "attention (no mscale is applied)")
+    if get("n_group", 1) != 1 or get("topk_group", 1) != 1:
+        raise ValueError("group-limited expert choice (n_group > 1) "
+                         "is not supported")
+    if get("scoring_func") != "sigmoid" or not get("norm_topk_prob"):
+        raise ValueError("only sigmoid scoring with normalised top-k "
+                         "gates is supported")
+    if get("moe_layer_freq", 1) != 1:
+        raise ValueError("moe_layer_freq != 1 is not supported")
+    if get("attention_bias", False):
+        raise ValueError("attention_bias=True is not supported")
+    return LatentMoEConfig(**{
+        "vocab_size": hf_config.vocab_size,
+        "d_model": hf_config.hidden_size,
+        "n_layers": hf_config.num_hidden_layers,
+        "n_heads": hf_config.num_attention_heads,
+        "d_ff": hf_config.intermediate_size,
+        "max_seq_len": get("max_position_embeddings", 4096),
+        "rope_theta": float(get("rope_theta", 10000.0)),
+        "norm_eps": float(get("rms_norm_eps", 1e-6)),
+        "q_lora_rank": hf_config.q_lora_rank,
+        "kv_lora_rank": hf_config.kv_lora_rank,
+        "qk_nope_head_dim": hf_config.qk_nope_head_dim,
+        "qk_rope_head_dim": hf_config.qk_rope_head_dim,
+        "v_head_dim": hf_config.v_head_dim,
+        "n_dense_layers": hf_config.first_k_dense_replace,
+        "n_experts": hf_config.n_routed_experts,
+        "top_k": hf_config.num_experts_per_tok,
+        "d_expert": hf_config.moe_intermediate_size,
+        "n_shared_experts": get("n_shared_experts", 0),
+        "routed_scale": float(get("routed_scaling_factor", 1.0)),
+        **overrides})
+
+
+def config_from_hf_json(config: dict, **overrides):
+    """A published ``config.json`` (as a dict) -> the program's config,
+    by its ``model_type``; one this tree cannot run raises."""
+    import types
+    ns = types.SimpleNamespace(**config)
+    kind = config.get("model_type")
+    if kind == "joyai_llm_flash":
+        return latent_moe_config_from_hf(ns, **overrides)
+    if kind == "mixtral":
+        cfg = moe_config_from_hf(ns)
+    elif kind in ("llama", "mistral"):
+        cfg = config_from_hf(ns)
+    else:
+        raise ValueError(f"model_type {kind!r} is not supported")
+    return type(cfg)(**{**cfg.__dict__, **overrides})
+
+
 def load_hf_pretrained(name_or_path: str, *,
                        dtype: Any = jnp.bfloat16) -> tuple[dict, Any]:
     """``from_pretrained`` (local path or cached hub name, torch CPU)

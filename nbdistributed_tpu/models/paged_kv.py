@@ -62,7 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..serving_fast.paging import BlockAllocator, blocks_needed
-from .generate import _attend, init_kv_cache, kv_cache_shardings
+from .generate import init_kv_cache, kv_cache_shardings
 
 
 def make_paged_pool(cfg, n_blocks: int, block_tokens: int, *,
@@ -173,37 +173,35 @@ def reads_in_place(cfg, mesh) -> bool:
 class PagedKV:
     """The paged pool's side of :func:`~.generate.forward_with_cache`'s
     seam (see :class:`~.generate.DenseKV` for the contract): the whole
-    ``(L, NB+1, Hkv, bt, D)`` pool is held across the layer scan and
-    updated in place, and a layer takes only its index."""
+    ``(L, NB+1, Hkv, bt, width)`` pool is held across the layer scans
+    and updated in place, and a layer takes only its index.  What a
+    page holds is the mixer's business: K and V heads
+    (:class:`~.generate.GQAMixer`) or one latent row
+    (:class:`~.mla.MLAMixer`)."""
 
-    def __init__(self, pool: dict, table, active, scale, cfg, mesh):
+    def __init__(self, pool: dict, table, active, mixer, cfg, mesh):
         self.held = pool
         n_layers = jax.tree_util.tree_leaves(pool)[0].shape[0]
         self.per_layer = jnp.arange(n_layers, dtype=jnp.int32)
         self._table, self._active = table, active
-        self._env = (scale, cfg, mesh)
+        self._mixer = mixer
+        self._in_place = reads_in_place(cfg, mesh)
 
-    def layer(self, pool, layer, q, new, positions):
+    def layer(self, pool, layer_idx, q, new, positions, layer):
         if q.shape[1] != 1:
             raise ValueError("a paged pool takes decode steps only "
                              f"(one new token a row, got {q.shape[1]})")
-        scale, cfg, mesh = self._env
         pos = positions[:, 0]
         # Write first, attend second: the kernel sees position pos.
-        pool = write_token(pool, layer, new, self._table, pos,
+        pool = write_token(pool, layer_idx, new, self._table, pos,
                            self._active)
-        if reads_in_place(cfg, mesh):
-            from ..ops.decode import paged_decode_attention
-            o = paged_decode_attention(
-                q[:, 0], pool["k"], pool["v"], layer, self._table, pos,
-                active=self._active, scale=scale,
-                window=getattr(cfg, "sliding_window", None),
-                k_s=pool.get("k_s"), v_s=pool.get("v_s"))
-            o = o.reshape(q.shape[0], 1, -1)
+        if self._in_place:
+            o = self._mixer.attend_paged(q, pool, layer_idx,
+                                         self._table, pos,
+                                         self._active, layer)
         else:
-            view = gather_layer(pool, layer, self._table)
-            o = _attend(q, view["k"], view["v"], view.get("k_s"),
-                        view.get("v_s"), positions, *self._env)
+            view = gather_layer(pool, layer_idx, self._table)
+            o = self._mixer.attend(q, view, positions, layer)
         return o, pool, None
 
     def result(self, pool, per_layer):

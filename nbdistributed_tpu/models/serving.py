@@ -77,6 +77,19 @@ from .generate import (_sample, forward_with_cache, init_kv_cache,
                        kv_cache_shardings)
 from .transformer import TransformerConfig
 
+def _capacity_dispatch(cfg, mesh, ep_axis: str) -> bool:
+    """Whether the config's experts are dispatched into per-expert (or
+    per-shard) capacity buffers whose size follows the call's token
+    count — what ties a request's result to the shape it was run at.
+    Dropless routing on one shard has no capacity, and neither have
+    :class:`~.mla.LatentMoEConfig`'s experts."""
+    from .moe import MoEConfig
+    if not isinstance(cfg, MoEConfig):
+        return False
+    sharded = mesh is not None and ep_axis in mesh.shape
+    return cfg.moe_dispatch != "dropless" or sharded
+
+
 # The phases of one :meth:`DecodeServer.step`, in order.  Adjacent
 # phases share their boundary instant, so a step's phases sum to its
 # wall time; the same names are the ``serve/step/*`` spans and
@@ -170,24 +183,27 @@ class DecodeServer:
                                  "vocabulary")
             if gamma < 1:
                 raise ValueError(f"gamma must be >= 1, got {gamma}")
-        from .moe import MoEConfig
-        if isinstance(cfg, MoEConfig):
+        if _capacity_dispatch(cfg, mesh, ep_axis):
             # Expert capacity is computed from the *static* token count
             # of the prefill shape: a padded bucket would inflate it
             # past what a solo generate() run of the same prompt gets,
             # and capacity changes which tokens drop — silently
-            # breaking the solo-request exactness guarantee.  MoE
+            # breaking the solo-request exactness guarantee.  Such
             # admission therefore compiles per distinct prompt length
-            # (pad_to=1); dense configs keep the bucket economy.
+            # (pad_to=1); dense configs and dropless experts keep the
+            # bucket economy.
             pad_to = 1
             if prefill_chunk is not None:
                 # Chunked admission derives capacity from the CHUNK's
                 # token count — again not a solo run's.  Same reason.
                 raise ValueError(
-                    "prefill_chunk is a dense-family option: MoE "
-                    "expert capacity is shape-derived, so per-chunk "
-                    "capacity would differ from a solo run's and "
-                    "change which tokens drop")
+                    "prefill_chunk needs dense layers or dropless "
+                    "experts: capacity-based expert dispatch derives "
+                    "capacity from the shape, so per-chunk capacity "
+                    "would differ from a solo run's and change which "
+                    "tokens drop")
+        from .mla import LatentMoEConfig
+        self._routed = isinstance(cfg, LatentMoEConfig)
         self._params = params
         self._cfg = cfg
         self._mesh = mesh
@@ -301,6 +317,12 @@ class DecodeServer:
         # each tick's deltas, as for the token counters).
         self.kv_read_bytes_total = 0
         self.decode_steps_total = 0
+        # Routing load of the decode steps since :meth:`take_moe_load`
+        # (a config whose experts report one: ``_routed``), fetched
+        # with each step's tokens: experts touched summed over steps
+        # (each the mean over the expert layers), the most rows one
+        # expert took in a step, rows routed a layer summed over steps.
+        self.moe_load = [0.0, 0.0, 0.0]
         # Cumulative seconds per phase of step() (and of submit()'s
         # admission, which is prefill), on this process's
         # perf_counter; the worker's serve_step handler reports each
@@ -366,17 +388,22 @@ class DecodeServer:
         # Every jitted serving program carries a name that says what
         # it is (``jit_nbd_decode_step*`` / ``jit_nbd_prefill*``): the
         # profile's "XLA Modules" line splits device time by it.
+        routed = self._routed
+
         def nbd_decode_step(params, cache, lens, last, active, key,
                             table=None):
-            logits, cache = forward_with_cache(
+            """-> (cache, lens, next tokens, the step's routing load
+            where the config's experts report one, else None)."""
+            logits, cache, *load = forward_with_cache(
                 params, last[:, None], cache, lens, cfg, mesh=mesh,
-                ep_axis=ep_axis, row_mask=active, block_table=table)
+                ep_axis=ep_axis, row_mask=active, block_table=table,
+                with_moe_load=routed)
             with jax.named_scope("sample"):
                 nxt = _sample(logits[:, -1], temperature, key, top_k,
                               top_p)
             nxt = jnp.where(active, nxt, last)
             lens = lens + active.astype(lens.dtype)
-            return cache, lens, nxt
+            return cache, lens, nxt, (load[0] if routed else None)
 
         return nbd_decode_step
 
@@ -415,6 +442,7 @@ class DecodeServer:
                           self._paged.device_row(int(slot)), prompt,
                           start, length)
 
+        wrapper.program = jit_fn    # to lower it without a live slot
         return wrapper
 
     def _jit_step_paged(self):
@@ -439,8 +467,8 @@ class DecodeServer:
                                  keys):
             def body(carry, k):
                 cache, lens, last = carry
-                cache, lens, nxt = step(params, cache, lens, last,
-                                        active, k)
+                cache, lens, nxt, _load = step(params, cache, lens,
+                                               last, active, k)
                 return (cache, lens, nxt), nxt
 
             (cache, lens, last), toks = jax.lax.scan(
@@ -634,15 +662,16 @@ class DecodeServer:
         positions are absolute, so the copied rows are bit-identical
         to a full prefill's.
 
-        Dense family only: MoE expert capacity is shape-derived, so a
-        suffix-length prefill would change which tokens drop vs a solo
-        run (the same reason MoE rejects ``prefill_chunk``).
+        Not for capacity-based expert dispatch: capacity is
+        shape-derived, so a suffix-length prefill would change which
+        tokens drop vs a solo run (the same reason it rejects
+        ``prefill_chunk``).
         """
-        from .moe import MoEConfig
-        if isinstance(self._cfg, MoEConfig):
+        if _capacity_dispatch(self._cfg, self._mesh, self._ep_axis):
             raise ValueError(
-                "prefix caching is a dense-family option: MoE expert "
-                "capacity is shape-derived, so suffix prefill would "
+                "prefix caching needs dense layers or dropless "
+                "experts: capacity-based expert dispatch derives "
+                "capacity from the shape, so suffix prefill would "
                 "differ from a solo run and change which tokens drop")
         if self._paged is not None:
             raise ValueError(
@@ -912,6 +941,11 @@ class DecodeServer:
         t3 = time.perf_counter()
         ph["sync"] += t3 - t2
         with obs_spans.phase("serve/step/emit", tick):
+            if self._routed and self._draft_cfg is None:
+                touched, most, rows = (float(v) for v in out[1])
+                self.moe_load[0] += touched
+                self.moe_load[1] = max(self.moe_load[1], most)
+                self.moe_load[2] += rows
             emitted: dict[int, list[int]] = {}
             for slot, rid in list(self._slot_req.items()):
                 emitted[rid] = self._emit(slot, rid,
@@ -944,10 +978,10 @@ class DecodeServer:
             table = (self._paged.device_table(),)
             self.kv_read_bytes_total += self._step_kv_read_bytes()
         self.decode_steps_total += 1
-        self._cache, self._lens, self._last = self._step_fn(
+        self._cache, self._lens, self._last, load = self._step_fn(
             self._params, self._cache, *table, self._lens, self._last,
             self._active, self._sample_key())
-        return self._last
+        return self._last if load is None else (self._last, load)
 
     def _step_kv_read_bytes(self) -> int:
         """Bytes of K and V pages the next decode step's attention
@@ -968,7 +1002,8 @@ class DecodeServer:
         if self._draft_cfg is not None:
             cand, n_acc = out
             return [int(t) for t in cand[slot][: int(n_acc[slot]) + 1]]
-        return [int(out[slot])]
+        toks = out[0] if self._routed else out
+        return [int(toks[slot])]
 
     def _emit(self, slot: int, rid: int, toks: list[int]) -> list[int]:
         """Budget-then-EOS truncation + bookkeeping for a multi-token
@@ -1119,6 +1154,12 @@ class DecodeServer:
         gateway's observatory can annotate prefill[chunk i/n]."""
         return {st[0]: (st[3], len(st[1]))
                 for st in self._prefilling.values()}
+
+    def take_moe_load(self) -> list[float]:
+        """:attr:`moe_load` since the last call, which it resets (the
+        worker reports it once a tick beside the steps that ran)."""
+        load, self.moe_load = self.moe_load, [0.0, 0.0, 0.0]
+        return load
 
     def kv_snapshot(self) -> dict | None:
         """Paged-mode block occupancy (``{"blocks", "block_tokens",

@@ -424,7 +424,7 @@ class ServingObservatory:
                   turnaround: float | None = None,
                   idled: bool = False,
                   t_wall: float | None = None,
-                  kv_read=None) -> dict | None:
+                  kv_read=None, moe=None) -> dict | None:
         """One tick of one rank: the gateway's phase seconds, the
         worker's (its ``tick["ph"]``), the worker's compile delta
         ``[count, seconds]`` and the ``turnaround`` it waited since
@@ -432,7 +432,10 @@ class ServingObservatory:
         marks a tick that followed a wait for work: its turnaround is
         no part of a decode period.  ``kv_read`` is the worker's
         ``[bytes, steps]``: K and V pages its decode steps fetched
-        from a paged pool in this tick.  Returns the tick's record when
+        from a paged pool in this tick; ``moe`` its ``[experts touched
+        summed over those steps, most rows on one expert, rows routed
+        a layer summed]`` where the model routes to fine-grained
+        experts.  Returns the tick's record when
         it was slow (kept under ``slow``; the caller writes it to the
         flight recorder, once), else None."""
         wk = {k: max(0.0, float(worker.get(k) or 0.0))
@@ -450,6 +453,7 @@ class ServingObservatory:
                             3),
             "gw": gw, "wk": wk, "cmp": cmp, "idled": bool(idled),
             "kvr": [int(kv_bytes), int(kv_steps)],
+            "moe": None if moe is None else [float(v) for v in moe],
             "turnaround": (None if turnaround is None
                            else max(0.0, float(turnaround))),
             "handler": handler,
@@ -508,6 +512,17 @@ class ServingObservatory:
                          sum(t["kvr"][0] for t in ticks)
                          / max(1, sum(t["kvr"][1] for t in ticks))),
                      "slow": slow}
+        routed = [t for t in ticks if t["moe"] is not None]
+        if routed:
+            # a decode step's routing load: means over the steps, the
+            # most rows one expert took in any
+            steps = max(1, sum(t["kvr"][1] for t in routed))
+            out["moe"] = {
+                "experts_touched": round(
+                    sum(t["moe"][0] for t in routed) / steps, 2),
+                "max_rows": max(t["moe"][1] for t in routed),
+                "rows_routed": round(
+                    sum(t["moe"][2] for t in routed) / steps, 2)}
         if not ticks:
             return out
         periods = [t["period"] for t in ticks

@@ -242,7 +242,12 @@ def _paged_decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref,
     ``Hkv``.  Same recurrence, masks and float32 state as
     :func:`_decode_kernel`; pages are whole blocks of the pool, so
     there is no padded tail to zero.  A row whose ``pos`` is negative
-    takes no part: no page of it is live, and it writes zeros."""
+    takes no part: no page of it is live, and it writes zeros.
+
+    ``v_ref`` None: the values are a prefix of the keys (a latent
+    pool's page holds ``[c_kv | k_rope]``: the scores take all of it,
+    the weighted sum its first ``o_ref.shape[-1]`` columns), so the
+    page is fetched once a grid step for both products."""
     from jax.experimental import pallas as pl
 
     del layer_ref, table_ref            # consumed by the index maps
@@ -262,7 +267,8 @@ def _paged_decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref,
     def _page():
         q = q_ref[0].astype(jnp.float32) * scale        # (Hkv, group, D)
         k_pg = k_ref[...].astype(jnp.float32)           # (Hkv, bt, D)
-        v_pg = v_ref[...].astype(jnp.float32)
+        v_pg = (k_pg[..., :o_ref.shape[-1]] if v_ref is None
+                else v_ref[...].astype(jnp.float32))
         s = jax.lax.dot_general(
             q, k_pg, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)         # (Hkv, group, bt)
@@ -290,11 +296,17 @@ def _paged_decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "window"))
+                   static_argnames=("scale", "interpret", "window",
+                                    "v_width"))
 def _paged_decode_call(q, k_pool, v_pool, layer, table, pos, *,
                        scale: float, interpret: bool,
-                       window: int | None = None, k_s=None, v_s=None):
-    """The pool stays where it lies: ``layer``, ``table`` and ``pos``
+                       window: int | None = None, k_s=None, v_s=None,
+                       v_width: int | None = None):
+    """``v_pool`` None makes the pool a latent one, whose values are
+    the first ``v_width`` columns of its keys (one operand, one copy of
+    a page a grid step; the call carries its own name in a profile).
+
+    The pool stays where it lies: ``layer``, ``table`` and ``pos``
     are scalar-prefetch operands, and the K/V index map turns grid step
     ``(b, kb)`` into physical block ``table[b, j]`` of layer ``layer``,
     with ``j`` the logical page ``kb`` clamped to the row's live range
@@ -322,12 +334,18 @@ def _paged_decode_call(q, k_pool, v_pool, layer, table, pos, *,
     def row(b, kb, layer, table, pos):
         return (b, 0, 0, 0)
 
+    latent = v_pool is None
+    Dv = v_width if latent else D
+
     def _kernel(layer_ref, table_ref, pos_ref, *refs):
         *refs, o_ref, a, m, l = refs
-        if quantized:
+        ks_ref = vs_ref = v_ref = None
+        if latent:
+            q_ref, k_ref = refs
+        elif quantized:
             q_ref, k_ref, v_ref, ks_ref, vs_ref = refs
         else:
-            (q_ref, k_ref, v_ref), ks_ref, vs_ref = refs, None, None
+            q_ref, k_ref, v_ref = refs
         _paged_decode_kernel(layer_ref, table_ref, pos_ref, q_ref,
                              k_ref, v_ref, o_ref, a, m, l,
                              block_tokens=bt, scale=scale,
@@ -337,9 +355,11 @@ def _paged_decode_call(q, k_pool, v_pool, layer, table, pos, *,
     in_specs = [
         pl.BlockSpec((1, Hkv, group, D), row),                # q
         pl.BlockSpec((None, None, Hkv, bt, D), page),         # k
-        pl.BlockSpec((None, None, Hkv, bt, D), page),         # v
     ]
-    args = [layer.reshape(1), table, pos, q, k_pool, v_pool]
+    args = [layer.reshape(1), table, pos, q, k_pool]
+    if not latent:
+        in_specs.append(pl.BlockSpec((None, None, Hkv, bt, D), page))
+        args.append(v_pool)
     if quantized:
         in_specs += [pl.BlockSpec((None, None, Hkv, bt, 1), page)] * 2
         args += [k_s, v_s]
@@ -349,16 +369,17 @@ def _paged_decode_call(q, k_pool, v_pool, layer, table, pos, *,
             num_scalar_prefetch=3,
             grid=(S, num_kb),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, Hkv, group, D), row),
+            out_specs=pl.BlockSpec((1, Hkv, group, Dv), row),
             scratch_shapes=[
-                pltpu.VMEM((Hkv, group, D), jnp.float32),   # acc
+                pltpu.VMEM((Hkv, group, Dv), jnp.float32),  # acc
                 pltpu.VMEM((Hkv, group, 1), jnp.float32),   # running max
                 pltpu.VMEM((Hkv, group, 1), jnp.float32),   # normalizer
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((S, Hkv, group, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, Hkv, group, Dv), q.dtype),
         interpret=interpret,
-        name="nbd_flash_decode_paged",
+        name="nbd_mla_decode_paged" if latent
+        else "nbd_flash_decode_paged",
     )(*args)
 
 
@@ -471,3 +492,30 @@ def paged_decode_attention(q, k_pool, v_pool, layer, table, pos, *,
         pos, scale=float(scale), interpret=_use_interpret(),
         window=window, k_s=k_s, v_s=v_s)
     return out.reshape(S, H, D)
+
+
+def paged_latent_decode_attention(q, pool, layer, table, pos, *,
+                                  v_width: int, scale: float,
+                                  active=None):
+    """Absorbed latent (MLA) decode attention over a paged latent pool,
+    read in place like :func:`paged_decode_attention`: one KV head whose
+    keys are a whole page row ``[c_kv | k_rope]`` and whose values are
+    its first ``v_width`` columns.
+
+    q: (S, H, W) — per head ``[q_nope W_uk^T | q_rope]``, W the pool's
+    width; pool: (L, NB+1, 1, bt, W); ``layer``, ``table``, ``pos``,
+    ``active`` as there.  Returns (S, H, v_width): the probability-
+    weighted sum of ``c_kv``, still to be taken through ``W_uv``."""
+    S, H, W = q.shape
+    if pool.shape[2] != 1 or pool.shape[-1] != W:
+        raise ValueError(f"a latent pool holds one head of the "
+                         f"queries' width {W}, got {pool.shape}")
+    pos = jnp.asarray(pos, jnp.int32)
+    if active is not None:
+        pos = jnp.where(active, pos, -1)
+    out = _paged_decode_call(
+        q.reshape(S, 1, H, W), pool, None,
+        jnp.asarray(layer, jnp.int32), jnp.asarray(table, jnp.int32),
+        pos, scale=float(scale), interpret=_use_interpret(),
+        v_width=int(v_width))
+    return out.reshape(S, H, v_width)

@@ -77,6 +77,34 @@ def top_k_routing(logits, top_k: int):
     return gates, expert_idx, probs
 
 
+def sigmoid_bias_routing(logits, bias, top_k: int, scale: float):
+    """Sigmoid scoring with a selection bias (DeepSeek-V3's
+    ``noaux_tc`` without a group limit): scores ``s = sigmoid(logits)``
+    in float32, the ``top_k`` experts chosen by ``s + bias``
+    (``bias`` (E,): a per-expert buffer that steers the choice and
+    never the weight), gates the chosen ``s`` over their sum
+    (+1e-20) times ``scale``.  logits (T, E) -> gates (T, k),
+    expert_idx (T, k)."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, expert_idx = jax.lax.top_k(s + bias.astype(jnp.float32), top_k)
+    g = jnp.take_along_axis(s, expert_idx, axis=-1)
+    g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-20)
+    return g * scale, expert_idx
+
+
+def routing_load(expert_idx, n_experts: int, token_mask=None):
+    """What one layer's routing asks of the expert weights, as three
+    float32 numbers: experts that received a row, the most rows on one
+    expert, rows routed.  Masked tokens count nowhere."""
+    T, k = expert_idx.shape
+    w = (jnp.ones((T,), jnp.int32) if token_mask is None
+         else token_mask.astype(jnp.int32))
+    counts = jnp.zeros((n_experts,), jnp.int32).at[
+        expert_idx.reshape(-1)].add(jnp.repeat(w, k))
+    return jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
+                      jnp.sum(counts)]).astype(jnp.float32)
+
+
 def make_dispatch(gates, expert_idx, n_experts: int, capacity: int,
                   token_mask=None):
     """Dense dispatch/combine tensors from routing decisions.
@@ -182,8 +210,8 @@ def _dropless_ffn(xt, params, gates, expert_idx, E: int,
     per-expert batching).
 
     Masked tokens sort into a sentinel bin PAST every real segment
-    (group_sizes covers only real experts, so ragged_dot's uncovered
-    tail rows are zeros) and their gate weight is zeroed — both belts.
+    (group_sizes covers only real experts), and both their rows and
+    their gate weights are zeroed.
     """
     T, D = xt.shape
     order, e_sorted, tok, counts = _route_sort(expert_idx, E,
@@ -198,6 +226,12 @@ def _dropless_ffn(xt, params, gates, expert_idx, E: int,
                                  e_sorted))
     rows = _ragged_expert_linear(h, params["w_down"], group_sizes,
                                  e_sorted)        # (kT, D)
+    # The rows past the covered total are zeros only in XLA's own
+    # ragged_dot; the TPU's grouped-matmul kernel leaves them unwritten,
+    # and 0 * (whatever memory held) may be NaN.  A masked token's
+    # NaN would reach the KV cache (the trash block, pad positions),
+    # and from there every row whose p @ v multiplies it by zero.
+    rows = jnp.where(keep[:, None], rows, 0)
     g_sorted = gates.T.reshape(-1)[order]
     w = jnp.where(keep, g_sorted, 0.0).astype(xt.dtype)
     return jnp.zeros((T, D), xt.dtype).at[tok].add(rows * w[:, None])
@@ -500,3 +534,43 @@ def moe_ffn(x, params: dict, *, top_k: int = 2,
     else:
         y = jnp.einsum("tec,ecd->td", combine.astype(x.dtype), ye)
     return y.reshape(orig_shape), aux
+
+
+def _swiglu(x, p):
+    """One SwiGLU feed-forward ``{w_gate, w_up, w_down}``."""
+    from ..models.transformer import qlinear
+    return qlinear(jax.nn.silu(qlinear(x, p["w_gate"]))
+                   * qlinear(x, p["w_up"]), p["w_down"])
+
+
+def shared_routed_ffn(x, params: dict, *, top_k: int,
+                      routed_scale: float, token_mask=None):
+    """Fine-grained experts as DeepSeek-V3 and its kin deploy them:
+    ``y = sum_i g_i E_i(x) + E_shared(x)`` with the gates of
+    :func:`sigmoid_bias_routing` (``params["router"]`` (D, E),
+    ``params["bias"]`` (E,)), the routed experts as dropless
+    ``ragged_dot`` segments (:func:`_dropless_ffn`: no capacity, so the
+    result of a token depends on no other token and on no shape —
+    bucketed, chunked and batched calls compute the same thing) and
+    ``params["shared"]`` a SwiGLU every token passes through.
+
+    ``token_mask`` (bool, ``x.shape[:-1]``): masked tokens (pad
+    positions, idle slots) route nowhere and touch no expert's
+    weights; their output rows are the shared expert's alone and are
+    never read.  x: (..., D) -> (same shape, :func:`routing_load`)."""
+    orig_shape = x.shape
+    xt = x.reshape(-1, orig_shape[-1])
+    E = params["router"].shape[-1]
+    mask_t = None if token_mask is None else token_mask.reshape(-1)
+    logits = jnp.matmul(xt.astype(jnp.float32),
+                        params["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    gates, expert_idx = sigmoid_bias_routing(
+        logits, params["bias"], top_k, routed_scale)
+    with jax.named_scope("experts"):
+        y = _dropless_ffn(xt, params, gates, expert_idx, E,
+                          token_mask=mask_t)
+    with jax.named_scope("shared_expert"):
+        y = y + _swiglu(xt, params["shared"])
+    return (y.reshape(orig_shape),
+            routing_load(expert_idx, E, mask_t))

@@ -1375,6 +1375,11 @@ class DistributedWorker:
                 # fetched from a paged pool, and how many steps ran.
                 "kvr": [getattr(srv, "kv_read_bytes_total", 0) - kvr0[0],
                         getattr(srv, "decode_steps_total", 0) - kvr0[1]]}
+        if getattr(srv, "_routed", False):
+            # The tick's routing load over its decode steps (kvr[1] of
+            # them): experts touched summed, most rows on one expert,
+            # rows routed a layer summed.
+            tick["moe"] = [round(v, 3) for v in srv.take_moe_load()]
         if st.t_reply is not None:
             tick["turnaround"] = round(t_in - st.t_reply, 6)
         st.t_reply = t_out

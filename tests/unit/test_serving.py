@@ -454,7 +454,7 @@ def test_chunked_prefill_rejected_for_moe():
     from nbdistributed_tpu.models import init_moe_model, tiny_moe_config
     cfg = tiny_moe_config(dtype=jnp.float32, use_flash=False)
     params = init_moe_model(jax.random.PRNGKey(4), cfg)
-    with pytest.raises(ValueError, match="dense-family"):
+    with pytest.raises(ValueError, match="capacity-based"):
         DecodeServer(params, cfg, max_batch=1, max_len=32,
                      prefill_chunk=8)
     with pytest.raises(ValueError, match="prefill_chunk"):
@@ -679,7 +679,7 @@ def test_prefix_cache_rejected_for_moe():
     cfg = tiny_moe_config(dtype=jnp.float32, use_flash=False)
     params = init_moe_model(jax.random.PRNGKey(0), cfg)
     srv = DecodeServer(params, cfg, max_batch=1, max_len=32)
-    with pytest.raises(ValueError, match="dense-family"):
+    with pytest.raises(ValueError, match="capacity-based"):
         srv.cache_prefix([1, 2, 3])
 
 
