@@ -1389,7 +1389,8 @@ class ServingManager:
             slow = self.obs.note_tick(
                 seq, rank, gw, tk.get("ph") or {}, tk.get("cmp"),
                 turnaround=tk.get("turnaround"), idled=idled,
-                kv_read=tk.get("kvr"), moe=tk.get("moe"))
+                kv_read=tk.get("kvr"), moe=tk.get("moe"),
+                prefill_keys=tk.get("pfk"))
             if slow is not None:
                 self._record("serve_slow_tick", **slow)
         if lost:

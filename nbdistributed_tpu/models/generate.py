@@ -310,14 +310,25 @@ class GQAMixer:
                        self.mesh)
 
     def attend_paged(self, q, pool, layer_idx, table, pos, active,
-                     layer):
-        from ..ops.decode import paged_decode_attention
-        o = paged_decode_attention(
-            q[:, 0], pool["k"], pool["v"], layer_idx, table, pos,
-            active=active, scale=self.scale,
-            window=getattr(self.cfg, "sliding_window", None),
-            k_s=pool.get("k_s"), v_s=pool.get("v_s"))
-        return o.reshape(q.shape[0], 1, -1)
+                     layer, length=None):
+        """One new token a row (a decode step: ``pos`` its position,
+        ``active`` the rows that take part), or a chunk of them
+        (``pos`` the first one's position, ``length`` the real ones of
+        each row's chunk)."""
+        from ..ops.decode import (paged_decode_attention,
+                                  paged_prefill_attention)
+        kw = dict(scale=self.scale,
+                  window=getattr(self.cfg, "sliding_window", None),
+                  k_s=pool.get("k_s"), v_s=pool.get("v_s"))
+        if q.shape[1] == 1:
+            o = paged_decode_attention(
+                q[:, 0], pool["k"], pool["v"], layer_idx, table, pos,
+                active=active, **kw)
+        else:
+            o = paged_prefill_attention(
+                q, pool["k"], pool["v"], layer_idx, table, pos, length,
+                **kw)
+        return o.reshape(*q.shape[:2], -1)
 
     def out(self, o, layer):
         return qlinear(o, layer["wo"])
@@ -431,12 +442,14 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
     for inactive streams; passing both ANDs them.
 
     ``block_table`` (B, MB) makes ``cache`` the *paged* physical pool
-    (:mod:`.paged_kv`) and this a decode step over it (S = 1, per-row
-    ``cache_len``): each layer writes its one new token into the
-    row's page and attends over the pool where it lies
-    (:class:`~.paged_kv.PagedKV`); rows outside ``row_mask`` write to
-    the trash block.  The layer's mathematics is the same code either
-    way; only where K/V are kept differs (:class:`DenseKV`).
+    (:mod:`.paged_kv`): each layer writes its new entries into the
+    row's pages and attends over the pool where it lies
+    (:class:`~.paged_kv.PagedKV`).  S = 1 with a per-row ``cache_len``
+    is a decode step (rows outside ``row_mask`` write to the trash
+    block); S > 1 is a chunk of a prefill, whose ``token_mask`` also
+    bounds the keys it attends (none at or past the last real token).
+    The layer's mathematics is the same code either way; only where K/V
+    are kept differs (:class:`DenseKV`).
 
     The stack need not be one homogeneous scan: a
     :class:`~.mla.LatentMoEConfig` holds its leading dense layers under
@@ -471,7 +484,8 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
         mixer = GQAMixer(cfg, mesh, kv_quantized="k_s" in cache)
     if block_table is not None:
         from .paged_kv import PagedKV
-        kv = PagedKV(cache, block_table, row_mask, mixer, cfg, mesh)
+        kv = PagedKV(cache, block_table, row_mask, mixer, cfg, mesh,
+                     token_mask=token_mask)
     else:
         kv = DenseKV(cache, cache_len, mixer)
 

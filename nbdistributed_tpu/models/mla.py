@@ -11,12 +11,14 @@ Two things set this family apart from the dense one
   by all, and the cache holds per token and layer only
   ``[c_kv | k_rope]`` — ``kv_lora_rank + qk_rope_head_dim`` values,
   in rows of whole 128-lane tiles (:class:`MLAMixer`,
-  :attr:`LatentMoEConfig.cache_width`).  Prefill attends with up-projected K and V;
-  a decode step is *absorbed*: ``W_uk`` goes into the query and
-  ``W_uv`` after the weighted sum, so attention runs over the latent
-  rows themselves (one KV head, the values a prefix of the keys) and a
-  paged pool is read in place by
-  :func:`~..ops.decode.paged_latent_decode_attention`.
+  :attr:`LatentMoEConfig.cache_width`).  Prefill over a dense cache
+  attends with up-projected K and V; a decode step, and a prefill
+  chunk over a paged pool, are *absorbed*: ``W_uk`` goes into the
+  query and ``W_uv`` after the weighted sum, so attention runs over the
+  latent rows themselves (one KV head, the values a prefix of the keys)
+  and a paged pool is read in place by
+  :func:`~..ops.decode.paged_latent_decode_attention` and
+  :func:`~..ops.decode.paged_prefill_attention`.
 * **The stack is not one homogeneous scan.**  ``n_dense_layers``
   leading layers carry a dense SwiGLU of width ``d_ff``; the rest carry
   ``n_experts`` routed experts of width ``d_expert`` chosen by sigmoid
@@ -225,9 +227,10 @@ class MLAMixer:
     seam (the contract is :class:`~.generate.GQAMixer`'s).  The cache's
     one leaf ``ckv`` holds ``[norm(c_kv) | RoPE(k_rope)]`` a token.
 
-    Several new tokens a row (prefill, a chunk of it) attend with K and
-    V up-projected from the row; one new token a row (a decode step)
-    attends absorbed, over the latent rows themselves.  Both compute
+    Several new tokens a row over a dense cache (prefill, a chunk of
+    it) attend with K and V up-projected from the row; one new token a
+    row (a decode step), and anything over a paged pool, attends
+    absorbed, over the latent rows themselves.  Both compute
     ``softmax((q_nope . k_nope + q_rope . k_rope) / sqrt(qk_head_dim))
     v`` to rounding.
 
@@ -313,13 +316,23 @@ class MLAMixer:
         return o.reshape(*o.shape[:2], -1).astype(q.dtype)
 
     def attend_paged(self, q, pool, layer_idx, table, pos, active,
-                     layer):
-        from ..ops.decode import paged_latent_decode_attention
-        o_lat = paged_latent_decode_attention(
-            self._absorb(q, layer)[:, 0], pool["ckv"], layer_idx, table,
-            pos, v_width=self.cfg.kv_lora_rank, scale=self.scale,
-            active=active)
-        return self._unabsorb(o_lat[:, None], layer)
+                     layer, length=None):
+        """Absorbed either way (the contract is
+        :meth:`~.generate.GQAMixer.attend_paged`'s): a chunk too runs
+        over the latent rows themselves, a page read once for both
+        products and no position of the row up-projected."""
+        from ..ops.decode import (paged_latent_decode_attention,
+                                  paged_prefill_attention)
+        qa, r = self._absorb(q, layer), self.cfg.kv_lora_rank
+        if q.shape[1] == 1:
+            o_lat = paged_latent_decode_attention(
+                qa[:, 0], pool["ckv"], layer_idx, table, pos, v_width=r,
+                scale=self.scale, active=active)[:, None]
+        else:
+            o_lat = paged_prefill_attention(
+                qa, pool["ckv"], None, layer_idx, table, pos, length,
+                v_width=r, scale=self.scale)
+        return self._unabsorb(o_lat, layer)
 
     def out(self, o, layer):
         return qlinear(o, layer["wo"])

@@ -1,4 +1,5 @@
-"""Paged KV storage: fixed-size blocks, read by the decode step in place.
+"""Paged KV storage: fixed-size blocks, read and written in place by the
+decode step and by the prefill chunk.
 
 The dense serving cache is one ``(L, max_batch, Hkv, max_len, D)``
 pool — every slot reserves ``max_len`` tokens of KV for its whole
@@ -16,25 +17,29 @@ by the same :func:`~.generate.init_kv_cache` (values + per-token
 scales), and every helper here tree-maps over the cache dict, so
 paged + quantized compose without new code.
 
-**Compute path.**  The decode step consumes the physical pool where
-it lies (:class:`PagedKV`, the pool's side of
+**Compute path.**  Both serving programs consume the physical pool
+where it lies (:class:`PagedKV`, the pool's side of
 :func:`~.generate.forward_with_cache`'s one seam).  The pool rides the
-layer scan as carry, updated in place under the step's donation, and
-each layer does two things to it: it *writes* the step's one new token
-per slot straight into its page (physical block ``table[b, pos //
-bt]``, offset ``pos % bt``, all heads: ``S x Hkv x D`` values a leaf),
-and it *attends*.  With ``cfg.use_flash`` on one device, attention is
-:func:`~..ops.decode.paged_decode_attention`: block table and positions
-are scalar-prefetch operands of the Pallas kernel, whose index maps
-pick each live page out of the pool, so a step reads the tokens its
-slots hold and never ``max_len``, and no dense view of the pool exists
+layer scan as carry, updated in place under the program's donation,
+and each layer does two things to it: it *writes* its new entries
+straight into the row's pages — a decode step one token a slot
+(:func:`write_token`: physical block ``table[b, pos // bt]``, offset
+``pos % bt``), a prefill chunk its ``S`` tokens (:func:`write_chunk`:
+the pages the span touches, whole) — and it *attends* through the
+table.  A step with ``cfg.use_flash`` on one device attends inside a
+Pallas kernel of :mod:`..ops.decode` (``paged_decode_attention``, the
+latent pool's twin): layer, block table and positions are
+scalar-prefetch operands, whose index maps pick each live page out of
+the pool, so a step reads the tokens its rows hold and never
+``max_len``, and no dense view of the pool exists
 (``DecodeServer.kv_view_bytes`` reads 0).  Otherwise (``use_flash``
-off, or under a mesh) :func:`gather_layer` takes *that layer's* blocks
-to a ``(S, Hkv, T', D)`` view for the dense attention paths of
-:mod:`.generate`; the view lives for one layer, and ``kv_view_bytes``
-counts what a step gathers that way.  Prefill still works on one
-slot's dense row (:func:`gather_row` / :func:`scatter_row`), a whole
-row per chunk program.
+off, or under a mesh) a step's :func:`gather_layer` takes *that
+layer's* blocks to a ``(S, Hkv, T', D)`` view for the dense attention
+paths of :mod:`.generate` (the view lives for one layer, and
+``kv_view_bytes`` counts what a step gathers that way).  A chunk runs
+the kernel's recurrence in ``jax.numpy`` over key tiles of whole pages
+taken through the table, as many as the row holds
+(``paged_prefill_attention``), in every case.
 
 **The trash block.**  Physical block ``n_blocks`` is never allocated.
 Unallocated table entries point at it, and the decode step's write
@@ -44,7 +49,10 @@ pool tolerates those because admission re-prefills the whole row;
 a paged block may be owned by someone else by then).  Garbage in the
 trash block — or in allocated-but-unwritten blocks — is unreachable by
 attention: positions ``> cache_len`` are masked, and a slot's
-``cache_len`` never passes its allocated token count.
+``cache_len`` never passes its allocated token count.  A prefill chunk
+keeps what no token of the row wrote out of the weighted sum too (a
+probability of zero does not clean a NaN); a decode step does not yet,
+so garbage has to be finite, as zeros and a former owner's tokens are.
 
 Exactness: a slot's pages hold, token for token, what its dense row
 would, so a paged greedy decode computes what the dense server (and a
@@ -103,33 +111,6 @@ def gather_layer(pool, layer, table):
     return jax.tree_util.tree_map(one, pool)
 
 
-@jax.named_scope("gather_row")
-def gather_row(pool, row_ids):
-    """One slot's blocks as a dense ``(L, 1, Hkv, MB*bt, D)`` row —
-    the prefill working view."""
-    def one(c):
-        g = jnp.take(c, row_ids, axis=1)      # (L, MB, Hkv, bt, D)
-        g = jnp.transpose(g, (0, 2, 1, 3, 4))
-        sh = g.shape
-        return g.reshape(sh[0], sh[1], sh[2] * sh[3],
-                         sh[4])[:, None]
-    return jax.tree_util.tree_map(one, pool)
-
-
-@jax.named_scope("scatter_row")
-def scatter_row(pool, row, row_ids):
-    """Write a slot's whole dense row back to its physical blocks.
-    Trash-mapped ids receive the row's pad garbage — harmless by
-    construction (see module docstring)."""
-    def one(c, r):
-        sh = c.shape                          # (L, NB+1, Hkv, bt, D)
-        r = r[:, 0]                           # (L, Hkv, MB*bt, D)
-        r = r.reshape(sh[0], sh[2], -1, sh[3], sh[4])
-        r = jnp.transpose(r, (0, 2, 1, 3, 4))  # (L, MB, Hkv, bt, D)
-        return c.at[:, row_ids].set(r)
-    return jax.tree_util.tree_map(one, pool, row)
-
-
 @jax.named_scope("write_token")
 def write_token(pool, layer, new, table, pos, active):
     """Write each slot's ONE new token of one layer into its page.
@@ -161,6 +142,46 @@ def write_token(pool, layer, new, table, pos, active):
     return jax.tree_util.tree_map(one, pool, new)
 
 
+@jax.named_scope("write_chunk")
+def write_chunk(pool, layer, new, table, start):
+    """Write each row's chunk of ``S`` new tokens of one layer into its
+    pages, where they lie.
+
+    ``new`` leaves ``(B, Hkv, S, D)``, ``start`` (B,) the position of
+    each row's first token; any ``start``, any ``S``.  The pages the
+    span ``[start, start + S)`` can touch are taken out of the layer
+    (one more than ``S`` fills, for a ``start`` inside a page), the
+    chunk is laid over them at ``start % bt``, and they go back whole,
+    one slice update a page as :func:`write_token` one a token.  The
+    padded tail of a chunk goes with it: entries the table maps to the
+    trash block, and pages past the table, land in the trash block."""
+    first = jax.tree_util.tree_leaves(pool)[0]
+    trash, bt = first.shape[1] - 1, first.shape[3]
+    B, _, S, _ = jax.tree_util.tree_leaves(new)[0].shape
+    n = (S - 1) // bt + 2
+    page = (start // bt)[:, None] + jnp.arange(n)[None, :]     # (B, n)
+    ids = jnp.where(
+        page < table.shape[1],
+        jnp.take_along_axis(table, jnp.minimum(page, table.shape[1] - 1),
+                            axis=1), trash)
+    off = start % bt
+
+    def one(c, x):
+        hkv, width = c.shape[2], c.shape[4]
+        x = x.astype(c.dtype)
+        for b in range(B):
+            span = c[layer, ids[b]].transpose(1, 0, 2, 3)  # (Hkv, n, bt, D)
+            span = jax.lax.dynamic_update_slice(
+                span.reshape(hkv, n * bt, width), x[b], (0, off[b], 0))
+            span = span.reshape(hkv, n, bt, width)
+            for j in range(n):
+                c = jax.lax.dynamic_update_slice(
+                    c, span[:, j][None, None],
+                    (layer, ids[b, j], 0, 0, 0))
+        return c
+    return jax.tree_util.tree_map(one, pool, new)
+
+
 def reads_in_place(cfg, mesh) -> bool:
     """Whether a decode step over a paged pool attends inside the
     Pallas kernel, block tables and all — what ``cfg.use_flash``
@@ -177,21 +198,40 @@ class PagedKV:
     and updated in place, and a layer takes only its index.  What a
     page holds is the mixer's business: K and V heads
     (:class:`~.generate.GQAMixer`) or one latent row
-    (:class:`~.mla.MLAMixer`)."""
+    (:class:`~.mla.MLAMixer`).
 
-    def __init__(self, pool: dict, table, active, mixer, cfg, mesh):
+    One new token a row is a decode step (rows outside ``active`` write
+    to the trash block and attend nothing); several are a chunk of a
+    prefill, every row of which takes part, ``token_mask`` telling its
+    real tokens from its padded tail.  Either way a layer writes its
+    new entries into the row's pages and attends through the table
+    over the keys the row holds, never a dense view of the row."""
+
+    def __init__(self, pool: dict, table, active, mixer, cfg, mesh,
+                 token_mask=None):
         self.held = pool
         n_layers = jax.tree_util.tree_leaves(pool)[0].shape[0]
         self.per_layer = jnp.arange(n_layers, dtype=jnp.int32)
         self._table, self._active = table, active
         self._mixer = mixer
         self._in_place = reads_in_place(cfg, mesh)
+        # A chunk's real tokens end at its last real one: no real
+        # query needs a key past it.
+        self._length = None if token_mask is None else jnp.max(
+            jnp.where(token_mask, jnp.arange(1, token_mask.shape[1] + 1),
+                      0), axis=1).astype(jnp.int32)
 
     def layer(self, pool, layer_idx, q, new, positions, layer):
-        if q.shape[1] != 1:
-            raise ValueError("a paged pool takes decode steps only "
-                             f"(one new token a row, got {q.shape[1]})")
         pos = positions[:, 0]
+        if q.shape[1] > 1:
+            if self._active is not None:
+                raise ValueError("a chunk of new tokens takes every "
+                                 "row: row_mask is the decode step's")
+            pool = write_chunk(pool, layer_idx, new, self._table, pos)
+            o = self._mixer.attend_paged(
+                q, pool, layer_idx, self._table, pos, None, layer,
+                length=self._length)
+            return o, pool, None
         # Write first, attend second: the kernel sees position pos.
         pool = write_token(pool, layer_idx, new, self._table, pos,
                            self._active)

@@ -317,6 +317,11 @@ class DecodeServer:
         # each tick's deltas, as for the token counters).
         self.kv_read_bytes_total = 0
         self.decode_steps_total = 0
+        # Paged pool: cumulative keys the prefill chunk programs
+        # attended (live pages x block, from each chunk's ``start`` and
+        # ``length``) and the chunk programs run.
+        self.prefill_keys_total = 0
+        self.prefill_chunks_total = 0
         # Routing load of the decode steps since :meth:`take_moe_load`
         # (a config whose experts report one: ``_routed``), fetched
         # with each step's tokens: experts touched summed over steps
@@ -414,36 +419,55 @@ class DecodeServer:
     def _make_prefill_paged(self):
         """Paged prefill, shaped like the dense one so
         :meth:`_run_prefill` (bucketing + chunk streaming) drives both:
-        gather the slot's blocks into a dense row, run the same
-        forward, scatter the whole row back to its physical blocks.
-        The wrapper resolves the slot's block table host-side; the
-        jitted inner program takes the ids as data, so one compile
-        serves every slot and every (re)allocation."""
-        from .paged_kv import gather_row, scatter_row
-
+        one forward over the pool itself, with the slot's one-row block
+        table.  Each layer writes the chunk's new entries into the
+        row's pages where they lie and attends through the table over
+        the keys the row holds (:class:`~.paged_kv.PagedKV`): what a
+        chunk costs goes with ``start + length``, not with ``max_len``,
+        and both are data, so one compile a chunk shape serves every
+        slot, every (re)allocation and every offset.  The wrapper
+        resolves the slot's table host-side and counts the keys the
+        program attends (:attr:`prefill_keys_total`)."""
         cfg, mesh, ep_axis = self._cfg, self._mesh, self._ep_axis
 
         def nbd_prefill_paged(params, pool, row_ids, prompt, start,
                               length):
-            row = gather_row(pool, row_ids)
             s_pad = prompt.shape[1]
             mask = (jnp.arange(s_pad)[None, :] < length)
-            logits, row = forward_with_cache(
-                params, prompt, row, start, cfg, mesh=mesh,
+            logits, pool = forward_with_cache(
+                params, prompt, pool, start, cfg, mesh=mesh,
                 ep_axis=ep_axis, token_mask=mask,
-                last_index=(length - 1)[None])
-            pool = scatter_row(pool, row, row_ids)
+                last_index=(length - 1)[None],
+                block_table=row_ids[None])
             return pool, logits[0, 0]                  # (V,)
 
         jit_fn = jax.jit(nbd_prefill_paged, donate_argnums=(1,))
 
         def wrapper(params, pool, prompt, slot, start, length):
+            self.prefill_keys_total += self._chunk_keys(int(start),
+                                                        int(length))
+            self.prefill_chunks_total += 1
             return jit_fn(params, pool,
                           self._paged.device_row(int(slot)), prompt,
                           start, length)
 
         wrapper.program = jit_fn    # to lower it without a live slot
         return wrapper
+
+    def _chunk_keys(self, start: int, length: int) -> int:
+        """Keys a prefill chunk program attends: whole pages, from the
+        page of its first token's window to the page of its last real
+        token (as :meth:`_step_kv_read_bytes` counts a step's)."""
+        bt = self._paged.block_tokens
+        last = (start + length - 1) // bt
+        return (last - self._first_live_page(start) + 1) * bt
+
+    def _first_live_page(self, pos: int) -> int:
+        """The first page a query at ``pos`` attends: its window's."""
+        window = getattr(self._cfg, "sliding_window", None)
+        if not window:
+            return 0
+        return max(0, pos + 1 - window) // self._paged.block_tokens
 
     def _jit_step_paged(self):
         """The paged decode step: the SAME step computation over the
@@ -988,12 +1012,10 @@ class DecodeServer:
         fetches, all layers: for every active slot the pages from the
         window's first to the one its new token lands in."""
         bt = self._paged.block_tokens
-        window = getattr(self._cfg, "sliding_window", None)
         pages = 0
         for rid in self._slot_req.values():
             pos = len(self.prompts[rid]) + len(self.outputs[rid]) - 1
-            first = max(0, pos + 1 - window) // bt if window else 0
-            pages += pos // bt - first + 1
+            pages += pos // bt - self._first_live_page(pos) + 1
         return pages * self._page_bytes
 
     def _step_tokens(self, out, slot: int) -> list[int]:

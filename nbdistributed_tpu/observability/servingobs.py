@@ -424,7 +424,8 @@ class ServingObservatory:
                   turnaround: float | None = None,
                   idled: bool = False,
                   t_wall: float | None = None,
-                  kv_read=None, moe=None) -> dict | None:
+                  kv_read=None, moe=None,
+                  prefill_keys=None) -> dict | None:
         """One tick of one rank: the gateway's phase seconds, the
         worker's (its ``tick["ph"]``), the worker's compile delta
         ``[count, seconds]`` and the ``turnaround`` it waited since
@@ -435,7 +436,10 @@ class ServingObservatory:
         from a paged pool in this tick; ``moe`` its ``[experts touched
         summed over those steps, most rows on one expert, rows routed
         a layer summed]`` where the model routes to fine-grained
-        experts.  Returns the tick's record when
+        experts; ``prefill_keys`` its ``[keys, chunks]``: keys its
+        prefill chunk programs attended over a paged pool, and the
+        chunk programs it ran (None from a worker that does not
+        count them).  Returns the tick's record when
         it was slow (kept under ``slow``; the caller writes it to the
         flight recorder, once), else None."""
         wk = {k: max(0.0, float(worker.get(k) or 0.0))
@@ -447,12 +451,14 @@ class ServingObservatory:
         n_cmp, s_cmp = cmp or (0, 0.0)
         cmp = [int(n_cmp), float(s_cmp)]
         kv_bytes, kv_steps = kv_read or (0, 0)
+        pf_keys, pf_chunks = prefill_keys or (0, 0)
         rec = {
             "seq": int(seq), "rank": int(rank),
             "t_wall": round(self._now() if t_wall is None else t_wall,
                             3),
             "gw": gw, "wk": wk, "cmp": cmp, "idled": bool(idled),
             "kvr": [int(kv_bytes), int(kv_steps)],
+            "pfk": [int(pf_keys), int(pf_chunks)],
             "moe": None if moe is None else [float(v) for v in moe],
             "turnaround": (None if turnaround is None
                            else max(0.0, float(turnaround))),
@@ -511,6 +517,11 @@ class ServingObservatory:
                      "kv_read_bytes": round(
                          sum(t["kvr"][0] for t in ticks)
                          / max(1, sum(t["kvr"][1] for t in ticks))),
+                     # mean keys a prefill chunk program attended
+                     # over a paged pool (0 with no chunk)
+                     "prefill_keys": round(
+                         sum(t["pfk"][0] for t in ticks)
+                         / max(1, sum(t["pfk"][1] for t in ticks))),
                      "slow": slow}
         routed = [t for t in ticks if t["moe"] is not None]
         if routed:

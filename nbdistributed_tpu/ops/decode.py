@@ -17,6 +17,16 @@ the cache layout + per-batch valid-length masking (cache slots
 t <= pos[b] attend; later slots are unwritten).  On non-TPU backends
 the kernel runs in interpreter mode, so tests exercise the identical
 code path everywhere.
+
+Over a **paged** pool (``models/paged_kv.py``) the same recurrence runs
+where the pool lies, the block table inside the kernel
+(:func:`paged_decode_attention`, :func:`paged_latent_decode_attention`;
+``nbd_flash_decode_paged`` / ``nbd_mla_decode_paged`` in a profile):
+the index maps clamp the page to what the row holds, so the traffic
+and the work go with the tokens held, not with ``max_len``.  A prefill
+chunk (many queries a row, causal among themselves) runs it in
+``jax.numpy`` over the row's live pages
+(:func:`paged_prefill_attention`), under the same bound.
 """
 
 from __future__ import annotations
@@ -519,3 +529,120 @@ def paged_latent_decode_attention(q, pool, layer, table, pos, *,
         pos, scale=float(scale), interpret=_use_interpret(),
         v_width=int(v_width))
     return out.reshape(S, H, v_width)
+
+
+# Keys a trip of :func:`paged_prefill_attention`'s loop takes, in whole
+# pages: enough to fill the lanes of the scores and to spread the
+# rescaling of the accumulator over many keys.
+_PREFILL_TILE_KEYS = 512
+
+
+def paged_prefill_attention(q, k_pool, v_pool, layer, table, start,
+                            length=None, *, scale: float,
+                            window: int | None = None,
+                            v_width: int | None = None,
+                            k_s=None, v_s=None):
+    """Attention of a chunk of new tokens over the paged pool:
+    :func:`paged_decode_attention` with ``S`` queries a row, causal
+    among themselves.  The chunk's own keys must already be in the
+    pool.
+
+    q: (B, S, H, D) — token ``i`` of row ``b`` sits at position
+    ``start[b] + i``; ``length`` (B,) the real tokens of each row's
+    chunk (the rest is its padded tail; default all): a key attends if
+    a real token wrote it, at or before the query (and inside
+    ``window``);
+    k_pool/v_pool, ``layer``, ``table``, ``k_s``/``v_s`` as there;
+    ``v_pool`` None makes the pool a latent one
+    (:func:`paged_latent_decode_attention`), the values its keys' first
+    ``v_width`` columns.
+    Returns (B, S, H, Dv) in the queries' dtype.
+
+    No Pallas: the decode kernels' online softmax (float32 scores,
+    state and accumulator, the finite mask value) in ``jax.numpy``,
+    over key tiles of whole pages taken out of the pool through the
+    table, in a loop that runs from the tile of the chunk's first
+    window to the tile of its last real token.  So a chunk costs the
+    keys its row holds, whatever ``S`` and the table's width, and
+    ``start`` and ``length`` are data: one compiled program a chunk
+    shape.  A Pallas form of it tied this one on the chip (PERF.md,
+    PR 28) and was not kept; this one also serves a mesh and an int8
+    pool.  No real query needs a key at or past ``start + length``, and
+    what lies there (the chunk's padded tail, a former owner's tokens)
+    is kept out of both products, values zeroed: a probability of zero
+    does not clean a NaN."""
+    B, S, H, D = q.shape
+    Hkv = k_pool.shape[2]
+    if H % Hkv:
+        raise ValueError(f"n_heads {H} not divisible by n_kv_heads {Hkv}")
+    if (k_s is None) != (v_s is None):
+        raise ValueError("pass both k_s and v_s, or neither")
+    if window is not None and window < 1:
+        raise ValueError(f"window must be >= 1, got {window}")
+    if (v_pool is None) == (v_width is None):
+        raise ValueError("a latent pool (v_pool None) takes v_width, "
+                         "and no other does")
+    group, R = H // Hkv, S * (H // Hkv)
+    Dv = v_pool.shape[-1] if v_width is None else int(v_width)
+    start = jnp.broadcast_to(jnp.asarray(start, jnp.int32), (B,))
+    length = jnp.broadcast_to(
+        jnp.asarray(S if length is None else length, jnp.int32), (B,))
+    layer = jnp.asarray(layer, jnp.int32)
+    bt, trash = k_pool.shape[3], k_pool.shape[1] - 1
+    pages = max(1, min(table.shape[1], _PREFILL_TILE_KEYS // bt))
+    keys = pages * bt
+    table = jnp.asarray(table, jnp.int32)
+    table = jnp.pad(table, ((0, 0), (0, -table.shape[1] % pages)),
+                    constant_values=trash)
+    f32 = jnp.float32
+    # A KV head's queries folded into the matmul's rows, token-major.
+    qf = (q.reshape(B, S, Hkv, group, D).transpose(0, 2, 1, 3, 4)
+          .reshape(B, Hkv, R, D))
+    tok = jnp.arange(R) // group                        # row -> token
+    t_idx = jnp.arange(keys)
+
+    def row(b):
+        end = start[b] + length[b]
+        qpos = (start[b] + tok)[:, None]                # (R, 1)
+        last = jnp.maximum(jnp.minimum(start[b] + S, end) - 1, 0)
+        first = (jnp.maximum(start[b] + 1 - window, 0)
+                 if window is not None else 0)
+        qs = qf[b].astype(f32) * scale
+
+        def tile(c, ids):       # (L, NB+1, Hkv, bt, W) -> (Hkv, keys, W)
+            g = c[layer, ids].transpose(1, 0, 2, 3)
+            return g.reshape(Hkv, keys, -1).astype(f32)
+
+        def body(t, carry):
+            acc, m, l = carry
+            ids = jax.lax.dynamic_slice(table[b], (t * pages,), (pages,))
+            k = tile(k_pool, ids)
+            v = k[..., :Dv] if v_pool is None else tile(v_pool, ids)
+            if k_s is not None:
+                k, v = k * tile(k_s, ids), v * tile(v_s, ids)
+            ki = t * keys + t_idx                       # (keys,)
+            keep = (ki[None] <= qpos) & (ki[None] < end)
+            if window is not None:
+                keep &= ki[None] > qpos - window
+            v = jnp.where((ki < end)[None, :, None], v, 0.0)
+            s = jnp.einsum("hrd,htd->hrt", qs, k,
+                           preferred_element_type=f32)
+            s = jnp.where(keep[None], s, _NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m - m_new)
+            l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc * corr + jnp.einsum("hrt,htd->hrd", p, v,
+                                          preferred_element_type=f32)
+            return acc, m_new, l
+
+        acc, _m, l = jax.lax.fori_loop(
+            first // keys, last // keys + 1, body,
+            (jnp.zeros((Hkv, R, Dv), f32),
+             jnp.full((Hkv, R, 1), _NEG_INF, f32),
+             jnp.zeros((Hkv, R, 1), f32)))
+        return acc / jnp.maximum(l, 1e-30)
+
+    out = jnp.stack([row(b) for b in range(B)])         # (B, Hkv, R, Dv)
+    return (out.reshape(B, Hkv, S, group, Dv).transpose(0, 2, 1, 3, 4)
+            .reshape(B, S, H, Dv).astype(q.dtype))

@@ -1291,6 +1291,9 @@ class DistributedWorker:
         seq = data.get("seq")
         srv.tick = seq
         ph0 = dict(srv.phase_s)
+        # A prefill chunk runs in an admission or in a step: from here.
+        pfk0 = (getattr(srv, "prefill_keys_total", 0),
+                getattr(srv, "prefill_chunks_total", 0))
         cmp0 = obs_telemetry.compile_snapshot()
         errors: dict[str, str] = {}
         with obs_spans.phase("serve/step/admit", seq, wall=time.time()):
@@ -1374,7 +1377,12 @@ class DistributedWorker:
                 # Bytes of K and V pages the tick's decode steps
                 # fetched from a paged pool, and how many steps ran.
                 "kvr": [getattr(srv, "kv_read_bytes_total", 0) - kvr0[0],
-                        getattr(srv, "decode_steps_total", 0) - kvr0[1]]}
+                        getattr(srv, "decode_steps_total", 0) - kvr0[1]],
+                # Keys the tick's prefill chunk programs attended over
+                # a paged pool, and how many of them ran.
+                "pfk": [getattr(srv, "prefill_keys_total", 0) - pfk0[0],
+                        getattr(srv, "prefill_chunks_total", 0)
+                        - pfk0[1]]}
         if getattr(srv, "_routed", False):
             # The tick's routing load over its decode steps (kvr[1] of
             # them): experts touched summed, most rows on one expert,

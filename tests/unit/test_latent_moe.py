@@ -27,8 +27,7 @@ from nbdistributed_tpu.models import (DecodeServer, config_from_hf_json,
                                       latent_moe_shardings, tiny_config,
                                       tiny_latent_moe_config,
                                       tiny_moe_config)
-from nbdistributed_tpu.models.paged_kv import (gather_row, make_paged_pool,
-                                               scatter_row)
+from nbdistributed_tpu.models.paged_kv import make_paged_pool
 from nbdistributed_tpu.observability.servingobs import ServingObservatory
 from nbdistributed_tpu.ops.decode import paged_latent_decode_attention
 from nbdistributed_tpu.parallel.expert import (routing_load,
@@ -91,14 +90,13 @@ def test_prefill_then_absorbed_paged_decode_is_the_references_forward(
     pool = make_paged_pool(cfg, slots * mb, bt)
     table = np.full((slots, mb), slots * mb, np.int32)
     table[1, :4] = [7, 2, 11, 5]            # slot 1 owns scattered blocks
-    row_ids = jnp.asarray(table[1])
-    row = gather_row(pool, row_ids)
+    row_ids = jnp.asarray(table[1])[None]
     step = chunk or n_prompt
     for at in range(0, n_prompt, step):
         seg = jnp.asarray(toks[at:min(at + step, n_prompt)])[None]
-        logits, row = forward_with_cache(params, seg, row, at, cfg,
-                                         last_only=True)
-    pool = scatter_row(pool, row, row_ids)
+        logits, pool = forward_with_cache(params, seg, pool, at, cfg,
+                                          last_only=True,
+                                          block_table=row_ids)
     np.testing.assert_allclose(logits[0, 0], ref[n_prompt - 1],
                                rtol=2e-4, atol=2e-4)
     active = jnp.asarray([False, True, False])

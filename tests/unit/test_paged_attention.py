@@ -1,10 +1,13 @@
-"""The decode step reads the paged KV pool in place (ISSUE 26).
+"""The decode step (ISSUE 26) and the prefill chunk (ISSUE 28) read the
+paged KV pool in place.
 
-The paged attention call against an einsum oracle over the gathered
-view; a paged :class:`DecodeServer` whose step attends inside the kernel
-against one whose step takes the einsum fallback; what one step may
-touch in the pool; and the structure of the step's program: no
-intermediate the size of the pool or of its dense view.  Interpret
+The paged attention calls against an einsum oracle over the gathered
+view (a step's one query a row; a chunk's many, for both mixers); a
+paged :class:`DecodeServer` whose step attends inside the kernel
+against one that takes the fallback, and against the dense server; what no real token wrote,
+poisoned, reaching no served token; what one step may touch in the
+pool; and the structure of both programs: no intermediate the size of
+the pool, of its dense view or of a row of ``max_len`` keys.  Interpret
 mode, tiny shapes: kept out of the ``slow`` tier, so it counts where
 the driver counts."""
 
@@ -17,9 +20,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from nbdistributed_tpu.models import init_params, tiny_config
+from nbdistributed_tpu.models import (init_latent_moe_model, init_params,
+                                      tiny_config, tiny_latent_moe_config)
+from nbdistributed_tpu.models.generate import _cached_attention
+from nbdistributed_tpu.models.mla import MLAMixer
 from nbdistributed_tpu.models.serving import DecodeServer
-from nbdistributed_tpu.ops.decode import paged_decode_attention
+from nbdistributed_tpu.ops.decode import (paged_decode_attention,
+                                          paged_prefill_attention)
 
 pytestmark = [pytest.mark.unit, pytest.mark.serve]
 
@@ -153,6 +160,153 @@ def test_paged_call_validates_its_operands():
 
 
 # ----------------------------------------------------------------------
+# a chunk of new tokens: many queries a row, causal among themselves
+
+CHUNK = 2 * BT
+
+
+def dense_view(pool, layer, row_ids):
+    """One row's pages of one layer as a dense ``(1, Hkv, T, W)``
+    buffer a leaf: what the dense attention paths take."""
+    def one(c):
+        g = jnp.take(c[layer], row_ids, axis=0)         # (MB, Hkv, bt, W)
+        return g.transpose(1, 0, 2, 3).reshape(1, c.shape[2], T, -1)
+    return {n: one(c) for n, c in pool.items()}
+
+
+def poison(pool, row_ids, written):
+    """NaN (the int8 leaves: their scales) in the trash block, in every
+    block the row does not own, and at every position of the row at or
+    past ``written``: what no real token of the row has written."""
+    owned = np.asarray(row_ids)
+    at = np.arange(T).reshape(MB, BT) >= written          # (MB, bt)
+    out = {}
+    for n, c in pool.items():
+        if c.dtype == jnp.int8:
+            out[n] = c
+            continue
+        bad = np.ones(c.shape[1:4:2], bool)               # (NB+1, bt)
+        bad[owned] = at
+        out[n] = jnp.where(jnp.asarray(bad)[None, :, None, :, None],
+                           jnp.nan, c)
+    return out
+
+
+def chunk_table(seed=3):
+    """One row whose pages lie scattered over the pool, out of order."""
+    return jnp.asarray(np.random.default_rng(seed).permutation(NB)[:MB],
+                       jnp.int32)
+
+
+@pytest.fixture(params=[2 * BT, 512], ids=["2-page-tiles", "one-tile"])
+def tile_keys(request, monkeypatch):
+    """The key tile of the chunk's loop: two pages (several trips a
+    row) or, as shipped, more than these rows hold."""
+    from nbdistributed_tpu.ops import decode
+    monkeypatch.setattr(decode, "_PREFILL_TILE_KEYS", request.param)
+
+
+@pytest.mark.parametrize("start, length, window", [
+    (0, CHUNK, None),                   # a first chunk, whole
+    (CHUNK, CHUNK, None),               # one chunk in
+    (T - CHUNK, CHUNK, None),           # several in: the row's last
+    (BT + 3, CHUNK, None),              # a start inside a page
+    (CHUNK, 5, None),                   # a padded tail
+    (0, 1, None),                       # one real token
+    (CHUNK, CHUNK, 5),                  # a window inside the chunk
+    (T - CHUNK, 11, BT + 3),            # a window that skips whole pages
+])
+@pytest.mark.parametrize("hkv", [1, 2])
+def test_chunk_call_matches_the_dense_rows_attention(hkv, start, length,
+                                                     window, tile_keys):
+    """GQA: the chunk's real queries against ``_cached_attention`` over
+    a dense copy of the same pages; what no real token wrote is NaN in
+    the pool the call reads."""
+    pool, row_ids, layer = make_pool(hkv, seed=start + length), \
+        chunk_table(), 1
+    q = jax.random.normal(jax.random.PRNGKey(4),
+                          (1, CHUNK, hkv * GROUP, D))
+    scale = 1.0 / np.sqrt(D)
+    got = paged_prefill_attention(
+        q, *(poison(pool, row_ids, start + length)[n] for n in "kv"),
+        layer, row_ids[None], jnp.asarray([start]),
+        jnp.asarray([length]), scale=scale, window=window)
+    view = dense_view(pool, layer, row_ids)
+    want = _cached_attention(
+        q, view["k"], view["v"], start + jnp.arange(CHUNK)[None], scale,
+        window=window)
+    np.testing.assert_allclose(
+        np.asarray(got).reshape(1, CHUNK, -1)[:, :length],
+        np.asarray(want)[:, :length], atol=3e-6, rtol=1e-5)
+    assert np.isfinite(np.asarray(got)).all()       # the padded tail too
+
+
+def test_an_int8_pools_chunk_takes_its_scales():
+    pool, row_ids = make_pool(2, quantized=True), chunk_table()
+    q = jax.random.normal(jax.random.PRNGKey(4), (1, CHUNK, 4, D))
+    view = dense_view(pool, 0, row_ids)
+    k, v = (view[n].astype(jnp.float32) * view[n + "_s"] for n in "kv")
+    bad = poison(pool, row_ids, CHUNK + 9)
+    got = paged_prefill_attention(
+        q, bad["k"], bad["v"], 0, row_ids[None], CHUNK, 9, scale=0.25,
+        k_s=bad["k_s"], v_s=bad["v_s"])
+    want = _cached_attention(q, k, v, CHUNK + jnp.arange(CHUNK)[None],
+                             0.25)
+    np.testing.assert_allclose(np.asarray(got).reshape(1, CHUNK, -1)[:, :9],
+                               np.asarray(want)[:, :9], atol=2e-5)
+
+
+@pytest.fixture(scope="module")
+def latent():
+    cfg = tiny_latent_moe_config(dtype=jnp.float32)
+    params = init_latent_moe_model(jax.random.PRNGKey(0), cfg)
+    return cfg, params["layers"][0]
+
+
+@pytest.mark.parametrize("start, length", [
+    (0, CHUNK), (CHUNK, CHUNK), (T - CHUNK, CHUNK), (BT + 3, CHUNK),
+    (CHUNK, 5)])
+def test_latent_chunk_absorbed_over_the_pool_matches_the_up_projected_row(
+        latent, start, length, tile_keys):
+    """``MLAMixer.attend_paged`` over the latent pages (absorbed: no
+    position of the row up-projected) against ``MLAMixer.attend`` over
+    a dense copy of them (K and V up-projected from the whole row)."""
+    cfg, layer = latent
+    mixer = MLAMixer(cfg, None)
+    rng = np.random.default_rng(start + length)
+    pool = {"ckv": jnp.asarray(rng.normal(size=(
+        L, NB + 1, 1, BT, cfg.cache_width)), jnp.float32)}
+    row_ids = chunk_table()
+    q = jnp.asarray(rng.normal(size=(
+        1, CHUNK, cfg.n_heads, cfg.qk_head_dim)), jnp.float32)
+    got = mixer.attend_paged(
+        q, poison(pool, row_ids, start + length), 1, row_ids[None],
+        jnp.asarray([start]), None, layer, length=jnp.asarray([length]))
+    want = mixer.attend(q, dense_view(pool, 1, row_ids),
+                        start + jnp.arange(CHUNK)[None], layer)
+    np.testing.assert_allclose(np.asarray(got)[:, :length],
+                               np.asarray(want)[:, :length],
+                               atol=2e-5, rtol=2e-5)
+    assert np.isfinite(np.asarray(got)).all()
+
+
+def test_chunk_call_validates_its_operands():
+    pool, row = make_pool(2), chunk_table()[None]
+    q = jnp.zeros((1, CHUNK, 4, D))
+    with pytest.raises(ValueError, match="divisible"):
+        paged_prefill_attention(q[:, :, :3], pool["k"], pool["v"], 0, row,
+                                0, scale=1.0)
+    with pytest.raises(ValueError, match="window"):
+        paged_prefill_attention(q, pool["k"], pool["v"], 0, row, 0,
+                                scale=1.0, window=0)
+    with pytest.raises(ValueError, match="latent"):
+        paged_prefill_attention(q, pool["k"], None, 0, row, 0, scale=1.0)
+    with pytest.raises(ValueError, match="latent"):
+        paged_prefill_attention(q, pool["k"], pool["v"], 0, row, 0,
+                                scale=1.0, v_width=8)
+
+
+# ----------------------------------------------------------------------
 # the server
 
 
@@ -227,6 +381,91 @@ def test_a_step_leaves_every_block_no_active_slot_owns_bit_identical(
         assert {tuple(d[1:]) for d in diff} == {(blk, lens[0] % 8)}
 
 
+# prompts of one chunk, of several, of a whole number of them, and one
+# short of a page edge; a bucketed one
+PROMPTS = [list(range(3, 3 + n)) for n in (41, 16, 23, 7)]
+
+
+def latent_model():
+    cfg = tiny_latent_moe_config(dtype=jnp.float32)
+    return cfg, init_latent_moe_model(jax.random.PRNGKey(1), cfg)
+
+
+def serve_chunked(cfg, params, *, poisoned=False, **kw):
+    srv = DecodeServer(params, cfg, max_batch=2, max_len=64, pad_to=8,
+                       prefill_chunk=16, **kw)
+    if poisoned:
+        srv._cache = jax.tree_util.tree_map(
+            lambda c: jnp.full_like(c, jnp.nan), srv._cache)
+    rids = [srv.submit([t % cfg.vocab_size for t in p], 6)
+            for p in PROMPTS]
+    srv.run_until_done(max_steps=200)
+    return [list(srv.outputs[r]) for r in rids], srv
+
+
+@pytest.mark.parametrize("use_flash", [True, False],
+                         ids=["step-in-kernel", "step-gathers"])
+@pytest.mark.parametrize("family", ["gqa", "latent"])
+def test_chunked_paged_server_serves_the_dense_servers_tokens(
+        model, family, use_flash):
+    """float32, token for token: a chunk that writes its own pages and
+    attends through the table computes what a chunk over the dense
+    pool's row does, interleaved with decode steps or not."""
+    cfg, params = model if family == "gqa" else latent_model()
+    cfg = dataclasses.replace(cfg, use_flash=use_flash)
+    want, _ = serve_chunked(cfg, params)
+    got, srv = serve_chunked(cfg, params, kv_block_tokens=8,
+                             interleave_prefill=True)
+    assert got == want
+    # 41 -> 3 chunks, 23 -> 2, 16 and 7 one program each
+    assert srv.prefill_chunks_total == 7
+    got, _ = serve_chunked(cfg, params, kv_block_tokens=8)
+    assert got == want
+
+
+@pytest.mark.parametrize("served", [
+    "chunk",
+    pytest.param("steps", marks=pytest.mark.xfail(strict=True, reason=(
+        "the decode kernels multiply a zero probability by what the "
+        "tail of the row's last page holds (as the parent's; a decode "
+        "step is not this PR's to change): ROADMAP S2"))),
+])
+@pytest.mark.parametrize("family", ["gqa", "latent"])
+def test_what_no_real_token_wrote_reaches_no_served_token(model, family,
+                                                          served):
+    """The whole pool NaN before the first admission, trash block and
+    all: every page a request is given holds NaN wherever no token of
+    it has written yet.  The chunk keeps such keys out of both products
+    (a probability of zero does not clean a NaN), so each request's
+    first token, which its last chunk's logits give, is the clean
+    pool's.  The tokens of the decode steps after it are not yet: a
+    step whose token opens a fresh page reads the page's tail."""
+    cfg, params = model if family == "gqa" else latent_model()
+    cfg = dataclasses.replace(cfg, use_flash=True)
+    kw = dict(kv_block_tokens=8, interleave_prefill=True)
+    want, _ = serve_chunked(cfg, params, **kw)
+    got, srv = serve_chunked(cfg, params, poisoned=True, **kw)
+    if served == "chunk":
+        got, want = ([toks[0] for toks in t] for t in (got, want))
+    assert got == want
+
+
+def test_prompts_of_several_lengths_compile_one_program_a_chunk_shape(
+        model):
+    """``start`` and ``length`` are data: the chunks of every prompt
+    longer than the chunk, their padded tails too, run one compiled
+    program (a bucketed short prompt is a shape of its own)."""
+    cfg, params = model
+    srv = DecodeServer(params, dataclasses.replace(cfg, use_flash=True),
+                       max_batch=2, max_len=64, pad_to=8,
+                       prefill_chunk=16, kv_block_tokens=8)
+    for n in (17, 41, 32, 55, 23):
+        srv.submit(list(range(1, n + 1)), 2)
+    srv.run_until_done(max_steps=200)
+    assert srv.prefill_chunks_total == 2 + 3 + 2 + 4 + 2
+    assert srv._prefill_fn.program._cache_size() == 1
+
+
 # ----------------------------------------------------------------------
 # the step's program
 
@@ -293,3 +532,54 @@ def test_no_intermediate_of_the_step_is_as_large_as_the_pool_or_its_view():
     assert [(p, n) for p, n in sizes_e if n == layer_view]
     assert not [(p, n) for p, n in sizes_e
                 if n >= min(pool_leaf, view) and p not in PASSES_THE_POOL_ON]
+
+
+def test_no_intermediate_of_the_prefill_chunk_is_a_row_of_max_len_keys():
+    """The chunk program at the benchmark's rehearsal geometry, with
+    ``max_len`` a number no width of the model shares: nothing in it
+    has ``max_len`` (or the whole table's pages) for an axis, nothing
+    outside the hand-on ops is as large as the pool or as a slot's
+    dense row.  The parent's program gathered
+    the row, ``(L, 1, Hkv, max_len, D)``, and made ``(H, chunk,
+    max_len)`` scores."""
+    from benchmarks.drivers.train_worker import program_config
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    cfg = json.load(open(os.path.join(
+        root, "benchmarks/configs/mistral7b-serve.json")))
+    cfg = {**cfg, **cfg["rehearse"]}
+    geo, pc = cfg["assumed"], program_config(cfg)
+    assert pc.use_flash
+    bt, ck = geo["kv_block_tokens"], geo["prefill_chunk"]
+    max_len = 37 * bt
+    params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), pc))
+    srv = DecodeServer(params, pc, max_batch=geo["max_batch"],
+                       max_len=max_len, pad_to=geo["pad_to"],
+                       kv_block_tokens=bt, prefill_chunk=ck,
+                       interleave_prefill=True)
+    widths = {pc.d_model, pc.d_ff, pc.vocab_size, pc.n_heads * pc.head_dim,
+              pc.n_kv_heads * pc.head_dim, ck}
+    assert not {max_len, srv._paged.max_blocks} & widths
+    jaxpr = jax.make_jaxpr(srv._prefill_fn.program)(
+        params, srv._cache, srv._paged.device_row(0),
+        jnp.zeros((1, ck), jnp.int32), jnp.int32(ck), jnp.int32(ck))
+
+    def shapes(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            out += [(eqn.primitive.name, tuple(v.aval.shape))
+                    for v in eqn.outvars if hasattr(v.aval, "shape")]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                shapes(sub, out)
+        return out
+
+    seen = shapes(jaxpr.jaxpr, [])
+    rows = [(p, sh) for p, sh in seen
+            if max_len in sh or (srv._paged.max_blocks in sh
+                                 and len(sh) > 2)]    # not the table
+    assert not rows, rows
+    leaf = srv._cache["k"]
+    row = leaf.size // leaf.shape[1] * srv._paged.max_blocks
+    big = [(p, sh) for p, sh in seen
+           if int(np.prod(sh, dtype=np.int64)) >= min(leaf.size, row)
+           and p not in PASSES_THE_POOL_ON]
+    assert not big, big

@@ -163,6 +163,42 @@ def test_kv_read_bytes_leaves_out_pages_below_the_window(setup):
     assert srv.kv_read_bytes_total == 2 * srv._page_bytes
 
 
+def test_prefill_keys_counts_the_pages_a_chunk_program_attends(setup):
+    """Each chunk program adds the keys from the page of its first
+    token's window to the page of its last real token, whole pages:
+    ``start + length`` rounded up to a page with no window.  The
+    handler reports the tick's delta with the chunk programs run,
+    wherever in the handler they ran."""
+    cfg, params = setup
+    srv = _paged(setup, max_batch=2, max_len=64, prefill_chunk=8,
+                 interleave_prefill=True)
+    w = _worker(srv)
+    # 21 tokens in chunks of 8, one a step: starts 0, 8, 16
+    tick = _step(w, 1, admit=[{"rid": "a", "prompt": list(range(1, 22)),
+                               "max_new": 4}], steps=2)["tick"]
+    assert tick["pfk"] == [8 + 16, 2]
+    tick = _step(w, 2, steps=1)["tick"]         # the tail: 5 real of 8
+    assert tick["pfk"] == [24, 1]               # 16 + 5 -> three pages
+    assert (srv.prefill_keys_total, srv.prefill_chunks_total) == (48, 3)
+    # a short prompt is one bucketed program, run inside the admission
+    tick = _step(w, 3, admit=[{"rid": "b", "prompt": [5, 9, 2],
+                               "max_new": 2}], steps=0)["tick"]
+    assert tick["pfk"] == [8, 1]
+    assert _step(w, 4, steps=1)["tick"]["pfk"] == [0, 0]
+    # below a window the pages are not read, and not counted
+    win = DecodeServer(params, dataclasses.replace(cfg, sliding_window=8),
+                       max_batch=1, max_len=64, pad_to=4,
+                       kv_block_tokens=8, prefill_chunk=8)
+    win.submit(list(range(1, 30)), 2)           # starts 0, 8, 16, 24 (5)
+    # keys (start - 8, start + length): 1, 2, 2, 2 pages
+    assert (win.prefill_keys_total, win.prefill_chunks_total) == (56, 4)
+    # a dense pool has no pages to count
+    dense = DecodeServer(params, cfg, max_batch=1, max_len=64, pad_to=4,
+                         prefill_chunk=8)
+    dense.submit(list(range(1, 22)), 2)
+    assert (dense.prefill_keys_total, dense.prefill_chunks_total) == (0, 0)
+
+
 @pytest.mark.parametrize("paged", [True, False])
 def test_serving_programs_are_named_for_what_they_are(setup, paged):
     """The benchmark's ``docs_prefill_program_share`` matches the

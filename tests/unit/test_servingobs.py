@@ -275,7 +275,8 @@ def test_ticks_block_present_with_no_finished_request():
     obs = ServingObservatory(now=FakeClock())
     tk = obs.summary()["ticks"]
     assert tk == {"count": 0, "compiles": 0, "compile_ms": 0.0,
-                  "kv_view_bytes": 0, "kv_read_bytes": 0, "slow": []}
+                  "kv_view_bytes": 0, "kv_read_bytes": 0,
+                  "prefill_keys": 0, "slow": []}
     _tick(obs, 1)
     s = obs.summary()
     assert s["count"] == 0 and "stages" not in s
@@ -304,6 +305,22 @@ def test_kv_read_bytes_is_the_mean_over_the_rings_decode_steps():
         obs.note_tick(seq, 0, dict(GW), dict(WK), [0, 0.0],
                       turnaround=0.01, kv_read=kvr)
     assert obs.ticks_summary()["kv_read_bytes"] == 180
+
+
+@pytest.mark.parametrize("pfk, want", [
+    ([(1024, 2), (0, 0), (4096, 2)], 1280),     # ring's keys / chunks
+    ([(512, 1), None], 512),                    # an old worker's tick
+    ([None, None], 0)])                         # no worker counts
+def test_prefill_keys_is_the_mean_over_the_rings_chunk_programs(pfk, want):
+    """``pfk`` = [keys, chunks] a tick; a worker that sends none (an
+    older one, or a tick without the key) adds nothing and breaks
+    nothing."""
+    obs = ServingObservatory(now=FakeClock())
+    for seq, one in enumerate(pfk, 1):
+        obs.note_tick(seq, 0, dict(GW), dict(WK), [0, 0.0],
+                      turnaround=0.01, prefill_keys=one)
+    assert obs.ticks_summary()["prefill_keys"] == want
+    assert obs.summary()["ticks"]["prefill_keys"] == want
 
 
 def test_worker_phases_sum_to_the_handler_time_exactly():
