@@ -594,84 +594,16 @@ _srv.run_until_done(max_steps=4 * _N)
 _dt_srv = _time.time() - _t0
 assert all(len(_srv.outputs[_r]) == _N for _r in _rids)
 
-# step_many(8): 8 decode steps per host sync — amortizes the
-# per-token host round-trip.
-_srv2 = DecodeServer(_p, _cfg, max_batch=_B, max_len=256, pad_to=_L)
-_w = _srv2.submit(_prompts[0], 10)      # warm prefill AND the 8-step
-while not _srv2.done():                 # scan program pre-_t0
-    _srv2.step_many(8)
-_srv2.release(_w)
-_t0 = _time.time()
-_rids2 = [_srv2.submit(_pr, _N) for _pr in _prompts]
-while not _srv2.done():
-    _srv2.step_many(8)
-_dt_many = _time.time() - _t0
-assert all(len(_srv2.outputs[_r]) == _N for _r in _rids2)
-
-# Speculative server with spec_step_many(2): up to 2*(gamma+1) tokens
-# per host sync — the compounded amortization (self-draft = the
-# gamma-acceptance upper bound, as in the SPEC row).
-_srv3 = DecodeServer(_p, _cfg, max_batch=_B, max_len=256, pad_to=_L,
-                     draft_params=_p, draft_cfg=_cfg, gamma=4)
-_w = _srv3.submit(_prompts[0], 10)      # warm prefills + the scan
-while not _srv3.done():
-    _srv3.spec_step_many(2)
-_srv3.release(_w)
-_t0 = _time.time()
-_rids3 = [_srv3.submit(_pr, _N) for _pr in _prompts]
-while not _srv3.done():
-    _srv3.spec_step_many(2)
-_dt_spec_many = _time.time() - _t0
-assert all(len(_srv3.outputs[_r]) == _N for _r in _rids3)
-
-# Prefix-cache admission cost: _B requests sharing a
-# 128-token system prefix + 8-token suffixes.  Admission with
-# cache_prefix = one HBM copy + an 8-token suffix prefill vs a full
-# 136-token prefill — time ONLY the submit() loop (admission runs
-# prefill eagerly; no decode steps intrude).
-_PL, _SL = 128, 8
-_pfx = [(13 * _j) % 100 + 1 for _j in range(_PL)]
-_sfx = [[(7 * _i + _j) % 100 + 1 for _j in range(_SL)]
-        for _i in range(_B)]
-# Warm with a suffix the timed loop never submits (same prompt values
-# after an identical release would hand a result cache a free hit).
-_wsfx = [(11 * _j) % 100 + 101 for _j in range(_SL)]
-_srv4 = DecodeServer(_p, _cfg, max_batch=_B, max_len=256, pad_to=8)
-_w = _srv4.submit(_pfx + _wsfx, 1)              # warm both buckets
-_srv4.run_until_done(); _srv4.release(_w)
-_t0 = _time.time()
-for _s in _sfx:
-    _srv4.submit(_pfx + _s, 1)
-_srv4.run_until_done()
-_dt_admit_plain = _time.time() - _t0
-_srv5 = DecodeServer(_p, _cfg, max_batch=_B, max_len=256, pad_to=8)
-_srv5.cache_prefix(_pfx)
-_w = _srv5.submit(_pfx + _wsfx, 1)              # warm absorb + suffix
-_srv5.run_until_done(); _srv5.release(_w)
-_t0 = _time.time()
-for _s in _sfx:
-    _srv5.submit(_pfx + _s, 1)
-_srv5.run_until_done()
-_dt_admit_pfx = _time.time() - _t0
-assert all(_srv4.outputs[_r] == _srv5.outputs[_r]
-           for _r in _srv4.outputs if _r in _srv5.outputs)
-
 _tot = _B * _N
 _json.dumps({
     "batch": _B, "new_tokens": _N,
     "sequential_tok_per_s": round(_tot / _dt_seq, 1),
     "batched_generate_tok_per_s": round(_tot / _dt_bat, 1),
     "server_tok_per_s": round(_tot / _dt_srv, 1),
-    "server_stepmany8_tok_per_s": round(_tot / _dt_many, 1),
-    "server_spec_many2_tok_per_s": round(_tot / _dt_spec_many, 1),
     "batching_speedup": round(_dt_seq / _dt_bat, 2),
     "server_vs_sequential": round(_dt_seq / _dt_srv, 2),
     "per_step_host_sync_ms": round(
         (_dt_srv - _dt_bat) / _N * 1e3, 2),
-    "admit_prefix_len": _PL,
-    "admit_ms_plain": round(_dt_admit_plain / _B * 1e3, 1),
-    "admit_ms_prefix_cached": round(_dt_admit_pfx / _B * 1e3, 1),
-    "admit_prefix_speedup": round(_dt_admit_plain / _dt_admit_pfx, 2),
 })
 """
 

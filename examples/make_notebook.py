@@ -216,22 +216,19 @@ prompt = jnp.ones((1, 4), jnp.int32) * (rank + 1)
 out_tokens = generate(params, prompt, cfg, max_new_tokens=8)
 print(f"rank {rank}: {out_tokens[0].tolist()}")""")
 
-md("""## Continuous-batching serving with prefix caching
+md("""## Continuous-batching serving
 
 `DecodeServer` (seeded in every worker namespace) serves staggered
-requests from one slot-pool KV cache — every decode step is one shared
-batched forward no matter how requests arrive, and greedy outputs are
-bit-identical per request to standalone `generate`.  A shared system
-prompt registered with `cache_prefix` is prefilled ONCE; matching
-requests then admit by one HBM-to-HBM copy plus a suffix-only prefill
-(causal attention + absolute RoPE make the copied KV rows exact).""")
+requests from one paged KV pool — every decode step is one shared
+batched forward no matter how requests arrive, a request holds only
+the pages its tokens need, and greedy outputs are bit-identical per
+request to standalone `generate`.""")
 
 code("""\
 %%rank [0]
 srv = DecodeServer(params, cfg, max_batch=2, max_len=64, pad_to=4)
 system_prompt = [7, 3, 9, 1]
-srv.cache_prefix(system_prompt)          # prefilled once
-ra = srv.submit(system_prompt + [5], 6)  # admits via HBM copy + suffix
+ra = srv.submit(system_prompt + [5], 6)
 rb = srv.submit(system_prompt + [8, 2], 6)
 srv.run_until_done()
 print("request A:", srv.outputs[ra])

@@ -42,8 +42,8 @@ the continuous-batching engine.  This module connects them:
   **re-admits every unfinished request from its journal**: the new
   prompt is ``prompt + emitted-prefix`` and the budget is what
   remains, so greedy decoding continues bit-identically (prefill of a
-  prefix computes the same cache rows decode did — the exactness
-  argument :meth:`DecodeServer.cache_prefix` already makes).  Every
+  prefix computes the same cache rows decode did: causal attention
+  and absolute positions).  Every
   emission carries its worker-side offset; the journal's length is
   the delivery cursor, so redelivered or replayed tokens are DROPPED
   by offset (``nbd_serve_dup_dropped_total`` — pinned to zero by the
@@ -1386,11 +1386,7 @@ class ServingManager:
             tk = (replies.get(rank) or {}).get("tick") or {}
             if tk.get("seq") != seq:
                 continue        # refused, or a worker without the account
-            slow = self.obs.note_tick(
-                seq, rank, gw, tk.get("ph") or {}, tk.get("cmp"),
-                turnaround=tk.get("turnaround"), idled=idled,
-                kv_read=tk.get("kvr"), moe=tk.get("moe"),
-                prefill_keys=tk.get("pfk"))
+            slow = self.obs.note_tick(seq, rank, gw, tk, idled=idled)
             if slow is not None:
                 self._record("serve_slow_tick", **slow)
         if lost:

@@ -1,11 +1,11 @@
 """Paged KV storage: fixed-size blocks, read and written in place by the
 decode step and by the prefill chunk.
 
-The dense serving cache is one ``(L, max_batch, Hkv, max_len, D)``
+A dense serving cache is one ``(L, max_batch, Hkv, max_len, D)``
 pool — every slot reserves ``max_len`` tokens of KV for its whole
 lifetime, so a server sized for long contexts wastes almost all of its
 cache on short chats.  This module pages that storage: the pool
-becomes ``(L, n_blocks + 1, Hkv, block_tokens, D)`` — one "batch row"
+is ``(L, n_blocks + 1, Hkv, block_tokens, D)`` — one "batch row"
 per fixed-size *block* — and each slot holds a table of physical block
 ids covering exactly ``ceil((prompt + max_new) / block_tokens)``
 blocks.  Capacity is then measured in blocks (the
@@ -44,9 +44,8 @@ taken through the table, as many as the row holds
 **The trash block.**  Physical block ``n_blocks`` is never allocated.
 Unallocated table entries point at it, and the decode step's write
 redirects *inactive* slots there, so a freed-and-reallocated block can
-never be corrupted by a stale slot's frozen-position write (the dense
-pool tolerates those because admission re-prefills the whole row;
-a paged block may be owned by someone else by then).  Garbage in the
+never be corrupted by a stale slot's frozen-position write (the
+block may be owned by someone else by then).  Garbage in the
 trash block — or in allocated-but-unwritten blocks — is unreachable by
 attention: positions ``> cache_len`` are masked, and a slot's
 ``cache_len`` never passes its allocated token count.  A prefill chunk
@@ -55,8 +54,8 @@ probability of zero does not clean a NaN); a decode step does not yet,
 so garbage has to be finite, as zeros and a former owner's tokens are.
 
 Exactness: a slot's pages hold, token for token, what its dense row
-would, so a paged greedy decode computes what the dense server (and a
-solo :func:`~.generate.generate`) computes.  In float32 the tokens are
+would, so a paged greedy decode computes what a solo
+:func:`~.generate.generate` computes.  In float32 the tokens are
 bit-identical — asserted by the paged-decode unit tests on the CPU
 (including the quantized round-trip tolerance) and by ``chip_smoke.py``
 on the TPU at ``highest`` matmul precision; in bf16 on the TPU see the

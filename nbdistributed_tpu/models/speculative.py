@@ -71,8 +71,7 @@ def spec_round(params, draft_params, cfg, draft_cfg, *, gamma: int,
                last_tok, key, active, mesh=None, ep_axis: str = "ep",
                top_k: int | None = None, top_p: float | None = None):
     """ONE draft-propose / target-verify round for B streams — the
-    engine shared by :func:`speculative_generate`'s closed loop and
-    the continuous-batching server's speculative mode.
+    engine of :func:`speculative_generate`'s closed loop.
 
     State contract (the lag-one cache discipline): both caches hold
     exactly the committed tokens' K/V below their pointers, and

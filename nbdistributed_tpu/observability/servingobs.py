@@ -152,9 +152,9 @@ class ServingObservatory:
         self._util: deque = deque(maxlen=max(8, ring))
         self._ticks: deque = deque(maxlen=TICK_RING)
         self._slow: deque = deque(maxlen=SLOW_TICKS)
-        # Bytes one decode step gathers from a paged pool into dense
+        # Bytes one decode step gathers from the paged pool into dense
         # views on a rank (the worker's serve_open reports it; 0 = a
-        # dense pool, or a kernel that reads the paged pool in place).
+        # kernel that reads the pool in place).
         self.kv_view_bytes = 0
         self.completed = 0
         self.dropped = 0
@@ -420,38 +420,37 @@ class ServingObservatory:
     # the tick's account (per serve_step round trip and rank)
 
     def note_tick(self, seq: int, rank: int, gateway: dict,
-                  worker: dict, cmp=None, *,
-                  turnaround: float | None = None,
-                  idled: bool = False,
-                  t_wall: float | None = None,
-                  kv_read=None, moe=None,
-                  prefill_keys=None) -> dict | None:
-        """One tick of one rank: the gateway's phase seconds, the
-        worker's (its ``tick["ph"]``), the worker's compile delta
-        ``[count, seconds]`` and the ``turnaround`` it waited since
-        its last reply (None on a server's first tick).  ``idled``
-        marks a tick that followed a wait for work: its turnaround is
-        no part of a decode period.  ``kv_read`` is the worker's
-        ``[bytes, steps]``: K and V pages its decode steps fetched
-        from a paged pool in this tick; ``moe`` its ``[experts touched
-        summed over those steps, most rows on one expert, rows routed
-        a layer summed]`` where the model routes to fine-grained
-        experts; ``prefill_keys`` its ``[keys, chunks]``: keys its
-        prefill chunk programs attended over a paged pool, and the
-        chunk programs it ran (None from a worker that does not
-        count them).  Returns the tick's record when
-        it was slow (kept under ``slow``; the caller writes it to the
-        flight recorder, once), else None."""
+                  tick: dict, *, idled: bool = False,
+                  t_wall: float | None = None) -> dict | None:
+        """One tick of one rank: the gateway's phase seconds and the
+        worker's ``tick`` block whole, as its ``serve_step`` reply
+        carried it (``DecodeServer.take_account`` and the handler's
+        own keys); this reads the keys it knows.  ``ph``: the worker's
+        phase seconds; ``cmp``: its compile delta ``[count, seconds]``;
+        ``turnaround``: what it waited since its last reply (absent on
+        a server's first tick).  ``idled`` marks a tick that followed a
+        wait for work: its turnaround is no part of a decode period.
+        ``kvr`` = ``[bytes, steps]``: K and V pages its decode steps
+        fetched from the paged pool in this tick; ``moe`` = ``[experts
+        touched summed over those steps, most rows on one expert, rows
+        routed a layer summed]`` where the model routes to fine-grained
+        experts; ``pfk`` = ``[keys, chunks]``: keys its prefill chunk
+        programs attended, and the chunk programs it ran (absent from a
+        worker that does not count them).  Returns the tick's record
+        when it was slow (kept under ``slow``; the caller writes it to
+        the flight recorder, once), else None."""
+        worker = tick.get("ph") or {}
+        turnaround, moe = tick.get("turnaround"), tick.get("moe")
         wk = {k: max(0.0, float(worker.get(k) or 0.0))
               for k in WORKER_PHASES}
         gw = {k: max(0.0, float(v)) for k, v in gateway.items()}
         handler = sum(wk.values())
         waited = (None if turnaround is None or idled
                   else max(0.0, float(turnaround)))
-        n_cmp, s_cmp = cmp or (0, 0.0)
+        n_cmp, s_cmp = tick.get("cmp") or (0, 0.0)
         cmp = [int(n_cmp), float(s_cmp)]
-        kv_bytes, kv_steps = kv_read or (0, 0)
-        pf_keys, pf_chunks = prefill_keys or (0, 0)
+        kv_bytes, kv_steps = tick.get("kvr") or (0, 0)
+        pf_keys, pf_chunks = tick.get("pfk") or (0, 0)
         rec = {
             "seq": int(seq), "rank": int(rank),
             "t_wall": round(self._now() if t_wall is None else t_wall,
@@ -513,7 +512,7 @@ class ServingObservatory:
                      "compile_ms": _ms(sum(t["cmp"][1] for t in ticks)),
                      "kv_view_bytes": self.kv_view_bytes,
                      # mean bytes of K and V pages a decode step
-                     # fetched (0 with no step, or a dense pool)
+                     # fetched (0 with no step)
                      "kv_read_bytes": round(
                          sum(t["kvr"][0] for t in ticks)
                          / max(1, sum(t["kvr"][1] for t in ticks))),

@@ -99,7 +99,7 @@ class _WorkerServe:
     """
 
     __slots__ = ("server", "rids", "sent", "tokens_total", "window",
-                 "pf_seen", "dc_seen", "t_reply")
+                 "t_reply")
 
     def __init__(self, server):
         self.server = server
@@ -107,11 +107,6 @@ class _WorkerServe:
         self.sent: dict[str, int] = {}      # gateway rid -> reported
         self.tokens_total = 0
         self.window: list[tuple[float, int]] = []  # (t, tokens_total)
-        # Cumulative prefill/decode token counts already reported —
-        # each serve_step reply carries the per-tick DELTAS (the
-        # observatory's prefill-vs-decode split, ISSUE 18).
-        self.pf_seen = 0
-        self.dc_seen = 0
         # perf_counter at which the last serve_step reply was built:
         # the next handler's entry minus this is the tick's
         # ``turnaround``, the time the chip's owner waited for the
@@ -1218,15 +1213,15 @@ class DistributedWorker:
                                f"{pname!r}/{cname!r} — run the model "
                                f"spec first (%dist_serve start)"},
                 rank=self.rank)
-        # Serving fast path (ISSUE 17): paged KV geometry + chunked
-        # prefill, forwarded from the gateway's serve_open.  A chunk
-        # size implies interleaved prefill — long prompts advance one
-        # chunk per tick between decode steps so TPOT stays bounded.
+        # Serving fast path (ISSUE 17): the paged pool's geometry (the
+        # server's default block where the request names none) +
+        # chunked prefill, forwarded from the gateway's serve_open.  A
+        # chunk size implies interleaved prefill — long prompts advance
+        # one chunk per tick between decode steps so TPOT stays bounded.
         kw: dict = {}
-        if data.get("kv_block_tokens"):
-            kw["kv_block_tokens"] = int(data["kv_block_tokens"])
-            if data.get("kv_blocks"):
-                kw["kv_blocks"] = int(data["kv_blocks"])
+        for name in ("kv_block_tokens", "kv_blocks"):
+            if data.get(name):
+                kw[name] = int(data[name])
         if data.get("prefill_chunk"):
             kw["prefill_chunk"] = int(data["prefill_chunk"])
             kw["interleave_prefill"] = True
@@ -1283,17 +1278,15 @@ class DistributedWorker:
         # The tick's account (ISSUE 25): phases that telescope from
         # this entry to the reply being built, on perf_counter, under
         # the gateway's sequence number.  ``admit`` and ``collect``
-        # are this handler's own; prefill / dispatch / sync / emit are
-        # the server's, wherever in the handler they ran (an
-        # admission's prefill runs inside ``submit``).
+        # are this handler's own: what of its two halves the server
+        # spent in no phase of its own (an admission's prefill runs
+        # inside ``submit``).  The server's phases and counters are
+        # the server's to report (``take_account``).
         t_in = time.perf_counter()
         srv = st.server
         seq = data.get("seq")
         srv.tick = seq
-        ph0 = dict(srv.phase_s)
-        # A prefill chunk runs in an admission or in a step: from here.
-        pfk0 = (getattr(srv, "prefill_keys_total", 0),
-                getattr(srv, "prefill_chunks_total", 0))
+        busy0 = sum(srv.phase_s.values())
         cmp0 = obs_telemetry.compile_snapshot()
         errors: dict[str, str] = {}
         with obs_spans.phase("serve/step/admit", seq, wall=time.time()):
@@ -1323,9 +1316,7 @@ class DistributedWorker:
                             pass
         steps = max(0, int(data.get("steps") or 0))
         t_step0 = time.perf_counter()
-        admit_prefill_s = srv.phase_s["prefill"] - ph0["prefill"]
-        kvr0 = (getattr(srv, "kv_read_bytes_total", 0),
-                getattr(srv, "decode_steps_total", 0))
+        busy1 = sum(srv.phase_s.values())
         for _ in range(steps):
             if srv.done():
                 break
@@ -1346,48 +1337,27 @@ class DistributedWorker:
                     finished.append(rid)
             st.note_rate()
             self._publish_serve_snap()
-            # Tick telemetry (ISSUE 18): compute seconds, the tick's
-            # prefill/decode token split (deltas of the server's
-            # cumulative counters), and per-request prefill progress
-            # — the gateway's serving observatory clock-corrects the
-            # wall stamp and attributes the compute to active
-            # requests.
-            pf_tot = getattr(srv, "prefill_tokens_total", 0)
-            dc_tot = getattr(srv, "decode_tokens_total", 0)
-            pf_d, dc_d = pf_tot - st.pf_seen, dc_tot - st.dc_seen
-            st.pf_seen, st.dc_seen = pf_tot, dc_tot
+            # Per-request prefill progress (ISSUE 18), for the
+            # gateway's serving observatory.
             local_rids = {v: k for k, v in st.rids.items()}
             pfp = {local_rids[lid]: [int(w), int(n)]
                    for lid, (w, n) in srv.prefill_progress().items()
                    if lid in local_rids}
         cmp1 = obs_telemetry.compile_snapshot()
         t_out = time.perf_counter()
-        ph = {k: v - ph0[k] for k, v in srv.phase_s.items()}
-        ph["admit"] = (t_step0 - t_in) - admit_prefill_s
+        busy2 = sum(srv.phase_s.values())
+        tick = {"now": time.time(), "step_s": round(step_s, 6),
+                "seq": seq,
+                "cmp": [cmp1[0] - cmp0[0],
+                        round(cmp1[1] - cmp0[1], 3)],
+                **srv.take_account()}
+        ph = tick["ph"]
+        ph["admit"] = (t_step0 - t_in) - (busy1 - busy0)
         # What of the handler after the admissions lies in no phase
         # of the server: building the reply, and the step loop's own
         # few microseconds.  So the phases sum to t_out - t_in.
-        ph["collect"] = (t_out - t_step0) - (
-            sum(ph[k] for k in srv.phase_s) - admit_prefill_s)
-        tick = {"now": time.time(), "step_s": round(step_s, 6),
-                "pf": int(pf_d), "dc": int(dc_d), "seq": seq,
-                "ph": {k: round(v, 6) for k, v in ph.items()},
-                "cmp": [cmp1[0] - cmp0[0],
-                        round(cmp1[1] - cmp0[1], 3)],
-                # Bytes of K and V pages the tick's decode steps
-                # fetched from a paged pool, and how many steps ran.
-                "kvr": [getattr(srv, "kv_read_bytes_total", 0) - kvr0[0],
-                        getattr(srv, "decode_steps_total", 0) - kvr0[1]],
-                # Keys the tick's prefill chunk programs attended over
-                # a paged pool, and how many of them ran.
-                "pfk": [getattr(srv, "prefill_keys_total", 0) - pfk0[0],
-                        getattr(srv, "prefill_chunks_total", 0)
-                        - pfk0[1]]}
-        if getattr(srv, "_routed", False):
-            # The tick's routing load over its decode steps (kvr[1] of
-            # them): experts touched summed, most rows on one expert,
-            # rows routed a layer summed.
-            tick["moe"] = [round(v, 3) for v in srv.take_moe_load()]
+        ph["collect"] = (t_out - t_step0) - (busy2 - busy1)
+        tick["ph"] = {k: round(v, 6) for k, v in ph.items()}
         if st.t_reply is not None:
             tick["turnaround"] = round(t_in - st.t_reply, 6)
         st.t_reply = t_out
@@ -1427,19 +1397,17 @@ class DistributedWorker:
             slots += st.server._B
             tps += st.tokens_per_s()
             kv = st.server.kv_snapshot()
-            if kv is not None:
-                kv_used += kv["used"]
-                kv_total += kv["blocks"]
-                # Largest contiguous free run, min across tenants —
-                # the most fragmented pool is the binding constraint
-                # (%dist_top frag column, ISSUE 18).
-                run = kv.get("largest_run")
-                if run is not None:
-                    frag = run if frag is None else min(frag, run)
+            kv_used += kv["used"]
+            kv_total += kv["blocks"]
+            # Largest contiguous free run, min across tenants — the
+            # most fragmented pool is the binding constraint
+            # (%dist_top frag column, ISSUE 18).
+            run = kv.get("largest_run")
+            if run is not None:
+                frag = run if frag is None else min(frag, run)
         self._serve_snap = {"tok": tot, "tps": round(tps, 2),
                             "occ": occ, "slots": slots,
-                            **({"kvb": [kv_used, kv_total]}
-                               if kv_total else {}),
+                            "kvb": [kv_used, kv_total],
                             **({"frag": frag}
                                if frag is not None else {})}
 

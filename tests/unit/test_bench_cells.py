@@ -115,14 +115,11 @@ def test_serve_cell_executes():
     cell = bench.SERVE_CELL.replace("smol_135m_config", "tiny_config")
     cell = cell.replace("_N, _B, _L = 48, 4, 16",
                         "_N, _B, _L = 6, 2, 4")
-    cell = cell.replace("_PL, _SL = 128, 8", "_PL, _SL = 12, 4")
     cell = cell.replace("use_flash=True", "use_flash=False")
     res = run_cell(cell)
     assert res["server_tok_per_s"] > 0
     assert res["sequential_tok_per_s"] > 0
     assert res["batch"] == 2 and res["new_tokens"] == 6
-    assert res["admit_ms_plain"] > 0
-    assert res["admit_ms_prefix_cached"] > 0
 
 
 def test_run_families_bails_after_consecutive_spawn_failures():

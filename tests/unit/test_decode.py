@@ -454,9 +454,9 @@ def test_speculative_on_sp_mesh_matches_greedy():
 
 
 def test_decode_server_on_sp_mesh():
-    """DecodeServer with its cache pool sharded dp×sp: outputs match
-    solo decode (slot admission writes cross sp shard boundaries via
-    GSPMD; reads combine by lse)."""
+    """DecodeServer over a dp×tp×sp mesh: outputs match solo decode
+    (the paged pool's KV heads are tp-sharded; each layer's gathered
+    view is attended sp-sharded, reads combined by lse)."""
     from nbdistributed_tpu.models import generate, init_params, tiny_config
     from nbdistributed_tpu.models.serving import DecodeServer
     from nbdistributed_tpu.models.transformer import param_shardings

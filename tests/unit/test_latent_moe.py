@@ -213,7 +213,7 @@ def test_a_masked_row_changes_no_live_row_and_routes_nowhere(model):
 # ----------------------------------------------------------------------
 # DecodeServer: what it refuses, what it counts
 
-def test_capacity_dispatch_still_refuses_chunks_and_prefixes():
+def test_capacity_dispatch_still_refuses_chunks():
     cfg = tiny_moe_config(dtype=jnp.float32, use_flash=False,
                           moe_dispatch="sparse")
     params = init_moe_model(jax.random.PRNGKey(0), cfg)
@@ -221,12 +221,10 @@ def test_capacity_dispatch_still_refuses_chunks_and_prefixes():
         DecodeServer(params, cfg, max_batch=2, max_len=32, prefill_chunk=8)
     srv = DecodeServer(params, cfg, max_batch=2, max_len=32, pad_to=16)
     assert srv._pad_to == 1
-    with pytest.raises(ValueError, match="capacity-based"):
-        srv.cache_prefix([1, 2, 3])
 
 
 @pytest.mark.parametrize("family", ["dropless", "latent"])
-def test_dropless_experts_take_buckets_chunks_and_prefixes(family, model):
+def test_dropless_experts_take_buckets_and_chunks(family, model):
     if family == "latent":
         cfg, params = model
     else:
@@ -239,13 +237,11 @@ def test_dropless_experts_take_buckets_chunks_and_prefixes(family, model):
     srv = DecodeServer(params, cfg, max_batch=2, max_len=48, pad_to=8,
                        prefill_chunk=8)
     assert srv._pad_to == 8
-    pid = srv.cache_prefix(prompt[:11])
     rid = srv.submit(prompt, 6)
     other = srv.submit(prompt[:5], 6)       # a second live row
     srv.run_until_done(100)
     assert srv.outputs[rid] == want
     assert len(srv.outputs[other]) == 6
-    srv.drop_prefix(pid)
 
 
 def test_kv_read_bytes_and_moe_load_count_what_a_step_touches(model):
@@ -262,10 +258,12 @@ def test_kv_read_bytes_and_moe_load_count_what_a_step_touches(model):
     srv.step()
     pages = sum((n + 1 - 1) // bt + 1 for n in lens)
     assert srv.kv_read_bytes_total == pages * srv._page_bytes
-    touched, most, rows = srv.take_moe_load()
+    account = srv.take_account()
+    assert account["kvr"] == [pages * srv._page_bytes, 1]
+    touched, most, rows = account["moe"]
     assert rows == 2 * cfg.top_k            # two live rows, one idle slot
     assert cfg.top_k <= touched <= 2 * cfg.top_k and 1 <= most <= 2
-    assert srv.take_moe_load() == [0.0, 0.0, 0.0]
+    assert srv.take_account()["moe"] == [0.0, 0.0, 0.0]
 
 
 def test_a_dense_model_reports_no_routing_load():
@@ -280,21 +278,24 @@ def test_a_dense_model_reports_no_routing_load():
     rid = srv.submit([1, 2, 3], 3)
     srv.run_until_done(10)
     assert len(srv.outputs[rid]) == 3 and srv.moe_load == [0.0, 0.0, 0.0]
+    assert "moe" not in srv.take_account()
 
 
 def test_ticks_moe_is_the_mean_over_the_steps_and_the_largest_expert():
     obs = ServingObservatory()
     wk = {"sync": 0.01}
-    obs.note_tick(1, 0, {"roundtrip": 0.02}, wk, kv_read=[800, 8],
-                  moe=[1200.0, 5.0, 2048.0])
-    obs.note_tick(2, 0, {"roundtrip": 0.02}, wk, kv_read=[400, 4],
-                  moe=[480.0, 7.0, 1024.0])
+    obs.note_tick(1, 0, {"roundtrip": 0.02},
+                  {"ph": wk, "kvr": [800, 8],
+                   "moe": [1200.0, 5.0, 2048.0]})
+    obs.note_tick(2, 0, {"roundtrip": 0.02},
+                  {"ph": wk, "kvr": [400, 4], "moe": [480.0, 7.0, 1024.0]})
     ticks = obs.ticks_summary()
     assert ticks["moe"] == {"experts_touched": 140.0, "max_rows": 7.0,
                             "rows_routed": 256.0}
     assert ticks["kv_read_bytes"] == 100
     plain = ServingObservatory()
-    plain.note_tick(1, 0, {"roundtrip": 0.02}, wk, kv_read=[800, 8])
+    plain.note_tick(1, 0, {"roundtrip": 0.02},
+                    {"ph": wk, "kvr": [800, 8]})
     assert "moe" not in plain.ticks_summary()
 
 

@@ -1,9 +1,9 @@
 """Paged KV-cache decode (ISSUE 17): the device half of the block
 allocator.  A slot's table-selected blocks hold, token for token, what
-its dense row would, so paged greedy serving must be BIT-IDENTICAL to solo
-``generate()`` — with dense admission order, quantized caches, and
-chunked/interleaved prefill all invisible to the numerics — while the
-allocator-backed pool recycles blocks across requests.
+a dense row would, so paged greedy serving must be BIT-IDENTICAL to solo
+``generate()`` while the allocator-backed pool recycles blocks across
+requests.  (Staggered admission and interleaved chunked prefill at
+this file's blocks of 8 are cases of ``test_serving.py``'s tests.)
 """
 
 import jax
@@ -30,27 +30,6 @@ def solo(params, cfg, prompt, n, **kw):
     out = generate(params, jnp.asarray(prompt, jnp.int32)[None], cfg,
                    n, **kw)
     return [int(t) for t in np.asarray(out)[0][len(prompt):]]
-
-
-def test_paged_staggered_matches_solo_generate(setup):
-    """Staggered admission into a paged 2-slot pool: every request's
-    greedy stream equals its standalone generate() run — paging must
-    change capacity accounting only, never tokens."""
-    cfg, params = setup
-    reqs = [([5, 9, 2], 7), ([7, 1, 3, 11, 4], 5), ([2, 2], 6)]
-    srv = DecodeServer(params, cfg, max_batch=2, max_len=32, pad_to=4,
-                       kv_block_tokens=8)
-    r0 = srv.submit(*reqs[0])
-    srv.step()
-    r1 = srv.submit(*reqs[1])
-    srv.step()
-    r2 = srv.submit(*reqs[2])          # queues until a slot frees
-    srv.run_until_done(max_steps=100)
-    for rid, (prompt, n) in zip((r0, r1, r2), reqs):
-        assert srv.outputs[rid] == solo(params, cfg, prompt, n), rid
-    # Every block returned to the pool at finish.
-    snap = srv.kv_snapshot()
-    assert snap["used"] == 0 and snap["owners"] == {}
 
 
 def test_paged_block_starved_pool_recycles(setup):
@@ -86,25 +65,6 @@ def test_paged_int8_kv_matches_int8_generate(setup):
     assert srv.outputs[rid] == ref
 
 
-def test_paged_interleaved_chunked_prefill_matches_solo(setup):
-    """A long prompt streamed in 4-token chunks BETWEEN decode ticks
-    of an already-active request: both streams bit-identical to their
-    solo runs — the chunk boundary is KV-exact and interleaving
-    changes latency shape only."""
-    cfg, params = setup
-    short, long = ([5, 9, 2], 6), ([7, 1, 3, 11, 4, 2, 8, 6, 1, 9,
-                                    4, 4, 2, 7], 5)
-    srv = DecodeServer(params, cfg, max_batch=2, max_len=32, pad_to=4,
-                       kv_block_tokens=8, prefill_chunk=4,
-                       interleave_prefill=True)
-    r_short = srv.submit(*short)
-    srv.step()                         # short is decoding
-    r_long = srv.submit(*long)         # streams in one chunk per step
-    srv.run_until_done(max_steps=100)
-    assert srv.outputs[r_short] == solo(params, cfg, *short)
-    assert srv.outputs[r_long] == solo(params, cfg, *long)
-
-
 def test_cancel_frees_blocks_immediately(setup):
     """A cancelled mid-decode request must return its blocks NOW (a
     shed request cannot pin KV until its stream would have ended) and
@@ -124,13 +84,9 @@ def test_cancel_frees_blocks_immediately(setup):
 
 
 def test_kv_snapshot_surface(setup):
-    """The snapshot the heartbeat telemetry reads: paged servers
-    report block occupancy with per-request owner counts; dense
-    servers report None."""
+    """The snapshot the heartbeat telemetry reads: block occupancy
+    with per-request owner counts."""
     cfg, params = setup
-    dense = DecodeServer(params, cfg, max_batch=1, max_len=16,
-                         pad_to=4)
-    assert dense.kv_snapshot() is None
     srv = DecodeServer(params, cfg, max_batch=2, max_len=16, pad_to=4,
                        kv_block_tokens=4)
     rid = srv.submit([5, 9, 2], 4)     # ceil((3+4)/4) = 2 blocks
@@ -144,13 +100,16 @@ def test_kv_snapshot_surface(setup):
 
 def test_step_kernels_probe_only_traces(setup):
     """What serve_open reports: compiled Pallas kernels in the step
-    program the server runs, dense or paged.  The CPU interprets
-    kernels, so none here; and lowering must not consume the donated
-    pool — the server serves afterwards as if never asked."""
+    program the server runs, a row one page (the default block) or
+    several.  The CPU interprets kernels, so none here; and lowering
+    must not consume the donated pool — the server serves afterwards
+    as if never asked."""
     cfg, params = setup
     for kw in ({}, {"kv_block_tokens": 8}):
         srv = DecodeServer(params, cfg, max_batch=2, max_len=32,
                            pad_to=4, **kw)
+        assert srv.kv_snapshot()["block_tokens"] == kw.get(
+            "kv_block_tokens", 64)
         assert srv.step_kernels() == 0
         rid = srv.submit([5, 9, 2], 6)
         srv.run_until_done(max_steps=50)
@@ -162,9 +121,6 @@ def test_paged_validation(setup):
     with pytest.raises(ValueError, match="kv_block_tokens"):
         DecodeServer(params, cfg, max_batch=1, max_len=16,
                      kv_block_tokens=0)
-    with pytest.raises(ValueError, match="kv_blocks"):
-        DecodeServer(params, cfg, max_batch=1, max_len=16,
-                     kv_blocks=4)
     with pytest.raises(ValueError, match="interleave_prefill"):
         DecodeServer(params, cfg, max_batch=1, max_len=16,
                      interleave_prefill=True)

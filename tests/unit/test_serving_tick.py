@@ -119,8 +119,9 @@ def test_kv_view_bytes_is_what_a_step_gathers_into_dense_views(setup):
     # layers x rows x max_len x KV heads x head dim x (K and V) x itemsize
     want = cfg.n_layers * 2 * 32 * cfg.n_kv_heads * cfg.head_dim * 2 * 4
     assert srv.kv_view_bytes == want
-    dense = DecodeServer(params, cfg, max_batch=2, max_len=32, pad_to=4)
-    assert dense.kv_view_bytes == 0
+    # a row of one page (the default block of 64) gathers the page
+    one_page = DecodeServer(params, cfg, max_batch=2, max_len=32, pad_to=4)
+    assert one_page.kv_view_bytes == want * 64 // 32
     flash = dataclasses.replace(cfg, use_flash=True)
     in_place = DecodeServer(params, flash, max_batch=2, max_len=32,
                             pad_to=4, kv_block_tokens=8)
@@ -143,13 +144,18 @@ def test_kv_read_bytes_counts_the_live_pages_of_the_active_slots(setup):
         page * (1 + 1), 1)
     srv.step()                                  # pos 8: a second page
     assert srv.kv_read_bytes_total == page * (2 + 2 + 1)
+    # the account is what the server did since it last gave one
+    assert srv.take_account()["kvr"] == [page * (2 + 2 + 1), 2]
     w = _worker(srv)
     tick = _step(w, 1, steps=2)["tick"]
     assert tick["kvr"] == [page * 2 * (2 + 1), 2]
-    dense = DecodeServer(setup[1], cfg, max_batch=2, max_len=32, pad_to=4)
-    dense.submit([5, 9], 3)
-    dense.step()
-    assert (dense.kv_read_bytes_total, dense.decode_steps_total) == (0, 1)
+    # a row of one page (the default block of 64) reads it every step
+    one_page = DecodeServer(setup[1], cfg, max_batch=2, max_len=32,
+                            pad_to=4)
+    one_page.submit([5, 9], 3)
+    one_page.step()
+    assert (one_page.kv_read_bytes_total, one_page.decode_steps_total) == (
+        page * 64 // 8, 1)
 
 
 def test_kv_read_bytes_leaves_out_pages_below_the_window(setup):
@@ -192,40 +198,34 @@ def test_prefill_keys_counts_the_pages_a_chunk_program_attends(setup):
     win.submit(list(range(1, 30)), 2)           # starts 0, 8, 16, 24 (5)
     # keys (start - 8, start + length): 1, 2, 2, 2 pages
     assert (win.prefill_keys_total, win.prefill_chunks_total) == (56, 4)
-    # a dense pool has no pages to count
-    dense = DecodeServer(params, cfg, max_batch=1, max_len=64, pad_to=4,
-                         prefill_chunk=8)
-    dense.submit(list(range(1, 22)), 2)
-    assert (dense.prefill_keys_total, dense.prefill_chunks_total) == (0, 0)
+    # a row of one page (the default block of 64): every chunk's keys
+    # are that page's
+    one_page = DecodeServer(params, cfg, max_batch=1, max_len=64, pad_to=4,
+                            prefill_chunk=8)
+    one_page.submit(list(range(1, 22)), 2)
+    assert (one_page.prefill_keys_total,
+            one_page.prefill_chunks_total) == (3 * 64, 3)
 
 
-@pytest.mark.parametrize("paged", [True, False])
-def test_serving_programs_are_named_for_what_they_are(setup, paged):
+@pytest.mark.parametrize("block", [8, 64])
+def test_serving_programs_are_named_for_what_they_are(setup, block):
     """The benchmark's ``docs_prefill_program_share`` matches the
-    profile's "XLA Modules" names by regex: pin them."""
+    profile's "XLA Modules" names by regex: pin them, a row several
+    pages or one."""
     cfg, params = setup
-    srv = (_paged(setup) if paged else
-           DecodeServer(params, cfg, max_batch=2, max_len=32, pad_to=4))
-    table = (srv._paged.device_table(),) if paged else ()
+    srv = DecodeServer(params, cfg, max_batch=2, max_len=32, pad_to=4,
+                       kv_block_tokens=block)
     step = srv._step_fn.lower(
-        params, srv._cache, *table, srv._lens, srv._last, srv._active,
-        srv._key).as_text()
-    want = "jit_nbd_decode_step_paged" if paged else "jit_nbd_decode_step"
-    assert f"module @{want} " in step
-    prompt = jnp.zeros((1, 4), jnp.int32)
-    if paged:
-        # the jitted program sits behind a wrapper that resolves the
-        # slot's block table: reach it through the closure
-        fn = next(c.cell_contents for c in srv._prefill_fn.__closure__
-                  if hasattr(c.cell_contents, "lower"))
-        text = fn.lower(params, srv._cache, srv._paged.device_row(0),
-                        prompt, jnp.int32(0), jnp.int32(3)).as_text()
-    else:
-        text = srv._prefill_fn.lower(
-            params, srv._cache, prompt, jnp.int32(0), jnp.int32(0),
-            jnp.int32(3)).as_text()
-    want = "jit_nbd_prefill_paged" if paged else "jit_nbd_prefill"
-    assert f"module @{want} " in text
+        params, srv._cache, srv._paged.device_table(), srv._lens,
+        srv._last, srv._active, srv._key).as_text()
+    assert "module @jit_nbd_decode_step_paged " in step
+    # the jitted program sits behind a wrapper that resolves the
+    # slot's block table
+    text = srv._prefill_fn.program.lower(
+        params, srv._cache, srv._paged.device_row(0),
+        jnp.zeros((1, 4), jnp.int32), jnp.int32(0),
+        jnp.int32(3)).as_text()
+    assert "module @jit_nbd_prefill_paged " in text
 
 
 # ----------------------------------------------------------------------
@@ -301,3 +301,71 @@ def test_tick_cmp_counts_a_compile_on_the_first_use_of_a_bucket_only(
     d = _step(w, 5, admit=[{"rid": "c", "prompt": long[::-1],
                             "max_new": 2}])
     assert d["tick"]["cmp"] == [0, 0.0]
+
+
+# ----------------------------------------------------------------------
+# one producer, one reader
+
+
+def test_a_quantity_added_to_the_servers_account_reaches_note_tick_unnamed(
+        setup, tmp_path, monkeypatch):
+    """A new counter is an edit to ``DecodeServer.take_account`` and to
+    ``ServingObservatory.note_tick``: the handler, the wire and the
+    gateway's tick hand the block through whole.  Here a server whose
+    account carries ``xq`` and a reader that looks for it, with the real
+    ``_handle_serve_step`` and the real ``ServingManager._tick``
+    between them; the tick's record in the ring (the source of
+    ``summary()["ticks"]``) is made from the same block."""
+    import time
+
+    from nbdistributed_tpu.gateway.serving import ServingManager
+
+    real_account = DecodeServer.take_account
+    monkeypatch.setattr(
+        DecodeServer, "take_account",
+        lambda self: dict(real_account(self), xq=[self.n_active, 7]))
+    w = _worker(_paged(setup))
+
+    class BridgeComm:
+        """One in-process worker behind the comm's surface."""
+        num_workers = 1
+
+        def dead_ranks(self):
+            return set()
+
+        def post(self, ranks, msg_type, data=None):
+            pass
+
+        def send_to_ranks(self, ranks, msg_type, data=None, **kw):
+            if msg_type != "serve_step":
+                return {0: Message(msg_type="response",
+                                   data={"status": "open"})}
+            return {0: w._handle_serve_step(
+                Message(msg_type=msg_type, data=data))}
+
+    mgr = ServingManager(BridgeComm(), str(tmp_path), world_size=1,
+                         max_batch=2, max_len=32, pad_to=4, steps=2,
+                         kv_block_tokens=8, step_timeout=30.0)
+    seen = []
+    real_note = mgr.obs.note_tick
+
+    def note_tick(seq, rank, gateway, tick, **kw):
+        seen.append((seq, tick.get("xq")))
+        return real_note(seq, rank, gateway, tick, **kw)
+
+    monkeypatch.setattr(mgr.obs, "note_tick", note_tick)
+    mgr.start()
+    try:
+        rid = mgr.submit("t1", [5, 9, 2], 6)["rid"]
+        deadline = time.monotonic() + 60
+        while not mgr.result(rid)["done"]:
+            assert time.monotonic() < deadline
+            time.sleep(0.02)
+    finally:
+        mgr.stop()
+    assert seen and all(xq is not None and xq[1] == 7 for _, xq in seen)
+    assert any(xq[0] == 1 for _, xq in seen)    # a row was decoding
+    ring = list(mgr.obs._ticks)
+    assert [t["seq"] for t in ring] == [seq for seq, _ in seen]
+    assert sum(t["kvr"][1] for t in ring) == 5  # six tokens, one at admission
+    assert mgr.describe()["lat"]["summary"]["ticks"]["count"] == len(seen)

@@ -266,9 +266,10 @@ WK = {"admit": 0.001, "prefill": 0.0, "dispatch": 0.02, "sync": 0.42,
 
 def _tick(obs, seq, *, gw=None, wk=None, cmp=(0, 0.0), turnaround=0.01,
           idled=False, rank=0):
-    return obs.note_tick(seq, rank, dict(GW, **(gw or {})),
-                         dict(WK, **(wk or {})), list(cmp),
-                         turnaround=turnaround, idled=idled)
+    return obs.note_tick(
+        seq, rank, dict(GW, **(gw or {})),
+        {"ph": dict(WK, **(wk or {})), "cmp": list(cmp),
+         "turnaround": turnaround}, idled=idled)
 
 
 def test_ticks_block_present_with_no_finished_request():
@@ -302,8 +303,7 @@ def test_kv_read_bytes_is_the_mean_over_the_rings_decode_steps():
     bytes by the ring's steps (a tick with no step adds nothing)."""
     obs = ServingObservatory(now=FakeClock())
     for seq, kvr in enumerate([(800, 8), (0, 0), (1000, 2), None], 1):
-        obs.note_tick(seq, 0, dict(GW), dict(WK), [0, 0.0],
-                      turnaround=0.01, kv_read=kvr)
+        obs.note_tick(seq, 0, dict(GW), {"ph": dict(WK), "kvr": kvr})
     assert obs.ticks_summary()["kv_read_bytes"] == 180
 
 
@@ -317,8 +317,7 @@ def test_prefill_keys_is_the_mean_over_the_rings_chunk_programs(pfk, want):
     nothing."""
     obs = ServingObservatory(now=FakeClock())
     for seq, one in enumerate(pfk, 1):
-        obs.note_tick(seq, 0, dict(GW), dict(WK), [0, 0.0],
-                      turnaround=0.01, prefill_keys=one)
+        obs.note_tick(seq, 0, dict(GW), {"ph": dict(WK), "pfk": one})
     assert obs.ticks_summary()["prefill_keys"] == want
     assert obs.summary()["ticks"]["prefill_keys"] == want
 
