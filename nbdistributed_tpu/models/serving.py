@@ -26,6 +26,22 @@ the KV-cache machinery was built to support.  Design:
   pool's trash block, and for MoE configs ``row_mask`` keeps them out
   of expert capacity dispatch, so an empty or finished slot never
   perturbs a live one.
+* **One step in flight.**  :meth:`DecodeServer.step` dispatches
+  decode step n + 1 before it fetches step n's tokens: the next step's
+  inputs (the pool, the rows' lengths and last tokens) are device
+  arrays that step n returned, so nothing of the host's work between
+  two steps (the fetch, emission, admission, building a chunk, the
+  reply of a tick and the next request for one) needs the chip to
+  wait.  What the host knows without the fetch decides which rows a
+  step runs: a budget's end is counted at dispatch; an EOS or a
+  cancel is learned a step late, and that row runs one surplus step
+  whose token is dropped and whose write lands in pages the row still
+  reserved.  Each step in flight carries the ``{slot: request}`` it
+  was dispatched with, and a token is emitted only to the request
+  that still holds its slot; a freed slot's next request is ordered
+  behind the step in flight by the device's program order.  There is
+  no other mode: the server runs ahead whenever a row is active and
+  drains when none is.
 * **Prefill-on-admit** runs the prompt as a single-row forward into
   the slot's pages, right-padded to a length *bucket* (one compile per
   bucket, ``pad_to`` granularity), or in fixed chunks
@@ -62,9 +78,11 @@ caveat as batched speculative decoding.
 from __future__ import annotations
 
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..observability import spans as obs_spans
 from ..serving_fast.paging import BlocksExhausted
@@ -93,6 +111,16 @@ def _capacity_dispatch(cfg, mesh, ep_axis: str) -> bool:
 STEP_PHASES = ("prefill", "dispatch", "sync", "emit")
 
 
+class _InFlight(NamedTuple):
+    """A decode step that was dispatched and whose tokens the host has
+    not fetched yet."""
+    tokens: jax.Array           # (B,) the slots' next tokens
+    load: jax.Array | None      # the step's routing load (a config
+    #                             whose experts report one)
+    rows: dict[int, int]        # {slot: request id} it ran
+    kv_bytes: int               # K and V page bytes its attention reads
+
+
 class DecodeServer:
     """Continuous-batching server around one model and one paged pool.
 
@@ -103,7 +131,8 @@ class DecodeServer:
         srv = DecodeServer(params, cfg, max_batch=8, max_len=512)
         rid = srv.submit([1, 2, 3], max_new_tokens=16)
         while not srv.done():
-            srv.step()   # 1 token per active request
+            srv.step()   # dispatches a step, emits the one before:
+                         # 1 token per request that one ran
         tokens = srv.outputs[rid]
 
     ``kv_block_tokens`` / ``kv_blocks`` are the pool's geometry
@@ -221,6 +250,14 @@ class DecodeServer:
         self._free = list(range(max_batch))
         self._slot_req: dict[int, int] = {}      # slot -> request id
         self._budget: dict[int, int] = {}        # request id -> remaining
+        # The rows the next decode step runs, as the host knows them
+        # without a fetch: slot -> [position the step writes, steps
+        # left to dispatch].  ``_active[slot]`` on the device is true
+        # exactly for these.  A budget's end is known here, at
+        # dispatch; an EOS only at the fetch, one step late.
+        self._run: dict[int, list[int]] = {}
+        # The one decode step in flight (dispatched, not fetched).
+        self._flying: _InFlight | None = None
         self._pending: list[tuple[int, list[int], int]] = []
         self._next_id = 0
         self.outputs: dict[int, list[int]] = {}
@@ -239,10 +276,14 @@ class DecodeServer:
         self.prefill_tokens_total = 0
         self.decode_tokens_total = 0
         # bytes of K and V pages the decode steps' attention fetched
-        # and the steps that ran, counted on the host from the active
-        # slots' lengths;
+        # and the steps that ran, counted on the host from the rows'
+        # positions at dispatch and added when the step's tokens are
+        # fetched, as everything of a step is (so a ratio over steps
+        # has both its ends from the same steps); of those steps, the
+        # ones fetched with their successor already dispatched;
         self.kv_read_bytes_total = 0
         self.decode_steps_total = 0
+        self.ahead_steps_total = 0
         # keys the prefill chunk programs attended (live pages x
         # block, from each chunk's ``start`` and ``length``) and the
         # chunk programs run;
@@ -311,13 +352,15 @@ class DecodeServer:
         # compile count.
         jit_fn = jax.jit(nbd_prefill_paged, donate_argnums=(1,))
 
-        def wrapper(params, pool, prompt, slot, start, length):
-            self.prefill_keys_total += self._chunk_keys(int(start),
-                                                        int(length))
+        def wrapper(params, pool, prompt, slot: int, start: int,
+                    length: int):
+            # Host integers in, never device scalars read back: a
+            # read would wait for the step in flight, and the chunk
+            # would then be launched with the chip idle.
+            self.prefill_keys_total += self._chunk_keys(start, length)
             self.prefill_chunks_total += 1
-            return jit_fn(params, pool,
-                          self._paged.device_row(int(slot)), prompt,
-                          start, length)
+            return jit_fn(params, pool, self._paged.device_row(slot),
+                          prompt, np.int32(start), np.int32(length))
 
         wrapper.program = jit_fn    # to lower it without a live slot
         return wrapper
@@ -427,11 +470,10 @@ class DecodeServer:
         the last real token.  The pad is clamped so the padded write
         never reaches past max_len."""
         width = min(width, self._T - start)
-        seg = jnp.asarray(tokens + [0] * (width - len(tokens)),
-                          jnp.int32)[None, :]
+        seg = np.asarray(tokens + [0] * (width - len(tokens)),
+                         np.int32)[None, :]
         self._cache, logits = self._prefill_fn(
-            self._params, self._cache, seg, jnp.int32(slot),
-            jnp.int32(start), jnp.int32(len(tokens)))
+            self._params, self._cache, seg, slot, start, len(tokens))
         return logits
 
     def _run_prefill(self, prompt: list, slot: int):
@@ -501,13 +543,20 @@ class DecodeServer:
         else:
             self._slot_req[slot] = rid
             self._budget[rid] = budget - 1
+            self._run[slot] = [len(prompt), budget - 1]
             self._active = self._active.at[slot].set(True)
 
     def _finish(self, slot: int, rid: int) -> None:
+        """Free the slot and its pages.  A step in flight may still
+        run the row: its token is dropped at the fetch (the slot is no
+        longer ``rid``'s) and its write lands in pages the row had
+        reserved, before any program of the slot's next request
+        (program order: they consume the pool that step returns)."""
         self._finished.add(rid)
         self._slot_req.pop(slot, None)
         self._budget.pop(rid, None)
-        self._active = self._active.at[slot].set(False)
+        if self._run.pop(slot, None) is not None:
+            self._active = self._active.at[slot].set(False)
         self._free.append(slot)
         self._paged.free(slot)
 
@@ -556,12 +605,19 @@ class DecodeServer:
         return False
 
     def step(self) -> dict[int, list[int]]:
-        """One decode step for every active slot; returns
-        {request_id: tokens emitted this step} — one token per active
-        request.  Admits pending requests first, then advances at most
-        one mid-prefill chunk (interleave mode).  Each phase
-        (:data:`STEP_PHASES`) adds its seconds to :attr:`phase_s`; the
-        step's phases telescope."""
+        """Dispatch the next decode step, then fetch and emit the one
+        before it; returns {request_id: tokens emitted by this call} —
+        one token per request the fetched step ran.  In order: admit
+        pending requests and advance at most one mid-prefill chunk
+        (interleave mode); dispatch step n + 1 for the rows of
+        :attr:`_run`, from the device arrays step n returned; fetch
+        step n's tokens, computed already or computing ahead of what
+        was just queued; emit them.  So one step stays in flight from
+        call to call (and from tick to tick: the chip works on it
+        while the reply travels), the first call of a busy spell
+        emits nothing, and a call with no row left to run drains the
+        step in flight.  Each phase (:data:`STEP_PHASES`) adds its
+        seconds to :attr:`phase_s`; the call's phases telescope."""
         ph, tick = self.phase_s, self.tick
         t0 = time.perf_counter()
         with obs_spans.phase("serve/step/prefill", tick):
@@ -569,61 +625,84 @@ class DecodeServer:
             self._advance_prefill()
         t1 = time.perf_counter()
         ph["prefill"] += t1 - t0
-        if not self._slot_req:
+        flying = self._flying
+        if flying is None and not self._run:
             return {}
         with obs_spans.phase("serve/step/dispatch", tick):
             # Returns before the chip is done.
-            out = self._dispatch_step()
+            self._flying = self._dispatch_step() if self._run else None
         t2 = time.perf_counter()
         ph["dispatch"] += t2 - t1
         with obs_spans.phase("serve/step/sync", tick):
-            # The host blocked on the chip: one fetch per step.
-            toks, load = jax.device_get(out)
+            # The host blocked on the chip: the wait for step n, with
+            # step n + 1 queued behind it.
+            toks, load = (jax.device_get((flying.tokens, flying.load))
+                          if flying else (None, None))
         t3 = time.perf_counter()
         ph["sync"] += t3 - t2
         with obs_spans.phase("serve/step/emit", tick):
-            if load is not None:
-                touched, most, rows = (float(v) for v in load)
-                self.moe_load[0] += touched
-                self.moe_load[1] = max(self.moe_load[1], most)
-                self.moe_load[2] += rows
-            emitted: dict[int, list[int]] = {}
-            for slot, rid in list(self._slot_req.items()):
-                emitted[rid] = self._emit(slot, rid, [int(toks[slot])])
+            emitted = self._emit_step(flying, toks, load) if flying else {}
         t4 = time.perf_counter()
         ph["emit"] += t4 - t3
         if self._pending:
             self._admit_as_prefill(t4)
         return emitted
 
-    def _dispatch_step(self):
-        """Enqueue one decode step and return the device arrays the
-        host has to fetch: the slots' tokens, and the routing load
-        where the config's experts report one (else None)."""
-        self.kv_read_bytes_total += self._step_kv_read_bytes()
-        self.decode_steps_total += 1
+    def _dispatch_step(self) -> _InFlight:
+        """Enqueue one decode step over the rows of :attr:`_run` and
+        start the copy of what the host will fetch, so that the fetch
+        does not queue behind programs dispatched later.  A row whose
+        budget this step ends leaves :attr:`_run` here, before the
+        step's tokens are known."""
+        rows = {slot: self._slot_req[slot] for slot in self._run}
+        kv_bytes = self._step_kv_read_bytes()
         self._cache, self._lens, self._last, load = self._step_fn(
             self._params, self._cache, self._paged.device_table(),
             self._lens, self._last, self._active, self._sample_key())
-        return self._last, load
+        self._last.copy_to_host_async()
+        if load is not None:
+            load.copy_to_host_async()
+        for slot, st in list(self._run.items()):
+            st[0] += 1
+            st[1] -= 1
+            if not st[1]:
+                del self._run[slot]
+                self._active = self._active.at[slot].set(False)
+        return _InFlight(self._last, load, rows, kv_bytes)
 
     def _step_kv_read_bytes(self) -> int:
         """Bytes of K and V pages the next decode step's attention
-        fetches, all layers: for every active slot the pages from the
+        fetches, all layers: for every row it runs the pages from the
         window's first to the one its new token lands in."""
         bt = self._paged.block_tokens
-        pages = 0
-        for rid in self._slot_req.values():
-            pos = len(self.prompts[rid]) + len(self.outputs[rid]) - 1
-            pages += pos // bt - self._first_live_page(pos) + 1
+        pages = sum(pos // bt - self._first_live_page(pos) + 1
+                    for pos, _ in self._run.values())
         return pages * self._page_bytes
+
+    def _emit_step(self, step: _InFlight, toks, load) -> dict:
+        """Count a fetched step and emit its tokens: a row's only if
+        the request it was dispatched for still holds the slot (an EOS
+        is learned one step late, a cancel at any time: the surplus
+        token is dropped)."""
+        self.kv_read_bytes_total += step.kv_bytes
+        self.decode_steps_total += 1
+        self.ahead_steps_total += self._flying is not None
+        if load is not None:
+            touched, most, rows = (float(v) for v in load)
+            self.moe_load[0] += touched
+            self.moe_load[1] = max(self.moe_load[1], most)
+            self.moe_load[2] += rows
+        return {rid: self._emit(slot, rid, [int(toks[slot])])
+                for slot, rid in step.rows.items()
+                if self._slot_req.get(slot) == rid}
 
     def _emit(self, slot: int, rid: int, toks: list[int]) -> list[int]:
         """Budget-then-EOS truncation + bookkeeping for an emission —
-        the ONE definition of the cut semantics (a step that emits
-        several tokens a slot can overshoot device-side; the surplus
-        is discarded here and the slot's stale device state dies with
-        the slot)."""
+        the ONE definition of the cut semantics, and the one place a
+        token enters :attr:`outputs` after admission (the device can
+        overshoot: the surplus is discarded here or, for a step in
+        flight when the slot was freed, in :meth:`_emit_step`, and the
+        slot's stale device state dies with the slot)."""
         toks = toks[: self._budget[rid]]
         if self._eos is not None and self._eos in toks:
             toks = toks[: toks.index(self._eos) + 1]
@@ -655,8 +734,10 @@ class DecodeServer:
         return toks
 
     def done(self) -> bool:
+        """Nothing left to do: a step in flight is work, since its
+        tokens are not emitted yet."""
         return (not self._slot_req and not self._pending
-                and not self._prefilling)
+                and not self._prefilling and self._flying is None)
 
     def run_until_done(self, max_steps: int | None = None):
         """Drive :meth:`step` until every request finishes; returns
@@ -690,7 +771,7 @@ class DecodeServer:
         return (self.prefill_tokens_total, self.decode_tokens_total,
                 self.kv_read_bytes_total, self.decode_steps_total,
                 self.prefill_keys_total, self.prefill_chunks_total,
-                *self.phase_s.values())
+                self.ahead_steps_total, *self.phase_s.values())
 
     def take_account(self) -> dict:
         """This server's part of a tick's account: what it did since
@@ -701,15 +782,20 @@ class DecodeServer:
         ``pf`` / ``dc``: prompt tokens prefilled, tokens decoded;
         ``ph``: seconds in each of :data:`STEP_PHASES`; ``kvr``: bytes
         of K and V pages the decode steps' attention fetched, and the
-        steps; ``pfk``: keys the prefill chunk programs attended, and
-        the programs run; ``moe`` (a config that routes): experts
-        touched summed over those steps, most rows on one expert, rows
-        routed a layer summed."""
+        steps; ``ahd``: of those steps, the ones whose tokens were
+        fetched with the next step already dispatched behind them,
+        and the steps; ``pfk``: keys the prefill chunk programs
+        attended, and the programs run; ``moe`` (a config that
+        routes): experts touched summed over those steps, most rows on
+        one expert, rows routed a layer summed.  A decode step counts,
+        in all of these, when its tokens are fetched: the step in
+        flight at the call is the next account's."""
         now = self._totals()
         d = [a - b for a, b in zip(now, self._accounted)]
         self._accounted = now
         account = {"pf": d[0], "dc": d[1], "kvr": d[2:4], "pfk": d[4:6],
-                   "ph": dict(zip(self.phase_s, d[6:]))}
+                   "ahd": [d[6], d[3]],
+                   "ph": dict(zip(self.phase_s, d[7:]))}
         if self._routed:
             account["moe"] = [round(v, 3) for v in self.moe_load]
             self.moe_load = [0.0, 0.0, 0.0]

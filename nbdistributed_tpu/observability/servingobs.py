@@ -431,7 +431,10 @@ class ServingObservatory:
         a server's first tick).  ``idled`` marks a tick that followed a
         wait for work: its turnaround is no part of a decode period.
         ``kvr`` = ``[bytes, steps]``: K and V pages its decode steps
-        fetched from the paged pool in this tick; ``moe`` = ``[experts
+        fetched from the paged pool in this tick; ``ahd`` = ``[steps
+        whose tokens were fetched with the next step already dispatched
+        behind them, steps fetched]`` (absent from a worker that keeps
+        no step in flight); ``moe`` = ``[experts
         touched summed over those steps, most rows on one expert, rows
         routed a layer summed]`` where the model routes to fine-grained
         experts; ``pfk`` = ``[keys, chunks]``: keys its prefill chunk
@@ -451,6 +454,7 @@ class ServingObservatory:
         cmp = [int(n_cmp), float(s_cmp)]
         kv_bytes, kv_steps = tick.get("kvr") or (0, 0)
         pf_keys, pf_chunks = tick.get("pfk") or (0, 0)
+        ahead, fetched = tick.get("ahd") or (0, 0)
         rec = {
             "seq": int(seq), "rank": int(rank),
             "t_wall": round(self._now() if t_wall is None else t_wall,
@@ -458,6 +462,7 @@ class ServingObservatory:
             "gw": gw, "wk": wk, "cmp": cmp, "idled": bool(idled),
             "kvr": [int(kv_bytes), int(kv_steps)],
             "pfk": [int(pf_keys), int(pf_chunks)],
+            "ahd": [int(ahead), int(fetched)],
             "moe": None if moe is None else [float(v) for v in moe],
             "turnaround": (None if turnaround is None
                            else max(0.0, float(turnaround))),
@@ -521,6 +526,11 @@ class ServingObservatory:
                      "prefill_keys": round(
                          sum(t["pfk"][0] for t in ticks)
                          / max(1, sum(t["pfk"][1] for t in ticks))),
+                     # share of the decode steps fetched while the
+                     # next was already dispatched (0 with no step)
+                     "ahead": round(
+                         sum(t["ahd"][0] for t in ticks)
+                         / max(1, sum(t["ahd"][1] for t in ticks)), 4),
                      "slow": slow}
         routed = [t for t in ticks if t["moe"] is not None]
         if routed:

@@ -1262,11 +1262,17 @@ class DistributedWorker:
                          rank=self.rank)
 
     def _handle_serve_step(self, msg: Message) -> Message:
-        """One decode tick: admit new requests, run up to ``steps``
-        decode steps, reply with per-request emissions AT OFFSETS.
-        ``release`` frees finished requests' host-side records.  The
-        reply is cached by the replay cache like any mutating request,
-        so a redelivered tick never decodes twice."""
+        """One decode tick: admit new requests, call the server's
+        ``step()`` up to ``steps`` times, reply with per-request
+        emissions AT OFFSETS.  The server keeps one decode step in
+        flight, and it outlives this handler: the reply carries the
+        tokens fetched so far, the chip works on the step dispatched
+        last while the reply and the next ``serve_step`` travel, and
+        the next tick's first ``step()`` fetches it (a server's first
+        tick emits a token a row less than it dispatched, every later
+        one as many).  ``release`` frees finished requests' host-side
+        records.  The reply is cached by the replay cache like any
+        mutating request, so a redelivered tick never decodes twice."""
         data = msg.data or {}
         tenant = data.get("tenant") or msg.tenant
         st = self._serve.get(tenant)

@@ -256,10 +256,15 @@ def test_kv_read_bytes_and_moe_load_count_what_a_step_touches(model):
     for n in lens:
         srv.submit([int(t) for t in tokens(n, seed=n)[0]], 5)
     srv.step()
+    # the step is in flight: its bytes, its routing load and its count
+    # enter the account together, when its tokens are fetched
+    assert srv.take_account()["kvr"] == [0, 0] and srv.moe_load[2] == 0
+    srv.step()                              # dispatches 2, fetches 1
     pages = sum((n + 1 - 1) // bt + 1 for n in lens)
     assert srv.kv_read_bytes_total == pages * srv._page_bytes
     account = srv.take_account()
     assert account["kvr"] == [pages * srv._page_bytes, 1]
+    assert account["ahd"] == [1, 1] and account["dc"] == 2
     touched, most, rows = account["moe"]
     assert rows == 2 * cfg.top_k            # two live rows, one idle slot
     assert cfg.top_k <= touched <= 2 * cfg.top_k and 1 <= most <= 2

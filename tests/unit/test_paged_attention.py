@@ -324,18 +324,23 @@ def serve(model, use_flash, **kw):
 
 
 def drive(srv):
-    """Staggered admissions over two slots, a request cancelled in
-    flight, and a pool so small that the fourth request waits for the
-    blocks the first three give back."""
+    """Staggered admissions over two slots, a request cancelled with
+    a step of its row in flight, and a pool so small that the fourth
+    request waits for the blocks the first three give back."""
     out = {}
     out["a"] = srv.submit([5, 9, 2], 7)
-    srv.step()
+    srv.step()                                  # a's first step leaves
     out["b"] = srv.submit([7, 1, 3, 11, 4, 8, 6], 12)   # crosses a page
-    srv.step()
+    srv.step()                                  # a's second, with b
+    assert [len(srv.outputs[out[k]]) for k in "ab"] == [2, 1]
     out["c"] = srv.submit([2, 2], 6)                    # waits for a slot
     out["d"] = srv.submit(list(range(1, 20)), 9)        # 4 recycled blocks
     for _ in range(3):
         srv.step()
+    # five steps dispatched, four fetched: one token at admission and
+    # one a fetched step that ran the row
+    assert [len(srv.outputs[out[k]]) for k in "ab"] == [5, 4]
+    assert out["b"] in srv._flying.rows.values()
     assert srv.cancel(out["b"])
     srv.run_until_done(max_steps=100)
     assert srv.kv_snapshot()["used"] == 0
@@ -351,7 +356,8 @@ def test_server_on_the_kernel_emits_the_einsum_servers_tokens(
     got, want = drive(kernel), drive(einsum)
     assert got == want
     assert [len(want[k]) for k in "acd"] == [7, 6, 9]
-    assert 1 <= len(want["b"]) < 12             # cancelled in flight
+    assert len(want["b"]) == 4      # cancelled: the step in flight's
+    #                                 token for its row was dropped
     assert kernel.kv_read_bytes_total == einsum.kv_read_bytes_total > 0
 
 
@@ -362,12 +368,15 @@ def test_a_step_leaves_every_block_no_active_slot_owns_bit_identical(
                 interleave_prefill=True)
     srv.submit([5, 9, 2, 7, 1, 3], 4)           # active after admission
     srv.submit(list(range(1, 20)), 4)           # mid-prefill: inactive
-    srv.step()                                  # one chunk of the second
+    srv.step()                  # one chunk of the second, and the
+    #                             first's first step, left in flight
     assert srv._prefilling and list(srv._slot_req) == [0]
+    assert srv._flying.rows == {0: 0} and list(srv._run) == [0]
     owned = set(srv._paged.allocator._tables["0"])
     before = jax.tree_util.tree_map(np.asarray, srv._cache)
-    lens = np.asarray(srv._lens)
-    srv._dispatch_step()                        # the decode step alone
+    lens = np.asarray(srv._lens)                # after that step
+    assert lens[0] == 6 + 1 == srv._run[0][0]
+    srv._dispatch_step()                        # the next step alone
     after = jax.tree_util.tree_map(np.asarray, srv._cache)
     trash = srv._paged.trash
     others = [b for b in range(trash) if b not in owned]

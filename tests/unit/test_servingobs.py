@@ -277,7 +277,7 @@ def test_ticks_block_present_with_no_finished_request():
     tk = obs.summary()["ticks"]
     assert tk == {"count": 0, "compiles": 0, "compile_ms": 0.0,
                   "kv_view_bytes": 0, "kv_read_bytes": 0,
-                  "prefill_keys": 0, "slow": []}
+                  "prefill_keys": 0, "ahead": 0.0, "slow": []}
     _tick(obs, 1)
     s = obs.summary()
     assert s["count"] == 0 and "stages" not in s
@@ -320,6 +320,21 @@ def test_prefill_keys_is_the_mean_over_the_rings_chunk_programs(pfk, want):
         obs.note_tick(seq, 0, dict(GW), {"ph": dict(WK), "pfk": one})
     assert obs.ticks_summary()["prefill_keys"] == want
     assert obs.summary()["ticks"]["prefill_keys"] == want
+
+
+@pytest.mark.parametrize("ahd, want", [
+    ([(7, 7), (0, 0), (8, 8), (3, 4)], round(18 / 19, 4)),
+    ([(8, 8), None], 1.0),                      # a tick without the key
+    ([None, None], 0.0)])                       # a worker that is serial
+def test_ahead_is_the_share_of_the_rings_steps_fetched_with_a_successor(
+        ahd, want):
+    """``ahd`` = [steps fetched with the next step already dispatched,
+    steps fetched] a tick: the summary divides the ring's sums."""
+    obs = ServingObservatory(now=FakeClock())
+    for seq, one in enumerate(ahd, 1):
+        obs.note_tick(seq, 0, dict(GW), {"ph": dict(WK), "ahd": one})
+    assert obs.ticks_summary()["ahead"] == want
+    assert obs.summary()["ticks"]["ahead"] == want
 
 
 def test_worker_phases_sum_to_the_handler_time_exactly():
