@@ -83,8 +83,8 @@ F32_EXACT = (0, 2, 4)       # 24, 200 (two chunks) and 130 tokens
 _STEP_FIXED_GB, _STEP_PER_LAYER_GB, _HBM_SHARE = 3.8, 3.34, 0.85
 
 
-PHASES = ("fleet", "kernels", "train", "generate", "teardown", "serve",
-          "parent_off_chip")
+PHASES = ("fleet", "kernels", "hybrid", "train", "generate", "teardown",
+          "serve", "parent_off_chip")
 
 
 class PhaseFailed(Exception):
@@ -270,6 +270,18 @@ class Smoke:
                if not (c["mosaic"] >= 1 and c["err"] <= c["tol"])]
         if bad:
             raise PhaseFailed(f"kernel checks failed: {bad}")
+
+    def hybrid(self):
+        """The state-space layer's one-token update and a windowed
+        differential decode step over a ring of pages, at the published
+        widths of Phi-4-mini-flash and ``highest`` precision, against
+        ``jax.numpy``."""
+        k = self.cell(_HYBRID_CELL, ranks="[0]")[0]
+        self.facts["hybrid"] = k["checks"]
+        bad = [c for c in k["checks"]
+               if not (c["mosaic"] >= c["kernels"] and c["err"] <= c["tol"])]
+        if bad:
+            raise PhaseFailed(f"hybrid checks failed: {bad}")
 
     def train(self):
         res = self.cell(_HEADER.format(layers=self.layers) + _TRAIN_CELL)
@@ -463,7 +475,7 @@ class Smoke:
         try:
             self.shell()
             if self.phase("fleet", self.fleet):
-                for name in ("kernels", "train", "generate"):
+                for name in ("kernels", "hybrid", "train", "generate"):
                     self.phase(name, getattr(self, name))
             self.phase("teardown", self.teardown_fleet)
             if self.phases["fleet"]["ok"]:
@@ -577,6 +589,92 @@ _check("decode_int8",
            _dequantize_kv(v8, v_s).astype(jnp.bfloat16), pos),
        qd, k8, v8, pos, k_s, v_s)
 del q, k, v, do, qd, kc, vc, k8, v8, k_s, v_s
+_emit(checks=checks)
+'''
+
+# float32 at ``highest``: the largest error allowed is 1e-4 of the
+# reference's largest magnitude (sums in another order).
+_HYBRID_CELL = _EMIT + '''
+from nbdistributed_tpu.models.hybrid import (DiffAttnMixer, HybridCache,
+    SSMMixer, init_layer, make_hybrid_cache, phi4_mini_flash_config)
+hcfg = phi4_mini_flash_config(dtype=jnp.float32)
+rows, bt, max_len = 8, 64, 2048
+_ks = jax.random.split(jax.random.PRNGKey(11), 8)
+checks = []
+def _check(name, kernels, fn, ref, *args):
+    with jax.default_matmul_precision("highest"):
+        jf = jax.jit(fn)
+        mosaic = jf.lower(*args).as_text().count("tpu_custom_call")
+        got, want = jax.tree.leaves(jf(*args)), jax.tree.leaves(jax.jit(ref)(*args))
+    err = max(float(jnp.max(jnp.abs(g - w))) for g, w in zip(got, want))
+    top = max(float(jnp.max(jnp.abs(w))) for w in want)
+    checks.append(dict(name=name, mosaic=mosaic, kernels=kernels, err=err,
+                       tol=1e-4 * top))
+C, N, K, R = hcfg.d_inner, hcfg.d_state, hcfg.d_conv, hcfg.dt_rank
+ssm = init_layer(_ks[0], hcfg, "ssm")
+h = jax.random.normal(_ks[1], (rows, 1, hcfg.d_model))
+state = jax.random.normal(_ks[2], (rows, N, C))
+tail = jax.random.normal(_ks[3], (rows, K - 1, C))
+live = jnp.arange(rows) % 4 != 3            # every fourth row sits out
+def _ssm(h, state, tail):
+    return SSMMixer(hcfg).mix(h, ssm, state, tail, live[:, None])
+def _ssm_ref(h, state, tail):
+    xz = h[:, 0] @ ssm["w_in"]
+    x, z = xz[:, :C], xz[:, C:]
+    win = jnp.concatenate([tail, x[:, None]], 1)            # (rows, K, C)
+    x = jax.nn.silu(jnp.einsum("bkc,kc->bc", win, ssm["conv_w"]) + ssm["conv_b"])
+    dbc = x @ ssm["w_x"]
+    d = jax.nn.softplus(dbc[:, :R] @ ssm["w_dt"] + ssm["b_dt"])
+    s = (jnp.exp(jnp.einsum("bc,nc->bnc", d, -jnp.exp(ssm["A_log"]))) * state
+         + jnp.einsum("bc,bn->bnc", d * x, dbc[:, R:R + N]))
+    y = jnp.einsum("bnc,bn->bc", s, dbc[:, R + N:]) + ssm["D"] * x
+    keep = live[:, None, None]
+    return ((y * jax.nn.silu(z)) @ ssm["w_out"])[:, None], y[:, None], \
+        jnp.where(keep, s, state), jnp.where(keep, win[:, 1:], tail)
+def _live_rows(fn):         # a row that sits out returns no output of use
+    def run(*a):
+        out, y, s, t = fn(*a)
+        return out * live[:, None, None], y * live[:, None, None], s, t
+    return run
+_check("state_update", 0, _live_rows(_ssm), _live_rows(_ssm_ref), h, state, tail)
+att = init_layer(_ks[4], hcfg, "window")
+att = {**att, "bq": 0.02 * jax.random.normal(_ks[5], att["bq"].shape),
+       "bkv": 0.02 * jax.random.normal(_ks[6], att["bkv"].shape)}
+cache = make_hybrid_cache(hcfg, rows * max_len // bt, bt, rows=rows,
+                          max_len=max_len, chunk=512)
+wk = jax.random.normal(_ks[7], cache["window"]["k"].shape)
+pool = {"k": wk, "v": wk[::-1] * 0.5}
+pos = jnp.asarray([0, 63, 64, 511, 512, 1087, 1088, 2047], jnp.int32)
+table = jnp.broadcast_to(jnp.arange(max_len // bt, dtype=jnp.int32), (rows, max_len // bt))
+hx = jax.random.normal(_ks[1], (rows, 1, hcfg.d_model))
+def _parts(pool, hx):
+    kv = HybridCache({**cache, "window": pool}, hcfg, table, slot=None,
+                     active=jnp.ones((rows,), bool), length=None, start=None)
+    return kv, kv.window.project_q(hx, att), kv.window.project_kv(hx, att)
+def _step(pool, hx):
+    kv, q, new = _parts(pool, hx)
+    o, _pool = kv.window_layer(pool, jnp.int32(3), q, new, pos[:, None])
+    return kv.window.out(o, att, 7)
+def _step_ref(pool, hx):
+    # the same written pool, read through a dense view of the ring with
+    # the two softmaxes a pair apart, heads in the program's order
+    from nbdistributed_tpu.models.paged_kv import gather_layer, write_token
+    kv, q, new = _parts(pool, hx)
+    pool = write_token(pool, jnp.int32(3), new, kv._ring_table, pos, None)
+    view = gather_layer(pool, jnp.int32(3), kv._ring_table)
+    P, Dh = hcfg.kv_pairs, hcfg.head_dim
+    qq = q.reshape(rows, P, 4, 2 * Dh)
+    t = jnp.arange(view["k"].shape[2])
+    keep = (t[None] <= pos[:, None]) & (t[None] > pos[:, None] - hcfg.sliding_window)
+    def soft(qh, kh):       # (rows, P, 2, Dh), (rows, P, T, Dh)
+        sc = jnp.einsum("bpgd,bptd->bpgt", qh, kh) / Dh ** 0.5
+        p = jax.nn.softmax(jnp.where(keep[:, None, None], sc, -jnp.inf), -1)
+        return jnp.einsum("bpgt,bptd->bpgd", p, view["v"])
+    o = jnp.concatenate([soft(qq[:, :, :2, :Dh], view["k"][..., :Dh]),
+                         soft(qq[:, :, 2:, Dh:], view["k"][..., Dh:])], 2)
+    return kv.window.out(o.reshape(rows, 1, -1), att, 7)
+_check("windowed_differential_step", 1, _step, _step_ref, pool, hx)
+del cache, pool, wk, state, tail
 _emit(checks=checks)
 '''
 

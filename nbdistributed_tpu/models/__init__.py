@@ -6,9 +6,13 @@ from .generate import (forward_with_cache, generate, init_kv_cache,
                        kv_cache_shardings, make_generate_fn,
                        prefill_chunked)
 from .hf import (config_from_hf, config_from_hf_json,
+                 hybrid_config_from_hf,
                  latent_moe_config_from_hf, load_hf_pretrained,
                  moe_config_from_hf, moe_params_from_hf,
                  params_from_hf)
+from .hybrid import (HybridConfig, init_hybrid_model, layer_kinds_for,
+                     make_hybrid_cache, phi4_mini_flash_config,
+                     tiny_hybrid_config)
 from .mla import (LatentMoEConfig, init_latent_moe_model,
                   joyai_flash_config, latent_moe_forward,
                   latent_moe_shardings, tiny_latent_moe_config)
@@ -55,6 +59,9 @@ __all__ = ["SeqParallel", "TransformerConfig", "forward",
            "config_from_hf", "config_from_hf_json",
            "latent_moe_config_from_hf", "load_hf_pretrained",
            "params_from_hf",
+           "HybridConfig", "init_hybrid_model", "layer_kinds_for",
+           "make_hybrid_cache", "phi4_mini_flash_config",
+           "tiny_hybrid_config", "hybrid_config_from_hf",
            "LatentMoEConfig", "init_latent_moe_model",
            "joyai_flash_config", "latent_moe_forward",
            "latent_moe_shardings", "tiny_latent_moe_config",

@@ -413,7 +413,8 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
                        last_only: bool = False, last_index=None,
                        mesh=None, ep_axis: str = "ep", row_mask=None,
                        token_mask=None, block_table=None,
-                       with_moe_load: bool = False):
+                       with_moe_load: bool = False, slot=None,
+                       final: bool = True):
     """Run ``tokens`` (B, S) through the model, reading/writing the KV
     cache at offset ``cache_len`` (traced scalar ok, or a per-row
     ``(B,)`` vector when the streams in the batch sit at different
@@ -463,8 +464,29 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
     ``[experts touched (mean over layers), most rows on one expert,
     rows routed a layer]`` (zeros for a config that routes nothing
     that way).
+
+    A :class:`~.hybrid.HybridConfig` (state-space layers beside window
+    and full attention) keeps three kinds of cache and runs
+    :func:`~.hybrid.hybrid_forward_with_cache`, over the paged caches
+    only: ``slot`` is the row a prefill chunk belongs to (its state is
+    counted in rows), and ``final`` (static) whether the chunk ends its
+    prompt, since one that does not runs nothing past the shared K/V's
+    projection and returns None for logits.
     """
+    from .hybrid import HybridConfig, hybrid_forward_with_cache
     from .mla import LatentMoEConfig, MLAMixer
+    if isinstance(cfg, HybridConfig):
+        if block_table is None or mesh is not None:
+            raise ValueError("state-space layers are served over the "
+                             "paged caches on one device: pass "
+                             "block_table (and no mesh)")
+        out = hybrid_forward_with_cache(
+            params, tokens, cache, cache_len, cfg,
+            block_table=block_table, row_mask=row_mask,
+            token_mask=token_mask, last_index=last_index, slot=slot,
+            final=final)
+        return (*out, jnp.zeros((3,), jnp.float32)) if with_moe_load \
+            else out
     B, S = tokens.shape
     cache_len = jnp.asarray(cache_len)
     per_row = cache_len.ndim == 1  # per-stream cache pointers

@@ -278,6 +278,55 @@ def latent_moe_config_from_hf(hf_config, **overrides):
         **overrides})
 
 
+def hybrid_config_from_hf(hf_config, **overrides):
+    """Map a ``model_type: phi4flash`` config onto
+    :class:`~nbdistributed_tpu.models.hybrid.HybridConfig`.  The kind of
+    every layer is derived from ``num_hidden_layers`` and
+    ``mb_per_layer`` (:func:`~.hybrid.layer_kinds_for`), never listed;
+    what the model's own file hard-codes and ``config.json`` does not
+    carry (``d_state`` 16, ``d_conv`` 4, ``expand`` 2, ``dt_rank =
+    ceil(hidden / 16)``) are that class's defaults.
+
+    Refused rather than mis-served: an untied head, a head or MLP bias.
+
+    A real checkpoint would also need its weights laid out on the way
+    in; no weight converter for this family exists yet.  The fused
+    ``Wqkv`` is ``[q | k | v]`` by columns and goes to ``wq`` and
+    ``wkv = [k | v]``; within ``wq`` the query heads of a KV pair ``j``
+    are reordered from ``4j, 4j + 1, 4j + 2, 4j + 3`` to ``4j, 4j + 2,
+    4j + 1, 4j + 3`` (first softmax's two heads, then the second's: the
+    pairing assumed is neighbouring heads ``2p, 2p + 1``, and it has to
+    be checked against the checkpoint's own reshape before the first
+    real weight is served); the fused ``gate_up`` splits into
+    ``w_gate`` and ``w_up``; ``A_log`` is stored with the channels
+    minor, ``(d_state, d_inner)``, the transpose of the checkpoint's;
+    every ``nn.Linear`` weight is transposed to ``(in, out)``."""
+    import math
+
+    from .hybrid import HybridConfig, layer_kinds_for
+
+    get = lambda k, d=None: getattr(hf_config, k, d)
+    if not get("tie_word_embeddings", True):
+        raise ValueError("an untied head is not supported for "
+                         "model_type phi4flash")
+    if get("mlp_bias", False) or get("lm_head_bias", False):
+        raise ValueError("mlp_bias / lm_head_bias are not supported")
+    n_layers = hf_config.num_hidden_layers
+    return HybridConfig(**{
+        "vocab_size": hf_config.vocab_size,
+        "d_model": hf_config.hidden_size,
+        "n_layers": n_layers,
+        "n_heads": hf_config.num_attention_heads,
+        "n_kv_heads": hf_config.num_key_value_heads,
+        "d_ff": hf_config.intermediate_size,
+        "max_seq_len": get("max_position_embeddings", 4096),
+        "norm_eps": float(get("layer_norm_eps", 1e-5)),
+        "sliding_window": hf_config.sliding_window,
+        "layer_kinds": layer_kinds_for(n_layers, get("mb_per_layer", 2)),
+        "dt_rank": math.ceil(hf_config.hidden_size / 16),
+        **overrides})
+
+
 def config_from_hf_json(config: dict, **overrides):
     """A published ``config.json`` (as a dict) -> the program's config,
     by its ``model_type``; one this tree cannot run raises."""
@@ -286,6 +335,8 @@ def config_from_hf_json(config: dict, **overrides):
     kind = config.get("model_type")
     if kind == "joyai_llm_flash":
         return latent_moe_config_from_hf(ns, **overrides)
+    if kind == "phi4flash":
+        return hybrid_config_from_hf(ns, **overrides)
     if kind == "mixtral":
         cfg = moe_config_from_hf(ns)
     elif kind in ("llama", "mistral"):

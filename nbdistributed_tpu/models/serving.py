@@ -111,6 +111,16 @@ def _capacity_dispatch(cfg, mesh, ep_axis: str) -> bool:
 STEP_PHASES = ("prefill", "dispatch", "sync", "emit")
 
 
+class _KVKind(NamedTuple):
+    """One kind of paged K/V that attention reads: a model whose layers
+    all keep their own pages alike has one, a model with layer kinds
+    (:mod:`.hybrid`) one a kind that keeps pages."""
+    name: str
+    window: int | None          # its layers' window; None: all a row holds
+    page_bytes: int             # one live page of K and V, over the
+    #                             layers that read it in a decode step
+
+
 class _InFlight(NamedTuple):
     """A decode step that was dispatched and whose tokens the host has
     not fetched yet."""
@@ -118,7 +128,8 @@ class _InFlight(NamedTuple):
     load: jax.Array | None      # the step's routing load (a config
     #                             whose experts report one)
     rows: dict[int, int]        # {slot: request id} it ran
-    kv_bytes: int               # K and V page bytes its attention reads
+    kv_bytes: tuple             # K and V page bytes its attention
+    #                             reads, one count a :class:`_KVKind`
 
 
 class DecodeServer:
@@ -200,8 +211,16 @@ class DecodeServer:
                     "capacity from the shape, so per-chunk capacity "
                     "would differ from a solo run's and change which "
                     "tokens drop")
+        from .hybrid import HybridConfig
         from .mla import LatentMoEConfig
         self._routed = isinstance(cfg, LatentMoEConfig)
+        # Layer kinds that keep state beside (or in place of) pages:
+        # three kinds of cache, and a prefill chunk that is told its
+        # row and whether it ends the prompt.
+        self._hybrid = isinstance(cfg, HybridConfig)
+        if self._hybrid and (kv_quantized or mesh is not None):
+            raise ValueError("state-space layers are served from "
+                             "unquantized caches on one device")
         self._params = params
         self._cfg = cfg
         self._mesh = mesh
@@ -226,22 +245,48 @@ class DecodeServer:
         self._paged = PagedKVCache(
             slots=max_batch, max_len=max_len, n_blocks=kv_blocks,
             block_tokens=kv_block_tokens)
-        self._cache = make_paged_pool(
-            cfg, kv_blocks, kv_block_tokens, mesh=mesh,
-            quantized=kv_quantized)
-        # One page of K and V over all layers, in bytes: what a
-        # step's attention fetches per live page of a slot
+        # One page of K and V in bytes, over the layers that read it:
+        # what a step's attention fetches per live page of a slot
         # (``step`` sums them into ``kv_read_bytes_total``).
-        self._page_bytes = sum(
+        page_bytes = lambda pool: sum(
             c.nbytes // c.shape[1]
-            for c in jax.tree_util.tree_leaves(self._cache))
+            for c in jax.tree_util.tree_leaves(pool))
+        # Bytes of per-row state a decode step reads and writes (every
+        # row's: the update is elementwise over the whole array).
+        self._state_bytes = 0
+        if self._hybrid:
+            from .hybrid import hybrid_stacks, make_hybrid_cache
+            # Rows of state and of window rings are the slots: a free
+            # slot is a free row, so they never refuse a request that
+            # the slot count admits, and the allocator (the gateway's
+            # mirror of it too) goes on counting the full layer's
+            # blocks alone.
+            self._cache = make_hybrid_cache(
+                cfg, kv_blocks, kv_block_tokens, rows=max_batch,
+                max_len=max_len, chunk=prefill_chunk)
+            _pairs, readers = hybrid_stacks(cfg)
+            self._kinds = (
+                _KVKind("full", cfg.window_of("full"),
+                        page_bytes(self._cache["full"]) * (1 + readers)),
+                _KVKind("window", cfg.window_of("window"),
+                        page_bytes(self._cache["window"])))
+            self._state_bytes = 2 * sum(
+                c.nbytes for c in
+                jax.tree_util.tree_leaves(self._cache["ssm"]))
+        else:
+            self._cache = make_paged_pool(
+                cfg, kv_blocks, kv_block_tokens, mesh=mesh,
+                quantized=kv_quantized)
+            self._kinds = (_KVKind("kv", cfg.sliding_window,
+                                   page_bytes(self._cache)),)
         # Bytes a decode step gathers from the pool into dense
         # views, all layers: 0 where the kernel reads the pool in
         # place, else every slot's whole block table once a layer
         # (the fallback's cost per step; a count from shapes).
         self.kv_view_bytes = (
             0 if reads_in_place(cfg, mesh) else
-            self._page_bytes * self._paged.max_blocks * max_batch)
+            sum(k.page_bytes for k in self._kinds)
+            * self._paged.max_blocks * max_batch)
         self._lens = jnp.zeros((max_batch,), jnp.int32)
         self._last = jnp.zeros((max_batch,), jnp.int32)
         self._active = jnp.zeros((max_batch,), bool)
@@ -276,19 +321,28 @@ class DecodeServer:
         self.prefill_tokens_total = 0
         self.decode_tokens_total = 0
         # bytes of K and V pages the decode steps' attention fetched
+        # (a count a kind of K/V; ``kv_read_bytes_total`` their sum)
         # and the steps that ran, counted on the host from the rows'
         # positions at dispatch and added when the step's tokens are
         # fetched, as everything of a step is (so a ratio over steps
         # has both its ends from the same steps); of those steps, the
-        # ones fetched with their successor already dispatched;
-        self.kv_read_bytes_total = 0
+        # ones fetched with their successor already dispatched; bytes
+        # of per-row state those steps read and wrote;
+        self.kv_read_bytes_by_kind = [0] * len(self._kinds)
         self.decode_steps_total = 0
         self.ahead_steps_total = 0
+        self.state_bytes_total = 0
         # keys the prefill chunk programs attended (live pages x
-        # block, from each chunk's ``start`` and ``length``) and the
-        # chunk programs run;
+        # block, from each chunk's ``start`` and ``length``; the
+        # windowed kind's where there are several) and the chunk
+        # programs run; of those, the programs that ran the layers
+        # past the shared K/V (a model whose prefill runs them on the
+        # prompt's last token only: one a prompt), and the shared
+        # layer's keys those attended;
         self.prefill_keys_total = 0
         self.prefill_chunks_total = 0
+        self.cross_decoder_runs_total = 0
+        self.cross_decoder_keys_total = 0
         # seconds per phase of step() (and of submit()'s admission,
         # which is prefill), on this process's perf_counter;
         self.phase_s = dict.fromkeys(STEP_PHASES, 0.0)
@@ -336,49 +390,64 @@ class DecodeServer:
         cfg, mesh, ep_axis = self._cfg, self._mesh, self._ep_axis
 
         def nbd_prefill_paged(params, pool, row_ids, prompt, start,
-                              length):
+                              length, slot=None, final=True):
+            """``slot`` and ``final`` are what only a model with
+            per-row state is told: the row, and whether the chunk ends
+            its prompt (static: two programs a chunk shape, and one
+            that does not end it has no logits)."""
             s_pad = prompt.shape[1]
             mask = (jnp.arange(s_pad)[None, :] < length)
             logits, pool = forward_with_cache(
                 params, prompt, pool, start, cfg, mesh=mesh,
                 ep_axis=ep_axis, token_mask=mask,
                 last_index=(length - 1)[None],
-                block_table=row_ids[None])
-            return pool, logits[0, 0]                  # (V,)
+                block_table=row_ids[None], slot=slot, final=final)
+            return pool, (None if logits is None else logits[0, 0])
 
         # The pool is donated: admission updates it in place.  One jit
         # serves every prompt bucket — jax.jit retraces (and caches)
         # per input shape, so padding to pad_to multiples bounds the
         # compile count.
-        jit_fn = jax.jit(nbd_prefill_paged, donate_argnums=(1,))
+        jit_fn = jax.jit(nbd_prefill_paged, donate_argnums=(1,),
+                         static_argnames=("final",))
 
         def wrapper(params, pool, prompt, slot: int, start: int,
-                    length: int):
+                    length: int, final: bool = True):
             # Host integers in, never device scalars read back: a
             # read would wait for the step in flight, and the chunk
             # would then be launched with the chip idle.
             self.prefill_keys_total += self._chunk_keys(start, length)
             self.prefill_chunks_total += 1
-            return jit_fn(params, pool, self._paged.device_row(slot),
-                          prompt, np.int32(start), np.int32(length))
+            args = (params, pool, self._paged.device_row(slot), prompt,
+                    np.int32(start), np.int32(length))
+            if not self._hybrid:
+                return jit_fn(*args)
+            if final:
+                self.cross_decoder_runs_total += 1
+                self.cross_decoder_keys_total += start + length
+            return jit_fn(*args, slot=np.int32(slot), final=final)
 
         wrapper.program = jit_fn    # to lower it without a live slot
         return wrapper
 
     def _chunk_keys(self, start: int, length: int) -> int:
-        """Keys a prefill chunk program attends: whole pages, from the
-        page of its first token's window to the page of its last real
-        token (as :meth:`_step_kv_read_bytes` counts a step's)."""
+        """Keys a prefill chunk program attends, a layer: whole pages,
+        from the page of its first token's window to the page of its
+        last real token (as :meth:`_step_kv_read_bytes` counts a
+        step's), by the kind of K/V every token of a chunk attends
+        (the last kind: where a model has several, the others' layers
+        run on a prompt's last token only)."""
         bt = self._paged.block_tokens
         last = (start + length - 1) // bt
-        return (last - self._first_live_page(start) + 1) * bt
+        first = self._first_live_page(self._kinds[-1], start)
+        return (last - first + 1) * bt
 
-    def _first_live_page(self, pos: int) -> int:
-        """The first page a query at ``pos`` attends: its window's."""
-        window = getattr(self._cfg, "sliding_window", None)
-        if not window:
+    def _first_live_page(self, kind: _KVKind, pos: int) -> int:
+        """The first page a query at ``pos`` attends in K/V of
+        ``kind``: its window's."""
+        if not kind.window:
             return 0
-        return max(0, pos + 1 - window) // self._paged.block_tokens
+        return max(0, pos + 1 - kind.window) // self._paged.block_tokens
 
     def _jit_step(self):
         """The decode step over the physical pool, which it consumes
@@ -464,16 +533,18 @@ class DecodeServer:
         return k
 
     def _prefill_segment(self, slot: int, tokens: list, start: int,
-                         width: int):
+                         width: int, final: bool):
         """Run the prefill program over ``tokens`` at position
         ``start``, right-padded to ``width``; returns the logits at
-        the last real token.  The pad is clamped so the padded write
-        never reaches past max_len."""
+        the last real token (``final``: the segment ends its prompt; a
+        model told so returns none from one that does not).  The pad
+        is clamped so the padded write never reaches past max_len."""
         width = min(width, self._T - start)
         seg = np.asarray(tokens + [0] * (width - len(tokens)),
                          np.int32)[None, :]
         self._cache, logits = self._prefill_fn(
-            self._params, self._cache, seg, slot, start, len(tokens))
+            self._params, self._cache, seg, slot, start, len(tokens),
+            **({"final": final} if self._hybrid else {}))
         return logits
 
     def _run_prefill(self, prompt: list, slot: int):
@@ -492,10 +563,11 @@ class DecodeServer:
         ck = self._prefill_chunk
         if ck is None or len(prompt) <= ck:
             return self._prefill_segment(slot, prompt, 0,
-                                         self._bucket(len(prompt)))
+                                         self._bucket(len(prompt)), True)
         for start in range(0, len(prompt), ck):
             logits = self._prefill_segment(
-                slot, prompt[start:start + ck], start, ck)
+                slot, prompt[start:start + ck], start, ck,
+                start + ck >= len(prompt))
         return logits
 
     def _admit_pending(self) -> None:
@@ -573,7 +645,8 @@ class DecodeServer:
         rid, prompt, budget, written = st
         ck = self._prefill_chunk
         seg = prompt[written:written + ck]
-        logits = self._prefill_segment(slot, seg, written, ck)
+        logits = self._prefill_segment(slot, seg, written, ck,
+                                       written + ck >= len(prompt))
         self.prefill_tokens_total += len(seg)
         st[3] = written + len(seg)
         if st[3] < len(prompt):
@@ -670,21 +743,26 @@ class DecodeServer:
                 self._active = self._active.at[slot].set(False)
         return _InFlight(self._last, load, rows, kv_bytes)
 
-    def _step_kv_read_bytes(self) -> int:
+    def _step_kv_read_bytes(self) -> tuple:
         """Bytes of K and V pages the next decode step's attention
-        fetches, all layers: for every row it runs the pages from the
-        window's first to the one its new token lands in."""
+        fetches, a count a kind of K/V over the layers that read it:
+        for every row the step runs, the pages from the window's first
+        to the one its new token lands in."""
         bt = self._paged.block_tokens
-        pages = sum(pos // bt - self._first_live_page(pos) + 1
-                    for pos, _ in self._run.values())
-        return pages * self._page_bytes
+        return tuple(
+            kind.page_bytes * sum(
+                pos // bt - self._first_live_page(kind, pos) + 1
+                for pos, _ in self._run.values())
+            for kind in self._kinds)
 
     def _emit_step(self, step: _InFlight, toks, load) -> dict:
         """Count a fetched step and emit its tokens: a row's only if
         the request it was dispatched for still holds the slot (an EOS
         is learned one step late, a cancel at any time: the surplus
         token is dropped)."""
-        self.kv_read_bytes_total += step.kv_bytes
+        for i, n in enumerate(step.kv_bytes):
+            self.kv_read_bytes_by_kind[i] += n
+        self.state_bytes_total += self._state_bytes
         self.decode_steps_total += 1
         self.ahead_steps_total += self._flying is not None
         if load is not None:
@@ -767,11 +845,28 @@ class DecodeServer:
                 for st in self._prefilling.values()}
 
 
-    def _totals(self) -> tuple:
-        return (self.prefill_tokens_total, self.decode_tokens_total,
-                self.kv_read_bytes_total, self.decode_steps_total,
-                self.prefill_keys_total, self.prefill_chunks_total,
-                self.ahead_steps_total, *self.phase_s.values())
+    @property
+    def kv_read_bytes_total(self) -> int:
+        return sum(self.kv_read_bytes_by_kind)
+
+    @property
+    def _page_bytes(self) -> int:
+        """One live page of every kind of K/V, over its readers."""
+        return sum(k.page_bytes for k in self._kinds)
+
+    def _totals(self) -> dict:
+        return {"pf": self.prefill_tokens_total,
+                "dc": self.decode_tokens_total,
+                "steps": self.decode_steps_total,
+                "keys": self.prefill_keys_total,
+                "chunks": self.prefill_chunks_total,
+                "ahead": self.ahead_steps_total,
+                "state": self.state_bytes_total,
+                "xdec": self.cross_decoder_runs_total,
+                "xkeys": self.cross_decoder_keys_total,
+                **{"kv:" + k.name: n for k, n in
+                   zip(self._kinds, self.kv_read_bytes_by_kind)},
+                **{"ph:" + k: v for k, v in self.phase_s.items()}}
 
     def take_account(self) -> dict:
         """This server's part of a tick's account: what it did since
@@ -787,15 +882,26 @@ class DecodeServer:
         and the steps; ``pfk``: keys the prefill chunk programs
         attended, and the programs run; ``moe`` (a config that
         routes): experts touched summed over those steps, most rows on
-        one expert, rows routed a layer summed.  A decode step counts,
-        in all of these, when its tokens are fetched: the step in
-        flight at the call is the next account's."""
+        one expert, rows routed a layer summed.  A model with several
+        kinds of cache adds ``kvk``: ``kvr``'s bytes a kind of K/V;
+        ``st``: bytes of per-row state the decode steps read and
+        wrote, and the steps; ``xdec``: chunk programs that ran the
+        layers past the shared K/V, the chunk programs, and the shared
+        layer's keys the former attended.  A decode step counts, in
+        all of these, when its tokens are fetched: the step in flight
+        at the call is the next account's."""
         now = self._totals()
-        d = [a - b for a, b in zip(now, self._accounted)]
+        d = {k: v - self._accounted[k] for k, v in now.items()}
         self._accounted = now
-        account = {"pf": d[0], "dc": d[1], "kvr": d[2:4], "pfk": d[4:6],
-                   "ahd": [d[6], d[3]],
-                   "ph": dict(zip(self.phase_s, d[7:]))}
+        kv = {k.name: d["kv:" + k.name] for k in self._kinds}
+        account = {"pf": d["pf"], "dc": d["dc"],
+                   "kvr": [sum(kv.values()), d["steps"]],
+                   "pfk": [d["keys"], d["chunks"]],
+                   "ahd": [d["ahead"], d["steps"]],
+                   "ph": {k: d["ph:" + k] for k in self.phase_s}}
+        if self._hybrid:
+            account.update(kvk=kv, st=[d["state"], d["steps"]],
+                           xdec=[d["xdec"], d["chunks"], d["xkeys"]])
         if self._routed:
             account["moe"] = [round(v, 3) for v in self.moe_load]
             self.moe_load = [0.0, 0.0, 0.0]
@@ -804,5 +910,21 @@ class DecodeServer:
     def kv_snapshot(self) -> dict:
         """The pool's block occupancy (``{"blocks", "block_tokens",
         "used", "free", "owners"}``) — the worker's heartbeat telemetry
-        and status surfaces read this."""
-        return self._paged.snapshot()
+        and status surfaces read this.  A model with several kinds of
+        cache adds ``kinds``: the blocks above are its ``full`` layer's;
+        a ``window`` ring and a row of ``state`` belong to a slot, so
+        their rows in use are the slots taken."""
+        snap = self._paged.snapshot()
+        if self._hybrid:
+            from .hybrid import cache_bytes_by_kind
+            size = cache_bytes_by_kind(self._cache)
+            taken = self._B - len(self._free)
+            ring = (self._cache["window"]["k"].shape[1] - 1) // self._B
+            snap["kinds"] = {
+                "full": {"blocks": snap["blocks"], "used": snap["used"],
+                         "bytes": size["full"]},
+                "window": {"rows": self._B, "used": taken,
+                           "ring_pages": ring, "bytes": size["window"]},
+                "state": {"rows": self._B, "used": taken,
+                          "bytes": size["ssm"]}}
+        return snap

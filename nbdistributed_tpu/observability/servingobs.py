@@ -439,7 +439,12 @@ class ServingObservatory:
         routed a layer summed]`` where the model routes to fine-grained
         experts; ``pfk`` = ``[keys, chunks]``: keys its prefill chunk
         programs attended, and the chunk programs it ran (absent from a
-        worker that does not count them).  Returns the tick's record
+        worker that does not count them).  From a model with several
+        kinds of cache also ``kvk`` = ``{kind: bytes}``, ``kvr``'s
+        bytes a kind of K/V; ``st`` = ``[bytes, steps]``: per-row state
+        its decode steps read and wrote; ``xdec`` = ``[programs that
+        ran the layers past the shared K/V, chunk programs, the shared
+        layer's keys the former attended]``.  Returns the tick's record
         when it was slow (kept under ``slow``; the caller writes it to
         the flight recorder, once), else None."""
         worker = tick.get("ph") or {}
@@ -464,6 +469,9 @@ class ServingObservatory:
             "pfk": [int(pf_keys), int(pf_chunks)],
             "ahd": [int(ahead), int(fetched)],
             "moe": None if moe is None else [float(v) for v in moe],
+            "kvk": {k: int(v) for k, v in (tick.get("kvk") or {}).items()},
+            "st": [int(v) for v in tick.get("st") or (0, 0)],
+            "xdec": [int(v) for v in tick.get("xdec") or (0, 0, 0)],
             "turnaround": (None if turnaround is None
                            else max(0.0, float(turnaround))),
             "handler": handler,
@@ -532,6 +540,20 @@ class ServingObservatory:
                          sum(t["ahd"][0] for t in ticks)
                          / max(1, sum(t["ahd"][1] for t in ticks)), 4),
                      "slow": slow}
+        if any(t["st"][1] or t["xdec"][1] for t in ticks):
+            # a model with several kinds of cache: mean bytes of
+            # per-row state a decode step read and wrote, mean bytes a
+            # step fetched a kind of K/V, and the share of the chunk
+            # programs that ran the layers past the shared K/V
+            steps = max(1, sum(t["st"][1] for t in ticks))
+            out["state_bytes"] = round(
+                sum(t["st"][0] for t in ticks) / steps)
+            out["kv_read_bytes_by_kind"] = {
+                k: round(sum(t["kvk"].get(k, 0) for t in ticks) / steps)
+                for k in sorted({k for t in ticks for k in t["kvk"]})}
+            out["cross_decoder_share"] = round(
+                sum(t["xdec"][0] for t in ticks)
+                / max(1, sum(t["xdec"][1] for t in ticks)), 4)
         routed = [t for t in ticks if t["moe"] is not None]
         if routed:
             # a decode step's routing load: means over the steps, the
