@@ -539,8 +539,8 @@ class HybridCache:
         # A step's window spans at most pages(window) + 1 pages: hand
         # the kernel a table of those alone, and the position counted
         # from the first of them (the window's mask is the same under
-        # a shift), so its grid is the window's pages and not the
-        # row's.
+        # a shift), so the table it keeps in scalar memory is the
+        # window's pages and not the row's.
         bt, ring = self._bt, self._ring
         span = -(-self.cfg.sliding_window // bt) + 1
         base = jnp.maximum(pos + 1 - self.cfg.sliding_window, 0) // bt

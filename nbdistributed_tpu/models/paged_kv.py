@@ -29,13 +29,14 @@ the pages the span touches, whole) — and it *attends* through the
 table.  A step with ``cfg.use_flash`` on one device attends inside a
 Pallas kernel of :mod:`..ops.decode` (``paged_decode_attention``, the
 latent pool's twin): layer, block table and positions are
-scalar-prefetch operands, whose index maps pick each live page out of
-the pool, so a step reads the tokens its rows hold and never
-``max_len``, and no dense view of the pool exists
+scalar-prefetch operands, and each row's grid step copies the row's
+live pages out of the pool itself, so a step reads the tokens its rows
+hold and never ``max_len``, and no dense view of the pool exists
 (``DecodeServer.kv_view_bytes`` reads 0).  Otherwise (``use_flash``
-off, or under a mesh) a step's :func:`gather_layer` takes *that
-layer's* blocks to a ``(S, Hkv, T', D)`` view for the dense attention
-paths of :mod:`.generate` (the view lives for one layer, and
+off, under a mesh, or an int8 pool) a step's :func:`gather_layer`
+takes *that layer's* blocks to a ``(S, Hkv, T', D)`` view for the
+dense attention paths of :mod:`.generate` (the view lives for one
+layer, and
 ``kv_view_bytes`` counts what a step gathers that way).  A chunk runs
 the kernel's recurrence in ``jax.numpy`` over key tiles of whole pages
 taken through the table, as many as the row holds
@@ -181,13 +182,16 @@ def write_chunk(pool, layer, new, table, start):
     return jax.tree_util.tree_map(one, pool, new)
 
 
-def reads_in_place(cfg, mesh) -> bool:
+def reads_in_place(cfg, mesh, quantized: bool = False) -> bool:
     """Whether a decode step over a paged pool attends inside the
     Pallas kernel, block tables and all — what ``cfg.use_flash``
     chooses on one device, as everywhere else in
     :func:`~.generate.forward_with_cache` — or gathers a per-layer
-    view for the dense attention paths."""
-    return bool(cfg.use_flash) and mesh is None
+    view for the dense attention paths.  An int8 pool gathers: the
+    kernel copies pages out of the pool itself, and Mosaic refuses the
+    copy of a scale leaf's ``(Hkv, bt, 1)`` page (a slice one lane
+    wide)."""
+    return bool(cfg.use_flash) and mesh is None and not quantized
 
 
 class PagedKV:
@@ -213,7 +217,7 @@ class PagedKV:
         self.per_layer = jnp.arange(n_layers, dtype=jnp.int32)
         self._table, self._active = table, active
         self._mixer = mixer
-        self._in_place = reads_in_place(cfg, mesh)
+        self._in_place = reads_in_place(cfg, mesh, "k_s" in pool)
         # A chunk's real tokens end at its last real one: no real
         # query needs a key past it.
         self._length = None if token_mask is None else jnp.max(

@@ -284,7 +284,7 @@ class DecodeServer:
         # place, else every slot's whole block table once a layer
         # (the fallback's cost per step; a count from shapes).
         self.kv_view_bytes = (
-            0 if reads_in_place(cfg, mesh) else
+            0 if reads_in_place(cfg, mesh, kv_quantized) else
             sum(k.page_bytes for k in self._kinds)
             * self._paged.max_blocks * max_batch)
         self._lens = jnp.zeros((max_batch,), jnp.int32)

@@ -21,9 +21,15 @@ code path everywhere.
 Over a **paged** pool (``models/paged_kv.py``) the same recurrence runs
 where the pool lies, the block table inside the kernel
 (:func:`paged_decode_attention`, :func:`paged_latent_decode_attention`;
-``nbd_flash_decode_paged`` / ``nbd_mla_decode_paged`` in a profile):
-the index maps clamp the page to what the row holds, so the traffic
-and the work go with the tokens held, not with ``max_len``.  A prefill
+``nbd_flash_decode_paged`` / ``nbd_mla_decode_paged`` in a profile).
+The grid is the rows; the pool stays in HBM and a row's grid step
+walks its own live pages, from the window's first to the page of
+``pos``: a loop whose trip count is data, each trip copying a tile of
+pages into one of two VMEM buffers while the tile before it is
+computed, the next row's first tile started before this row ends.  So
+the traffic, the work and the steps all go with the tokens held, not
+with ``max_len`` or the table's width.  How many pages a tile takes
+comes from the operands' shapes (:func:`_pages_per_tile`).  A prefill
 chunk (many queries a row, causal among themselves) runs it in
 ``jax.numpy`` over the row's live pages
 (:func:`paged_prefill_attention`), under the same bound.
@@ -240,67 +246,154 @@ def _decode_call(q, kc, vc, pos, *, block_k: int, scale: float,
     )(*args)
 
 
-def _paged_decode_kernel(layer_ref, table_ref, pos_ref, q_ref, k_ref,
-                         v_ref, o_ref, acc_s, m_s, l_s, *,
-                         block_tokens: int, scale: float, num_kb: int,
-                         window: int | None = None,
-                         ks_ref=None, vs_ref=None):
-    """One grid step = one (row, logical page), all KV heads of the
-    page at once: ``k_ref``/``v_ref`` hold the ``(Hkv, bt, D)`` page
-    the index map picked out of the physical pool (see
-    :func:`_paged_decode_call`), so the scores are one dot batched over
-    ``Hkv``.  Same recurrence, masks and float32 state as
-    :func:`_decode_kernel`; pages are whole blocks of the pool, so
-    there is no padded tail to zero.  A row whose ``pos`` is negative
-    takes no part: no page of it is live, and it writes zeros.
+# VMEM a paged call gives its page tiles, both buffers of every pool
+# operand together: what sets the pages a trip of the kernel's loop
+# takes (:func:`_pages_per_tile`).
+_TILE_VMEM_BYTES = 4 << 20
 
-    ``v_ref`` None: the values are a prefix of the keys (a latent
-    pool's page holds ``[c_kv | k_rope]``: the scores take all of it,
-    the weighted sum its first ``o_ref.shape[-1]`` columns), so the
-    page is fetched once a grid step for both products."""
+
+def _pages_per_tile(pools, width: int) -> int:
+    """Pages a trip of :func:`_paged_decode_kernel`'s loop fetches and
+    computes: as many as ``_TILE_VMEM_BYTES`` holds twice over (a page
+    of every operand, its minor axis padded to whole 128-lane tiles as
+    VMEM keeps it), and no more than the table has."""
+    page = sum(int(np.prod(c.shape[2:-1])) * -(-c.shape[-1] // 128) * 128
+               * c.dtype.itemsize for c in pools)
+    return max(1, min(width, _TILE_VMEM_BYTES // (2 * page)))
+
+
+def _paged_decode_kernel(layer_ref, table_ref, pos_ref, q_ref, *refs,
+                         pages: int, scale: float, window: int | None,
+                         latent: bool, quantized: bool):
+    """One grid step = one row, which walks its own live pages: a loop
+    from the row's first live page (the window's) to the page of
+    ``pos``, ``pages`` of them a trip.  The pools stay in HBM; a trip's
+    pages are copied (``make_async_copy``) side by side into one of two
+    VMEM tiles ``(Hkv, pages * bt, W)`` an operand, the next trip's
+    started before this one's are waited for, and the next row's first
+    tile before this row's last is computed, so only a row after an
+    idle one (or the first) waits for a copy it has just started.  So a
+    call costs the pages its rows hold, whatever the table's width.
+
+    The scores of a tile are one dot batched over ``Hkv``; same
+    recurrence, masks and float32 state as :func:`_decode_kernel`.  A
+    tile's slots past the row's last page are not fetched, and a last
+    page's tail no token has written: what lies there (an earlier
+    row's page, whatever the pool held) is kept out of both products by
+    position, values zeroed, since a probability of zero does not clean
+    a NaN.  A row whose ``pos`` is negative copies nothing and writes
+    zeros.
+
+    ``latent``: the values are a prefix of the keys (a latent pool's
+    page holds ``[c_kv | k_rope]``: the scores take all of it, the
+    weighted sum its first ``o_ref.shape[-1]`` columns), so a page is
+    fetched once for both products."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    del layer_ref, table_ref            # consumed by the index maps
-    b = pl.program_id(0)
-    kb = pl.program_id(1)
-    valid = pos_ref[b] + 1                              # keys [0, valid)
-    lo = jnp.maximum(valid - window, 0) if window is not None else 0
+    n = 1 if latent else 4 if quantized else 2
+    pools, o_ref, bufs = refs[:n], refs[n], refs[n + 1:2 * n + 1]
+    sem, slot_s, acc_s, m_s, l_s = refs[2 * n + 1:]
+    bt = pools[0].shape[3]
+    keys = pages * bt
+    width = table_ref.shape[1]
+    b, rows = pl.program_id(0), pl.num_programs(0)
+    layer = layer_ref[0]
 
-    @pl.when(kb == 0)
-    def _init():
+    def span(r):
+        """(takes part, first live page, last) of row ``r``."""
+        p = pos_ref[r]
+        last = jnp.minimum(jnp.maximum(p, 0) // bt, width - 1)
+        first = (jnp.maximum(p + 1 - window, 0) // bt
+                 if window is not None else 0)
+        return p >= 0, first, last
+
+    def tile(r, j0, last, slot, wait=False):
+        """Start (or wait for) the copies of row ``r``'s pages
+        ``[j0, j0 + pages)`` that are live into buffer ``slot``."""
+        for i in range(pages):
+            @pl.when(j0 + i <= last)
+            def _copy():
+                # a wait needs the copy's shape alone
+                phys = 0 if wait else table_ref[r, j0 + i]
+                for c, (pool, buf) in enumerate(zip(pools, bufs)):
+                    cp = pltpu.make_async_copy(
+                        pool.at[layer, phys],
+                        buf.at[slot, :, pl.ds(i * bt, bt), :],
+                        sem.at[slot, c])
+                    cp.wait() if wait else cp.start()
+
+    live, first, last = span(b)
+    nb = jnp.minimum(b + 1, rows - 1)
+    next_live, next_first, next_last = span(nb)
+    next_live &= b + 1 < rows
+    prev_live = (b > 0) & (pos_ref[jnp.maximum(b - 1, 0)] >= 0)
+
+    @pl.when(b == 0)
+    def _first_row():
+        slot_s[0] = 0
+
+    @pl.when(live & ~prev_live)
+    def _cold():                        # nobody started this row's first
+        tile(b, first, last, slot_s[0])
+
+    @pl.when(~live)
+    def _idle():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live)
+    def _row():
+        valid = pos_ref[b] + 1                          # keys [0, valid)
+        lo = jnp.maximum(valid - window, 0) if window is not None else 0
+        trips = (last - first) // pages + 1
+        slot0 = slot_s[0]
+        q = q_ref[0].astype(jnp.float32) * scale        # (Hkv, group, D)
         acc_s[...] = jnp.zeros_like(acc_s)
         m_s[...] = jnp.full_like(m_s, _NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
 
-    @pl.when((kb * block_tokens < valid)
-             & ((kb + 1) * block_tokens > lo))
-    def _page():
-        q = q_ref[0].astype(jnp.float32) * scale        # (Hkv, group, D)
-        k_pg = k_ref[...].astype(jnp.float32)           # (Hkv, bt, D)
-        v_pg = (k_pg[..., :o_ref.shape[-1]] if v_ref is None
-                else v_ref[...].astype(jnp.float32))
-        s = jax.lax.dot_general(
-            q, k_pg, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)         # (Hkv, group, bt)
-        if ks_ref is not None:
-            s = s * ks_ref[...][:, :, 0][:, None, :]
-        ki = (kb * block_tokens
-              + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2))
-        s = jnp.where((ki < valid) & (ki >= lo), s, _NEG_INF)
-        m_prev, l_prev = m_s[...], l_s[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_s[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        if vs_ref is not None:
-            p = p * vs_ref[...][:, :, 0][:, None, :]
-        acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
-            p, v_pg, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)         # (Hkv, group, D)
-        m_s[...] = m_new
+        def trip(t, _):
+            slot = (slot0 + t) % 2
+            j0 = first + t * pages
+            more = t + 1 < trips
 
-    @pl.when(kb == num_kb - 1)
-    def _finalize():
+            @pl.when(more | next_live)
+            def _prefetch():            # the next tile, or the next row's
+                tile(jnp.where(more, b, nb),
+                     jnp.where(more, j0 + pages, next_first),
+                     jnp.where(more, last, next_last), 1 - slot)
+
+            tile(b, j0, last, slot, wait=True)
+            k_tl = bufs[0][slot].astype(jnp.float32)    # (Hkv, keys, D)
+            v_tl = (k_tl[..., :o_ref.shape[-1]] if latent
+                    else bufs[1][slot].astype(jnp.float32))
+            s = jax.lax.dot_general(
+                q, k_tl, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)     # (Hkv, group, keys)
+            if quantized:
+                s = s * bufs[2][slot][:, :, 0][:, None, :]
+            ki = j0 * bt + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            keep = (ki < valid) & (ki >= lo)
+            s = jnp.where(keep, s, _NEG_INF)
+            kv = j0 * bt + jax.lax.broadcasted_iota(
+                jnp.int32, (1, keys, 1), 1)         # keys down the rows
+            v_tl = jnp.where((kv < valid) & (kv >= lo), v_tl, 0.0)
+            m_prev, l_prev = m_s[...], l_s[...]
+            m_new = jnp.maximum(m_prev,
+                                jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_s[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+            if quantized:
+                vs = bufs[3][slot][:, :, 0][:, None, :]
+                p = p * jnp.where(keep, vs, 0.0)
+            acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
+                p, v_tl, (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)     # (Hkv, group, Dv)
+            m_s[...] = m_new
+
+        jax.lax.fori_loop(0, trips, trip, None)
+        slot_s[0] = (slot0 + trips) % 2
         o_ref[0] = (acc_s[...]
                     / jnp.maximum(l_s[...], 1e-30)).astype(o_ref.dtype)
 
@@ -314,73 +407,44 @@ def _paged_decode_call(q, k_pool, v_pool, layer, table, pos, *,
                        v_width: int | None = None):
     """``v_pool`` None makes the pool a latent one, whose values are
     the first ``v_width`` columns of its keys (one operand, one copy of
-    a page a grid step; the call carries its own name in a profile).
+    a page for both products; the call carries its own name in a
+    profile).
 
-    The pool stays where it lies: ``layer``, ``table`` and ``pos``
-    are scalar-prefetch operands, and the K/V index map turns grid step
-    ``(b, kb)`` into physical block ``table[b, j]`` of layer ``layer``,
-    with ``j`` the logical page ``kb`` clamped to the row's live range
-    (the window's first page to the page of ``pos``).  Past that range
-    the block index repeats, so Pallas issues no new copy; a row that
-    takes no part (``pos < 0``) points at the trash block, and a run of
-    such rows costs one copy of it."""
+    The pool stays where it lies (``memory_space=ANY``): ``layer``,
+    ``table`` and ``pos`` are scalar-prefetch operands, the grid is the
+    rows, and the kernel copies physical block ``table[b, j]`` of layer
+    ``layer`` itself, for the logical pages ``j`` of the row's live
+    range alone (:func:`_paged_decode_kernel`).  How many a trip comes
+    from the operands' shapes (:func:`_pages_per_tile`)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     S, Hkv, group, D = q.shape
     bt = k_pool.shape[3]
-    trash = k_pool.shape[1] - 1
-    num_kb = table.shape[1]
-    quantized = k_s is not None
+    latent, quantized = v_pool is None, k_s is not None
+    Dv = v_width if latent else D
+    pools = [c for c in (k_pool, v_pool, k_s, v_s) if c is not None]
+    pages = _pages_per_tile(pools, table.shape[1])
 
-    def page(b, kb, layer, table, pos):
-        p = pos[b]
-        last = jnp.maximum(p, 0) // bt
-        first = (jnp.maximum(p + 1 - window, 0) // bt
-                 if window is not None else 0)
-        j = jnp.minimum(jnp.maximum(kb, first), last)
-        return (layer[0], jnp.where(p < 0, trash, table[b, j]), 0, 0, 0)
-
-    def row(b, kb, layer, table, pos):
+    def row(b, layer, table, pos):
         return (b, 0, 0, 0)
 
-    latent = v_pool is None
-    Dv = v_width if latent else D
-
-    def _kernel(layer_ref, table_ref, pos_ref, *refs):
-        *refs, o_ref, a, m, l = refs
-        ks_ref = vs_ref = v_ref = None
-        if latent:
-            q_ref, k_ref = refs
-        elif quantized:
-            q_ref, k_ref, v_ref, ks_ref, vs_ref = refs
-        else:
-            q_ref, k_ref, v_ref = refs
-        _paged_decode_kernel(layer_ref, table_ref, pos_ref, q_ref,
-                             k_ref, v_ref, o_ref, a, m, l,
-                             block_tokens=bt, scale=scale,
-                             num_kb=num_kb, window=window,
-                             ks_ref=ks_ref, vs_ref=vs_ref)
-
-    in_specs = [
-        pl.BlockSpec((1, Hkv, group, D), row),                # q
-        pl.BlockSpec((None, None, Hkv, bt, D), page),         # k
-    ]
-    args = [layer.reshape(1), table, pos, q, k_pool]
-    if not latent:
-        in_specs.append(pl.BlockSpec((None, None, Hkv, bt, D), page))
-        args.append(v_pool)
-    if quantized:
-        in_specs += [pl.BlockSpec((None, None, Hkv, bt, 1), page)] * 2
-        args += [k_s, v_s]
+    kernel = functools.partial(
+        _paged_decode_kernel, pages=pages, scale=scale, window=window,
+        latent=latent, quantized=quantized)
     return pl.pallas_call(
-        _kernel,
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(S, num_kb),
-            in_specs=in_specs,
+            grid=(S,),
+            in_specs=[pl.BlockSpec((1, Hkv, group, D), row)]      # q
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
             out_specs=pl.BlockSpec((1, Hkv, group, Dv), row),
             scratch_shapes=[
+                pltpu.VMEM((2, Hkv, pages * bt, c.shape[-1]), c.dtype)
+                for c in pools] + [
+                pltpu.SemaphoreType.DMA((2, len(pools))),
+                pltpu.SMEM((1,), jnp.int32),    # the next tile's buffer
                 pltpu.VMEM((Hkv, group, Dv), jnp.float32),  # acc
                 pltpu.VMEM((Hkv, group, 1), jnp.float32),   # running max
                 pltpu.VMEM((Hkv, group, 1), jnp.float32),   # normalizer
@@ -390,7 +454,7 @@ def _paged_decode_call(q, k_pool, v_pool, layer, table, pos, *,
         interpret=interpret,
         name="nbd_mla_decode_paged" if latent
         else "nbd_flash_decode_paged",
-    )(*args)
+    )(layer.reshape(1), table, pos, q, *pools)
 
 
 # (T, head_dim, gqa_group) -> block_k, measured on a live chip by
@@ -480,7 +544,10 @@ def paged_decode_attention(q, k_pool, v_pool, layer, table, pos, *,
     already be in the pool;
     active: (S,) bool — slots that take part; the others fetch and
     compute nothing and come back as zeros;
-    ``k_s``/``v_s``: (L, NB+1, Hkv, bt, 1) fp32 scales of an int8 pool.
+    ``k_s``/``v_s``: (L, NB+1, Hkv, bt, 1) fp32 scales of an int8 pool
+    (they ride the kernel's copies in interpret mode; on the chip Mosaic
+    refuses the copy of a page of them, one lane wide, so a served int8
+    pool gathers: :func:`~..models.paged_kv.reads_in_place`).
     Returns (S, H, D).  Only the pages of a slot's live range are
     copied out of the pool: the step's traffic goes with the tokens
     held, not with ``max_len``."""

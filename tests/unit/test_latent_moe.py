@@ -160,6 +160,43 @@ def test_latent_kernel_is_an_einsum_over_the_dense_latent_row(pos):
     assert got.shape == (S, H, R_)
 
 
+# a tile of n pages (the kernel walks a row's live pages itself, n a
+# trip): a position on a tile's last key and on the next tile's first,
+# a long row before a short one, an idle row between live ones, and NaN
+# wherever no live row's tokens lie
+@pytest.mark.parametrize("pos", [[15, 16, 63], [63, 2, 40], [23, 24, 0]])
+@pytest.mark.parametrize("n", [1, 2, 3])
+def test_latent_kernel_walks_the_live_pages_in_tiles(monkeypatch, n, pos):
+    from nbdistributed_tpu.ops import decode
+    monkeypatch.setattr(decode, "_pages_per_tile",
+                        lambda pools, width: min(width, n))
+    decode._paged_decode_call.clear_cache()         # the count is traced
+    L, S, BT, MB, H, W, R_ = 2, 4, 8, 8, 4, 128, 96
+    rng = np.random.default_rng(n)
+    pos = np.asarray(pos + [MB * BT - 1])           # the last row is idle
+    active = np.asarray([True, True, True, False])
+    ids = rng.permutation(S * MB).reshape(S, MB)
+    owned = active[:, None] & (np.arange(MB)[None] <= pos[:, None] // BT)
+    pool = rng.normal(size=(L, S * MB + 1, 1, BT, W)).astype(np.float32)
+    pool[:, S * MB] = np.nan
+    pool[:, ids[~owned]] = np.nan
+    q = rng.normal(size=(S, H, W)).astype(np.float32)
+    got = paged_latent_decode_attention(
+        jnp.asarray(q), jnp.asarray(pool), 1, jnp.asarray(ids, jnp.int32),
+        jnp.asarray(pos, jnp.int32), v_width=R_, scale=0.3,
+        active=jnp.asarray(active))
+    decode._paged_decode_call.clear_cache()
+    keep = (np.arange(MB * BT)[None] <= pos[:, None]) & active[:, None]
+    rows = np.where(keep[..., None],
+                    pool[1][ids][:, :, 0].reshape(S, MB * BT, W), 0.0)
+    s = np.where(keep[:, None], np.einsum("shw,stw->sht", q, rows) * 0.3,
+                 -1e30)
+    p = np.exp(s - s.max(-1, keepdims=True)) * keep[:, None]
+    p = p / np.maximum(p.sum(-1, keepdims=True), 1e-30)
+    want = np.einsum("sht,str->shr", p, rows[..., :R_])
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
 def test_latent_kernel_refuses_a_pool_that_is_not_one_head_of_its_width():
     q = jnp.zeros((2, 4, 128))
     with pytest.raises(ValueError, match="latent pool"):

@@ -160,6 +160,214 @@ def test_paged_call_validates_its_operands():
 
 
 # ----------------------------------------------------------------------
+# the kernel walks a row's live pages itself (ISSUE 32): what a grid
+# over every page of the table never told apart
+
+@pytest.fixture
+def tile_pages(monkeypatch):
+    """Make a trip of the kernel's loop take ``n`` pages (the table's
+    width at most), whatever the operands' shapes would give."""
+    from nbdistributed_tpu.ops import decode
+
+    def set_to(n):
+        monkeypatch.setattr(decode, "_pages_per_tile",
+                            lambda pools, width: min(width, n))
+        decode._paged_decode_call.clear_cache()    # the count is traced
+    yield set_to
+    decode._paged_decode_call.clear_cache()
+
+
+def walk_case(pos, *, mb, bt=8, hkv=2, group=2, d=16, w=None, v_width=None,
+              window=None, seed=0, poisoned=True, layers=2):
+    """One call against the einsum oracle at any geometry: rows of
+    ``mb`` pages of ``bt`` tokens scattered over the pool, ``pos`` < 0
+    a row that takes no part.  ``w`` makes the pool a latent one (one
+    head of width ``w``, values its first ``v_width`` columns).  NaN in
+    the trash block, in an idle row's pages and in every page a live
+    row's ``pos`` (and window) leaves out: nothing may look there."""
+    from nbdistributed_tpu.ops.decode import paged_latent_decode_attention
+    latent = w is not None
+    if latent:
+        hkv, d = 1, w
+    rows, t_max = len(pos), mb * bt
+    nb = rows * mb
+    rng = np.random.default_rng(seed)
+    pos = np.asarray(pos)
+    ids = rng.permutation(nb).reshape(rows, mb)
+    last = np.maximum(pos, 0) // bt
+    first = (np.maximum(pos + 1 - window, 0) // bt if window is not None
+             else np.zeros_like(pos))
+    j = np.arange(mb)[None]
+    owned = (pos[:, None] >= 0) & (j >= first[:, None]) & (j <= last[:, None])
+    leaves = {}
+    for name in ("k",) if latent else ("k", "v"):
+        c = rng.normal(size=(layers, nb + 1, hkv, bt, d)).astype(np.float32)
+        if poisoned:
+            c[:, nb] = np.nan
+            c[:, ids[~owned]] = np.nan
+        leaves[name] = jnp.asarray(c)
+    table = jnp.asarray(ids, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(rows, hkv * group, d)), jnp.float32)
+    scale, layer = 1.0 / np.sqrt(d), layers - 1
+    posj = jnp.asarray(pos, jnp.int32)
+    if latent:
+        got = paged_latent_decode_attention(
+            q, leaves["k"], layer, table, jnp.maximum(posj, 0),
+            v_width=v_width, scale=scale, active=posj >= 0)
+    else:
+        got = paged_decode_attention(
+            q, leaves["k"], leaves["v"], layer, table, posj, scale=scale,
+            window=window)      # a negative position is an idle row
+
+    t = np.arange(t_max)[None]
+    keep = (t <= pos[:, None]) & (pos[:, None] >= 0)
+    if window is not None:
+        keep &= t > pos[:, None] - window
+
+    def view(c):            # (rows, hkv, T, W), zeros where nothing attends
+        g = np.asarray(c)[layer][ids].transpose(0, 2, 1, 3, 4)
+        return np.where(keep[:, None, :, None],
+                        g.reshape(rows, hkv, t_max, -1), 0.0)
+    k = view(leaves["k"])
+    v = k[..., :v_width] if latent else view(leaves["v"])
+    qg = np.asarray(q).reshape(rows, hkv, group, d) * scale
+    sc = np.where(keep[:, None, None], np.einsum("bkgd,bktd->bkgt", qg, k),
+                  -np.inf)
+    with np.errstate(invalid="ignore"):
+        pr = np.exp(sc - sc.max(-1, keepdims=True))
+        pr = np.nan_to_num(pr / pr.sum(-1, keepdims=True))  # an idle row
+    want = np.einsum("bkgt,bktd->bkgd", pr, v).reshape(rows, hkv * group, -1)
+    np.testing.assert_allclose(np.asarray(got), want, atol=3e-6, rtol=2e-5)
+    return got
+
+
+# a tile of n pages of 8 tokens, a table of 8 pages: the last key of
+# the first tile, the first of the second, one page, the whole table
+@pytest.mark.parametrize("edge", ["tile-1", "tile", "page", "table"])
+@pytest.mark.parametrize("n", [2, 3])       # 3 does not divide 8
+def test_positions_at_a_tiles_edges(tile_pages, n, edge):
+    tile_pages(n)
+    p = {"tile-1": n * 8 - 1, "tile": n * 8, "page": 7, "table": 63}[edge]
+    walk_case([p, 63 - p, (p + 8 * n) % 64], mb=8)
+
+
+# the window layers' cut table: nine pages, which no tile above one
+# divides, eight or nine of them live
+@pytest.mark.parametrize("n", [2, 4, 6, 9])
+def test_a_table_of_nine_pages_under_tiles_that_do_not_divide_it(
+        tile_pages, n):
+    tile_pages(n)
+    walk_case([9 * 8 - 1, 8 * 8 - 1, 8 * 8 + 3, 5], mb=9, window=8 * 8)
+
+
+# a long row before a short one (what the tiles held must not be read
+# again), an idle row between live ones, a first and a last row idle
+@pytest.mark.parametrize("pos", [
+    [63, 2, -1, 31, 0], [-1, 63, -1, -1, 9], [5, -1, 63, 17, -1],
+    [-1, -1, -1, -1, -1]], ids=["long-short", "idle-first", "idle-last",
+                                 "all-idle"])
+@pytest.mark.parametrize("n", [1, 2, 4])
+def test_rows_of_very_different_lengths_in_one_call(tile_pages, n, pos):
+    tile_pages(n)
+    walk_case(pos, mb=8)
+
+
+# a window that opens inside the table: the walk starts at its first
+# page, several tiles on, and that page's keys below the window are
+# held (no NaN there) and masked
+@pytest.mark.parametrize("window", [8 + 3, 3 * 8, 5 * 8 + 1])
+@pytest.mark.parametrize("n", [2, 3])
+def test_a_window_whose_first_page_lies_tiles_into_the_row(tile_pages, n,
+                                                           window):
+    tile_pages(n)
+    walk_case([63, 40, 2 * 8 + 1], mb=8, window=window)
+
+
+# the served geometries, scaled down, each under the tile its own
+# shapes give: phi and Mistral (group 4, several KV heads, 64-page
+# tables, phi's window tables of nine), JoyAI (one head of 640 with
+# values 512 wide: 80 and 64 here; a 128-page table)
+@pytest.mark.parametrize("geometry", [
+    dict(hkv=5, group=4, d=32, mb=64, bt=4),
+    dict(hkv=5, group=4, d=32, mb=9, bt=4, window=32),
+    dict(hkv=4, group=4, d=32, mb=64, bt=4, window=256),
+    dict(w=80, v_width=64, group=8, mb=128, bt=4),
+], ids=["phi-full", "phi-window", "mistral", "joyai"])
+def test_the_served_geometries_scaled_down(geometry, monkeypatch):
+    from nbdistributed_tpu.ops import decode
+    seen = []
+    real = decode._pages_per_tile
+    monkeypatch.setattr(decode, "_pages_per_tile",
+                        lambda *a: seen.append(real(*a)) or seen[-1])
+    t_max = geometry["mb"] * geometry["bt"]
+    walk_case([t_max - 1, 5, -1, t_max // 3], **geometry)
+    assert seen == [geometry["mb"]]         # tiny pages: one tile a row
+
+
+@pytest.mark.parametrize("pool, width, pages", [
+    (((10, 64, 128),) * 2, 64, 6),          # phi's shared layer
+    (((10, 64, 128),) * 2, 9, 6),           # its window layers' table
+    (((8, 64, 128),) * 2, 64, 8),           # Mistral
+    (((1, 64, 640),), 128, 25),             # JoyAI's latent pool
+    (((8, 64, 128),) * 2, 3, 3),            # the table's width caps it
+    (((64, 64, 512),) * 2, 64, 1),          # a page over the budget
+], ids=["phi", "phi-window", "mistral", "joyai", "narrow", "huge"])
+def test_pages_a_tile_come_from_the_operands_shapes(pool, width, pages):
+    from nbdistributed_tpu.ops.decode import _pages_per_tile
+    leaves = [jax.ShapeDtypeStruct((2, 17) + sh, jnp.bfloat16)
+              for sh in pool]
+    assert _pages_per_tile(leaves, width) == pages
+
+
+# The TPU interpreter runs a copy when it is waited for, starts every
+# buffer as NaN and watches for a buffer read while a copy into it is
+# in flight: a wait left out, a tile read before its wait, a slot not
+# fetched and not masked all show here and not in the plain interpreter
+@pytest.mark.parametrize("kind", ["gqa", "window", "latent"])
+@pytest.mark.parametrize("n", [1, 2, 3])
+def test_the_double_buffer_under_the_race_detecting_interpreter(
+        tile_pages, monkeypatch, n, kind):
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+    from nbdistributed_tpu.ops import decode
+    tile_pages(n)
+    monkeypatch.setattr(decode, "_use_interpret", lambda: pltpu.InterpretParams(
+        dma_execution_mode="on_wait", detect_races=True,
+        uninitialized_memory="nan"))
+    kw = {"gqa": {}, "window": dict(window=2 * 8 + 3),
+          "latent": dict(w=32, v_width=24, group=4)}[kind]
+    walk_case([63, 2, -1, 31, 17, -1, 0], mb=8, **kw)
+    assert not interpret_pallas_call.races.races_found
+
+
+def test_the_steps_kernel_has_no_grid_axis_over_the_tables_pages(model):
+    """The grid is the rows: a call's cost cannot go with the table's
+    width, which only the scalar-prefetched table itself carries."""
+    cfg, params = model
+    srv = DecodeServer(params, dataclasses.replace(cfg, use_flash=True),
+                       max_batch=3, max_len=5 * 8, pad_to=4,
+                       kv_block_tokens=8)
+    assert srv._paged.max_blocks == 5
+    jaxpr = jax.make_jaxpr(srv._step_fn)(
+        params, srv._cache, srv._paged.device_table(), srv._lens,
+        srv._last, srv._active, srv._key)
+
+    def calls(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                out.append(eqn.params)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                calls(sub, out)
+        return out
+
+    found = calls(jaxpr.jaxpr, [])
+    assert found
+    for params_ in found:
+        assert tuple(params_["grid_mapping"].grid) == (3,)
+        assert params_["name"] == "nbd_flash_decode_paged"
+
+
+# ----------------------------------------------------------------------
 # a chunk of new tokens: many queries a row, causal among themselves
 
 CHUNK = 2 * BT
@@ -352,7 +560,9 @@ def test_server_on_the_kernel_emits_the_einsum_servers_tokens(
         model, kv_quantized):
     kw = dict(max_len=32, kv_blocks=5, kv_quantized=kv_quantized)
     kernel, einsum = serve(model, True, **kw), serve(model, False, **kw)
-    assert kernel.kv_view_bytes == 0 < einsum.kv_view_bytes
+    # an int8 pool's step gathers (Mosaic refuses a scale page's copy)
+    assert (kernel.kv_view_bytes == 0) == (not kv_quantized)
+    assert einsum.kv_view_bytes > 0
     got, want = drive(kernel), drive(einsum)
     assert got == want
     assert [len(want[k]) for k in "acd"] == [7, 6, 9]
@@ -432,23 +642,17 @@ def test_chunked_paged_server_serves_the_dense_servers_tokens(
     assert got == want
 
 
-@pytest.mark.parametrize("served", [
-    "chunk",
-    pytest.param("steps", marks=pytest.mark.xfail(strict=True, reason=(
-        "the decode kernels multiply a zero probability by what the "
-        "tail of the row's last page holds (as the parent's; a decode "
-        "step is not this PR's to change): ROADMAP S2"))),
-])
+@pytest.mark.parametrize("served", ["chunk", "steps"])
 @pytest.mark.parametrize("family", ["gqa", "latent"])
 def test_what_no_real_token_wrote_reaches_no_served_token(model, family,
                                                           served):
     """The whole pool NaN before the first admission, trash block and
     all: every page a request is given holds NaN wherever no token of
-    it has written yet.  The chunk keeps such keys out of both products
-    (a probability of zero does not clean a NaN), so each request's
-    first token, which its last chunk's logits give, is the clean
-    pool's.  The tokens of the decode steps after it are not yet: a
-    step whose token opens a fresh page reads the page's tail."""
+    it has written yet.  The chunk and the decode kernel keep such keys
+    out of both products by position (a probability of zero does not
+    clean a NaN), so each request's first token, which its last chunk's
+    logits give, and the tokens of the decode steps after it, one of
+    which opens a fresh page, are the clean pool's."""
     cfg, params = model if family == "gqa" else latent_model()
     cfg = dataclasses.replace(cfg, use_flash=True)
     kw = dict(kv_block_tokens=8, interleave_prefill=True)
