@@ -274,7 +274,9 @@ class Smoke:
     def hybrid(self):
         """The state-space layer's one-token update and a windowed
         differential decode step over a ring of pages, at the published
-        widths of Phi-4-mini-flash and ``highest`` precision, against
+        widths of Phi-4-mini-flash; the Mamba-2 mixer's one-token update
+        and its block form against that update token by token, at
+        Nemotron-3-Nano's; all at ``highest`` precision, against
         ``jax.numpy``."""
         k = self.cell(_HYBRID_CELL, ranks="[0]")[0]
         self.facts["hybrid"] = k["checks"]
@@ -649,7 +651,9 @@ table = jnp.broadcast_to(jnp.arange(max_len // bt, dtype=jnp.int32), (rows, max_
 hx = jax.random.normal(_ks[1], (rows, 1, hcfg.d_model))
 def _parts(pool, hx):
     kv = HybridCache({**cache, "window": pool}, hcfg, table, slot=None,
-                     active=jnp.ones((rows,), bool), length=None, start=None)
+                     active=jnp.ones((rows,), bool), length=None, start=None,
+                     mixers={"full": DiffAttnMixer(hcfg, None),
+                             "window": DiffAttnMixer(hcfg, hcfg.sliding_window)})
     return kv, kv.window.project_q(hx, att), kv.window.project_kv(hx, att)
 def _step(pool, hx):
     kv, q, new = _parts(pool, hx)
@@ -675,6 +679,61 @@ def _step_ref(pool, hx):
     return kv.window.out(o.reshape(rows, 1, -1), att, 7)
 _check("windowed_differential_step", 1, _step, _step_ref, pool, hx)
 del cache, pool, wk, state, tail
+
+# The Mamba-2 mixer at Nemotron-3-Nano's published widths: its one-token
+# update against the equations written out, and the block form over a
+# padded chunk, from a state that is not zero, against that update run
+# token by token.
+from nbdistributed_tpu.models.nemotron_h import (Mamba2Mixer,
+    init_layer as _init_m2, nemotron3_nano_config)
+ncfg = nemotron3_nano_config(dtype=jnp.float32)
+m2 = _init_m2(_ks[0], ncfg, "mamba2")
+m2 = {**m2, "conv_b": 0.02 * jax.random.normal(_ks[5], m2["conv_b"].shape),
+      "dt_bias": m2["dt_bias"] + jax.random.normal(_ks[6], m2["dt_bias"].shape)}
+mix2 = Mamba2Mixer(ncfg)
+H2, P2, G2, N2 = ncfg.ssm_heads, ncfg.ssm_head_dim, ncfg.ssm_groups, ncfg.d_state
+C2, W2, K2 = ncfg.d_inner, ncfg.conv_width, ncfg.d_conv
+h1 = jax.random.normal(_ks[1], (rows, 1, ncfg.d_model))
+st2 = jax.random.normal(_ks[2], (rows, H2, P2, N2))
+tl2 = jax.random.normal(_ks[3], (rows, K2 - 1, W2))
+def _m2_step(h, st, tl):
+    out, st, tl = mix2.mix(h, m2, st, tl, live[:, None])
+    return out * live[:, None, None], st, tl
+def _m2_step_ref(h, st, tl):
+    zxd = h[:, 0] @ m2["w_in"]
+    z, xbc, dt = zxd[:, :C2], zxd[:, C2:C2 + W2], zxd[:, C2 + W2:]
+    win = jnp.concatenate([tl, xbc[:, None]], 1)            # (rows, K, W)
+    xbc = jax.nn.silu(jnp.einsum("bkc,kc->bc", win, m2["conv_w"]) + m2["conv_b"])
+    x = xbc[:, :C2].reshape(rows, H2, P2)
+    of_head = lambda m: jnp.repeat(m.reshape(rows, G2, N2), H2 // G2, 1)
+    Bm, Cm = of_head(xbc[:, C2:C2 + G2 * N2]), of_head(xbc[:, C2 + G2 * N2:])
+    d = jax.nn.softplus(dt + m2["dt_bias"])
+    s = (jnp.exp(d * -jnp.exp(m2["A_log"]))[..., None, None] * st
+         + jnp.einsum("bh,bhp,bhn->bhpn", d, x, Bm))
+    y = jnp.einsum("bhpn,bhn->bhp", s, Cm) + m2["D"][:, None] * x
+    y = y.reshape(rows, C2) * jax.nn.silu(z)
+    yg = y.reshape(rows, G2, C2 // G2)
+    yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True) + ncfg.norm_eps)
+    out = (yg.reshape(rows, C2) * m2["gate_norm"]) @ m2["w_out"]
+    return (out[:, None] * live[:, None, None],
+            jnp.where(live[:, None, None, None], s, st),
+            jnp.where(live[:, None, None], win[:, 1:], tl))
+_check("mamba2_state_update", 0, _m2_step, _m2_step_ref, h1, st2, tl2)
+S2 = 384                                    # three blocks of 128
+hs = jax.random.normal(_ks[4], (2, S2, ncfg.d_model))
+real = jnp.arange(S2)[None, :] < jnp.asarray([S2, 300])[:, None]
+def _m2_blocks(h, st, tl):
+    out, st, tl = mix2.mix(h, m2, st, tl, real)
+    return out * real[..., None], st, tl
+def _m2_tokens(h, st, tl):
+    def token(carry, inp):
+        ht, v = inp
+        out, st, tl = mix2.mix(ht[:, None], m2, *carry, v[:, None])
+        return (st, tl), out[:, 0]
+    (st, tl), out = jax.lax.scan(token, (st, tl), (h.swapaxes(0, 1), real.T))
+    return out.swapaxes(0, 1) * real[..., None], st, tl
+_check("mamba2_block_form", 0, _m2_blocks, _m2_tokens, hs, 0.1 * st2[:2], tl2[:2])
+del st2, tl2, hs
 _emit(checks=checks)
 '''
 

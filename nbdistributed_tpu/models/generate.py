@@ -465,28 +465,36 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
     rows routed a layer]`` (zeros for a config that routes nothing
     that way).
 
-    A :class:`~.hybrid.HybridConfig` (state-space layers beside window
-    and full attention) keeps three kinds of cache and runs
-    :func:`~.hybrid.hybrid_forward_with_cache`, over the paged caches
-    only: ``slot`` is the row a prefill chunk belongs to (its state is
-    counted in rows), and ``final`` (static) whether the chunk ends its
-    prompt, since one that does not runs nothing past the shared K/V's
-    projection and returns None for logits.
+    A :class:`~.hybrid.StatefulConfig` (state-space layers beside
+    attention: :class:`~.hybrid.HybridConfig`,
+    :class:`~.nemotron_h.NemotronHConfig`) keeps several kinds of cache
+    and runs its family's own forward
+    (:func:`~.hybrid.hybrid_forward_with_cache`,
+    :func:`~.nemotron_h.nemotron_h_forward_with_cache`), over the paged
+    caches only: ``slot`` is the row a prefill chunk belongs to (its
+    state is counted in rows), and ``final`` (static) whether the chunk
+    ends its prompt, since one that does not runs nothing past the last
+    layer that writes a cache and returns None for logits.
     """
-    from .hybrid import HybridConfig, hybrid_forward_with_cache
+    from .hybrid import StatefulConfig, hybrid_forward_with_cache
     from .mla import LatentMoEConfig, MLAMixer
-    if isinstance(cfg, HybridConfig):
+    from .nemotron_h import NemotronHConfig, nemotron_h_forward_with_cache
+    if isinstance(cfg, StatefulConfig):
         if block_table is None or mesh is not None:
             raise ValueError("state-space layers are served over the "
                              "paged caches on one device: pass "
                              "block_table (and no mesh)")
-        out = hybrid_forward_with_cache(
-            params, tokens, cache, cache_len, cfg,
-            block_table=block_table, row_mask=row_mask,
-            token_mask=token_mask, last_index=last_index, slot=slot,
-            final=final)
-        return (*out, jnp.zeros((3,), jnp.float32)) if with_moe_load \
-            else out
+        kw = dict(block_table=block_table, row_mask=row_mask,
+                  token_mask=token_mask, last_index=last_index, slot=slot,
+                  final=final)
+        if isinstance(cfg, NemotronHConfig):
+            *out, load = nemotron_h_forward_with_cache(
+                params, tokens, cache, cache_len, cfg, **kw)
+        else:
+            out = hybrid_forward_with_cache(params, tokens, cache,
+                                            cache_len, cfg, **kw)
+            load = jnp.zeros((3,), jnp.float32)
+        return (*out, load) if with_moe_load else tuple(out)
     B, S = tokens.shape
     cache_len = jnp.asarray(cache_len)
     per_row = cache_len.ndim == 1  # per-stream cache pointers

@@ -327,6 +327,101 @@ def hybrid_config_from_hf(hf_config, **overrides):
         **overrides})
 
 
+def nemotron_h_config_from_hf(hf_config, **overrides):
+    """Map a ``model_type: nemotron_h`` config onto
+    :class:`~nbdistributed_tpu.models.nemotron_h.NemotronHConfig`: one
+    mixer a layer, in the order ``hybrid_override_pattern`` spells
+    (``M`` Mamba-2, ``*`` attention, ``E`` experts; ``-``, a dense MLP
+    layer of older checkpoints of the type, is refused).
+
+    Two keys beside the published ones say which share of every expert
+    layer the model holds, for a deployment that splits a layer's
+    experts over chips: ``experts_routed_over`` (the router's width,
+    by default ``n_routed_experts``) and ``experts_held_first`` (the
+    first expert held, by default 0); ``n_routed_experts`` is then the
+    count held.
+
+    Refused rather than mis-served: a group-limited choice, gates that
+    are not normalised, an expert that is not ``relu2``, a tied head,
+    biases on the projections, no bias on the convolution.
+    ``rope_theta`` and ``partial_rotary_factor`` are read and dropped:
+    the published block applies no rotary embedding in its attention
+    layers (to be checked against the checkpoint's own modelling file
+    before the first real weight is served, as everything below).
+
+    A real checkpoint would also need its weights laid out on the way
+    in; no weight converter for this family exists yet.  Under
+    ``backbone.layers.{i}``: ``norm.weight`` -> ``norm``; a Mamba-2
+    layer's ``mixer.in_proj.weight`` (transposed to ``(in, out)``, its
+    columns already ``[z | x | B | C | dt]``) -> ``w_in``,
+    ``mixer.conv1d.weight`` ``(channels, 1, 4)`` -> ``conv_w`` ``(4,
+    channels)``, ``conv1d.bias`` -> ``conv_b``, ``mixer.dt_bias`` /
+    ``A_log`` / ``D`` as they are (a value a head), ``mixer.norm.weight``
+    -> ``gate_norm`` (the gated norm's group size is ``d_inner /
+    n_groups``: check ``norm_before_gate`` is false), ``mixer.out_proj``
+    -> ``w_out``; an attention layer's ``q_proj`` -> ``wq``, ``k_proj``
+    and ``v_proj`` side by side -> ``wkv``, ``o_proj`` -> ``wo``; an
+    expert layer's ``mixer.gate.weight`` (float32) -> ``moe.router``,
+    ``gate.e_score_correction_bias`` -> ``moe.bias``,
+    ``mixer.experts.{e}.up_proj`` / ``down_proj`` for the experts held,
+    stacked on a leading axis, -> ``moe.w_up`` / ``moe.w_down``,
+    ``mixer.shared_experts`` -> ``moe.shared``; ``backbone.norm_f`` ->
+    ``final_norm``, ``backbone.embeddings`` -> ``embed``, ``lm_head``
+    transposed.  ``time_step_limit`` is taken as ``(0, inf)``: the step
+    size is not clamped."""
+    from .nemotron_h import LETTERS, NemotronHConfig
+
+    get = lambda k, d=None: getattr(hf_config, k, d)
+    pattern = hf_config.hybrid_override_pattern
+    if set(pattern) - set(LETTERS):
+        raise ValueError(
+            f"hybrid_override_pattern {pattern!r}: only layers of kinds "
+            f"{''.join(LETTERS)} are supported")
+    if get("n_group", 1) != 1 or get("topk_group", 1) != 1:
+        raise ValueError("group-limited expert choice (n_group > 1) "
+                         "is not supported")
+    if not get("norm_topk_prob", True):
+        raise ValueError("only normalised top-k gates are supported")
+    if get("mlp_hidden_act", "relu2") != "relu2":
+        raise ValueError("only relu2 experts are supported for "
+                         "model_type nemotron_h")
+    if get("tie_word_embeddings", False):
+        raise ValueError("a tied head is not supported for model_type "
+                         "nemotron_h")
+    if (get("attention_bias", False) or get("mlp_bias", False)
+            or get("mamba_proj_bias", False) or get("use_bias", False)
+            or not get("use_conv_bias", True)):
+        raise ValueError("projection biases (attention_bias, mlp_bias, "
+                         "mamba_proj_bias, use_bias) and a convolution "
+                         "without one are not supported")
+    held = hf_config.n_routed_experts
+    return NemotronHConfig(**{
+        "vocab_size": hf_config.vocab_size,
+        "d_model": hf_config.hidden_size,
+        "n_layers": hf_config.num_hidden_layers,
+        "n_heads": hf_config.num_attention_heads,
+        "n_kv_heads": hf_config.num_key_value_heads,
+        "attn_head_dim": hf_config.head_dim,
+        "d_ff": 0,
+        "max_seq_len": get("max_position_embeddings", 4096),
+        "norm_eps": float(get("layer_norm_epsilon", 1e-5)),
+        "pattern": pattern,
+        "ssm_heads": hf_config.mamba_num_heads,
+        "ssm_head_dim": hf_config.mamba_head_dim,
+        "ssm_groups": hf_config.n_groups,
+        "d_state": hf_config.ssm_state_size,
+        "d_conv": hf_config.conv_kernel,
+        "ssm_block": get("chunk_size", 128),
+        "n_experts": get("experts_routed_over", held),
+        "experts_held": (get("experts_held_first", 0), held),
+        "top_k": hf_config.num_experts_per_tok,
+        "d_expert": hf_config.moe_intermediate_size,
+        "d_shared": hf_config.moe_shared_expert_intermediate_size
+        * get("n_shared_experts", 1),
+        "routed_scale": float(get("routed_scaling_factor", 1.0)),
+        **overrides})
+
+
 def config_from_hf_json(config: dict, **overrides):
     """A published ``config.json`` (as a dict) -> the program's config,
     by its ``model_type``; one this tree cannot run raises."""
@@ -337,6 +432,8 @@ def config_from_hf_json(config: dict, **overrides):
         return latent_moe_config_from_hf(ns, **overrides)
     if kind == "phi4flash":
         return hybrid_config_from_hf(ns, **overrides)
+    if kind == "nemotron_h":
+        return nemotron_h_config_from_hf(ns, **overrides)
     if kind == "mixtral":
         cfg = moe_config_from_hf(ns)
     elif kind in ("llama", "mistral"):

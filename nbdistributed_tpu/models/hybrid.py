@@ -26,8 +26,11 @@ cross)`` (:func:`layer_kinds_for` derives it from the published keys and
 :func:`hybrid_stacks` refuses any other order); the pairs are stacked on
 a leading axis and scanned, the two layers between them stand alone.
 
-**Three kinds of cache** (:func:`make_hybrid_cache`), all donated
-through the serving programs as one tree:
+**Three kinds of cache** (:func:`make_hybrid_cache`, from what a
+:class:`StatefulConfig` says it keeps: :mod:`.nemotron_h` is the second
+family on the same :class:`HybridCache`, with no ``window`` kind and its
+state a leaf a layer), all donated through the serving programs as one
+tree:
 
 * ``full``: today's block pool with one layer, a request's
   ``ceil((prompt + max_new) / block)`` blocks taken from the allocator;
@@ -73,6 +76,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -84,8 +88,49 @@ from .transformer import TransformerConfig, _preset, qlinear
 KINDS = ("ssm", "window", "full", "gmu", "cross")
 
 
+class PagePool(NamedTuple):
+    """One kind of paged K/V of a model that keeps several kinds of
+    cache: ``layers`` layers write pages of ``heads`` rows ``width``
+    wide a token; ``window`` (None: all a row holds) bounds what a query
+    attends; ``readers`` layers read a page in a decode step for each
+    layer that wrote it."""
+    layers: int
+    heads: int
+    width: int
+    window: int | None = None
+    readers: int = 1
+
+
 @dataclasses.dataclass(frozen=True)
-class HybridConfig(TransformerConfig):
+class StatefulConfig(TransformerConfig):
+    """A model whose layers keep per-row recurrent state beside (or in
+    place of) pages: what :func:`make_hybrid_cache`,
+    :class:`HybridCache` and :class:`~.serving.DecodeServer` ask of
+    it.  Two families are: :class:`HybridConfig` here and
+    :class:`~.nemotron_h.NemotronHConfig`."""
+
+    def page_pools(self) -> dict[str, PagePool]:
+        """kind -> :class:`PagePool`; ``"full"`` is the kind whose
+        blocks the allocator hands out, a kind with a window is a ring
+        of pages a row."""
+        raise NotImplementedError
+
+    def state_leaves(self) -> tuple[int, dict]:
+        """(state-space layers, name -> (a row's shape, dtype)) of the
+        ``ssm`` kind's leaves."""
+        raise NotImplementedError
+
+    # Whether the ``ssm`` leaves carry a leading layer axis (a stack
+    # that a layer scan carries and updates by slice at the layer's
+    # index) or are tuples of one array a layer (layers that are
+    # unrolled: a layer's state is then a buffer of its own, read once
+    # and written once where it lies, and no layer's update can make
+    # XLA keep a second copy of another's).
+    state_stacked = True
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig(StatefulConfig):
     """``layer_kinds``: one of :data:`KINDS` a layer, in order.
     ``sliding_window`` is the ``window`` layers' alone; ``d_ff`` every
     layer's SwiGLU."""
@@ -112,6 +157,18 @@ class HybridConfig(TransformerConfig):
     def window_of(self, kind: str) -> int | None:
         """The window of a kind of attention layer."""
         return self.sliding_window if kind == "window" else None
+
+    def page_pools(self) -> dict[str, PagePool]:
+        p, q = hybrid_stacks(self)
+        page = (self.kv_pairs, self.pair_dim)
+        return {"full": PagePool(1, *page, readers=1 + q),
+                "window": PagePool(p, *page, window=self.sliding_window)}
+
+    def state_leaves(self) -> tuple[int, dict]:
+        p, _q = hybrid_stacks(self)
+        return p + 1, {
+            "state": ((self.d_state, self.d_inner), jnp.float32),
+            "conv": ((self.d_conv - 1, self.d_inner), self.dtype)}
 
     def num_params(self) -> int:
         per_kind = {k: sum(math.prod(s) for s in
@@ -270,34 +327,42 @@ def init_hybrid_model(key, cfg: HybridConfig) -> dict:
 # ----------------------------------------------------------------------
 # the caches
 
-def ring_pages(cfg: HybridConfig, block_tokens: int, max_len: int,
-               chunk: int | None) -> int:
-    """Pages a row's ring holds a window layer: a window's, a chunk's
-    (the longest run of tokens one program writes: ``chunk``, or the
-    whole row where prompts are not chunked) and one more (a chunk that
-    starts inside a page), never more than the row's pages."""
+def ring_pages(cfg: StatefulConfig, block_tokens: int, max_len: int,
+               chunk: int | None, window: int | None = None) -> int:
+    """Pages a row's ring holds a window layer: a window's (``window``,
+    by default ``cfg.sliding_window``), a chunk's (the longest run of
+    tokens one program writes: ``chunk``, or the whole row where prompts
+    are not chunked) and one more (a chunk that starts inside a page),
+    never more than the row's pages."""
     pages = lambda t: blocks_needed(t, block_tokens)
-    return min(pages(max_len), pages(cfg.sliding_window)
+    return min(pages(max_len), pages(window or cfg.sliding_window)
                + pages(chunk or max_len) + 1)
 
 
-def make_hybrid_cache(cfg: HybridConfig, n_blocks: int, block_tokens: int,
-                      *, rows: int, max_len: int,
+def make_hybrid_cache(cfg: StatefulConfig, n_blocks: int,
+                      block_tokens: int, *, rows: int, max_len: int,
                       chunk: int | None = None) -> dict:
-    """The three kinds of cache, zeroed (the module docstring lays them
-    out); each paged pool ends in a trash block."""
-    p, _q = hybrid_stacks(cfg)
-    page = (cfg.kv_pairs, int(block_tokens), cfg.pair_dim)
-    ring = ring_pages(cfg, block_tokens, max_len, chunk)
-    kv = lambda layers, blocks: {
-        name: jnp.zeros((layers, blocks + 1) + page, cfg.dtype)
-        for name in ("k", "v")}
-    return {"full": kv(1, int(n_blocks)),
-            "window": kv(p, rows * ring),
-            "ssm": {"state": jnp.zeros((p + 1, rows, cfg.d_state,
-                                        cfg.d_inner), jnp.float32),
-                    "conv": jnp.zeros((p + 1, rows, cfg.d_conv - 1,
-                                       cfg.d_inner), cfg.dtype)}}
+    """The kinds of cache ``cfg`` keeps, zeroed (the module docstring
+    lays them out): one paged pool a :class:`PagePool`, each ending in
+    a trash block, and the ``ssm`` leaves, a row a slot."""
+    cache = {}
+    for kind, pool in cfg.page_pools().items():
+        blocks = int(n_blocks) if pool.window is None else rows * ring_pages(
+            cfg, block_tokens, max_len, chunk, pool.window)
+        cache[kind] = {
+            name: jnp.zeros((pool.layers, blocks + 1, pool.heads,
+                             int(block_tokens), pool.width), cfg.dtype)
+            for name in ("k", "v")}
+    layers, leaves = cfg.state_leaves()
+    if cfg.state_stacked:
+        cache["ssm"] = {name: jnp.zeros((layers, rows) + shape, dtype)
+                        for name, (shape, dtype) in leaves.items()}
+    else:
+        cache["ssm"] = {
+            name: tuple(jnp.zeros((rows,) + shape, dtype)
+                        for _ in range(layers))
+            for name, (shape, dtype) in leaves.items()}
+    return cache
 
 
 def cache_bytes_by_kind(cache: dict) -> dict:
@@ -382,36 +447,16 @@ class SSMMixer:
         return out, y, state, tail
 
 
-class DiffAttnMixer:
-    """Differential attention's half of the seam (the contract is
-    :class:`~.generate.GQAMixer`'s, in two halves since a layer may
-    project queries alone): queries come out as four heads a KV pair,
-    zero-padded to the pair's width, keys and values as one head a pair
-    (the module docstring says why), and :meth:`out` takes the second
-    softmax's output from the first's."""
+class PagedAttention:
+    """What every attention mixer of a :class:`StatefulConfig` shares:
+    grouped-query attention with no positional encoding over K and V
+    heads ``width`` wide, through a gathered view (:meth:`attend`) or
+    over the paged pool where it lies (:meth:`attend_paged`).  A
+    subclass projects queries, keys and values and the output."""
 
-    def __init__(self, cfg: HybridConfig, window: int | None):
-        self.cfg, self.window = cfg, window
-        self.scale = 1.0 / float(cfg.head_dim) ** 0.5
-
-    def project_q(self, h, layer):
-        cfg = self.cfg
-        B, S = h.shape[:2]
-        q = (qlinear(h, layer["wq"]) + layer["bq"].astype(h.dtype)) \
-            .reshape(B, S, cfg.kv_pairs, 4, cfg.head_dim)
-        zero = jnp.zeros_like(q[..., :2, :])
-        q = jnp.concatenate(
-            [jnp.concatenate([q[..., :2, :], zero], axis=-1),
-             jnp.concatenate([zero, q[..., 2:, :]], axis=-1)], axis=-2)
-        return q.reshape(B, S, cfg.n_heads, cfg.pair_dim)
-
-    def project_kv(self, h, layer):
-        cfg = self.cfg
-        B, S = h.shape[:2]
-        kv = (qlinear(h, layer["wkv"]) + layer["bkv"].astype(h.dtype)) \
-            .reshape(B, S, 2, cfg.kv_pairs, cfg.pair_dim)
-        kv = kv.transpose(2, 0, 3, 1, 4)            # (2, B, pairs, S, 2Dh)
-        return {"k": kv[0], "v": kv[1]}
+    def __init__(self, width: int, window: int | None):
+        self.window = window
+        self.scale = 1.0 / float(width) ** 0.5
 
     def attend(self, q, view, positions):
         from .generate import _cached_attention
@@ -431,6 +476,38 @@ class DiffAttnMixer:
                 q, pool["k"], pool["v"], layer_idx, table, pos, length,
                 scale=self.scale, window=self.window)
         return o.reshape(*q.shape[:2], -1)
+
+
+class DiffAttnMixer(PagedAttention):
+    """Differential attention's half of the seam (the contract is
+    :class:`~.generate.GQAMixer`'s, in two halves since a layer may
+    project queries alone): queries come out as four heads a KV pair,
+    zero-padded to the pair's width, keys and values as one head a pair
+    (the module docstring says why), and :meth:`out` takes the second
+    softmax's output from the first's."""
+
+    def __init__(self, cfg: HybridConfig, window: int | None):
+        super().__init__(cfg.head_dim, window)
+        self.cfg = cfg
+
+    def project_q(self, h, layer):
+        cfg = self.cfg
+        B, S = h.shape[:2]
+        q = (qlinear(h, layer["wq"]) + layer["bq"].astype(h.dtype)) \
+            .reshape(B, S, cfg.kv_pairs, 4, cfg.head_dim)
+        zero = jnp.zeros_like(q[..., :2, :])
+        q = jnp.concatenate(
+            [jnp.concatenate([q[..., :2, :], zero], axis=-1),
+             jnp.concatenate([zero, q[..., 2:, :]], axis=-1)], axis=-2)
+        return q.reshape(B, S, cfg.n_heads, cfg.pair_dim)
+
+    def project_kv(self, h, layer):
+        cfg = self.cfg
+        B, S = h.shape[:2]
+        kv = (qlinear(h, layer["wkv"]) + layer["bkv"].astype(h.dtype)) \
+            .reshape(B, S, 2, cfg.kv_pairs, cfg.pair_dim)
+        kv = kv.transpose(2, 0, 3, 1, 4)            # (2, B, pairs, S, 2Dh)
+        return {"k": kv[0], "v": kv[1]}
 
     def out(self, o, layer, depth):
         """o (B, S, n_heads * pair_dim) -> (B, S, D).  ``depth`` is the
@@ -452,11 +529,14 @@ class DiffAttnMixer:
 
 
 class HybridCache:
-    """The cache's side of the seam, three kinds in one object: what a
-    ``window`` layer does to its ring (:meth:`window_layer`), what the
-    ``full`` layer writes (:meth:`full_write`), what it and the
-    ``cross`` layers read (:meth:`full_read`), and a state-space layer's
-    state in and out of the tree (:meth:`state` / :meth:`put_state`).
+    """The cache's side of the seam, every kind in one object: what a
+    ``window`` layer does to its ring (:meth:`window_layer`), what a
+    layer that keeps all its keys does to its pages
+    (:meth:`full_layer`; or, where later layers read one layer's pages,
+    :meth:`full_write` and :meth:`full_read` apart), and a state-space
+    layer's state in and out of the tree (:meth:`state` /
+    :meth:`put_state`).  ``mixers``: the :class:`PagedAttention` of
+    each kind of pages the cache holds.
 
     ``slot`` (a traced scalar) makes the call a prefill chunk of that
     one row: its tokens go into pages through ``write_chunk``, its
@@ -464,8 +544,8 @@ class HybridCache:
     a decode step over every row, one token each, ``active`` the rows
     that take part."""
 
-    def __init__(self, cache: dict, cfg: HybridConfig, block_table, *,
-                 slot, active, length, start):
+    def __init__(self, cache: dict, cfg: StatefulConfig, block_table, *,
+                 slot, active, length, start, mixers: dict):
         from .paged_kv import reads_in_place
         self.cfg = cfg
         self._table = block_table
@@ -473,40 +553,58 @@ class HybridCache:
         self._start = start
         self._in_place = reads_in_place(cfg, None)
         self._bt = cache["full"]["k"].shape[3]
-        rows_total = cache["ssm"]["state"].shape[1]
-        self._ring = (cache["window"]["k"].shape[1] - 1) // rows_total
+        self._stacked = cfg.state_stacked
+        rows_total = jax.tree_util.tree_leaves(cache["ssm"])[0].shape[
+            1 if self._stacked else 0]
         rows = (jnp.arange(rows_total, dtype=jnp.int32) if slot is None
                 else jnp.asarray(slot, jnp.int32).reshape(1))
         self._rows = rows
-        pages = jnp.arange(block_table.shape[1], dtype=jnp.int32)
-        self._ring_table = (rows[:, None] * self._ring
-                            + (pages % self._ring)[None, :])
-        self.window = DiffAttnMixer(cfg, cfg.window_of("window"))
-        self.full = DiffAttnMixer(cfg, cfg.window_of("full"))
+        self.full = mixers["full"]
+        self.window = mixers.get("window")
+        if self.window is not None:
+            self._ring = (cache["window"]["k"].shape[1] - 1) // rows_total
+            pages = jnp.arange(block_table.shape[1], dtype=jnp.int32)
+            self._ring_table = (rows[:, None] * self._ring
+                                + (pages % self._ring)[None, :])
 
     # -- state ---------------------------------------------------------
     def state(self, ssm: dict, i) -> tuple:
         """State-space layer ``i``'s (state, tail) over the call's
         rows: every row's, or the one row's of a chunk, zeros where the
-        chunk opens the prompt.  Taken from, and put back into, the
-        tree that the layer scan carries (:meth:`put_state`), so the
-        donated buffers are updated where they lie."""
+        chunk opens the prompt.  Taken from, and put back into
+        (:meth:`put_state`), the tree the layers hand on, so the
+        donated buffers are updated where they lie: a stack by slice at
+        the layer's index (``i`` may be traced), a tuple of one leaf a
+        layer by its element (``i`` a Python int)."""
+        if not self._stacked:
+            ssm = {k: c[i][None] for k, c in ssm.items()}
+            i = 0
         if self._slot is None:
             take = lambda c: jax.lax.dynamic_index_in_dim(
                 c, i, 0, keepdims=False)
             return take(ssm["state"]), take(ssm["conv"])
         keep = self._start.reshape(()) != 0
         take = lambda c: jax.lax.dynamic_slice(
-            c, (i, self._slot, 0, 0), (1, 1) + c.shape[2:])[0]
+            c, (i, self._slot) + (0,) * (c.ndim - 2),
+            (1, 1) + c.shape[2:])[0]
         return (take(ssm["state"]) * keep.astype(jnp.float32),
                 take(ssm["conv"]) * keep.astype(ssm["conv"].dtype))
 
     def put_state(self, ssm: dict, i, state, tail) -> dict:
-        at = (i, 0 if self._slot is None else self._slot, 0, 0)
-        put = lambda c, new: jax.lax.dynamic_update_slice(
-            c, new.astype(c.dtype)[None], at)
-        return {"state": put(ssm["state"], state),
-                "conv": put(ssm["conv"], tail)}
+        new = {"state": state, "conv": tail}
+        if not self._stacked:
+            if self._slot is None:
+                one = lambda c, n: n.astype(c.dtype)
+            else:
+                one = lambda c, n: jax.lax.dynamic_update_slice(
+                    c, n.astype(c.dtype),
+                    (self._slot,) + (0,) * (c.ndim - 1))
+            return {k: c[:i] + (one(c[i], new[k]),) + c[i + 1:]
+                    for k, c in ssm.items()}
+        row = 0 if self._slot is None else self._slot
+        return {k: jax.lax.dynamic_update_slice(
+            c, new[k].astype(c.dtype)[None],
+            (i, row) + (0,) * (c.ndim - 2)) for k, c in ssm.items()}
 
     # -- pages ---------------------------------------------------------
     def _write(self, pool, layer_idx, new, table, pos):
@@ -542,13 +640,26 @@ class HybridCache:
         # a shift), so the table it keeps in scalar memory is the
         # window's pages and not the row's.
         bt, ring = self._bt, self._ring
-        span = -(-self.cfg.sliding_window // bt) + 1
-        base = jnp.maximum(pos + 1 - self.cfg.sliding_window, 0) // bt
+        span = -(-self.window.window // bt) + 1
+        base = jnp.maximum(pos + 1 - self.window.window, 0) // bt
         table = (self._rows[:, None] * ring
                  + (base[:, None] + jnp.arange(span)[None, :]) % ring)
         return self.window.attend_paged(
             q, pool, layer_idx, table.astype(jnp.int32), pos - base * bt,
             self._active), pool
+
+    def full_layer(self, pool, layer_idx, q, new, positions):
+        """Write the new tokens into the rows' pages of layer
+        ``layer_idx``, then attend: every token of a chunk, or a
+        step's one a row."""
+        pos = positions[:, 0]
+        pool = self._write(pool, layer_idx, new, self._table, pos)
+        if self._slot is not None:
+            return self.full.attend_paged(
+                q, pool, layer_idx, self._table, pos, None,
+                length=self._length), pool
+        return self._read_one(self.full, pool, layer_idx, q, self._table,
+                              pos), pool
 
     def full_write(self, pool, new, positions):
         return self._write(pool, jnp.int32(0), new, self._table,
@@ -591,9 +702,12 @@ def hybrid_forward_with_cache(params: dict, tokens, cache: dict, cache_len,
     if row_mask is not None:
         valid = valid & row_mask[:, None]
     length = jnp.sum(valid, axis=1).astype(jnp.int32)
-    kv = HybridCache(cache, cfg, block_table, slot=slot, active=row_mask,
-                     length=length if slot is not None else None,
-                     start=offs if slot is not None else None)
+    kv = HybridCache(
+        cache, cfg, block_table, slot=slot, active=row_mask,
+        length=length if slot is not None else None,
+        start=offs if slot is not None else None,
+        mixers={"full": DiffAttnMixer(cfg, cfg.window_of("full")),
+                "window": DiffAttnMixer(cfg, cfg.window_of("window"))})
     ssm = SSMMixer(cfg)
     eps = cfg.norm_eps
     x = params["embed"][tokens].astype(cfg.dtype)

@@ -211,13 +211,14 @@ class DecodeServer:
                     "capacity from the shape, so per-chunk capacity "
                     "would differ from a solo run's and change which "
                     "tokens drop")
-        from .hybrid import HybridConfig
+        from .hybrid import StatefulConfig
         from .mla import LatentMoEConfig
-        self._routed = isinstance(cfg, LatentMoEConfig)
+        from .nemotron_h import NemotronHConfig
+        self._routed = isinstance(cfg, (LatentMoEConfig, NemotronHConfig))
         # Layer kinds that keep state beside (or in place of) pages:
-        # three kinds of cache, and a prefill chunk that is told its
+        # several kinds of cache, and a prefill chunk that is told its
         # row and whether it ends the prompt.
-        self._hybrid = isinstance(cfg, HybridConfig)
+        self._hybrid = isinstance(cfg, StatefulConfig)
         if self._hybrid and (kv_quantized or mesh is not None):
             raise ValueError("state-space layers are served from "
                              "unquantized caches on one device")
@@ -255,21 +256,19 @@ class DecodeServer:
         # row's: the update is elementwise over the whole array).
         self._state_bytes = 0
         if self._hybrid:
-            from .hybrid import hybrid_stacks, make_hybrid_cache
+            from .hybrid import make_hybrid_cache
             # Rows of state and of window rings are the slots: a free
             # slot is a free row, so they never refuse a request that
             # the slot count admits, and the allocator (the gateway's
-            # mirror of it too) goes on counting the full layer's
+            # mirror of it too) goes on counting the ``full`` kind's
             # blocks alone.
             self._cache = make_hybrid_cache(
                 cfg, kv_blocks, kv_block_tokens, rows=max_batch,
                 max_len=max_len, chunk=prefill_chunk)
-            _pairs, readers = hybrid_stacks(cfg)
-            self._kinds = (
-                _KVKind("full", cfg.window_of("full"),
-                        page_bytes(self._cache["full"]) * (1 + readers)),
-                _KVKind("window", cfg.window_of("window"),
-                        page_bytes(self._cache["window"])))
+            self._kinds = tuple(
+                _KVKind(kind, pool.window,
+                        page_bytes(self._cache[kind]) * pool.readers)
+                for kind, pool in cfg.page_pools().items())
             self._state_bytes = 2 * sum(
                 c.nbytes for c in
                 jax.tree_util.tree_leaves(self._cache["ssm"]))
@@ -911,20 +910,25 @@ class DecodeServer:
         """The pool's block occupancy (``{"blocks", "block_tokens",
         "used", "free", "owners"}``) — the worker's heartbeat telemetry
         and status surfaces read this.  A model with several kinds of
-        cache adds ``kinds``: the blocks above are its ``full`` layer's;
-        a ``window`` ring and a row of ``state`` belong to a slot, so
-        their rows in use are the slots taken."""
+        cache adds ``kinds``: the blocks above are its ``full`` layers';
+        a ``window`` ring (where it has window layers) and a row of
+        ``state`` belong to a slot, so their rows in use are the slots
+        taken."""
         snap = self._paged.snapshot()
         if self._hybrid:
             from .hybrid import cache_bytes_by_kind
             size = cache_bytes_by_kind(self._cache)
             taken = self._B - len(self._free)
-            ring = (self._cache["window"]["k"].shape[1] - 1) // self._B
-            snap["kinds"] = {
-                "full": {"blocks": snap["blocks"], "used": snap["used"],
-                         "bytes": size["full"]},
-                "window": {"rows": self._B, "used": taken,
-                           "ring_pages": ring, "bytes": size["window"]},
-                "state": {"rows": self._B, "used": taken,
-                          "bytes": size["ssm"]}}
+            snap["kinds"] = {"state": {"rows": self._B, "used": taken,
+                                       "bytes": size["ssm"]}}
+            for kind in self._kinds:
+                if kind.window is None:
+                    snap["kinds"][kind.name] = {
+                        "blocks": snap["blocks"], "used": snap["used"],
+                        "bytes": size[kind.name]}
+                    continue
+                ring = (self._cache[kind.name]["k"].shape[1] - 1) // self._B
+                snap["kinds"][kind.name] = {
+                    "rows": self._B, "used": taken, "ring_pages": ring,
+                    "bytes": size[kind.name]}
         return snap

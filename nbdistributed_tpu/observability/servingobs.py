@@ -75,6 +75,12 @@ DEFAULT_RING = 256
 # half minute), the last 8 slow ones, and what makes a tick slow: a
 # period over 3x the ring's median (once it holds 8) or over 1 s.
 TICK_RING = 64
+# ``ticks.totals``: decode steps fetched, tokens decoded, prompt tokens
+# prefilled, chunk programs, bytes of state those steps read and wrote,
+# experts touched (a mean over the expert layers, summed over the
+# steps), rows routed a layer (likewise).
+TICK_TOTALS = ("steps", "dc", "pf", "chunks", "state_bytes",
+               "moe_touched", "moe_rows")
 SLOW_TICKS = 8
 SLOW_FACTOR = 3.0
 SLOW_ABS_S = 1.0
@@ -152,6 +158,10 @@ class ServingObservatory:
         self._util: deque = deque(maxlen=max(8, ring))
         self._ticks: deque = deque(maxlen=TICK_RING)
         self._slow: deque = deque(maxlen=SLOW_TICKS)
+        # What the ticks counted, summed since the observatory began
+        # (the ring forgets): two readings' difference is what ran
+        # between them.
+        self._totals = dict.fromkeys(TICK_TOTALS, 0.0)
         # Bytes one decode step gathers from the paged pool into dense
         # views on a rank (the worker's serve_open reports it; 0 = a
         # kernel that reads the pool in place).
@@ -488,6 +498,12 @@ class ServingObservatory:
             periods = sorted(t["period"] for t in self._ticks
                              if t["period"] is not None)
             self._ticks.append(rec)
+            for k, v in (("steps", kv_steps), ("dc", tick.get("dc")),
+                         ("pf", tick.get("pf")), ("chunks", pf_chunks),
+                         ("state_bytes", rec["st"][0]),
+                         ("moe_touched", moe and moe[0]),
+                         ("moe_rows", moe and moe[2])):
+                self._totals[k] += float(v or 0)
             span = rec["period"] if rec["period"] is not None \
                 else handler
             slow = span > SLOW_ABS_S or (
@@ -513,6 +529,7 @@ class ServingObservatory:
         with self._lock:
             ticks = list(self._ticks)
             slow = list(self._slow)
+            totals = dict(self._totals)
 
         def _stats(vals: list[float]) -> dict:
             sv = sorted(vals)
@@ -539,6 +556,8 @@ class ServingObservatory:
                      "ahead": round(
                          sum(t["ahd"][0] for t in ticks)
                          / max(1, sum(t["ahd"][1] for t in ticks)), 4),
+                     # sums since the start, not over the ring
+                     "totals": totals,
                      "slow": slow}
         if any(t["st"][1] or t["xdec"][1] for t in ticks):
             # a model with several kinds of cache: mean bytes of

@@ -93,14 +93,17 @@ def sigmoid_bias_routing(logits, bias, top_k: int, scale: float):
 
 
 def routing_load(expert_idx, n_experts: int, token_mask=None):
-    """What one layer's routing asks of the expert weights, as three
-    float32 numbers: experts that received a row, the most rows on one
-    expert, rows routed.  Masked tokens count nowhere."""
+    """What one layer's routing asks of the expert weights it holds, as
+    three float32 numbers: experts that received a row, the most rows
+    on one expert, rows routed.  ``expert_idx`` counts from the first
+    expert held; a choice of ``n_experts`` or more fell on an expert
+    held elsewhere and counts nowhere, and neither do masked tokens."""
     T, k = expert_idx.shape
     w = (jnp.ones((T,), jnp.int32) if token_mask is None
          else token_mask.astype(jnp.int32))
-    counts = jnp.zeros((n_experts,), jnp.int32).at[
-        expert_idx.reshape(-1)].add(jnp.repeat(w, k))
+    counts = jnp.zeros((n_experts + 1,), jnp.int32).at[
+        jnp.minimum(expert_idx.reshape(-1), n_experts)].add(
+        jnp.repeat(w, k))[:n_experts]
     return jnp.stack([jnp.sum(counts > 0), jnp.max(counts),
                       jnp.sum(counts)]).astype(jnp.float32)
 
@@ -186,32 +189,44 @@ def _route_sort(expert_idx, E: int, token_mask=None):
     return order, e_sorted, (order % T).astype(jnp.int32), counts
 
 
-def _ragged_expert_linear(xs, w, group_sizes, e_sorted):
+def _ragged_expert_linear(xs, w, group_sizes, e_sorted,
+                          kernel: bool = False):
     """``ragged_dot`` over expert segments, supporting int8 weight-only
     quantized leaves: the per-(expert, output-channel) scales become a
     per-ROW rescale gathered by each row's expert id (constant along
-    the contraction dim, so the grouped dot still reads raw int8)."""
+    the contraction dim, so the grouped dot still reads raw int8).
+    ``kernel``: the Pallas grouped matmul with tiles from the shapes
+    (:func:`~..ops.grouped.grouped_matmul`) in the place of XLA's, for
+    experts whose widths leave XLA's kernel tiles of 128."""
     from ..models.transformer import is_quantized
     if is_quantized(w):
         y = jax.lax.ragged_dot(xs, w["q8"].astype(xs.dtype),
                                group_sizes)
         s_rows = w["s"][jnp.clip(e_sorted, 0, w["s"].shape[0] - 1), 0]
         return (y.astype(jnp.float32) * s_rows).astype(xs.dtype)
+    if kernel:
+        from ..ops.grouped import grouped_matmul
+        return grouped_matmul(xs, w, group_sizes)
     return jax.lax.ragged_dot(xs, w.astype(xs.dtype), group_sizes)
 
 
 def _dropless_ffn(xt, params, gates, expert_idx, E: int,
-                  token_mask=None):
+                  token_mask=None, expert: str = "swiglu",
+                  kernel: bool = False):
     """MegaBlocks-style dropless expert compute: sort the (token,
-    choice) pairs by expert and run the SwiGLU as grouped matmuls over
-    the variable-size segments (``jax.lax.ragged_dot``) — every routed
-    token is computed, no capacity buffer exists, and compute is
-    exactly sum_e n_e GEMM rows (what the MXU would do with perfect
-    per-expert batching).
+    choice) pairs by expert and run the experts (``expert``: one of
+    :data:`EXPERT_FORMS`) as grouped matmuls over the variable-size
+    segments (``jax.lax.ragged_dot``, or with ``kernel`` the Pallas
+    grouped matmul: :func:`_ragged_expert_linear`) — every routed token
+    is computed, no capacity buffer exists, and compute is exactly
+    sum_e n_e GEMM rows (what the MXU would do with perfect per-expert
+    batching).
 
-    Masked tokens sort into a sentinel bin PAST every real segment
-    (group_sizes covers only real experts), and both their rows and
-    their gate weights are zeroed.
+    ``E`` is the number of experts ``params`` holds.  Masked tokens,
+    and choices relabelled ``E`` (an expert held elsewhere:
+    :func:`shared_routed_ffn`), sort into a sentinel bin PAST every
+    real segment (group_sizes covers only the experts held), and both
+    their rows and their gate weights are zeroed.
     """
     T, D = xt.shape
     order, e_sorted, tok, counts = _route_sort(expert_idx, E,
@@ -220,12 +235,9 @@ def _dropless_ffn(xt, params, gates, expert_idx, E: int,
     group_sizes = counts[:E].astype(jnp.int32)
 
     xs = jnp.where(keep[:, None], xt[tok], 0)     # (kT, D)
-    h = (jax.nn.silu(_ragged_expert_linear(
-            xs, params["w_gate"], group_sizes, e_sorted))
-         * _ragged_expert_linear(xs, params["w_up"], group_sizes,
-                                 e_sorted))
-    rows = _ragged_expert_linear(h, params["w_down"], group_sizes,
-                                 e_sorted)        # (kT, D)
+    grouped = lambda x, w: _ragged_expert_linear(x, w, group_sizes,
+                                                 e_sorted, kernel)
+    rows = EXPERT_FORMS[expert](xs, params, grouped)    # (kT, D)
     # The rows past the covered total are zeros only in XLA's own
     # ragged_dot; the TPU's grouped-matmul kernel leaves them unwritten,
     # and 0 * (whatever memory held) may be NaN.  A masked token's
@@ -536,41 +548,97 @@ def moe_ffn(x, params: dict, *, top_k: int = 2,
     return y.reshape(orig_shape), aux
 
 
-def _swiglu(x, p):
-    """One SwiGLU feed-forward ``{w_gate, w_up, w_down}``."""
+def _swiglu(x, p, linear=None):
+    """One SwiGLU feed-forward ``{w_gate, w_up, w_down}``:
+    ``W_down (silu(W_gate x) * W_up x)``.  ``linear(x, w)`` is the
+    matmul: a plain one, or grouped over expert segments."""
+    linear = linear or _qlinear
+    return linear(jax.nn.silu(linear(x, p["w_gate"]))
+                  * linear(x, p["w_up"]), p["w_down"])
+
+
+def _relu2(x, p, linear=None):
+    """One squared-ReLU feed-forward ``{w_up, w_down}``, two matrices
+    and no gate: ``W_down relu(W_up x)^2``.  ``w_up`` may carry more
+    columns than ``w_down`` has rows (a width that is not whole
+    128-lane tiles, stored padded: a grouped matmul takes its operand in
+    the row-major layout, and XLA lays a parameter whose minor axis is
+    not whole tiles out otherwise and copies it for every call); the
+    surplus is dropped."""
+    linear = linear or _qlinear
+    h = linear(x, p["w_up"])[..., :_rows(p["w_down"])]
+    return linear(jnp.square(jax.nn.relu(h)), p["w_down"])
+
+
+def _rows(w) -> int:
+    from ..models.transformer import is_quantized
+    return (w["q8"] if is_quantized(w) else w).shape[-2]
+
+
+def _qlinear(x, w):
     from ..models.transformer import qlinear
-    return qlinear(jax.nn.silu(qlinear(x, p["w_gate"]))
-                   * qlinear(x, p["w_up"]), p["w_down"])
+    return qlinear(x, w)
+
+
+# The forms an expert (routed or shared) takes: name -> f(x, params,
+# linear).
+EXPERT_FORMS = {"swiglu": _swiglu, "relu2": _relu2}
 
 
 def shared_routed_ffn(x, params: dict, *, top_k: int,
-                      routed_scale: float, token_mask=None):
-    """Fine-grained experts as DeepSeek-V3 and its kin deploy them:
-    ``y = sum_i g_i E_i(x) + E_shared(x)`` with the gates of
-    :func:`sigmoid_bias_routing` (``params["router"]`` (D, E),
-    ``params["bias"]`` (E,)), the routed experts as dropless
-    ``ragged_dot`` segments (:func:`_dropless_ffn`: no capacity, so the
-    result of a token depends on no other token and on no shape —
-    bucketed, chunked and batched calls compute the same thing) and
-    ``params["shared"]`` a SwiGLU every token passes through.
+                      routed_scale: float, token_mask=None,
+                      held: tuple[int, int] | None = None,
+                      expert: str = "swiglu"):
+    """Fine-grained experts beside a shared one, as served from a chip
+    that holds all of a layer's routed experts or a share of them:
+    ``y = sum_{i held} g_i E_i(x) + E_shared(x)``.
+
+    The gates are :func:`sigmoid_bias_routing`'s over *all* the experts
+    the router knows (``params["router"]`` (D, E), ``params["bias"]``
+    (E,)), whoever holds them.  ``held = (first, count)`` says which
+    of them ``params`` carries on its leading expert axis: experts
+    ``first .. first + count - 1``, all ``E`` by default.  A choice
+    that falls outside is dropped here (it is another chip's to
+    compute, and nothing stands in for that chip or for the exchange
+    with it), so the shares ``(0, E/2)`` and ``(E/2, E/2)`` of one
+    layer, the shared expert counted once, add up to the whole layer.
+    ``expert`` names the form of every expert, routed and shared
+    (:data:`EXPERT_FORMS`: ``"swiglu"``, three matrices; ``"relu2"``,
+    two).  The routed experts run as dropless grouped-matmul segments
+    (:func:`_dropless_ffn`: no capacity, so the result of a token
+    depends on no other token and on no shape — bucketed, chunked and
+    batched calls compute the same thing), XLA's kernel or the Pallas
+    one by the experts' widths (:func:`~..ops.grouped.xla_tiles_narrow`);
+    every token passes through ``params["shared"]``.
 
     ``token_mask`` (bool, ``x.shape[:-1]``): masked tokens (pad
     positions, idle slots) route nowhere and touch no expert's
     weights; their output rows are the shared expert's alone and are
-    never read.  x: (..., D) -> (same shape, :func:`routing_load`)."""
+    never read.  x: (..., D) -> (same shape, :func:`routing_load` over
+    the experts held)."""
     orig_shape = x.shape
     xt = x.reshape(-1, orig_shape[-1])
     E = params["router"].shape[-1]
+    first, count = held or (0, E)
     mask_t = None if token_mask is None else token_mask.reshape(-1)
     logits = jnp.matmul(xt.astype(jnp.float32),
                         params["router"].astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     gates, expert_idx = sigmoid_bias_routing(
         logits, params["bias"], top_k, routed_scale)
+    if (first, count) != (0, E):
+        # Counted from the first expert held; ``count`` is the
+        # sentinel of a choice held elsewhere.
+        local = expert_idx - first
+        expert_idx = jnp.where((local >= 0) & (local < count), local,
+                               count)
+    from ..ops.grouped import xla_tiles_narrow
     with jax.named_scope("experts"):
-        y = _dropless_ffn(xt, params, gates, expert_idx, E,
-                          token_mask=mask_t)
+        y = _dropless_ffn(xt, params, gates, expert_idx, count,
+                          token_mask=mask_t, expert=expert,
+                          kernel=xla_tiles_narrow(
+                              xt.shape[-1], _rows(params["w_down"])))
     with jax.named_scope("shared_expert"):
-        y = y + _swiglu(xt, params["shared"])
+        y = y + EXPERT_FORMS[expert](xt, params["shared"])
     return (y.reshape(orig_shape),
-            routing_load(expert_idx, E, mask_t))
+            routing_load(expert_idx, count, mask_t))
