@@ -290,9 +290,9 @@ class Smoke:
         t0 = res[0]
         self.facts["train"] = {k: t0[k] for k in
                                ("params_m", "losses", "mosaic",
-                                "compile_s", "step_s")}
+                                "compile_s", "step_s", "collectives")}
         print(f"depth {self.layers} · {t0['params_m']} M parameters · "
-              f"losses {t0['losses']}")
+              f"losses {t0['losses']} · {t0['collectives']}")
         for t in res:
             ls = t["losses"]
             if not all(l == l and abs(l) != float("inf") for l in ls):
@@ -305,6 +305,17 @@ class Smoke:
                                   f"calls, expected flash fwd + 2 bwd")
             if t["losses"] != t0["losses"]:
                 raise PhaseFailed(f"losses differ across ranks: {res}")
+            # What still takes a blocking all-reduce over several
+            # shards: a layer's attention matrices (84 MB at this
+            # width; the loop's body is in the text once), the norms
+            # and the loss.  GSPMD's step reads 960 MB and no send.
+            c = t["collectives"]
+            if len(res) > 1 and not (
+                    c["async_sends"] > 0
+                    and c["blocking_all_reduce_bytes"] < 1 << 27):
+                raise PhaseFailed(
+                    f"the DDP step sums its large gradients by blocking "
+                    f"all-reduces, not by sends inside the backward: {c}")
         in_use = {r: d["devices"][0]["memory_gb"]["in_use"]
                   for r, d in self.status().items()}
         self.facts["train"]["in_use_gb"] = in_use
@@ -740,7 +751,8 @@ _emit(checks=checks)
 _TRAIN_CELL = '''
 import optax
 from nbdistributed_tpu.models import loss_fn, make_train_step
-from nbdistributed_tpu.parallel.data_parallel import ddp_init, make_ddp_step
+from nbdistributed_tpu.parallel.data_parallel import (collectives_of, ddp_init,
+                                                      make_ddp_step)
 S = 4096
 params = init_params(jax.random.PRNGKey(0), cfg)
 opt = optax.adamw(3e-4)
@@ -762,6 +774,7 @@ mosaic = lowered.as_text().count("tpu_custom_call")
 t0 = time.time()
 compiled = lowered.compile()
 compile_s = round(time.time() - t0, 1)
+collectives = collectives_of(compiled)
 losses, step_s = [], []
 for _ in range(4):
     t0 = time.time()
@@ -770,7 +783,8 @@ for _ in range(4):
     step_s.append(round(time.time() - t0, 3))
 del opt_state, batch, compiled, lowered
 _emit(params_m=round(cfg.num_params() / 1e6, 1), losses=losses,
-      mosaic=mosaic, compile_s=compile_s, step_s=step_s)
+      mosaic=mosaic, compile_s=compile_s, step_s=step_s,
+      collectives=collectives)
 '''
 
 # Greedy decode of the trained parameters, bf16 and int8 caches; the

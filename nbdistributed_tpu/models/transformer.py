@@ -28,6 +28,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops import flash_attention
+from ..parallel.overlap import hold_for_grad, sum_grads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -462,9 +463,11 @@ def _attention_block(x, layer, cfg: TransformerConfig, positions,
 
 def _mlp_block(x, layer, cfg: TransformerConfig):
     h = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-    gated = (jax.nn.silu(qlinear(h, layer["w_gate"]))
-             * qlinear(h, layer["w_up"]))
-    return x + qlinear(gated, layer["w_down"])
+    # hold_for_grad: where a data-parallel step sums these gradients
+    # inside the backward, each one's sends start at its own matmul
+    gated = (jax.nn.silu(qlinear(*hold_for_grad(h, layer["w_gate"])))
+             * qlinear(*hold_for_grad(h, layer["w_up"])))
+    return x + qlinear(*hold_for_grad(gated, layer["w_down"]))
 
 
 def make_layer_fn(cfg: TransformerConfig, positions,
@@ -534,15 +537,18 @@ def forward_hidden(params: dict, tokens, cfg: TransformerConfig,
     B, S = tokens.shape
     if positions is None:
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
-    x = params["embed"][tokens].astype(cfg.dtype)
+    x = sum_grads(params["embed"])[tokens].astype(cfg.dtype)
     one_layer = make_layer_fn(cfg, positions, sp,
                               segment_ids=segment_ids)
 
     def layer_step(x, layer):
-        return one_layer(x, layer), None
+        # sum_grads: under a data-parallel step's grad_sums a layer's
+        # gradients are summed over the shards inside the backward
+        # scan, as each is made; anywhere else it is not in the trace.
+        return one_layer(x, sum_grads(layer, of=params["layers"])), None
 
     x, _ = jax.lax.scan(layer_step, x, params["layers"])
-    return _rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return _rms_norm(x, sum_grads(params["final_norm"]), cfg.norm_eps)
 
 
 def forward(params: dict, tokens, cfg: TransformerConfig,
@@ -558,7 +564,8 @@ def forward(params: dict, tokens, cfg: TransformerConfig,
     positions attend only within their own document."""
     x = forward_hidden(params, tokens, cfg, positions, sp=sp,
                        segment_ids=segment_ids)
-    return qlinear(x, params["lm_head"]).astype(jnp.float32)
+    return qlinear(*hold_for_grad(x, sum_grads(params["lm_head"]))
+                   ).astype(jnp.float32)
 
 
 def shifted_xent(logits, tokens, segment_ids=None):

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from . import overlap
+
 
 def apply_shardings(tree, mesh, rules):
     """Place ``tree`` on ``mesh`` according to a matching pytree of
@@ -52,6 +54,14 @@ def make_tp_train_step(loss_fn, optimizer, mesh, param_rules, *,
     accumulator) — same numerics as the full batch for mean losses,
     activation memory divided by ``accum_steps``.
 
+    What "mean losses" asks: the step's loss is the mean over equal
+    pieces of the batch, each piece's loss computed alone — the
+    microbatches here; the ``dp`` shards in the pure DDP step over
+    several of them (``exchanged_grads_of`` below).  That is the
+    global batch's loss for an equal-weight mean over rows, and not
+    for a loss that weighs rows unequally (packed rows), sums, or
+    takes statistics over the batch.
+
     ``accum_rules``: optional pytree of ``PartitionSpec`` for the fp32
     accumulator (ZeRO-2; see :mod:`~nbdistributed_tpu.parallel.zero`).
     Without accumulation, gradients are transient inside the fused
@@ -79,11 +89,37 @@ def make_tp_train_step(loss_fn, optimizer, mesh, param_rules, *,
         else repl
     batch_sh = NamedSharding(mesh, P(dp_axis))
 
+    d = mesh.shape[dp_axis]
+
+    def exchanged_grads_of(params, batch):
+        """Pure DDP (ISSUE 36): each shard differentiates its own rows
+        with ``dp_axis`` manual, and the gradients are summed by
+        ``overlap.exchange_sum`` (the reduce half of a large leaf as
+        asynchronous sends; GSPMD's all-reduce stops the chip for as
+        long as the links need): those the loss marks with
+        ``overlap.sum_grads`` as the backward makes each one, the rest
+        here, after the backward.  The loss is the mean of the shards'
+        losses, each over its own rows: the global batch's loss where
+        that is an equal-weight mean over rows, not otherwise (packed
+        rows divide by the targets a shard keeps)."""
+        def local(params, batch):
+            with overlap.grad_sums(dp_axis) as sums:
+                loss, grads = jax.value_and_grad(
+                    lambda p: loss_fn(sums.own(p), batch) / d)(params)
+                grads = sums.sum_rest(grads)
+            return jax.lax.psum(loss, dp_axis), grads
+
+        return jax.shard_map(
+            local, mesh=mesh, in_specs=(P(), P(dp_axis)),
+            out_specs=(P(), P()), axis_names={dp_axis},
+            check_vma=False)(params, batch)
+
     def grads_of(params, batch):
+        if (accum_steps == 1 and param_rules is None
+                and opt_state_sh is None and d > 1):
+            return exchanged_grads_of(params, batch)
         if accum_steps == 1:
             return jax.value_and_grad(loss_fn)(params, batch)
-
-        d = mesh.shape[dp_axis]
 
         def split(x):
             B = x.shape[0]

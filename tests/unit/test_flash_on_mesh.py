@@ -77,13 +77,14 @@ def test_plain_call_without_a_mesh_or_inside_a_manual_one():
 
 def test_ddp_step_with_flash_is_the_single_device_step():
     """The README's DDP recipe with ``use_flash=True``: the plain
-    ``loss_fn(p, b, cfg)`` through ``make_ddp_step``."""
+    ``loss_fn(p, b, cfg)`` through ``make_ddp_step``: loss, updated
+    weights and optimizer state."""
     cfg = tiny_config(dtype=jnp.float32, use_flash=True, n_layers=1)
     params = init_params(jax.random.PRNGKey(0), cfg)
     opt = optax.adamw(1e-3)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0,
                                 cfg.vocab_size)
-    p1, _, l1 = jax.jit(make_train_step(cfg, opt))(
+    p1, s1, l1 = jax.jit(make_train_step(cfg, opt))(
         params, opt.init(params), {"tokens": tokens})
 
     mesh = mesh_mod.make_mesh({"dp": 4}, devices=jax.devices()[:4])
@@ -92,11 +93,46 @@ def test_ddp_step_with_flash_is_the_single_device_step():
     batch = mesh_mod.shard_batch({"tokens": tokens}, mesh)
     step = data_parallel.make_ddp_step(
         lambda p, b: loss_fn(p, b, cfg), opt, mesh, donate=False)
-    # forward, dq and dk/dv kernels each ride their own shard_map
-    assert step.lower(pr, st, batch).as_text().count(
-        "manual_computation") == 3
-    p2, _, l2 = step(pr, st, batch)
+    # Since ISSUE 36 the step differentiates inside ONE shard_map over
+    # dp (the gradients' sends need the axis manual), and the forward,
+    # dq and dk/dv kernels run local in it, as _flash_on_mesh documents
+    # for an axis an enclosing shard_map made manual; before, each rode
+    # a shard_map of its own (three manual computations).
+    text = step.lower(pr, st, batch).as_text()
+    assert text.count("manual_computation") == 1
+    p2, s2, l2 = step(pr, st, batch)
     np.testing.assert_allclose(float(l2), float(l1), rtol=1e-6)
-    for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
+    for a, b in zip(jax.tree.leaves((p1, s1)), jax.tree.leaves((p2, s2))):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=2e-4, rtol=1e-4)
+
+
+def test_ddp_step_with_flash_sends_inside_the_backward_scan(monkeypatch):
+    """At widths whose matrices are sent (all but ``wk`` and ``wv``,
+    which stay under the size a send is worth): the kernels still run
+    local in the step's one manual region, beside the sends, and the
+    step is the single-device step."""
+    from nbdistributed_tpu.parallel import overlap
+    monkeypatch.setattr(overlap, "EXCHANGE_MIN_SIZE", 1 << 16)
+    cfg = tiny_config(dtype=jnp.float32, use_flash=True, n_layers=2,
+                      d_model=256, d_ff=512, n_heads=4, n_kv_heads=2)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    opt = optax.sgd(1e-2, momentum=0.9)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 64), 0,
+                                cfg.vocab_size)
+    p1, s1, l1 = jax.jit(make_train_step(cfg, opt))(
+        params, opt.init(params), {"tokens": tokens})
+    mesh = mesh_mod.make_mesh({"dp": 4}, devices=jax.devices()[:4])
+    pr, _ = data_parallel.ddp_init(params, (), mesh)
+    st = opt.init(pr)
+    batch = mesh_mod.shard_batch({"tokens": tokens}, mesh)
+    step = data_parallel.make_ddp_step(
+        lambda p, b: loss_fn(p, b, cfg), opt, mesh, donate=False)
+    text = step.lower(pr, st, batch).as_text()
+    assert text.count("manual_computation") == 1
+    assert text.count("stablehlo.collective_permute") == 3 * (2 + 5)
+    p2, s2, l2 = step(pr, st, batch)
+    np.testing.assert_allclose(float(l2), float(l1), rtol=1e-6)
+    for a, b in zip(jax.tree.leaves((p1, s1)), jax.tree.leaves((p2, s2))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=1e-5, rtol=1e-4)

@@ -71,8 +71,9 @@ def test_loss_decreases_under_training(params, batch):
 
 def test_ddp_matches_single_device(params, batch):
     """DDP over 8 virtual devices must be numerically equivalent to
-    single-device training (same global batch)."""
-    opt = optax.sgd(1e-2)
+    single-device training (same global batch): loss, updated weights
+    and optimizer state."""
+    opt = optax.sgd(1e-2, momentum=0.9)
     loss = lambda p, b: loss_fn(p, b, CFG)
 
     # single device
@@ -91,10 +92,68 @@ def test_ddp_matches_single_device(params, batch):
     p2, s2, l2 = step(p_r, s_r, b_r)
 
     np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
-    for a, b in zip(jax.tree_util.tree_leaves(p1),
-                    jax.tree_util.tree_leaves(p2)):
+    for a, b in zip(jax.tree_util.tree_leaves((p1, s1)),
+                    jax.tree_util.tree_leaves((p2, s2))):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("dp", [1, 4])
+def test_ddp_step_program(params, batch, dp, monkeypatch):
+    """What the compiled DDP step holds.  Over several shards: the
+    reduce half of every leaf the shards divide and that is large
+    enough goes by asynchronous sends (the layers' inside the backward
+    scan), and no all-reduce returns such a leaf.  On a ``dp`` = 1 mesh: the
+    program before ISSUE 36, op for op — no send, no manual region,
+    and the plain step's count of every op."""
+    from nbdistributed_tpu.parallel import overlap
+    # (the size from which a leaf is sent is set for Mistral-7B's)
+    monkeypatch.setattr(overlap, "EXCHANGE_MIN_SIZE", 1 << 16)
+    cfg = tiny_config(dtype=jnp.float32, use_flash=False, d_model=256,
+                      d_ff=512, n_heads=4, n_kv_heads=2)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    opt = optax.adamw(1e-3)
+    loss = lambda p, b: loss_fn(p, b, cfg)
+    m = mesh_mod.make_mesh({"dp": dp}, devices=jax.devices()[:dp])
+    step = data_parallel.make_ddp_step(loss, opt, m, donate=False)
+    p_r, s_r = data_parallel.ddp_init(params, opt.init(params), m)
+    b_r = mesh_mod.shard_batch(batch, m)
+    lowered = step.lower(p_r, s_r, b_r)
+    counts = data_parallel.collectives_of(lowered.compile())
+    if dp > 1:
+        # a layer's leaves are one leaf of the scan's body
+        leaves = ([params["embed"], params["final_norm"],
+                   params["lm_head"]]
+                  + [x[0] for x in jax.tree.leaves(params["layers"])])
+        sent = [x for x in leaves if x.shape[0] % dp == 0
+                and x.size >= overlap.EXCHANGE_MIN_SIZE]
+        assert len(sent) >= 7
+        assert counts["async_sends"] == (dp - 1) * len(sent)
+        assert counts["blocking_all_gather_bytes"] == sum(
+            x.nbytes for x in sent)
+        assert counts["blocking_all_reduce_bytes"] <= 8 + sum(
+            x.nbytes for x in leaves if not any(x is y for y in sent))
+        body = lowered.as_text().split("stablehlo.while", 1)[1]
+        assert "collective_permute" in body
+        return
+    assert set(counts.values()) == {0}
+
+    def plain(p, s, b):        # the step as it was written before
+        lval, g = jax.value_and_grad(loss)(p, b)
+        u, s = opt.update(g, s, p)
+        return optax.apply_updates(p, u), s, lval
+
+    def ops(text):
+        import collections
+        import re
+        return collections.Counter(re.findall(r"= (?:stablehlo|sdy|func)"
+                                              r"\.([a-z_.]+)", text))
+
+    want = ops(jax.jit(plain).lower(params, opt.init(params),
+                                    batch).as_text())
+    got = ops(lowered.as_text())
+    assert got == want and sum(got.values()) > 500
+    assert "manual_computation" not in lowered.as_text()
 
 
 def test_tensor_parallel_matches_replicated(params, batch):

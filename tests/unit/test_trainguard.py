@@ -361,6 +361,54 @@ def test_real_guarded_step_skips_bitwise():
             f"{k} changed"
 
 
+def test_real_guarded_ddp_step_skips_on_one_bad_shard(monkeypatch):
+    """Over four shards the gradients are summed by sends inside the
+    backward (ISSUE 36); the fused finite check reads the *summed*
+    gradients, so rows that are non-finite on ONE shard still skip the
+    update on all, bitwise."""
+    import optax
+
+    from nbdistributed_tpu.parallel import data_parallel, overlap
+    from nbdistributed_tpu.parallel import mesh as mesh_mod
+
+    monkeypatch.setattr(overlap, "EXCHANGE_MIN_SIZE", 1 << 16)
+    n = 4
+    m = mesh_mod.make_mesh({"dp": n}, devices=jax.devices()[:n])
+
+    def loss_fn(params, batch):
+        x, y = batch
+        return jnp.mean((x @ params["w"] - y) ** 2)
+
+    params = {"w": jnp.asarray(
+        np.random.default_rng(0).normal(size=(512, 256)), jnp.float32)}
+    opt = optax.adam(1e-2)
+    p, _ = data_parallel.ddp_init(params, None, m)
+    s = jax.jit(opt.init)(p)
+    step = data_parallel.make_ddp_step(loss_fn, opt, m, guard=True,
+                                       donate=False)
+    x = np.ones((8, 512), np.float32)
+    y = np.zeros((8, 256), np.float32)
+    good = mesh_mod.shard_batch((x, y), m)
+    x_bad = x.copy()
+    x_bad[5, 3] = np.nan                # a row of the third shard
+    bad = mesh_mod.shard_batch((x_bad, y), m)
+    assert data_parallel.collectives_of(
+        step.lower(p, s, good).compile())["async_sends"] == n - 1
+
+    p1, s1, _, aux = step(p, s, good)
+    assert np.asarray(aux["v"])[0] == 1.0
+    assert not np.array_equal(np.asarray(p1["w"]), np.asarray(p["w"]))
+    p2, s2, _, aux2 = step(p1, s1, bad)
+    assert np.asarray(aux2["v"])[0] == 0.0          # skip verdict
+    for a, b in zip(jax.tree_util.tree_leaves((p1, s1)),
+                    jax.tree_util.tree_leaves((p2, s2))):
+        for shard_a, shard_b in zip(a.addressable_shards,
+                                    b.addressable_shards):
+            assert (np.asarray(shard_a.data).reshape(-1).view(np.uint8)
+                    == np.asarray(shard_b.data).reshape(-1).view(np.uint8)
+                    ).all()
+
+
 def test_real_guard_metrics_and_unguarded_api():
     import optax
 
