@@ -49,6 +49,7 @@ import sys
 import threading
 import time
 
+from ..observability import bringup as obs_bringup
 from ..observability import flightrec
 from ..observability import metrics as obs_metrics
 from ..resilience import session as session_mod
@@ -235,6 +236,10 @@ class GatewayDaemon:
         # The serving plane (ISSUE 11): one ServingManager per daemon,
         # created by serve_start.  Plain rebinds under _lock.
         self._serve_mgr = None
+        # `tenant_attach` starts at a tenant connection's first frame
+        # (its preamble), by client id until the hello names the
+        # tenant.  Under _lock.
+        self._tenant_dialed: dict[int, float] = {}
         self._close_lock = threading.Lock()
         self._close_started = False
         # One process, one black box: the CommunicationManager created
@@ -283,6 +288,14 @@ class GatewayDaemon:
                 world_size, self.comm.port, backend=backend,
                 extra_env=self._worker_env(1))
             wait_until_ready(self.comm, self.pm, attach_timeout)
+            # `daemon`: this process's creation -> its start_workers
+            # call (the first Popen): a second interpreter and a second
+            # set of imports in front of the workers'.  Read from the
+            # process's own start time, since the kernel's Popen stamp
+            # lives in another process.
+            self._daemon_s = round(
+                min(self.pm.spawned_at.values())
+                - obs_bringup.process_start_time(), 6)
             self.comm.set_output_callback(self._on_stream)
             self.world_size = world_size
 
@@ -313,6 +326,7 @@ class GatewayDaemon:
                 host=host, port=tenant_port,
                 auth_token=self.pool_token)
             self._tenants_listener.on_message = self._on_tenant_message
+            self._tenants_listener.on_connect = self._on_tenant_dial
             self._tenants_listener.on_disconnect = self._on_tenant_gone
             self._tenants_listener.start()
         except BaseException:
@@ -723,7 +737,13 @@ class GatewayDaemon:
         except TransportError:
             return False
 
+    def _on_tenant_dial(self, client_id: int) -> None:
+        with self._lock:
+            self._tenant_dialed[client_id] = time.time()
+
     def _on_tenant_gone(self, client_id: int) -> None:
+        with self._lock:
+            self._tenant_dialed.pop(client_id, None)
         t = self.registry.detach_client(client_id)
         if t is not None:
             self.flight.record("tenant_detached", tenant=t.name)
@@ -801,7 +821,8 @@ class GatewayDaemon:
                              daemon=True).start()
         elif mt == "pool_status":
             self._send_to_client(client_id, msg.reply(
-                data=self.status()))
+                data=self.status(tenant.name if tenant is not None
+                                 else None)))
         elif mt == "detach":
             t = self.registry.detach_client(client_id)
             evicted = False
@@ -1072,6 +1093,10 @@ class GatewayDaemon:
             "tenant hellos accepted",
             {"tenant": name, "kind": reply["status"]}).inc()
         self._send_to_client(client_id, msg.reply(data=reply))
+        with self._lock:
+            dialed = self._tenant_dialed.pop(client_id, None)
+        if dialed is not None:
+            t.attach_s = round(time.time() - dialed, 6)
         self._write_manifest()
 
     def _handle_mailbox(self, client_id: int, tenant, msg) -> None:
@@ -1371,7 +1396,8 @@ class GatewayDaemon:
                     reply = mgr.stream(str(data.get("rid")),
                                        int(data.get("from") or 0))
                 elif mt == "serve_status":
-                    reply = {"status": "serving", **mgr.describe()}
+                    reply = {"status": "serving", **mgr.describe(),
+                             "bringup": self.bringup(tenant.name)}
                 else:  # serve_stop
                     with self._lock:
                         self._serve_mgr = None
@@ -1646,7 +1672,38 @@ class GatewayDaemon:
             return {}
         return {"serving": mgr.obs.status_block()}
 
-    def status(self) -> dict:
+    def bringup(self, tenant: str | None = None) -> dict:
+        """Set-up's account from inside the program (ISSUE 37), the
+        ``bringup`` block of ``serve_status`` and ``pool_status``:
+
+        ``attach``   the critical rank's stages (``<stage>_s``), then
+                     ``daemon_s``, ``spawn_s``, ``wait_s``,
+                     ``attach_s``, ``unaccounted_s``, ``critical_rank``
+                     and the asking tenant's ``tenant_attach_s``
+        ``open``     the serve start: ``spec_s``, ``build_s``,
+                     ``kernels_s`` (none while nothing serves)
+        ``compile``  the compile watch's split, the maximum over ranks
+        ``ranks``    every rank's stages, for the status magics' lines
+        """
+        view = self.comm.bringup()
+        ranks = view["ranks"]
+        crit = ranks.get(view["critical_rank"]) or {}
+        attach = {f"{stage}_s": secs
+                  for stage, secs in (crit.get("stages") or {}).items()}
+        attach["daemon_s"] = self._daemon_s
+        for key in ("spawn_s", "wait_s", "attach_s", "unaccounted_s",
+                    "critical_rank"):
+            attach[key] = view.get(key)
+        t = self.registry.get(tenant) if tenant else None
+        attach["tenant_attach_s"] = t.attach_s if t is not None else None
+        mgr = self._serve_mgr
+        return {"attach": attach,
+                "open": mgr.open_seconds() if mgr is not None else {},
+                "compile": obs_bringup.max_compile(
+                    view["compile"].values()),
+                "ranks": {str(r): row for r, row in ranks.items()}}
+
+    def status(self, tenant: str | None = None) -> dict:
         """The ``%dist_pool status`` payload: scheduler counters,
         tenant table, and a per-rank busy view (tenant-attributed)
         assembled from heartbeat pings — renders even mid-cell."""
@@ -1689,7 +1746,8 @@ class GatewayDaemon:
                # Stage-attribution view (ISSUE 13): %dist_lat in
                # tenant mode reads this — the observatory lives in
                # THIS process, not the kernel's.
-               "latency": self.comm.lat.status_block()}
+               "latency": self.comm.lat.status_block(),
+               "bringup": self.bringup(tenant)}
         if self._metrics_httpd is not None:
             out["metrics_port"] = self._metrics_httpd.port
         mgr = self._serve_mgr

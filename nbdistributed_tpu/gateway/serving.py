@@ -451,6 +451,11 @@ class ServingManager:
         # rank -> compiled Pallas kernels in its decode step, as its
         # serve_open reported (0: interpreted, or the einsum path).
         self._step_kernels: dict[int, int] = {}
+        # The serve start's own stages (ISSUE 37): seconds of the
+        # model-spec cell, and per rank of the server's build and its
+        # kernels' count, as the rank's serve_open reply states them.
+        self._spec_s: float | None = None
+        self._open_s: dict[int, tuple[float, float]] = {}
         # rank -> monotonic deadline to avoid it: a rank whose
         # serve_open failed (missing namespace after a reconnect,
         # OOM building the server) must not be retried forever while
@@ -540,10 +545,13 @@ class ServingManager:
             live = self._live_ranks()
             if not live:
                 raise RuntimeError("no live ranks to serve on")
-            resps = self.comm.send_to_ranks(
-                live, "execute",
-                {"code": self.spec, "target_ranks": live},
-                tenant=self.tenant, timeout=spec_timeout)
+            t0 = time.time()
+            with obs_spans.phase("serve/open/spec"):
+                resps = self.comm.send_to_ranks(
+                    live, "execute",
+                    {"code": self.spec, "target_ranks": live},
+                    tenant=self.tenant, timeout=spec_timeout)
+            self._spec_s = round(time.time() - t0, 6)
             for r, m in resps.items():
                 err = (m.data or {}).get("error")
                 if err:
@@ -1007,6 +1015,17 @@ class ServingManager:
         d["lat"] = self.obs.status_block(records=64)
         return d
 
+    def open_seconds(self) -> dict:
+        """The serve start's stages: ``spec_s`` (the model-spec cell on
+        every rank, its slowest rank's reply), ``build_s``
+        (``DecodeServer(...)``: the pools) and ``kernels_s``
+        (``step_kernels()``), each the slowest opened rank's."""
+        with self._lock:
+            opened = list(self._open_s.values())
+        return {"spec_s": self._spec_s,
+                "build_s": max((b for b, _k in opened), default=None),
+                "kernels_s": max((k for _b, k in opened), default=None)}
+
     def forget_tenant(self, name: str) -> None:
         """Mirror the pool scheduler's eviction hygiene for the serve
         scheduler's per-submitter stats."""
@@ -1189,6 +1208,9 @@ class ServingManager:
                                               self.kv_block_tokens)
             self._step_kernels[rank] = int(
                 (resp[rank].data or {}).get("step_kernels") or 0)
+            self._open_s[rank] = (
+                float((resp[rank].data or {}).get("build_s") or 0.0),
+                float((resp[rank].data or {}).get("kernels_s") or 0.0))
             self._avoid.pop(rank, None)
         self.obs.kv_view_bytes = int(
             (resp[rank].data or {}).get("kv_view_bytes") or 0)

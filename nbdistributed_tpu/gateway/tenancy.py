@@ -51,7 +51,7 @@ class Tenant:
     __slots__ = ("name", "token", "epoch", "client_id", "mailbox",
                  "priority", "admitted_ts", "last_seen", "reattaches",
                  "cells_submitted", "cells_done", "cells_failed",
-                 "parked_total", "ns_unsafe", "ns_lock")
+                 "parked_total", "ns_unsafe", "ns_lock", "attach_s")
 
     def __init__(self, name: str, token: str, priority: int = 0):
         self.name = name
@@ -76,6 +76,9 @@ class Tenant:
         self.ns_lock = threading.Lock()
         self.admitted_ts = time.time()
         self.last_seen = time.time()
+        # Seconds from this tenant's latest connection's first frame
+        # to its hello's reply (the daemon's `tenant_attach` stage).
+        self.attach_s: float | None = None
         self.reattaches = 0
         self.cells_submitted = 0
         self.cells_done = 0

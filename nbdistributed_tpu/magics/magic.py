@@ -28,6 +28,7 @@ from IPython.core.magic_arguments import (argument, magic_arguments,
 
 from ..manager import ProcessManager
 from ..messaging import CommunicationManager, WorkerDied
+from ..observability import bringup as obs_bringup
 from ..utils import knobs as _knobs
 from . import display as display_mod
 from . import proxies, rankspec
@@ -540,7 +541,6 @@ class DistributedMagics(Magics):
             print(f"⚠️ {self._world} workers already running. "
                   "%dist_shutdown first.")
             return
-        t0 = time.time()
         num_workers = args.num_workers
         # Explicit chip pinning (reference: magic.py:454-488): parse
         # and sanity-check before anything spawns; full count/dup/
@@ -743,7 +743,9 @@ class DistributedMagics(Magics):
         print(_BANNER.format(n=num_workers,
                              backend=pm.backend,
                              transport=comm.transport,
-                             secs=time.time() - t0))
+                             # first Popen -> every rank attached: the
+                             # stamps %dist_status's timeline is made of
+                             secs=comm.bringup()["attach_s"]))
 
     def _maybe_start_metrics_httpd(self) -> None:
         """Start the live scrape endpoint when NBD_METRICS_PORT asks
@@ -1475,6 +1477,11 @@ class DistributedMagics(Magics):
                   f"{q.get('p99', 0)} ms · execute p99 "
                   f"{x.get('p99', 0)} ms "
                   f"({lat['count']} recorded — %dist_lat for stages)")
+        if st.get("bringup"):
+            # Set-up's account (ISSUE 37), as %dist_status prints it.
+            print("⏱ bring-up (s):")
+            print("\n".join(obs_bringup.format_pool_lines(
+                st["bringup"])))
         if st.get("metrics_port"):
             print(f"📈 scrape endpoint on port {st['metrics_port']} "
                   f"(/metrics, /healthz, /latency.json — pool token)")
@@ -3356,6 +3363,15 @@ class DistributedMagics(Magics):
                              if ping is not None else " · hb –")
             print(line_txt)
         if self._comm is not None:
+            # Set-up's account (ISSUE 37): one line a rank of the
+            # bring-up's stages, the critical rank marked, then what
+            # each rank's compiles were made of.  Idle ranks' replies
+            # are fresher than the heartbeat's copy.
+            lines = obs_bringup.format_lines(self._comm.bringup(
+                pulled={r: st.get("bringup") for r, st in live.items()}))
+            if lines:
+                print("⏱ bring-up (s):")
+                print("\n".join(lines))
             # Clock-skew surfacing (ISSUE 13 satellite): big offsets
             # silently degrade merged traces and stage attribution —
             # say so here, where the operator already looks.
@@ -4538,10 +4554,17 @@ class DistributedMagics(Magics):
 
     @classmethod
     def _nuclear_shutdown(cls) -> None:
-        """Last-resort sweep for orphaned workers (reference:
-        magic.py:878-961 pkills by pattern; same idea, our module name)."""
+        """Last-resort sweep for workers this kernel spawned and its
+        process manager lost track of (reference: magic.py:878-961
+        pkills by pattern; same idea, our module name).  Only this
+        process's own children: by pattern alone the sweep also killed
+        every other kernel's fleet on the machine, a gateway pool's
+        and, under pytest-xdist, the fleets of the test files beside
+        this one.  A dead kernel's orphans are a durable session's to
+        reattach or ``%dist_gc``'s to reap, not this sweep's."""
+        import os
         import subprocess
-        subprocess.run(["pkill", "-9", "-f",
+        subprocess.run(["pkill", "-9", "-P", str(os.getpid()), "-f",
                         "nbdistributed_tpu.runtime.worker"],
                        capture_output=True)
 

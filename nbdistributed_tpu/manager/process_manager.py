@@ -43,6 +43,9 @@ def wait_until_ready(comm, pm, timeout_s: float, *, poll_s: float = 2.0,
     while True:
         try:
             comm.wait_for_workers(timeout=poll_s)
+            # The spawner's stamps for the merged timeline
+            # (``comm.bringup()``): each rank's Popen, this wait.
+            comm.note_bringup(pm.spawned_at, (t0, time.time()))
             return
         except TimeoutError:
             pm.check_startup_failure()
@@ -182,6 +185,9 @@ class ProcessManager:
         self.hosts: dict[int, str] = {}
         # host label -> AgentClient for agent-launched hosts.
         self._agents: dict = {}
+        # rank -> time.time() just before its Popen (or its agent's
+        # spawn request): where the bring-up's timeline starts.
+        self.spawned_at: dict[int, float] = {}
         self._monitor_thread: threading.Thread | None = None
         self._monitor_stop = threading.Event()
         self._death_callbacks: list[Callable[[int, int | None], None]] = []
@@ -339,6 +345,7 @@ class ProcessManager:
                             auth_token=(agent_token if agent_token
                                         is not None else auth_token))
                         self._agents[launch.host] = client
+                    self.spawned_at[launch.rank] = time.time()
                     pid = client.spawn(launch.rank, launch.argv,
                                        dict(launch.env))
                     self.processes[launch.rank] = \
@@ -380,6 +387,7 @@ class ProcessManager:
         self._start_monitor()
 
     def _spawn(self, rank: int, cmd: list[str], env: dict) -> None:
+        self.spawned_at[rank] = time.time()
         proc = subprocess.Popen(
             cmd, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
             env=env, start_new_session=True,  # own pgid for group kill
@@ -431,7 +439,14 @@ class ProcessManager:
         """Per-rank exit status + captured stdio tail for the given
         ranks (default: all) — folded into attach-timeout errors so
         "workers [2] did not attach" also says WHY (exit code, the
-        ImportError, the bind failure...) without a second probe."""
+        ImportError, the bind failure...) without a second probe.  A
+        rank that is still running also says which bring-up stage its
+        flight record last entered, and for how long ("in `backend`
+        for 171 s"): the ring file is readable while its writer
+        hangs."""
+        from ..observability import bringup, flightrec
+        from ..utils import knobs
+        run_dir = knobs.get_str("NBD_RUN_DIR")   # the comm exported it
         lines = []
         for rank in sorted(ranks if ranks is not None
                            else self.processes):
@@ -443,6 +458,15 @@ class ProcessManager:
             state = (f"exited with code {rc}" if rc is not None
                      else f"still running (pid {proc.pid}, never "
                           f"attached)")
+            if rc is None and run_dir:
+                try:
+                    ring = flightrec.read_ring(flightrec.ring_path(
+                        run_dir, f"rank{rank}", proc.pid))
+                    at = bringup.stage_in(ring["events"], time.time())
+                except (OSError, KeyError, TypeError):
+                    at = None   # a remote rank: its ring is not here
+                if at is not None:
+                    state += f" in `{at[0]}` for {at[1]:.0f} s"
             lines.append(f"--- rank {rank}: {state}")
             io = self.io.get(rank)
             tail = io.tail(tail_lines) if io is not None else ""
@@ -534,6 +558,7 @@ class ProcessManager:
         self.processes.clear()
         self.io.clear()
         self.hosts.clear()
+        self.spawned_at.clear()
         self._reported_dead.clear()
         self.world_size = 0
 

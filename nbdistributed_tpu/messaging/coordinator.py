@@ -29,6 +29,7 @@ from typing import Any, Callable
 
 from ..gateway.scheduler import (ACTIVE, SHED, CellRejected, CellShed,
                                  Scheduler)
+from ..observability import bringup as obs_bringup
 from ..observability import flightrec
 from ..observability import metrics as obs_metrics
 from ..observability import spans as obs_spans
@@ -429,6 +430,12 @@ class CommunicationManager:
         # rode heartbeat pings (runtime/worker.py piggybacks them) —
         # the postmortem's "last known device state" for a dead rank.
         self._telemetry: dict[int, deque] = {}
+        # The bring-up's timeline (observability/bringup.py): the
+        # instant each rank first attached (stamped on the IO thread),
+        # and the spawner's stamps that wait_until_ready hands over.
+        self._attached_at: dict[int, float] = {}
+        self._spawned_at: dict[int, float] = {}
+        self._wait_span: tuple[float, float] | None = None
         # Native C++ listener when built (see messaging/native.py), the
         # pure-Python selector listener otherwise — same protocol.
         self._listener = make_listener(host=host, port=port,
@@ -548,6 +555,44 @@ class CommunicationManager:
             hist = self._telemetry.get(rank)
             return hist[-1] if hist else None
 
+    def note_bringup(self, spawned_at: dict[int, float],
+                     wait: tuple[float, float]) -> None:
+        """The spawner's side of the timeline: each rank's ``Popen``
+        stamp and ``wait_until_ready``'s start and end."""
+        with self._lock:
+            self._spawned_at = dict(spawned_at)
+            self._wait_span = wait
+
+    def bringup(self, pulled: dict[int, dict] | None = None) -> dict:
+        """The merged bring-up timeline (``bringup.merge``): per rank
+        the worker's stages, ``attach_s`` and ``unaccounted_s``; the
+        rank the others waited for (``critical_rank``), ``spawn_s``,
+        ``wait_s``, ``attach_s``; and per rank the compile watch's
+        split (``compile``).  The workers' lists arrive on the
+        telemetry piggyback of the first heartbeat (at most
+        ``HEARTBEAT_INTERVAL_S`` after the attach): no frame and no
+        round trip of their own.  ``pulled`` ({rank: the ``bringup``
+        block of a ``get_status`` reply the caller already holds})
+        is fresher, and fills in for ranks whose heartbeat has not
+        come."""
+        with self._lock:
+            # every snapshot carries both: the newest is enough
+            newest = {r: h[-1] for r, h in self._telemetry.items() if h}
+            stages = {r: s["bringup"] for r, s in newest.items()
+                      if s.get("bringup")}
+            compiles = {r: s["cw"] for r, s in newest.items()
+                        if s.get("cw")}
+            spawned, attached = dict(self._spawned_at), \
+                dict(self._attached_at)
+            wait = self._wait_span
+        for rank, got in (pulled or {}).items():
+            if got:
+                stages[rank] = got["stages"]
+                compiles[rank] = got["compile"]
+        view = obs_bringup.merge(stages, spawned, attached, wait)
+        view["compile"] = compiles
+        return view
+
     def telemetry_history(self, rank: int) -> list[dict]:
         """The last few telemetry snapshots for ``rank`` (bounded) —
         what the postmortem bundles as the dead rank's final device
@@ -665,6 +710,9 @@ class CommunicationManager:
             self._last_seen.clear()
             self._last_ping.clear()
             self._telemetry.clear()
+            self._attached_at.clear()
+            self._spawned_at.clear()
+            self._wait_span = None
             stale = list(self._pending.items())
             self._pending.clear()
         self.flight.record("world_reset", num_workers=num_workers,
@@ -971,7 +1019,8 @@ class CommunicationManager:
             self._connected.add(rank)
             self._ever_connected.add(rank)
             self._dead.discard(rank)
-            self._last_seen[rank] = time.time()
+            self._last_seen[rank] = now = time.time()
+            self._attached_at.setdefault(rank, now)
             all_in = len(self._connected) >= self.num_workers
         # Transport-level connect events land in the flight ring on
         # BOTH sides so a postmortem can tell "link flapped" (connect /

@@ -33,9 +33,16 @@ metrics from the coordinator and every rank land:
   (``NBD_RUN_DIR``); a reader recovers the ring — including a torn
   final record — from the file of a SIGKILLed process.
 - :mod:`~nbdistributed_tpu.observability.telemetry` — per-worker
-  device telemetry (HBM in-use/peak, live buffers, compile activity)
-  sampled off the hot path and piggybacked on heartbeat pings, so the
-  coordinator holds a push-based live view that works mid-cell.
+  device telemetry (HBM in-use/peak, live buffers, compile activity
+  and what it was made of: tracing, lowering, backend compiles, cache
+  loads) sampled off the hot path and piggybacked on heartbeat pings,
+  so the coordinator holds a push-based live view that works mid-cell.
+- :mod:`~nbdistributed_tpu.observability.bringup` — set-up on one
+  timeline (ISSUE 37): a worker's contiguous bring-up stages
+  (``interpreter`` … ``connect``) as flight records and as a list on
+  the first heartbeat's telemetry, merged with the spawner's ``Popen``
+  and attach stamps into ``comm.bringup()``; the lines ``%dist_status``
+  and ``%dist_pool status`` print.
 - :mod:`~nbdistributed_tpu.observability.postmortem` — assembles the
   flight rings, last telemetry, coordinator spans, and fault events
   into a postmortem bundle (merged Chrome trace + human report) when a

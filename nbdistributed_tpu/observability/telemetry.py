@@ -16,7 +16,8 @@ heartbeat)::
      "hbm": [{"id", "in_use", "peak", "limit"}, ...],   # bytes | None
      "bufs": live jax.Array count,
      "compiles": backend_compile count, "compile_s": cumulative seconds,
-     ...extra_fn() fields (dedup hits, msgs seen, ...)}
+     "cw": compile_split(),             # trace / lower / backend / cache
+     ...extra_fn() fields (dedup hits, msgs seen, the bring-up stages)}
 
 The module imports no JAX at import time (the observability package
 stays coordinator-safe); all device access is lazy and fail-soft.
@@ -56,16 +57,39 @@ def device_memory(device) -> dict | None:
 
 
 class _CompileWatch:
-    """Counts XLA backend compiles via ``jax.monitoring`` duration
-    events — the only compile signal that fires *inside* the blocking
-    compile path, which is exactly when the serial loop can't answer a
-    status probe.  Process-global (listeners cannot be unregistered);
-    instances read deltas off the shared counters."""
+    """The one ``jax.monitoring`` listener: counts XLA backend compiles
+    from duration events — the only compile signal that fires *inside*
+    the blocking compile path, which is exactly when the serial loop
+    can't answer a status probe — and splits what a compile is made of
+    (ISSUE 37): tracing, lowering, the backend's own compile, and the
+    persistent cache's retrieval, with the cache's hits and misses and
+    the eight longest programs by name.  Cumulative since the listener
+    was installed (where the worker imports jax).  Process-global
+    (listeners cannot be unregistered); instances read deltas off the
+    shared counters.
+
+    ``jaxpr_trace_duration`` nests (an outer jit's trace holds its
+    inner jits' traces, and a lowering may trace again), so each
+    thread keeps the depth from the ``record_scalar`` that opens every
+    such event and only an outermost one adds its seconds: the four
+    sums never hold the same instant twice."""
 
     _lock = threading.Lock()
     _installed = False
+    _local = threading.local()   # .depth, .how: this thread's compile
     count = 0
     seconds = 0.0
+    trace_s = 0.0
+    lower_s = 0.0
+    backend_s = 0.0
+    cache_load_s = 0.0
+    hits = 0
+    misses = 0
+    slowest: list = []           # [program, seconds, hit|miss|uncached]
+
+    _PARTS = {"jaxpr_trace_duration": "trace_s",
+              "jaxpr_to_mlir_module_duration": "lower_s",
+              "backend_compile_duration": "backend_s"}
 
     @classmethod
     def install(cls) -> bool:
@@ -75,31 +99,107 @@ class _CompileWatch:
             try:
                 import jax.monitoring as jmon
 
-                def _on_duration(name: str, secs: float, **kw) -> None:
-                    if name.endswith("backend_compile_duration"):
-                        with cls._lock:
-                            cls.count += 1
-                            cls.seconds += secs
-
-                jmon.register_event_duration_secs_listener(_on_duration)
+                jmon.register_scalar_listener(cls._on_enter)
+                jmon.register_event_duration_secs_listener(
+                    cls._on_duration)
+                jmon.register_event_listener(cls._on_event)
             except Exception:
                 return False
             cls._installed = True
             return True
 
     @classmethod
+    def _on_enter(cls, name: str, _value, **kw) -> None:
+        if name.rpartition("/")[2] in cls._PARTS:
+            loc = cls._local
+            loc.depth = getattr(loc, "depth", 0) + 1
+
+    @classmethod
+    def _on_event(cls, name: str, **kw) -> None:
+        # jax 0.9: a hit is recorded where the executable was read back,
+        # a miss where a compiled one was written (a compile under the
+        # cache's thresholds is neither).  Both fire inside the
+        # backend_compile_duration block of the same thread.
+        if name.endswith("/compilation_cache/cache_hits"):
+            cls._local.how = "hit"
+            with cls._lock:
+                cls.hits += 1
+        elif name.endswith("/compilation_cache/cache_misses"):
+            cls._local.how = "miss"
+            with cls._lock:
+                cls.misses += 1
+
+    @classmethod
+    def _on_duration(cls, name: str, secs: float, **kw) -> None:
+        tail = name.rpartition("/")[2]
+        if tail == "cache_retrieval_time_sec":
+            with cls._lock:
+                cls.cache_load_s += secs
+            return
+        part = cls._PARTS.get(tail)
+        if part is None:
+            return
+        loc = cls._local
+        loc.depth = max(0, getattr(loc, "depth", 1) - 1)
+        outermost = loc.depth == 0
+        if part != "backend_s":
+            if outermost:
+                with cls._lock:
+                    setattr(cls, part, getattr(cls, part) + secs)
+            return
+        how = getattr(loc, "how", None) or "uncached"
+        loc.how = None
+        with cls._lock:
+            cls.count += 1
+            cls.seconds += secs
+            if how != "hit" and outermost:
+                cls.backend_s += secs
+            cls.slowest = sorted(
+                cls.slowest + [[str(kw.get("fun_name") or "?"),
+                                round(secs, 3), how]],
+                key=lambda e: -e[1])[:8]
+
+    @classmethod
     def snapshot(cls) -> tuple[int, float]:
         with cls._lock:
             return cls.count, round(cls.seconds, 3)
 
+    @classmethod
+    def split(cls) -> dict:
+        with cls._lock:
+            return {"trace_s": round(cls.trace_s, 4),
+                    "lower_s": round(cls.lower_s, 4),
+                    "backend_s": round(cls.backend_s, 4),
+                    "cache_load_s": round(cls.cache_load_s, 4),
+                    "hits": cls.hits, "misses": cls.misses,
+                    "slowest": [list(e) for e in cls.slowest]}
+
+
+def install_compile_watch() -> bool:
+    """Install the listener now: the worker calls this where it imports
+    jax, so the namespace's own compiles are on the record."""
+    return _CompileWatch.install()
+
 
 def compile_snapshot() -> tuple[int, float]:
-    """``(backend compiles, their seconds)`` so far in this process
-    (installs the listener on first use): the serve_step handler
+    """``(count, seconds)`` of ``backend_compile_duration`` events so
+    far in this process (installs the listener on first use).  One
+    event is one program made ready, whichever way: compiled by the
+    backend, or read back from the persistent cache;
+    :func:`compile_split` tells the two apart.  The serve_step handler
     takes the delta across a tick, so a tick that compiled, or loaded
-    a program from the persistent cache, says so."""
+    a program from the cache, says so."""
     _CompileWatch.install()
     return _CompileWatch.snapshot()
+
+
+def compile_split() -> dict:
+    """What the compiles so far were made of: ``trace_s``, ``lower_s``,
+    ``backend_s`` (backend compiles that were no cache hit),
+    ``cache_load_s`` (the persistent cache's retrieval), the cache's
+    ``hits`` and ``misses``, and ``slowest``: the eight longest
+    programs as ``[name, seconds, "hit" | "miss" | "uncached"]``."""
+    return _CompileWatch.split()
 
 
 def compile_seconds() -> float:
@@ -175,6 +275,7 @@ class TelemetrySampler:
             n, secs = _CompileWatch.snapshot()
             snap["compiles"] = n
             snap["compile_s"] = secs
+            snap["cw"] = _CompileWatch.split()
             reg.gauge("nbd_backend_compiles",
                       "XLA backend compiles observed").set(n)
         if self._extra_fn is not None:

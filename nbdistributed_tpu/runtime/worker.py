@@ -32,7 +32,7 @@ import traceback
 
 from ..messaging import Message, TransportError, WorkerChannel
 from ..messaging import xfer as xfer_mod
-from ..observability import flightrec
+from ..observability import bringup, flightrec
 from ..observability import metrics as obs_metrics
 from ..observability import spans as obs_spans
 from ..observability import telemetry as obs_telemetry
@@ -142,7 +142,12 @@ class DistributedWorker:
                  backend: str | None = None,
                  dist_host: str | None = None,
                  gate: InterruptGate | None = None,
-                 fault_plan: FaultPlan | None = None):
+                 fault_plan: FaultPlan | None = None,
+                 stages: bringup.Stages | None = None):
+        # The bring-up's timeline (observability/bringup.py): main()
+        # opens it at the process's creation; a worker constructed
+        # directly (in-process tests) starts it here.
+        self._stages = stages or bringup.Stages("import_jax", time.time())
         self.rank = rank
         self.world_size = world_size
         self._shutdown = threading.Event()
@@ -241,6 +246,7 @@ class DistributedWorker:
         self._flight = flightrec.init(f"rank{rank}")
         self._flight.record("worker_start", rank=rank, pid=os.getpid(),
                             world_size=world_size)
+        self._stages.bind(self._flight)
         # Hang watchdog (ISSUE 5): when enabled (NBD_HANG, default on)
         # heartbeats also carry the in-flight request id, its optional
         # per-cell deadline, and the collective-progress snapshot from
@@ -288,6 +294,9 @@ class DistributedWorker:
 
         # --- data plane: JAX runtime init (reference: worker.py:145-151) --
         import jax
+        # The one jax.monitoring listener, from the first instant a
+        # compile can happen (the namespace's imports compile too).
+        obs_telemetry.install_compile_watch()
         if backend is not None:
             # The platform is the launcher's decision, not the
             # environment's: an inherited JAX_PLATFORMS must not turn
@@ -298,13 +307,17 @@ class DistributedWorker:
         if backend == "cpu" and world_size > 1:
             jax.config.update("jax_cpu_collectives_implementation",
                               "gloo")
+        stages = self._stages
         if world_size > 1 and dist_port is not None:
+            stages.enter("rendezvous")
             print(f"[worker {rank}] joining jax.distributed world "
-                  f"({world_size} processes)...", flush=True)
+                  f"({world_size} processes) after "
+                  f"{self._stage_seconds()}...", flush=True)
             jax.distributed.initialize(
                 coordinator_address=f"{dist_host}:{dist_port}",
                 num_processes=world_size,
                 process_id=rank)
+        stages.enter("backend")
         self._jax = jax
         _require_backend(rank, backend)
         # One persistent compile cache for every worker of every fleet
@@ -315,14 +328,19 @@ class DistributedWorker:
             os.makedirs(cache_dir, exist_ok=True)
             jax.config.update("jax_compilation_cache_dir", cache_dir)
         n_local = jax.local_device_count()
-        print(f"[worker {rank}] backend={jax.default_backend()} "
-              f"kind={jax.local_devices()[0].device_kind!r} "
-              f"local_devices={n_local} global_devices={jax.device_count()}",
-              flush=True)
+        device_line = (
+            f"[worker {rank}] backend={jax.default_backend()} "
+            f"kind={jax.local_devices()[0].device_kind!r} "
+            f"local_devices={n_local} global_devices={jax.device_count()}")
+        stages.enter("namespace")
+        # the seconds are the timeline's own stamps: the log and
+        # %dist_status cannot disagree
+        print(f"{device_line} ({self._stage_seconds()})", flush=True)
 
         # --- interactive namespace (reference: worker.py:160-177) --------
         self.namespace: dict = {}
         self._seed_namespace()
+        stages.enter("connect")
 
         # Telemetry sampler: snapshots HBM / live buffers / compile
         # activity off the hot path; the heartbeat thread piggybacks
@@ -344,6 +362,9 @@ class DistributedWorker:
         self.channel.fault_plan = fault_plan
         self.channel.local_host = self._host_label
         self.channel.peer_host = self._coord_label
+        # The preamble (the frame that marks this rank attached) went
+        # out in the channel's constructor: the timeline ends here.
+        stages.finish()
         self._flight.record("transport_connect", host=coordinator_host,
                             port=control_port)
         self._hb_thread = threading.Thread(target=self._heartbeat,
@@ -351,6 +372,11 @@ class DistributedWorker:
         self._hb_thread.start()
 
     # ------------------------------------------------------------------
+
+    def _stage_seconds(self) -> str:
+        """The stages ended so far, as the worker's log lines say them."""
+        return ", ".join(f"{stage} {dur:.2f}s"
+                         for stage, _t0, dur in self._stages.done)
 
     def _seed_namespace(self) -> None:
         import jax
@@ -522,7 +548,10 @@ class DistributedWorker:
         """Resilience counters riding each telemetry snapshot, so the
         coordinator's push-based view (and the postmortem's last
         snapshot) carries them without a status probe."""
-        extra = {"dedup": self._replay.hits, "msgs": self._msg_seen}
+        extra = {"dedup": self._replay.hits, "msgs": self._msg_seen,
+                 # The bring-up's stages reach the coordinator here,
+                 # on the first heartbeat: no frame inside the attach.
+                 "bringup": self._stages.done}
         busy = self._busy
         if busy is not None:
             extra["busy"] = busy[0]
@@ -797,6 +826,10 @@ class DistributedWorker:
         # dup/crc-reject counts from here.
         data["xfer"] = self._xfer.status()
         data["orphan_ttl_s"] = self._orphan_ttl
+        # Set-up's account (ISSUE 37): this rank's bring-up stages and
+        # what its compiles so far were made of.
+        data["bringup"] = {"stages": self._stages.done,
+                           "compile": obs_telemetry.compile_split()}
         # Gateway pools: which tenants have materialized a namespace on
         # this rank, and the shared segment's size.
         if self._tenant_ns:
@@ -1238,27 +1271,36 @@ class DistributedWorker:
         if len(local) > 1 and n_kv and n_kv % len(local) == 0:
             from ..parallel.mesh import make_mesh
             kw["mesh"] = make_mesh({"tp": len(local)}, devices=local)
+        t_build = time.time()
         try:
-            server = DecodeServer(
-                ns[pname], ns[cname],
-                max_batch=int(data.get("max_batch") or 8),
-                max_len=int(data.get("max_len") or 512),
-                pad_to=int(data.get("pad_to") or 16),
-                eos_id=data.get("eos_id"),
-                temperature=float(data.get("temperature") or 0.0),
-                **kw)
-            step_kernels = server.step_kernels()
+            with obs_spans.phase("serve/open/build"):
+                server = DecodeServer(
+                    ns[pname], ns[cname],
+                    max_batch=int(data.get("max_batch") or 8),
+                    max_len=int(data.get("max_len") or 512),
+                    pad_to=int(data.get("pad_to") or 16),
+                    eos_id=data.get("eos_id"),
+                    temperature=float(data.get("temperature") or 0.0),
+                    **kw)
+            t_kernels = time.time()
+            with obs_spans.phase("serve/open/kernels"):
+                step_kernels = server.step_kernels()
         except Exception as e:
             return msg.reply(data={"error": f"DecodeServer build "
                                             f"failed: {e}"},
                              rank=self.rank)
         self._serve[tenant] = _WorkerServe(server)
         self._publish_serve_snap()
+        build_s = round(t_kernels - t_build, 6)
+        kernels_s = round(time.time() - t_kernels, 6)
         self._flight.record("serve_open", tenant=tenant,
-                            max_batch=server._B, max_len=server._T)
+                            max_batch=server._B, max_len=server._T,
+                            build_s=build_s, kernels_s=kernels_s)
         return msg.reply(data={"status": "open", "slots": server._B,
                                "step_kernels": step_kernels,
-                               "kv_view_bytes": server.kv_view_bytes},
+                               "kv_view_bytes": server.kv_view_bytes,
+                               "build_s": build_s,
+                               "kernels_s": kernels_s},
                          rank=self.rank)
 
     def _handle_serve_step(self, msg: Message) -> Message:
@@ -1879,6 +1921,10 @@ class DistributedWorker:
 
 
 def main(argv: list[str] | None = None) -> int:
+    # `interpreter` ends here: Python's start and this package's own
+    # imports, from the instant the spawner's Popen made the process.
+    stages = bringup.Stages("interpreter", bringup.process_start_time())
+    stages.enter("import_jax")
     p = argparse.ArgumentParser(description="nbdistributed_tpu worker")
     p.add_argument("--rank", type=int, required=True)
     p.add_argument("--world-size", type=int, required=True)
@@ -1912,7 +1958,7 @@ def main(argv: list[str] | None = None) -> int:
         backend=args.backend, dist_host=args.dist_host, gate=gate,
         # NBD_FAULT_PLAN (JSON spec): deterministic fault injection
         # from process start — how CI chaos tests seed a worker.
-        fault_plan=FaultPlan.from_env())
+        fault_plan=FaultPlan.from_env(), stages=stages)
     try:
         worker.run()
     finally:
