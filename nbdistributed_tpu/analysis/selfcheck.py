@@ -711,8 +711,15 @@ def _protocol_planes(root: str) -> list[dict]:
                             "send_to_all": 0, "post": 1, "submit": 1}),
          "handled": _handled_types(root, worker_rx)},
         {"name": "worker-notice",
+         # The serving plane registers a sink on the coordinator's
+         # notify plane (add_notify_callback) for the worker's
+         # serve_emit frames: a receiver of this plane like the
+         # coordinator's own dispatch.
          "sent": _constructed_types(root, worker_rx),
-         "handled": _handled_types(root, coord_rx)},
+         "handled": {**_handled_types(root, coord_rx),
+                     **_handled_types(
+                         root, "nbdistributed_tpu/gateway/serving.py",
+                         cls="ServingManager")}},
         {"name": "tenant",
          # router.py is in the sender list (ISSUE 16): today it sends
          # only through client.py's admin helpers, but a direct send

@@ -21,7 +21,11 @@ the continuous-batching engine.  This module connects them:
   top) carrying that rank's admissions/releases and a step budget;
   the worker runs the admissions plus up to ``steps`` decode steps on
   its :class:`DecodeServer` and replies with per-request emissions at
-  explicit offsets.  With several decode ranks the steps are
+  explicit offsets; between two steps it sends what it has fetched
+  as an unsolicited ``serve_emit`` frame, which the *applier* thread
+  here applies while the tick is still running, so a stream hears
+  every step and not every tick (ISSUE 38; see
+  :meth:`ServingManager._apply_emitted`).  With several decode ranks the steps are
   pre-submitted through the ISSUE 14 submission/completion split so
   the ranks decode concurrently — continuous batching across the
   whole slice (ISSUE 17), each request living entirely on ONE rank so
@@ -63,6 +67,13 @@ counters; helpers suffixed ``_locked`` assert their callers hold it
 appends) happens OUTSIDE the lock; the journal serializes its file
 writes with its own lock and is always acquired under the manager
 lock-free path or strictly after ``self._lock`` (acyclic order).
+A stream has two sources, a tick's frames and its reply, and while an
+applier runs one writer: the driver hands the reply to the applier and
+waits for it (``_hand_to_applier``).  ``self._emit_lock`` still makes
+one request's merge, journal line, extension and push one critical
+section, for where both threads do write (an applier that has ended
+under a waiting driver); it is taken before ``self._lock`` and never
+under it.
 """
 
 from __future__ import annotations
@@ -93,6 +104,23 @@ REJECTED_V = "rejected"
 FAILED = "failed"
 
 SERVE_JOURNAL_NAME = "serve-{tenant}.jsonl"
+
+# Documented exemptions for the thread-shared-state self-lint
+# (analysis/selfcheck.py).
+_LINT_SINGLE_WRITER = {
+    "ServingManager._frames":
+        "a deque between two threads: the comm's IO thread appends, "
+        "the applier alone pops (both GIL-atomic); taking the manager "
+        "lock on the IO thread would make every worker's frames wait "
+        "for a tick's bookkeeping",
+    "ServingManager._applier_busy":
+        "written by the applier thread alone; the driver reads a "
+        "GIL-atomic float once a tick",
+    "ServingManager._replies_waiting":
+        "written by the driver thread alone, around its wait for the "
+        "applier; the applier reads a GIL-atomic int between two "
+        "requests",
+}
 
 # A migrated tenant's journal records, staged by ``tenant_import``
 # (ISSUE 16) for the destination's serving plane to adopt at its next
@@ -184,6 +212,41 @@ def merge_emission(have: int, base: int, offset: int,
     if skip >= len(toks):
         return [], len(toks)
     return list(toks[skip:]), skip
+
+
+def merge_frames(batch) -> dict[tuple, dict]:
+    """Coalesce what is queued for the applier, ``(rank, data)`` in
+    arrival order, into one emission a request a tick: ``{(rank, seq):
+    {"emitted": {rid: {"o", "t"}}, "now": newest worker stamp,
+    "replies": [...]}}``.  ``data`` is a ``serve_emit`` frame's, or a
+    tick's reply handed over by the driver (``data["reply"]`` is set:
+    it is listed under ``replies``, and its group is applied as a reply
+    is).  A piece that touches what its request has so far is joined
+    to it, on either side (a reply starts where its tick began, before
+    its frames); one that would leave a hole (the frame between was
+    lost) is left to the tick's reply, which repeats it."""
+    out: dict[tuple, dict] = {}
+    for rank, data in batch:
+        m = out.setdefault((rank, data.get("seq")),
+                           {"emitted": {}, "now": None, "replies": []})
+        m["now"] = data.get("now")
+        if data.get("reply") is not None:
+            m["replies"].append(data)
+        for rid, em in (data.get("emitted") or {}).items():
+            o, toks = int(em.get("o") or 0), list(em.get("t") or ())
+            cur = m["emitted"].get(rid)
+            if cur is None:
+                m["emitted"][rid] = {"o": o, "t": toks}
+                continue
+            end = cur["o"] + len(cur["t"])
+            if o > end or o + len(toks) < cur["o"]:
+                continue
+            if o + len(toks) > end:
+                cur["t"].extend(toks[max(0, end - o):])
+            if o < cur["o"]:
+                cur["t"][:0] = toks[:cur["o"] - o]
+                cur["o"] = o
+    return out
 
 
 class ServeJournal:
@@ -301,7 +364,7 @@ class _Req:
                  "ticket", "released", "submitted_ts", "finished_ts",
                  "resumes", "stream_resumed", "error",
                  "placed_ts", "first_tok_ts", "last_emit_ts",
-                 "first_batch", "rank")
+                 "first_batch", "rank", "framed")
 
     def __init__(self, rid: str, tenant: str, prompt: list[int],
                  max_new: int, priority: int, ticket):
@@ -315,6 +378,9 @@ class _Req:
         self.base = 0                  # stream offset of current placement
         self.placed = False            # admitted to a decode rank
         self.rank: int | None = None   # which decode rank holds it
+        # Tokens frames delivered since the last reply was applied:
+        # what the tick's own reply will repeat, and no redelivery.
+        self.framed = 0
         self.replay = False            # next admit is a journal replay
         self.released = False          # host-side record freed worker-side
         self.ticket = ticket
@@ -515,6 +581,30 @@ class ServingManager:
         self._seq = 0
         self._idled = True
         self._apply_s = {"journal": 0.0, "notify": 0.0}
+        # Emission a step (ISSUE 38).  The comm's IO thread queues
+        # ``serve_emit`` frames (``_on_frame``); the applier thread
+        # drains all that has arrived, merges it a request and applies
+        # it as a reply's emissions are applied, and the driver hands
+        # it each tick's reply; ``_emit_lock`` makes one request's
+        # application one critical section whoever runs it.
+        # ``_pushed`` (under ``_lock``):
+        # rank -> [tokens a frame delivered first, tokens applied,
+        # pushes to clients], taken by the driver at each tick's end.  ``_applier_busy``: seconds the
+        # applier spent applying, written by it alone; the driver
+        # hands each tick the seconds since the tick before
+        # (``_applier_seen``).
+        self._frames: deque = deque()
+        self._frames_wake = threading.Event()
+        self._emit_lock = threading.Lock()
+        self._applier: threading.Thread | None = None
+        self._pushed: dict[int, list[int]] = {}
+        self._applier_busy = 0.0
+        self._applier_seen = 0.0
+        # Replies the driver has handed to the applier and waits for
+        # (driver thread only): while there is one, the applier leaves
+        # a pass of frames where it stands, since the reply repeats
+        # every token of them.
+        self._replies_waiting = 0
 
     def _slo_hist(self, name: str, help: str, tenant: str):
         """Per-SUBMITTING-tenant SLO histogram, resolved through the
@@ -557,6 +647,14 @@ class ServingManager:
                 if err:
                     raise RuntimeError(
                         f"model spec failed on rank {r}: {err}")
+        if hasattr(self.comm, "add_notify_callback"):
+            # A comm without the sink (the unit tests' fakes) hears
+            # no frame: every token then arrives with its tick's reply.
+            self.comm.add_notify_callback(self._on_frame)
+            self._applier = threading.Thread(
+                target=self._run_applier,
+                name=f"nbd-serve-emit-{self.tenant}", daemon=True)
+            self._applier.start()
         self._driver = threading.Thread(target=self._run,
                                         name=f"nbd-serve-{self.tenant}",
                                         daemon=True)
@@ -737,9 +835,15 @@ class ServingManager:
     def stop(self, *, close_workers: bool = True) -> None:
         self._stop.set()
         self._wake.set()
+        self._frames_wake.set()
         d = self._driver
         if d is not None and d is not threading.current_thread():
             d.join(timeout=max(5.0, self.step_timeout + 5.0))
+        a = self._applier
+        if a is not None:
+            self.comm.remove_notify_callback(self._on_frame)
+            if a is not threading.current_thread():
+                a.join(timeout=5.0)
         if close_workers:
             try:
                 self.comm.post(self._live_ranks(), "serve_close",
@@ -1276,6 +1380,7 @@ class ServingManager:
             r.rank = best
             r.base = len(r.tokens)
             r.placed = True
+            r.framed = 0
             pf_chunk = self.prefill_chunk or self.max_len
             self.obs.note_placed(
                 r.rid, best, kv_alloc_s=kv_alloc_s, need_blocks=need,
@@ -1325,8 +1430,15 @@ class ServingManager:
         it is the span ``serve/tick``; its phases (place, roundtrip,
         apply, util) are child spans and, always, seconds on
         perf_counter that telescope, handed to the observatory with
-        the worker's own account of the same tick."""
-        seq = self._seq = self._seq + 1     # driver thread only
+        the worker's own account of the same tick.  A tick is
+        ``steps`` decode steps between two admissions and two
+        replies; its tokens reach the streams a step at a time, by
+        the frames the applier applies during ``roundtrip``, and the
+        reply's ``apply`` finds only the last step's still to
+        deliver."""
+        # Written by the driver alone; the applier reads it under the
+        # lock the tick's placements are made under.
+        seq = self._seq = self._seq + 1
         with obs_spans.phase("serve/tick", seq):
             self._tick_phases(seq)
 
@@ -1401,14 +1513,20 @@ class ServingManager:
             self._note_tick_util(ticks, replies)
             self._update_kv_gauges()
         t4 = time.perf_counter()
+        busy = self._applier_busy
         gw = {"place": t1 - t0, "roundtrip": t2 - t1, "apply": t3 - t2,
-              "util": t4 - t3,
+              "util": t4 - t3, "applier": busy - self._applier_seen,
               **{k: v - apply0[k] for k, v in self._apply_s.items()}}
+        self._applier_seen = busy
+        with self._lock:
+            pushed = {rank: self._pushed.pop(rank, None)
+                      for rank in ticks}
         for rank in ticks:
             tk = (replies.get(rank) or {}).get("tick") or {}
             if tk.get("seq") != seq:
                 continue        # refused, or a worker without the account
-            slow = self.obs.note_tick(seq, rank, gw, tk, idled=idled)
+            slow = self.obs.note_tick(seq, rank, gw, tk, idled=idled,
+                                      pushed=pushed[rank])
             if slow is not None:
                 self._record("serve_slow_tick", **slow)
         if lost:
@@ -1600,16 +1718,15 @@ class ServingManager:
 
     def _apply_reply(self, data: dict,
                      rank: int | None = None) -> None:
-        reg = obs_metrics.registry()
-        emitted = data.get("emitted") or {}
+        """Apply one tick's reply: prefill progress, per-request
+        errors, and the emissions, which repeat what the tick's
+        frames delivered and are dropped by offset where they do."""
         errors = data.get("errors") or {}
         # ISSUE 18 tick telemetry: the worker's wall clock at reply
         # time (clock-corrected per rank inside the observatory), the
         # tick's compute time, and per-request chunked-prefill
         # progress.
         tick = data.get("tick") or {}
-        t_worker = tick.get("now")
-        step_s = float(tick.get("step_s") or 0.0)
         pf_chunk = max(1, self.prefill_chunk or self.max_len)
         for rid, wn in (data.get("pfp") or {}).items():
             try:
@@ -1623,93 +1740,256 @@ class ServingManager:
                 req = self._reqs.get(rid)
             if req is not None and req.state == ACCEPTED:
                 self._finish(req, FAILED, error=str(err))
+        emitted = data.get("emitted") or {}
+        step_s = float(tick.get("step_s") or 0.0)
+        if not self._hand_to_applier(rank, tick, emitted, step_s):
+            self._apply_emitted(emitted, rank, t_worker=tick.get("now"),
+                                step_s=step_s)
+
+    def _hand_to_applier(self, rank, tick: dict, emitted: dict,
+                         step_s: float) -> bool:
+        """Have the applier apply a reply's emissions and wait for it:
+        with one thread writing the streams the reply never contends
+        with its own tick's frames, what is still queued of them is
+        merged into it (one pass, not two), and the pass the applier is
+        in is cut short (``_replies_waiting``).  False where there is
+        no applier (or it has ended): the driver applies them itself."""
+        applier = self._applier
+        if applier is None or not applier.is_alive():
+            return False
+        item = {"seq": tick.get("seq"), "emitted": emitted,
+                "now": tick.get("now"), "step_s": step_s,
+                "reply": threading.Event()}
+        self._replies_waiting += 1
+        try:
+            self._frames.append((rank, item))
+            self._frames_wake.set()
+            while not item["reply"].wait(0.2):
+                if not applier.is_alive():
+                    # stopping: by offset, applying again is harmless
+                    return False
+        finally:
+            self._replies_waiting -= 1
+        if "error" in item:
+            raise item["error"]     # as if the driver had applied it
+        return True
+
+    def _on_frame(self, rank: int, msg) -> None:
+        """The comm's sink for unsolicited messages, on its IO thread:
+        queue a ``serve_emit`` frame of this serving tenant for the
+        applier and do no work here.  A frame of an older session
+        epoch (a rank still living in a tenancy this coordinator has
+        replaced) is dropped like a stale reply."""
+        data = msg.data or {}
+        epoch = getattr(self.comm, "session_epoch", 0)
+        if (msg.msg_type == "serve_emit" and not self._stop.is_set()
+                and data.get("tenant") == self.tenant
+                and not (msg.epoch is not None and epoch
+                         and msg.epoch < epoch)):
+            self._frames.append((rank, data))
+            self._frames_wake.set()
+
+    def _run_applier(self) -> None:
+        """The applier thread: apply the frames that have arrived,
+        all of them at once.  While it keeps up, that is one frame and
+        a stream hears every step; while it does not (many rows a
+        step), the frames queued meanwhile are one merged emission a
+        request and one push, so it never does more work than it has
+        time for."""
+        while not self._stop.is_set():
+            self._frames_wake.wait(timeout=1.0)
+            self._frames_wake.clear()
+            try:
+                self._drain_frames()
+            except Exception as e:      # never kill the applier
+                self._record("serve_applier_error",
+                             error=f"{type(e).__name__}: {e}")
+
+    def _drain_frames(self) -> None:
+        """Apply everything queued: a tick's frames merged a request,
+        and with them a reply the driver handed over, whose group is
+        applied as a reply is.  The seconds spent on frames alone are
+        the applier's (``applier``); a reply's are the tick's
+        ``apply``, where the driver waits.  Whatever fails, a waiting
+        driver is released, with the failure to raise as if it had
+        applied the reply itself; a frame's failure is the caller's to
+        record, and the tick's reply repeats its tokens."""
+        batch = []
+        while self._frames:
+            batch.append(self._frames.popleft())
+        handed = [data for _rank, data in batch
+                  if data.get("reply") is not None]
+        try:
+            for (rank, seq), m in merge_frames(batch).items():
+                t0 = time.perf_counter()
+                replies = m["replies"]
+                self._apply_emitted(
+                    m["emitted"], rank, t_worker=m["now"],
+                    step_s=sum(r["step_s"] for r in replies),
+                    frame_seq=None if replies else seq)
+                for r in replies:
+                    r["reply"].set()
+                if not replies:
+                    self._applier_busy += time.perf_counter() - t0
+        except Exception as e:
+            for r in handed:
+                if not r["reply"].is_set():
+                    r["error"] = e
+            raise
+        finally:
+            for r in handed:
+                r["reply"].set()
+
+    def _apply_emitted(self, emitted: dict, rank: int | None, *,
+                       t_worker: float | None = None,
+                       step_s: float = 0.0,
+                       frame_seq: int | None = None) -> None:
+        """Merge emissions into their streams: journal, extend, push,
+        a request at a time, each under ``_emit_lock``: from reading
+        how much the stream holds to pushing what was new is one
+        critical section, so two threads applying (a reply's
+        emissions; frames', ``frame_seq`` = their tick) can never
+        journal or push one token twice, and pushes leave in stream
+        order.  Offsets decide, whatever the source; a token is
+        journaled before it is pushed.  A pass of frames ends where a
+        reply starts to wait (``_replies_waiting``): the reply repeats
+        what is left of it.
+
+        A frame is applied only for a request this tick's placement
+        holds on the frame's rank (``frame_seq`` is the tick in
+        flight, read under the lock that placements are made under:
+        a frame that outlived its tick, its rank or its request
+        changes nothing), and one that would leave a hole (an earlier
+        frame was lost) waits for the reply.  What a reply repeats of
+        its own tick's frames (``req.framed``) is no redelivery and
+        stays out of ``nbd_serve_dup_dropped_total``; a hole in a
+        reply still fails the request loudly."""
         for rid, em in emitted.items():
-            t_em0 = time.perf_counter()
-            with self._lock:
-                req = self._reqs.get(rid)
-                if req is None or req.state != ACCEPTED:
-                    continue
-                have = len(req.tokens)
-                base = req.base
-            new, dup = merge_emission(have, base,
-                                      int(em.get("o") or 0),
-                                      list(em.get("t") or ()))
-            if new is None:
-                # A gap would corrupt the stream: fail the request
-                # loudly rather than journal around a hole.
-                self._finish(req, FAILED,
-                             error="emission gap (protocol bug): "
-                                   f"offset {base + int(em.get('o') or 0)} "
-                                   f"past stream length {have}")
-                continue
+            if frame_seq is not None and self._replies_waiting:
+                return      # the reply repeats the rest
+            with self._emit_lock:
+                self._apply_one(rid, em, rank, t_worker, step_s,
+                                frame_seq)
+
+    def _apply_one(self, rid: str, em: dict, rank: int | None,
+                   t_worker: float | None, step_s: float,
+                   frame_seq: int | None) -> None:
+        """One request's part of :meth:`_apply_emitted`, under
+        ``_emit_lock``."""
+        reg = obs_metrics.registry()
+        from_frame = frame_seq is not None
+        rank_key = rank if rank is not None else 0
+        t_em0 = time.perf_counter()
+        with self._lock:
+            req = self._reqs.get(rid)
+            if req is None or req.state != ACCEPTED:
+                return
+            if from_frame and not (
+                    frame_seq == self._seq and req.placed
+                    and req.rank == rank
+                    and rank in self._open):
+                return
+            have = len(req.tokens)
+            base = req.base
+            framed = req.framed
+            if not from_frame:
+                req.framed = 0
+        new, dup = merge_emission(have, base, int(em.get("o") or 0),
+                                  list(em.get("t") or ()))
+        if new is None:
+            if from_frame:
+                return
+            # A gap would corrupt the stream: fail the request
+            # loudly rather than journal around a hole.
+            self._finish(req, FAILED,
+                         error="emission gap (protocol bug): "
+                               f"offset {base + int(em.get('o') or 0)} "
+                               f"past stream length {have}")
+            return
+        if not from_frame:
+            self.obs.note_decode(rid, step_s)
+            dup -= min(dup, framed)
             if dup:
                 with self._lock:
                     self.dup_dropped += dup
                 reg.counter(
                     "nbd_serve_dup_dropped_total",
-                    "tokens dropped by offset dedup (replayed or "
-                    "redelivered emissions) — exactly-once delivery's "
-                    "receipt", {"tenant": self.tenant}).inc(dup)
-            if not new:
-                continue
-            t_j0 = time.perf_counter()
-            self.journal.emit(rid, have, new)
+                    "tokens dropped by offset dedup (replayed "
+                    "or redelivered emissions) — exactly-once "
+                    "delivery's receipt",
+                    {"tenant": self.tenant}).inc(dup)
+        if not new:
+            return
+        t_j0 = time.perf_counter()
+        self.journal.emit(rid, have, new)
+        if not from_frame:
             self._apply_s["journal"] += time.perf_counter() - t_j0
-            now = time.time()
-            with self._lock:
-                req.tokens.extend(new)
-                self.tokens_total += len(new)
-                done = (len(req.tokens) >= req.max_new
-                        or (self.eos_id is not None
-                            and self.eos_id in new))
-                offset = have
-                first = req.first_tok_ts is None
-                if first:
-                    req.first_tok_ts = now
-                    req.first_batch = len(new)
-                    ttft = now - req.submitted_ts
-                else:
-                    gap = ((now - req.last_emit_ts) / len(new)
-                           if req.last_emit_ts is not None else None)
-                req.last_emit_ts = now
-            # Stage attribution (ISSUE 18): arrival + worker stamp
-            # (clock-corrected inside), the tick's decode compute,
-            # and the gateway's own emit-handling time so far.
-            self.obs.note_emission(
-                rid, rank if rank is not None else 0, len(new),
-                t_recv=now, t_worker=t_worker,
-                emit_s=time.perf_counter() - t_em0)
-            self.obs.note_decode(rid, step_s)
-            # SLO observations (outside the lock; per-SUBMITTING-
-            # tenant labels so eviction retires the series).
+        now = time.time()
+        with self._lock:
+            req.tokens.extend(new)
+            self.tokens_total += len(new)
+            if from_frame:
+                req.framed += len(new)
+            acc = self._pushed.setdefault(rank_key, [0, 0, 0])
+            acc[0] += len(new) if from_frame else 0
+            acc[1] += len(new)
+            acc[2] += 1
+            done = (len(req.tokens) >= req.max_new
+                    or (self.eos_id is not None
+                        and self.eos_id in new))
+            offset = have
+            first = req.first_tok_ts is None
             if first:
-                self._slo_hist(
-                    "nbd_serve_ttft_seconds",
-                    "serving time-to-first-token (submit → first "
-                    "emission delivered to the gateway)",
-                    req.tenant).observe(ttft)
-            elif gap is not None:
-                # Mean per-token gap of this emission batch — the
-                # inter-emission latency the client actually sees.
-                self._slo_hist(
-                    "nbd_serve_tpot_seconds",
-                    "serving per-token inter-emission latency",
-                    req.tenant).observe(gap)
-            reg.counter("nbd_serve_tokens_total",
-                        "generated tokens delivered",
-                        {"tenant": self.tenant}).inc(len(new))
-            if done:
-                self._finish(req, COMPLETED)
+                req.first_tok_ts = now
+                req.first_batch = len(new)
+                ttft = now - req.submitted_ts
             else:
-                t_n0 = time.perf_counter()
-                self._notify_tokens(req, offset, new)
+                gap = ((now - req.last_emit_ts) / len(new)
+                       if req.last_emit_ts is not None else None)
+            req.last_emit_ts = now
+        # Stage attribution (ISSUE 18): arrival + worker stamp
+        # (clock-corrected inside), the tick's decode compute (a
+        # reply's, above), and the gateway's own emit-handling time
+        # so far.
+        self.obs.note_emission(
+            rid, rank_key, len(new), t_recv=now, t_worker=t_worker,
+            emit_s=time.perf_counter() - t_em0)
+        # SLO observations (outside the lock; per-SUBMITTING-tenant
+        # labels so eviction retires the series).
+        if first:
+            self._slo_hist(
+                "nbd_serve_ttft_seconds",
+                "serving time-to-first-token (submit → first "
+                "emission delivered to the gateway)",
+                req.tenant).observe(ttft)
+        elif gap is not None:
+            # Mean per-token gap of this emission batch — the
+            # inter-emission latency the client actually sees.
+            self._slo_hist(
+                "nbd_serve_tpot_seconds",
+                "serving per-token inter-emission latency",
+                req.tenant).observe(gap)
+        reg.counter("nbd_serve_tokens_total",
+                    "generated tokens delivered",
+                    {"tenant": self.tenant}).inc(len(new))
+        if done:
+            self._finish(req, COMPLETED, account=not from_frame)
+        else:
+            t_n0 = time.perf_counter()
+            self._notify_tokens(req, offset, new)
+            if not from_frame:
                 self._apply_s["notify"] += time.perf_counter() - t_n0
 
     def _finish(self, req: _Req, status: str,
-                error: str | None = None) -> None:
+                error: str | None = None, *,
+                account: bool = True) -> None:
         """Terminal transition: journal the verdict, free the KV slot
         (promoting queued requests), and deliver the result
-        delivered-or-parked-exactly-once."""
+        delivered-or-parked-exactly-once.  Whoever gets here first
+        (a frame's last tokens, the reply's, a shed) makes it; the
+        others return at the state check.  ``account``: whether the
+        journal's and the delivery's seconds belong to the tick's
+        ``apply`` phase (not from the applier, beside the tick)."""
         slo = None
         with self._lock:
             if req.state != ACCEPTED:
@@ -1765,7 +2045,8 @@ class ServingManager:
                 req.tenant).observe(slo["e2e"])
         t_j0 = time.perf_counter()
         self.journal.done(req.rid, status)
-        self._apply_s["journal"] += time.perf_counter() - t_j0
+        if account:
+            self._apply_s["journal"] += time.perf_counter() - t_j0
         self.sched.complete(req.rid)
         self._wake.set()
         obs_metrics.registry().counter(
@@ -1788,7 +2069,8 @@ class ServingManager:
             self._deliver(req.tenant, reply)
         except Exception:
             pass
-        self._apply_s["notify"] += time.perf_counter() - t_n0
+        if account:
+            self._apply_s["notify"] += time.perf_counter() - t_n0
 
     def _notify_tokens(self, req: _Req, offset: int,
                        toks: list[int]) -> None:

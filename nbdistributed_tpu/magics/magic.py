@@ -1881,7 +1881,13 @@ class DistributedMagics(Magics):
                   f"sync {_p50('sync')} · host {_p50('host')} · "
                   f"turnaround {_p50('turnaround')} ms (p50 of "
                   f"{tk['count']}) · compiles {tk.get('compiles', 0)}"
-                  f" · slow {len(tk.get('slow') or ())}")
+                  f" · slow {len(tk.get('slow') or ())}"
+                  # emission a step (ISSUE 38): the share of the
+                  # tokens a frame delivered before its tick's reply,
+                  # and the steps' tokens a push to a client carried
+                  + (f" · pushed early {tk['pushed_share']:.0%}, "
+                     f"{tk['steps_per_push']:g} steps/push"
+                     if "pushed_share" in tk else ""))
         print(f"   accepted {st.get('accepted', 0)} · completed "
               f"{st.get('completed', 0)} · shed {st.get('shed', 0)} · "
               f"rejected {st.get('rejected', 0)} · replayed "

@@ -47,9 +47,9 @@ from .transport import TransportError
 # (or GIL-atomic mutation) that deliberately skip the lock.
 _LINT_SINGLE_WRITER = {
     "CommunicationManager._notify_callbacks":
-        "registered from the main thread at wiring time only; list "
-        "append is atomic under the GIL and the IO thread only "
-        "iterates",
+        "registered and removed at wiring time only (the daemon's "
+        "serve_start / serve_stop); list append and rebinding are "
+        "atomic under the GIL and the IO thread only iterates",
 }
 
 
@@ -481,6 +481,13 @@ class CommunicationManager:
         """Register a sink for unsolicited non-stream messages
         (heartbeats, profiler events, timeline marks)."""
         self._notify_callbacks.append(cb)
+
+    def remove_notify_callback(self, cb) -> None:
+        """Take a sink off again (a serving plane that stopped).  The
+        list is rebound, not edited: the IO thread may be iterating
+        the old one."""
+        self._notify_callbacks = [c for c in self._notify_callbacks
+                                  if c != cb]
 
     def set_fault_plan(self, plan) -> None:
         """Install (or clear, with ``None``) a chaos

@@ -78,14 +78,22 @@ TICK_RING = 64
 # ``ticks.totals``: decode steps fetched, tokens decoded, prompt tokens
 # prefilled, chunk programs, bytes of state those steps read and wrote,
 # experts touched (a mean over the expert layers, summed over the
-# steps), rows routed a layer (likewise).
+# steps), rows routed a layer (likewise); ``serve_emit`` frames the
+# worker sent and its ``step()`` calls that emitted; tokens a frame
+# delivered before its tick's reply, tokens applied (each carried by
+# one push, and a row gets one token a step: the steps the pushes
+# carried), and pushes to clients.
 TICK_TOTALS = ("steps", "dc", "pf", "chunks", "state_bytes",
-               "moe_touched", "moe_rows")
+               "moe_touched", "moe_rows", "frames", "steps_emitting",
+               "pushed_early", "pushed", "pushes")
 SLOW_TICKS = 8
 SLOW_FACTOR = 3.0
 SLOW_ABS_S = 1.0
 SLOW_MIN_RING = 8
 GATEWAY_PHASES = ("place", "roundtrip", "apply", "util")
+# Beside the phases: what split ``apply`` (journal, notify), and the
+# seconds the frames' applier worked while the tick ran.
+GATEWAY_SPLITS = ("journal", "notify", "applier")
 WORKER_PHASES = ("admit", "prefill", "dispatch", "sync", "emit",
                  "collect")
 # Worker phases that are the host's alone.  ``prefill`` stays apart:
@@ -431,7 +439,8 @@ class ServingObservatory:
 
     def note_tick(self, seq: int, rank: int, gateway: dict,
                   tick: dict, *, idled: bool = False,
-                  t_wall: float | None = None) -> dict | None:
+                  t_wall: float | None = None,
+                  pushed=None) -> dict | None:
         """One tick of one rank: the gateway's phase seconds and the
         worker's ``tick`` block whole, as its ``serve_step`` reply
         carried it (``DecodeServer.take_account`` and the handler's
@@ -454,7 +463,12 @@ class ServingObservatory:
         bytes a kind of K/V; ``st`` = ``[bytes, steps]``: per-row state
         its decode steps read and wrote; ``xdec`` = ``[programs that
         ran the layers past the shared K/V, chunk programs, the shared
-        layer's keys the former attended]``.  Returns the tick's record
+        layer's keys the former attended]``.  ``fr`` = ``[serve_emit
+        frames it sent, step() calls that emitted]`` (absent from a
+        worker that answers once a tick).  ``pushed`` is the
+        gateway's own count for this rank since the tick before:
+        ``[tokens a frame delivered before its tick's reply, tokens
+        applied, pushes to clients]`` (None: no token was applied).  Returns the tick's record
         when it was slow (kept under ``slow``; the caller writes it to
         the flight recorder, once), else None."""
         worker = tick.get("ph") or {}
@@ -470,6 +484,7 @@ class ServingObservatory:
         kv_bytes, kv_steps = tick.get("kvr") or (0, 0)
         pf_keys, pf_chunks = tick.get("pfk") or (0, 0)
         ahead, fetched = tick.get("ahd") or (0, 0)
+        frames, emitting = tick.get("fr") or (0, 0)
         rec = {
             "seq": int(seq), "rank": int(rank),
             "t_wall": round(self._now() if t_wall is None else t_wall,
@@ -478,6 +493,8 @@ class ServingObservatory:
             "kvr": [int(kv_bytes), int(kv_steps)],
             "pfk": [int(pf_keys), int(pf_chunks)],
             "ahd": [int(ahead), int(fetched)],
+            "fr": [int(frames), int(emitting)],
+            "pushed": [int(v) for v in pushed or (0, 0, 0)],
             "moe": None if moe is None else [float(v) for v in moe],
             "kvk": {k: int(v) for k, v in (tick.get("kvk") or {}).items()},
             "st": [int(v) for v in tick.get("st") or (0, 0)],
@@ -502,7 +519,11 @@ class ServingObservatory:
                          ("pf", tick.get("pf")), ("chunks", pf_chunks),
                          ("state_bytes", rec["st"][0]),
                          ("moe_touched", moe and moe[0]),
-                         ("moe_rows", moe and moe[2])):
+                         ("moe_rows", moe and moe[2]),
+                         ("frames", frames),
+                         ("steps_emitting", emitting),
+                         *zip(("pushed_early", "pushed", "pushes"),
+                              rec["pushed"])):
                 self._totals[k] += float(v or 0)
             span = rec["period"] if rec["period"] is not None \
                 else handler
@@ -573,6 +594,21 @@ class ServingObservatory:
             out["cross_decoder_share"] = round(
                 sum(t["xdec"][0] for t in ticks)
                 / max(1, sum(t["xdec"][1] for t in ticks)), 4)
+        pushes = sum(t["pushed"][2] for t in ticks)
+        if pushes:
+            # of the tokens applied, the share a frame delivered
+            # before its tick's reply (0 where the worker answers once
+            # a tick); the steps' tokens a push to a client carried
+            # (the tick's steps on that path, 1 where a stream hears
+            # every step); frames the workers sent and their step()
+            # calls that emitted
+            out["pushed_share"] = round(
+                sum(t["pushed"][0] for t in ticks)
+                / max(1, sum(t["pushed"][1] for t in ticks)), 4)
+            out["steps_per_push"] = round(
+                sum(t["pushed"][1] for t in ticks) / pushes, 3)
+            out["frames"] = [sum(t["fr"][0] for t in ticks),
+                             sum(t["fr"][1] for t in ticks)]
         routed = [t for t in ticks if t["moe"] is not None]
         if routed:
             # a decode step's routing load: means over the steps, the
@@ -593,7 +629,7 @@ class ServingObservatory:
             out["period_ms"] = {"p50": st["p50"], "p99": st["p99"]}
         for k in WORKER_PHASES:
             out[k] = _stats([t["wk"][k] for t in ticks])
-        for k in GATEWAY_PHASES + ("journal", "notify"):
+        for k in GATEWAY_PHASES + GATEWAY_SPLITS:
             out[k] = _stats([t["gw"].get(k, 0.0) for t in ticks])
         out["host"] = _stats([sum(t["wk"][k] for k in HOST_PHASES)
                               for t in ticks])

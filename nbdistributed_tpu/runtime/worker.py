@@ -93,9 +93,10 @@ class _WorkerServe:
     per-request emission cursors the ``serve_step`` protocol needs.
 
     ``sent[rid]`` is how many of the server's output tokens for that
-    request have ALREADY been put in a reply — each step reply carries
-    only the suffix beyond it, tagged with its offset, which is what
-    lets the gateway dedup replayed/redelivered emissions exactly.
+    request have ALREADY left this rank, in a ``serve_emit`` frame or
+    a reply — each emission carries a suffix tagged with its offset,
+    which is what lets the gateway dedup replayed, redelivered and
+    repeated emissions exactly.
     """
 
     __slots__ = ("server", "rids", "sent", "tokens_total", "window",
@@ -112,6 +113,21 @@ class _WorkerServe:
         # ``turnaround``, the time the chip's owner waited for the
         # gateway and the wire (None before the first tick).
         self.t_reply: float | None = None
+
+    def take_new(self) -> dict[str, dict]:
+        """What the server's outputs hold beyond ``sent``, a request at
+        its offset (``{rid: {"o": offset, "t": [tokens]}}``); ``sent``
+        advances past it."""
+        outputs = self.server.outputs
+        emitted: dict[str, dict] = {}
+        for rid, local in self.rids.items():
+            out = outputs.get(local, ())
+            o = self.sent.get(rid, 0)
+            if len(out) > o:
+                emitted[rid] = {"o": o, "t": [int(t) for t in out[o:]]}
+                self.tokens_total += len(out) - o
+                self.sent[rid] = len(out)
+        return emitted
 
     def note_rate(self) -> None:
         now = time.monotonic()
@@ -1307,14 +1323,27 @@ class DistributedWorker:
         """One decode tick: admit new requests, call the server's
         ``step()`` up to ``steps`` times, reply with per-request
         emissions AT OFFSETS.  The server keeps one decode step in
-        flight, and it outlives this handler: the reply carries the
-        tokens fetched so far, the chip works on the step dispatched
-        last while the reply and the next ``serve_step`` travel, and
-        the next tick's first ``step()`` fetches it (a server's first
-        tick emits a token a row less than it dispatched, every later
-        one as many).  ``release`` frees finished requests' host-side
-        records.  The reply is cached by the replay cache like any
-        mutating request, so a redelivered tick never decodes twice."""
+        flight, and it outlives this handler: the chip works on the
+        step dispatched last while the reply and the next
+        ``serve_step`` travel, and the next tick's first ``step()``
+        fetches it (a server's first tick emits a token a row less
+        than it dispatched, every later one as many).
+
+        **A stream hears each step** (ISSUE 38): before every
+        ``step()`` call the tokens not yet sent (what the step before
+        fetched; the first token of a prompt admitted whole) leave in
+        one unsolicited ``serve_emit`` frame
+        (:meth:`_send_serve_frame`), whatever the rows, on the
+        connection the reply will use; the last step's leave with the
+        reply.  The reply stays what it
+        was and stays authoritative: every token of the tick, from the
+        offset each request had when the tick began (``mark``), so a
+        lost frame costs a stream nothing but the earlier push, and
+        the gateway drops by offset what the frames delivered.
+        ``release`` frees finished requests' host-side records.  The
+        reply is cached by the replay cache like any mutating request,
+        so a redelivered tick never decodes twice and still carries
+        every token of the tick."""
         data = msg.data or {}
         tenant = data.get("tenant") or msg.tenant
         st = self._serve.get(tenant)
@@ -1363,24 +1392,31 @@ class DistributedWorker:
                         except Exception:
                             pass
         steps = max(0, int(data.get("steps") or 0))
+        # Where each request's stream stood when the tick began: the
+        # reply emits from here, the frames from ``st.sent``.
+        mark = dict(st.sent)
+        frames = emitting = 0
         t_step0 = time.perf_counter()
         busy1 = sum(srv.phase_s.values())
         for _ in range(steps):
             if srv.done():
                 break
-            srv.step()
+            # What the steps so far fetched (and a prompt's first
+            # token) leaves before the host blocks on the next; the
+            # last step's tokens leave with the reply.
+            frames += self._send_serve_frame(st, tenant, seq)
+            emitting += bool(srv.step())
         step_s = time.perf_counter() - t_step0
         with obs_spans.phase("serve/step/collect", seq):
+            st.take_new()
             emitted: dict[str, dict] = {}
             finished: list[str] = []
             for rid, local in st.rids.items():
                 out = srv.outputs.get(local, [])
-                o = st.sent.get(rid, 0)
+                o = mark.get(rid, 0)
                 if len(out) > o:
                     emitted[rid] = {"o": o,
                                     "t": [int(t) for t in out[o:]]}
-                    st.tokens_total += len(out) - o
-                    st.sent[rid] = len(out)
                 if local in srv.finished:
                     finished.append(rid)
             st.note_rate()
@@ -1398,6 +1434,8 @@ class DistributedWorker:
                 "seq": seq,
                 "cmp": [cmp1[0] - cmp0[0],
                         round(cmp1[1] - cmp0[1], 3)],
+                # frames sent, step() calls that emitted tokens
+                "fr": [frames, emitting],
                 **srv.take_account()}
         ph = tick["ph"]
         ph["admit"] = (t_step0 - t_in) - (busy1 - busy0)
@@ -1417,6 +1455,27 @@ class DistributedWorker:
                   "pending": len(srv._pending),
                   "tick": tick, "pfp": pfp},
             rank=self.rank)
+
+    def _send_serve_frame(self, st: _WorkerServe, tenant: str,
+                          seq) -> bool:
+        """Send what the server has emitted and no frame or reply has
+        carried yet as one unsolicited ``serve_emit`` frame: ``{tenant,
+        seq, emitted: {rid: {"o", "t"}}, now}``.  Best effort: the
+        tick's reply repeats every token of the tick, so a frame that
+        cannot be sent is only a later push.  Returns whether there
+        was anything to send."""
+        emitted = st.take_new()
+        if not emitted:
+            return False
+        try:
+            self._send_shielded(Message(
+                msg_type="serve_emit", rank=self.rank, tenant=tenant,
+                epoch=self._epoch or None,
+                data={"tenant": tenant, "seq": seq, "emitted": emitted,
+                      "now": time.time()}))
+        except Exception:
+            pass
+        return True
 
     def _handle_serve_close(self, msg: Message) -> Message:
         tenant = (msg.data or {}).get("tenant") or msg.tenant

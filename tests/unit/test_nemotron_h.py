@@ -41,7 +41,8 @@ from nbdistributed_tpu.models import (DecodeServer, NemotronHConfig,
                                       tiny_nemotron_h_config)
 from nbdistributed_tpu.models.hybrid import cache_bytes_by_kind
 from nbdistributed_tpu.models.nemotron_h import Mamba2Mixer, check_pattern
-from nbdistributed_tpu.observability.servingobs import ServingObservatory
+from nbdistributed_tpu.observability.servingobs import (TICK_TOTALS,
+                                                        ServingObservatory)
 from nbdistributed_tpu.parallel.expert import (_dropless_ffn, routing_load,
                                                shared_routed_ffn,
                                                sigmoid_bias_routing)
@@ -486,13 +487,18 @@ def test_tick_totals_outlast_the_ring_and_two_readings_give_a_slice():
             "moe": [500.0, 9.0, 3000.0]}
     for seq in range(70):                   # the ring holds 64
         obs.note_tick(seq, 0, {}, tick)
+    # (what these ticks do not carry, the frames' and the pushes'
+    # counts of PR 38, stays 0)
+    zero = dict.fromkeys(TICK_TOTALS, 0.0)
     first = obs.ticks_summary()["totals"]
-    assert first == {"steps": 560.0, "dc": 7000.0, "pf": 49000.0,
+    assert first == {**zero,
+                     "steps": 560.0, "dc": 7000.0, "pf": 49000.0,
                      "chunks": 140.0, "state_bytes": 70000.0,
                      "moe_touched": 35000.0, "moe_rows": 210000.0}
     obs.note_tick(70, 0, {}, {**tick, "moe": None})
     second = obs.ticks_summary()["totals"]
     assert {k: second[k] - first[k] for k in first} == {
+        **zero,
         "steps": 8.0, "dc": 100.0, "pf": 700.0, "chunks": 2.0,
         "state_bytes": 1000.0, "moe_touched": 0.0, "moe_rows": 0.0}
 

@@ -278,9 +278,7 @@ def test_ticks_block_present_with_no_finished_request():
     assert tk == {"count": 0, "compiles": 0, "compile_ms": 0.0,
                   "kv_view_bytes": 0, "kv_read_bytes": 0,
                   "prefill_keys": 0, "ahead": 0.0, "slow": [],
-                  "totals": dict.fromkeys(
-                      ("steps", "dc", "pf", "chunks", "state_bytes",
-                       "moe_touched", "moe_rows"), 0.0)}
+                  "totals": dict.fromkeys(servingobs.TICK_TOTALS, 0.0)}
     _tick(obs, 1)
     s = obs.summary()
     assert s["count"] == 0 and "stages" not in s
