@@ -83,8 +83,8 @@ F32_EXACT = (0, 2, 4)       # 24, 200 (two chunks) and 130 tokens
 _STEP_FIXED_GB, _STEP_PER_LAYER_GB, _HBM_SHARE = 3.8, 3.34, 0.85
 
 
-PHASES = ("fleet", "kernels", "hybrid", "train", "generate", "teardown",
-          "serve", "parent_off_chip")
+PHASES = ("fleet", "kernels", "hybrid", "diffusion", "train", "generate",
+          "teardown", "serve", "parent_off_chip")
 
 
 class PhaseFailed(Exception):
@@ -284,6 +284,25 @@ class Smoke:
                if not (c["mosaic"] >= c["kernels"] and c["err"] <= c["tol"])]
         if bad:
             raise PhaseFailed(f"hybrid checks failed: {bad}")
+
+    def diffusion(self):
+        """Generation by diffusion over blocks: a block server at a
+        small size (heads of 128, 16 experts, two layers) serves three
+        prompts (a remainder of 2, one of 1 under two blocks long, whole
+        blocks over two chunks) in float32 at ``highest`` precision;
+        every denoising pass is then replayed by a plain ``jax.numpy``
+        forward under the block-causal mask, teacher-forced by what was
+        served: the token a pass fixed must be the reference's best and
+        the position it fixed the reference's most confident, both
+        within ``tol`` (in logits: float32 on both sides, sums in
+        another order through two layers and a softmax; a wrong token
+        lies O(1) away).  The pass holds one Mosaic call a layer, and
+        every block took its scheduled passes."""
+        k = self.cell(_DIFFUSION_CELL, ranks="[0]")[0]
+        self.facts["diffusion"] = k
+        if not (k["mosaic"] == k["layers"] and k["err"] <= k["tol"]
+                and k["off_schedule"] == 0 and k["tokens"] == k["wanted"]):
+            raise PhaseFailed(f"diffusion check failed: {k}")
 
     def train(self):
         res = self.cell(_HEADER.format(layers=self.layers) + _TRAIN_CELL)
@@ -488,7 +507,8 @@ class Smoke:
         try:
             self.shell()
             if self.phase("fleet", self.fleet):
-                for name in ("kernels", "hybrid", "train", "generate"):
+                for name in ("kernels", "hybrid", "diffusion", "train",
+                             "generate"):
                     self.phase(name, getattr(self, name))
             self.phase("teardown", self.teardown_fleet)
             if self.phases["fleet"]["ok"]:
@@ -746,6 +766,93 @@ def _m2_tokens(h, st, tl):
 _check("mamba2_block_form", 0, _m2_blocks, _m2_tokens, hs, 0.1 * st2[:2], tl2[:2])
 del st2, tl2, hs
 _emit(checks=checks)
+'''
+
+_DIFFUSION_CELL = _EMIT + '''
+import re
+import numpy as np
+from nbdistributed_tpu.models import DecodeServer
+from nbdistributed_tpu.models.sdar import SDARConfig, init_sdar_model
+dcfg = SDARConfig(vocab_size=4096, d_model=512, n_layers=2, n_heads=8,
+                  n_kv_heads=2, head_dim=128, d_ff=256, n_experts=16,
+                  top_k=4, max_seq_len=512, rope_theta=1e6, norm_eps=1e-6,
+                  dtype=jnp.float32, mask_token_id=4095)
+L, T, MASK, PAD = dcfg.block_length, dcfg.denoise_steps, 4095, 192
+def _rms(x, w):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * w
+def _rope(x):                               # (S, heads, Dh)
+    half = x.shape[-1] // 2
+    ang = jnp.arange(x.shape[0])[:, None] * 1e6 ** (
+        -jnp.arange(half, dtype=jnp.float32) / half)[None]
+    c, s_ = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * c - b * s_, a * s_ + b * c], -1)
+def _ref_logits(p, toks):                   # (PAD,) -> (PAD, V), no cache
+    S, H, Hkv, Dh = toks.shape[0], 8, 2, 128
+    blk = jnp.arange(S) // L
+    keep = blk[None, :] <= blk[:, None]
+    x = p["embed"][toks]
+    for w in p["layers"]:
+        h = _rms(x, w["attn_norm"])
+        q = _rope(_rms((h @ w["wq"]).reshape(S, H, Dh), w["q_norm"]))
+        k = _rope(_rms((h @ w["wk"]).reshape(S, Hkv, Dh), w["k_norm"]))
+        v = (h @ w["wv"]).reshape(S, Hkv, Dh)
+        k, v = (jnp.repeat(a, H // Hkv, axis=1) for a in (k, v))
+        sc = jnp.einsum("shd,thd->hst", q, k) * Dh ** -0.5
+        pr = jax.nn.softmax(jnp.where(keep[None], sc, -jnp.inf), -1)
+        x = x + jnp.einsum("hst,thd->shd", pr, v).reshape(S, -1) @ w["wo"]
+        h, m = _rms(x, w["mlp_norm"]), w["moe"]
+        top, idx = jax.lax.top_k(jax.nn.softmax(h @ m["router"], -1), 4)
+        gates = jnp.zeros((S, 16)).at[jnp.arange(S)[:, None], idx].set(
+            top / top.sum(-1, keepdims=True))
+        hid = (jax.nn.silu(jnp.einsum("sd,edf->esf", h, m["w_gate"]))
+               * jnp.einsum("sd,edf->esf", h, m["w_up"]))
+        x = x + jnp.einsum("se,esd->sd", gates,
+                           jnp.einsum("esf,efd->esd", hid, m["w_down"]))
+    return _rms(x, p["final_norm"]) @ p["lm_head"]
+with jax.default_matmul_precision("highest"):
+    dparams = init_sdar_model(jax.random.PRNGKey(39), dcfg)
+    srv = DecodeServer(dparams, dcfg, max_batch=4, max_len=256, pad_to=64,
+                       kv_block_tokens=64, prefill_chunk=64,
+                       interleave_prefill=True)
+    # the compiled pass's calls of the paged kernel, by its name (the
+    # lowered text holds the jitted kernel wrapper once, however many
+    # layers call it; XLA's own grouped matmul is a tpu_custom_call too)
+    mosaic = len(re.findall(r"%nbd_flash_decode_paged[.\\d]* = ",
+                            srv._step_fn.lower(
+        srv._params, srv._cache, srv._paged.device_table(), srv._lens,
+        srv._block, srv._active, srv._key).compile().as_text()))
+    rng = np.random.default_rng(39)
+    reqs = [(rng.integers(0, 4000, n).tolist(), m)
+            for n, m in ((70, 10), (9, 11), (128, 8))]
+    rids = [srv.submit(p, m) for p, m in reqs]
+    outs = srv.run_until_done(400)
+    ref = jax.jit(_ref_logits)
+    err, off, seen = 0.0, 0, 0
+    for (prompt, m), rid in zip(reqs, rids):
+        toks, when = outs[rid], srv.fixed_at[rid]
+        seq = np.asarray(prompt + toks)
+        fixed = np.asarray([-1] * len(prompt) + when)
+        for b in range(len(prompt) // L, len(seq) // L):
+            at = slice(b * L, (b + 1) * L)
+            opened = int((fixed[at] >= 0).sum())
+            off += [int((fixed[at] == s).sum()) for s in range(T)] != [
+                1 if s < opened else 0 for s in range(T)]
+            for s_ in range(T):
+                now = np.full((PAD,), MASK)
+                now[:b * L] = seq[:b * L]
+                now[at] = np.where(fixed[at] < s_, seq[at], MASK)
+                lg = np.asarray(ref(dparams, jnp.asarray(now))[at])
+                conf = -np.log(np.exp(       # log of the max softmax
+                    lg - lg.max(-1, keepdims=True)).sum(-1))
+                for j in np.flatnonzero(fixed[at] == s_):
+                    rest = conf[fixed[at] > s_]
+                    err = max(err, float(lg[j].max() - lg[j, seq[at][j]]),
+                              float(rest.max() - conf[j]) if rest.size
+                              else 0.0)
+                    seen += 1
+_emit(mosaic=mosaic, layers=dcfg.n_layers, err=err, tol=1e-3,
+      off_schedule=off, tokens=seen, wanted=sum(m for _, m in reqs))
 '''
 
 _TRAIN_CELL = '''

@@ -419,7 +419,10 @@ class TenantClient:
 
     def serve_result(self, rid: str, *,
                      timeout: float | None = 60.0) -> dict:
-        """Poll one request: ``{"status", "tokens", "done"}``."""
+        """Poll one request: ``{"status", "tokens", "done"}``, and from
+        a block server, once the request is done, ``passes`` (the pass
+        of its block at which each token was fixed) from the stream
+        offset ``passes_from``."""
         return dict(self.request("serve_result", {"rid": rid},
                                  timeout=timeout).data or {})
 

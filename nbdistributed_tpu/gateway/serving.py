@@ -364,7 +364,7 @@ class _Req:
                  "ticket", "released", "submitted_ts", "finished_ts",
                  "resumes", "stream_resumed", "error",
                  "placed_ts", "first_tok_ts", "last_emit_ts",
-                 "first_batch", "rank", "framed")
+                 "first_batch", "rank", "framed", "passes")
 
     def __init__(self, rid: str, tenant: str, prompt: list[int],
                  max_new: int, priority: int, ticket):
@@ -381,6 +381,12 @@ class _Req:
         # Tokens frames delivered since the last reply was applied:
         # what the tick's own reply will repeat, and no redelivery.
         self.framed = 0
+        # A block server's record of the finished stream (the pass of
+        # its block at which each token was fixed) as ``[stream offset
+        # its placement began at, passes]``; it comes with the reply
+        # of the tick that finished the request, is not journalled,
+        # and ``result`` returns it.
+        self.passes: list | None = None
         self.replay = False            # next admit is a journal replay
         self.released = False          # host-side record freed worker-side
         self.ticket = ticket
@@ -995,6 +1001,9 @@ class ServingManager:
             return {"status": req.state, "rid": rid,
                     "tokens": list(req.tokens),
                     "done": req.state != ACCEPTED,
+                    **({"passes": list(req.passes[1]),
+                        "passes_from": req.passes[0]}
+                       if req.passes else {}),
                     **({"error": req.error} if req.error else {})}
 
     def stream(self, rid: str, from_offset: int = 0) -> dict:
@@ -1740,6 +1749,11 @@ class ServingManager:
                 req = self._reqs.get(rid)
             if req is not None and req.state == ACCEPTED:
                 self._finish(req, FAILED, error=str(err))
+        for rid, passes in (data.get("passes") or {}).items():
+            with self._lock:
+                req = self._reqs.get(rid)
+                if req is not None:
+                    req.passes = [req.base, list(passes)]
         emitted = data.get("emitted") or {}
         step_s = float(tick.get("step_s") or 0.0)
         if not self._hand_to_applier(rank, tick, emitted, step_s):

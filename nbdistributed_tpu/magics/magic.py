@@ -1884,10 +1884,18 @@ class DistributedMagics(Magics):
                   f" · slow {len(tk.get('slow') or ())}"
                   # emission a step (ISSUE 38): the share of the
                   # tokens a frame delivered before its tick's reply,
-                  # and the steps' tokens a push to a client carried
+                  # and the tokens a push to a client carried (the
+                  # key says steps: a row got one token a step when it
+                  # was named; a block server's push carries blocks)
                   + (f" · pushed early {tk['pushed_share']:.0%}, "
-                     f"{tk['steps_per_push']:g} steps/push"
-                     if "pushed_share" in tk else ""))
+                     f"{tk['steps_per_push']:g} tokens/push"
+                     if "pushed_share" in tk else "")
+                  # a block server: row-passes a block committed, and
+                  # positions fixed a denoising pass
+                  + (f" · {tk['denoise']['passes_per_block']:g} "
+                     f"passes/block, "
+                     f"{tk['denoise']['tokens_per_pass']:g} fixed/pass"
+                     if "denoise" in tk else ""))
         print(f"   accepted {st.get('accepted', 0)} · completed "
               f"{st.get('completed', 0)} · shed {st.get('shed', 0)} · "
               f"rejected {st.get('rejected', 0)} · replayed "

@@ -2,8 +2,10 @@
 tiny demo scale up to Llama-2-7B, matching BASELINE.json's acceptance
 configs), Mixtral-style experts (:mod:`.moe`), latent attention over
 fine-grained experts (:mod:`.mla`), state-space layers beside window
-and shared attention (:mod:`.hybrid`), and Mamba-2 / attention /
-expert layers in an order given as a string (:mod:`.nemotron_h`)."""
+and shared attention (:mod:`.hybrid`), Mamba-2 / attention /
+expert layers in an order given as a string (:mod:`.nemotron_h`), and
+generation by diffusion over blocks under a block-causal mask
+(:mod:`.sdar`)."""
 
 from .generate import (forward_with_cache, generate, init_kv_cache,
                        kv_cache_shardings, make_generate_fn,
@@ -12,7 +14,8 @@ from .hf import (config_from_hf, config_from_hf_json,
                  hybrid_config_from_hf,
                  latent_moe_config_from_hf, load_hf_pretrained,
                  moe_config_from_hf, moe_params_from_hf,
-                 nemotron_h_config_from_hf, params_from_hf)
+                 nemotron_h_config_from_hf, params_from_hf,
+                 sdar_config_from_hf)
 from .hybrid import (HybridConfig, init_hybrid_model, layer_kinds_for,
                      make_hybrid_cache, phi4_mini_flash_config,
                      tiny_hybrid_config)
@@ -21,6 +24,8 @@ from .mla import (LatentMoEConfig, init_latent_moe_model,
                   latent_moe_shardings, tiny_latent_moe_config)
 from .nemotron_h import (NemotronHConfig, init_nemotron_h_model,
                          nemotron3_nano_config, tiny_nemotron_h_config)
+from .sdar import (SDARConfig, init_sdar_model, sdar_30b_a3b_config,
+                   tiny_sdar_config)
 from .lora import (ALL_TARGETS, ATTN_TARGETS, lora_init, lora_merge,
                    lora_num_params, lora_shardings,
                    make_lora_train_step)
@@ -70,6 +75,8 @@ __all__ = ["SeqParallel", "TransformerConfig", "forward",
            "NemotronHConfig", "init_nemotron_h_model",
            "nemotron3_nano_config", "tiny_nemotron_h_config",
            "nemotron_h_config_from_hf",
+           "SDARConfig", "init_sdar_model", "sdar_30b_a3b_config",
+           "tiny_sdar_config", "sdar_config_from_hf",
            "LatentMoEConfig", "init_latent_moe_model",
            "joyai_flash_config", "latent_moe_forward",
            "latent_moe_shardings", "tiny_latent_moe_config",

@@ -292,10 +292,16 @@ class GQAMixer:
         cfg = self.cfg
         B, S = h.shape[:2]
         H, Hkv, Dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        q = _rope(qlinear(h, layer["wq"]).reshape(B, S, H, Dh),
-                  positions, cfg.rope_theta)
-        k = _rope(qlinear(h, layer["wk"]).reshape(B, S, Hkv, Dh),
-                  positions, cfg.rope_theta)
+        # ``qk_norm``: a learned RMS norm over each head's values,
+        # before the rotary embedding (the Qwen3 layer).
+        head_norm = ((lambda x, name: _rms_norm(x, layer[name],
+                                                cfg.norm_eps))
+                     if getattr(cfg, "qk_norm", False)
+                     else (lambda x, name: x))
+        q = _rope(head_norm(qlinear(h, layer["wq"]).reshape(B, S, H, Dh),
+                            "q_norm"), positions, cfg.rope_theta)
+        k = _rope(head_norm(qlinear(h, layer["wk"]).reshape(B, S, Hkv, Dh),
+                            "k_norm"), positions, cfg.rope_theta)
         v = qlinear(h, layer["wv"]).reshape(B, S, Hkv, Dh)
         # Heads-major for the cache: (B, S, Hkv, Dh) -> (B, Hkv, S, Dh).
         new = {"k": k.transpose(0, 2, 1, 3), "v": v.transpose(0, 2, 1, 3)}
@@ -314,7 +320,11 @@ class GQAMixer:
         """One new token a row (a decode step: ``pos`` its position,
         ``active`` the rows that take part), or a chunk of them
         (``pos`` the first one's position, ``length`` the real ones of
-        each row's chunk)."""
+        each row's chunk).  Under a block-causal mask (``cfg.
+        block_length`` > 1) a decode step carries a block a row
+        (``active`` given, ``pos`` the block's first position): its
+        queries share one bound on the keys, the block's last
+        position."""
         from ..ops.decode import (paged_decode_attention,
                                   paged_prefill_attention)
         kw = dict(scale=self.scale,
@@ -324,10 +334,14 @@ class GQAMixer:
             o = paged_decode_attention(
                 q[:, 0], pool["k"], pool["v"], layer_idx, table, pos,
                 active=active, **kw)
+        elif active is not None:        # a block a row: one shared bound
+            o = paged_decode_attention(
+                q, pool["k"], pool["v"], layer_idx, table,
+                pos + q.shape[1] - 1, active=active, **kw)
         else:
             o = paged_prefill_attention(
                 q, pool["k"], pool["v"], layer_idx, table, pos, length,
-                **kw)
+                block=getattr(self.cfg, "block_length", 1), **kw)
         return o.reshape(*q.shape[:2], -1)
 
     def out(self, o, layer):
@@ -390,7 +404,10 @@ def _make_mlp_fn(cfg: TransformerConfig, mesh, ep_axis: str,
     routed, else None."""
     from .mla import LatentMoEConfig, latent_moe_mlp_block
     from .moe import MoEConfig, _moe_mlp_block
+    from .sdar import SDARConfig, sdar_mlp_block
 
+    if isinstance(cfg, SDARConfig):
+        return lambda x, layer: sdar_mlp_block(x, layer, cfg, token_mask)
     if isinstance(cfg, MoEConfig):
         def mlp(x, layer):
             x, _aux = _moe_mlp_block(x, layer, cfg, mesh, ep_axis,
@@ -474,7 +491,17 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
     caches only: ``slot`` is the row a prefill chunk belongs to (its
     state is counted in rows), and ``final`` (static) whether the chunk
     ends its prompt, since one that does not runs nothing past the last
-    layer that writes a cache and returns None for logits.
+    layer that writes a cache and returns None for logits.  Every other
+    family takes ``final=False`` to mean the same: no head, None for
+    logits (a block server's prefill never asks for any).
+
+    A config with ``block_length`` > 1 (:class:`~.sdar.SDARConfig`)
+    attends under the block-causal mask, over the paged pool only: a
+    chunk's bound on the keys is the last position of the query's
+    block, and a decode step (``row_mask`` given) carries
+    ``block_length`` tokens a row at ``cache_len .. cache_len +
+    block_length - 1``, written to the row's pages and attended in
+    both directions, whose logits all come back.
     """
     from .hybrid import StatefulConfig, hybrid_forward_with_cache
     from .mla import LatentMoEConfig, MLAMixer
@@ -495,6 +522,9 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
                                             cache_len, cfg, **kw)
             load = jnp.zeros((3,), jnp.float32)
         return (*out, load) if with_moe_load else tuple(out)
+    if getattr(cfg, "block_length", 1) > 1 and block_table is None:
+        raise ValueError("a block-causal model is served over the paged "
+                         "pool: pass block_table")
     B, S = tokens.shape
     cache_len = jnp.asarray(cache_len)
     per_row = cache_len.ndim == 1  # per-stream cache pointers
@@ -565,6 +595,8 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
     per_layer = (outs[0] if len(outs) == 1 else jax.tree_util.tree_map(
         lambda *ys: jnp.concatenate(ys), *outs))
     new = kv.result(held, per_layer)
+    if not final:
+        return None, new
     if last_index is not None:
         idx = jnp.asarray(last_index, jnp.int32).reshape(B, 1, 1)
         x = jnp.take_along_axis(x, jnp.broadcast_to(

@@ -422,6 +422,59 @@ def nemotron_h_config_from_hf(hf_config, **overrides):
         **overrides})
 
 
+def sdar_config_from_hf(hf_config, **overrides):
+    """Map a ``model_type: sdar_moe`` config (Qwen3-MoE's keys) onto
+    :class:`~nbdistributed_tpu.models.sdar.SDARConfig`.  ``overrides``
+    are fields of that class: ``dtype``, ``use_flash``, and the three
+    the published config does not state (``block_length``,
+    ``denoise_steps``, ``mask_token_id``: they live in the release's
+    generation settings and tokenizer).
+
+    Refused rather than mis-served: dense layers among the expert ones
+    (``mlp_only_layers`` non-empty, ``decoder_sparse_step`` != 1), a
+    sliding window, rope scaling, gates that are not renormalised
+    (``norm_topk_prob`` false), biases, a tied head.
+    ``intermediate_size`` (the width a dense layer would have) is read
+    and dropped: no layer is dense."""
+    from .sdar import SDARConfig
+
+    get = lambda k, d=None: getattr(hf_config, k, d)
+    if get("mlp_only_layers"):
+        raise ValueError("mlp_only_layers is not supported for "
+                         "model_type sdar_moe: every layer routes")
+    if get("decoder_sparse_step", 1) != 1:
+        raise ValueError("decoder_sparse_step != 1 is not supported")
+    if get("use_sliding_window", False):
+        raise ValueError("use_sliding_window is not supported for "
+                         "model_type sdar_moe")
+    if get("rope_scaling"):
+        raise ValueError("rope_scaling is not supported (plain rotary "
+                         "only)")
+    if not get("norm_topk_prob", False):
+        raise ValueError("only renormalised top-k gates "
+                         "(norm_topk_prob) are supported")
+    if get("attention_bias", False):
+        raise ValueError("attention_bias=True is not supported")
+    if get("tie_word_embeddings", False):
+        raise ValueError("a tied head is not supported for model_type "
+                         "sdar_moe")
+    return SDARConfig(**{
+        "vocab_size": hf_config.vocab_size,
+        "d_model": hf_config.hidden_size,
+        "n_layers": hf_config.num_hidden_layers,
+        "n_heads": hf_config.num_attention_heads,
+        "n_kv_heads": hf_config.num_key_value_heads,
+        "head_dim": get("head_dim") or (hf_config.hidden_size
+                                        // hf_config.num_attention_heads),
+        "d_ff": hf_config.moe_intermediate_size,
+        "n_experts": hf_config.num_experts,
+        "top_k": hf_config.num_experts_per_tok,
+        "max_seq_len": get("max_position_embeddings", 32768),
+        "rope_theta": float(get("rope_theta", 1e6)),
+        "norm_eps": float(get("rms_norm_eps", 1e-6)),
+        **overrides})
+
+
 def config_from_hf_json(config: dict, **overrides):
     """A published ``config.json`` (as a dict) -> the program's config,
     by its ``model_type``; one this tree cannot run raises."""
@@ -434,6 +487,12 @@ def config_from_hf_json(config: dict, **overrides):
         return hybrid_config_from_hf(ns, **overrides)
     if kind == "nemotron_h":
         return nemotron_h_config_from_hf(ns, **overrides)
+    if kind == "sdar_moe":
+        # the generation settings ride the file beside the published
+        # keys where the caller put them there
+        gen = {k: config[k] for k in ("block_length", "denoise_steps",
+                                      "mask_token_id") if k in config}
+        return sdar_config_from_hf(ns, **{**gen, **overrides})
     if kind == "mixtral":
         cfg = moe_config_from_hf(ns)
     elif kind in ("llama", "mistral"):

@@ -95,9 +95,8 @@ class NemotronHConfig(StatefulConfig):
     routed_scale: float = 2.5
     state_stacked = False       # layers are unrolled: a leaf a layer
 
-    @property
-    def head_dim(self) -> int:
-        return self.attn_head_dim
+    def __post_init__(self):
+        object.__setattr__(self, "head_dim", self.attn_head_dim)
 
     @property
     def layer_kinds(self) -> tuple:

@@ -113,10 +113,14 @@ def gather_layer(pool, layer, table):
 
 @jax.named_scope("write_token")
 def write_token(pool, layer, new, table, pos, active):
-    """Write each slot's ONE new token of one layer into its page.
+    """Write each slot's ONE new token of one layer into its page,
+    or its one block of them.
 
-    ``new`` leaves ``(S, Hkv, 1, D)`` (the pool's leaves for the
-    step's token), ``pos`` (S,) the position written.  Physical block
+    ``new`` leaves ``(S, Hkv, n, D)`` (the pool's leaves for the
+    step's tokens; ``n`` is 1, or the block length of a block-causal
+    model, whose blocks start at multiples of ``n`` and so lie inside
+    one page where ``n`` divides the page), ``pos`` (S,) the position
+    of the first.  Physical block
     ``table[b, pos // bt]``, offset ``pos % bt``, all heads.  Inactive
     slots are redirected to the trash block — their frozen-position
     write must never land in a block that may have been reallocated to
@@ -134,7 +138,7 @@ def write_token(pool, layer, new, table, pos, active):
         # takes the pool in whatever layout it has and rewrites it in
         # place, where a scatter over the token axis makes XLA re-lay
         # the whole pool out around it, twice a layer.
-        n = n.astype(c.dtype)                 # (S, Hkv, 1, D)
+        n = n.astype(c.dtype)                 # (S, Hkv, n, D)
         for b in range(n.shape[0]):
             c = jax.lax.dynamic_update_slice(
                 c, n[b][None, None], (layer, phys[b], 0, off[b], 0))
@@ -204,7 +208,9 @@ class PagedKV:
     (:class:`~.mla.MLAMixer`).
 
     One new token a row is a decode step (rows outside ``active`` write
-    to the trash block and attend nothing); several are a chunk of a
+    to the trash block and attend nothing), and so, under a
+    block-causal mask, is a block of ``cfg.block_length`` tokens a row
+    with ``active`` given; several otherwise are a chunk of a
     prefill, every row of which takes part, ``token_mask`` telling its
     real tokens from its padded tail.  Either way a layer writes its
     new entries into the row's pages and attends through the table
@@ -218,6 +224,7 @@ class PagedKV:
         self._table, self._active = table, active
         self._mixer = mixer
         self._in_place = reads_in_place(cfg, mesh, "k_s" in pool)
+        self._block = getattr(cfg, "block_length", 1)
         # A chunk's real tokens end at its last real one: no real
         # query needs a key past it.
         self._length = None if token_mask is None else jnp.max(
@@ -226,7 +233,10 @@ class PagedKV:
 
     def layer(self, pool, layer_idx, q, new, positions, layer):
         pos = positions[:, 0]
-        if q.shape[1] > 1:
+        # A decode step carries one token a row, or (``row_mask``
+        # given) the block a row that a block-causal model denoises.
+        if q.shape[1] > 1 and not (self._active is not None
+                                   and q.shape[1] == self._block):
             if self._active is not None:
                 raise ValueError("a chunk of new tokens takes every "
                                  "row: row_mask is the decode step's")
@@ -242,6 +252,9 @@ class PagedKV:
             o = self._mixer.attend_paged(q, pool, layer_idx,
                                          self._table, pos,
                                          self._active, layer)
+        elif q.shape[1] > 1:
+            raise ValueError("a block step attends the pool in place: "
+                             "use_flash on one device, no int8 pool")
         else:
             view = gather_layer(pool, layer_idx, self._table)
             o = self._mixer.attend(q, view, positions, layer)

@@ -53,6 +53,18 @@ the KV-cache machinery was built to support.  Design:
   and evict real prompt tokens, and the lm_head runs only at the last
   real position (``last_index``).
 
+* **A block server** (a config with ``block_length`` > 1,
+  :mod:`.sdar`) runs a *pass* a step where the others run a token: a
+  row carries a block of ``L`` tokens and which of them are open, a
+  denoising pass fixes the scheduled number of open positions and
+  advances no position, and a pass that finds none open commits the
+  block (``lens`` moves, the block is emitted, the next is all masks).
+  The schedule is static, so the host knows without a fetch which pass
+  commits and whose budget ends, and one pass stays in flight exactly
+  as a step does.  A prompt's whole blocks are prefilled (no logits:
+  nothing is sampled from them) and its remainder is seated in the
+  row's first block.
+
 Greedy serving reproduces a standalone :func:`~.generate.generate`
 call per request: admission order, batch occupancy, and other
 requests' traffic cannot change any request's tokens for the dense
@@ -86,6 +98,7 @@ import numpy as np
 
 from ..observability import spans as obs_spans
 from ..serving_fast.paging import BlocksExhausted
+from . import sdar
 from .generate import _sample, forward_with_cache
 from .paged_kv import PagedKVCache, make_paged_pool, reads_in_place
 from .transformer import TransformerConfig
@@ -124,12 +137,19 @@ class _KVKind(NamedTuple):
 class _InFlight(NamedTuple):
     """A decode step that was dispatched and whose tokens the host has
     not fetched yet."""
-    tokens: jax.Array           # (B,) the slots' next tokens
+    tokens: object              # (B,) the slots' next tokens; a block
+    #                             server's pass: ((B, L) the blocks as
+    #                             the pass left them, (B, L) the pass
+    #                             at which each position was fixed)
     load: jax.Array | None      # the step's routing load (a config
     #                             whose experts report one)
     rows: dict[int, int]        # {slot: request id} it ran
     kv_bytes: tuple             # K and V page bytes its attention
     #                             reads, one count a :class:`_KVKind`
+    commits: dict | None = None  # a block server's pass: {slot: index
+    #                             of the block's first new token} of
+    #                             the rows whose block it commits
+    fixed: int = 0              # ... and positions it fixes, all rows
 
 
 class DecodeServer:
@@ -214,7 +234,28 @@ class DecodeServer:
         from .hybrid import StatefulConfig
         from .mla import LatentMoEConfig
         from .nemotron_h import NemotronHConfig
-        self._routed = isinstance(cfg, (LatentMoEConfig, NemotronHConfig))
+        self._routed = isinstance(cfg, (LatentMoEConfig, NemotronHConfig,
+                                        sdar.SDARConfig))
+        # Generation by diffusion over blocks: a row carries a block of
+        # ``_L`` tokens, a step is a pass over it (1: a token a step).
+        self._blocks = isinstance(cfg, sdar.SDARConfig)
+        self._L = cfg.block_length if self._blocks else 1
+        if self._blocks:
+            L = self._L
+            bad = {k: v for k, v in (("max_len", max_len),
+                                     ("kv_block_tokens", kv_block_tokens),
+                                     ("pad_to", pad_to),
+                                     ("prefill_chunk", prefill_chunk))
+                   if v is not None and v % L}
+            if bad:
+                raise ValueError(f"a block server of block length {L} "
+                                 f"needs multiples of it: {bad}")
+            if temperature or kv_quantized or mesh is not None \
+                    or not cfg.use_flash:
+                raise ValueError(
+                    "a block server is greedy (temperature 0) and reads "
+                    "its pool in place: use_flash on one device, no "
+                    "int8 pool")
         # Layer kinds that keep state beside (or in place of) pages:
         # several kinds of cache, and a prefill chunk that is told its
         # row and whether it ends the prompt.
@@ -289,6 +330,8 @@ class DecodeServer:
         self._lens = jnp.zeros((max_batch,), jnp.int32)
         self._last = jnp.zeros((max_batch,), jnp.int32)
         self._active = jnp.zeros((max_batch,), bool)
+        if self._blocks:
+            self._block = sdar.fresh_block(cfg, max_batch)
 
         # Host-side bookkeeping.
         self._free = list(range(max_batch))
@@ -298,13 +341,19 @@ class DecodeServer:
         # without a fetch: slot -> [position the step writes, steps
         # left to dispatch].  ``_active[slot]`` on the device is true
         # exactly for these.  A budget's end is known here, at
-        # dispatch; an EOS only at the fetch, one step late.
+        # dispatch; an EOS only at the fetch, one step late.  A block
+        # server's row: slot -> [position of its block, tokens left
+        # to dispatch, open positions left in the block, tokens the
+        # block yields (its length less a prompt's remainder)].
         self._run: dict[int, list[int]] = {}
         # The one decode step in flight (dispatched, not fetched).
         self._flying: _InFlight | None = None
         self._pending: list[tuple[int, list[int], int]] = []
         self._next_id = 0
         self.outputs: dict[int, list[int]] = {}
+        # A block server's record beside ``outputs``, token for token:
+        # the pass of its block at which each was fixed.
+        self.fixed_at: dict[int, list[int]] = {}
         self.prompts: dict[int, list[int]] = {}
         self._finished: set[int] = set()
         # Interleaved chunked prefill (ISSUE 17): slots whose prompt
@@ -342,6 +391,14 @@ class DecodeServer:
         self.prefill_chunks_total = 0
         self.cross_decoder_runs_total = 0
         self.cross_decoder_keys_total = 0
+        # a block server's row-passes: a row's denoising passes, its
+        # commit passes, the blocks that reached their request, the
+        # positions the denoising passes fixed (counted, like a step,
+        # when the pass is fetched);
+        self.denoise_passes_total = 0
+        self.commit_passes_total = 0
+        self.blocks_committed_total = 0
+        self.tokens_fixed_total = 0
         # seconds per phase of step() (and of submit()'s admission,
         # which is prefill), on this process's perf_counter;
         self.phase_s = dict.fromkeys(STEP_PHASES, 0.0)
@@ -419,6 +476,9 @@ class DecodeServer:
             self.prefill_chunks_total += 1
             args = (params, pool, self._paged.device_row(slot), prompt,
                     np.int32(start), np.int32(length))
+            if self._blocks:
+                # nothing is sampled from a prompt's logits: no head
+                return jit_fn(*args, final=False)
             if not self._hybrid:
                 return jit_fn(*args)
             if final:
@@ -459,6 +519,25 @@ class DecodeServer:
         temperature, top_k, top_p = (self._temperature, self._top_k,
                                      self._top_p)
         routed = self._routed
+        if self._blocks:
+            def nbd_denoise_step_paged(params, pool, table, lens, block,
+                                       active, key):
+                """One pass over every row's block -> (pool, lens, the
+                rows' blocks, what the pass left of them for the host,
+                the routing load): the forward over the ``L`` tokens a
+                row at ``lens .. lens + L - 1``, whose K/V land in the
+                row's pages on every pass, then
+                :func:`~.sdar.denoise`.  ``key`` is unused: a block
+                server is greedy."""
+                logits, pool, load = forward_with_cache(
+                    params, block["tokens"], pool, lens, cfg,
+                    row_mask=active, block_table=table,
+                    with_moe_load=True)
+                block, lens, out = sdar.denoise(logits, block, lens,
+                                                active, cfg)
+                return pool, lens, block, out, load
+
+            return jax.jit(nbd_denoise_step_paged, donate_argnums=(1,))
 
         def nbd_decode_step_paged(params, pool, table, lens, last,
                                   active, key):
@@ -485,8 +564,8 @@ class DecodeServer:
         untouched."""
         return self._step_fn.lower(
             self._params, self._cache, self._paged.device_table(),
-            self._lens, self._last, self._active,
-            self._key).as_text().count("tpu_custom_call")
+            self._lens, self._block if self._blocks else self._last,
+            self._active, self._key).as_text().count("tpu_custom_call")
 
     # ---- host-side API ---------------------------------------------------
 
@@ -507,6 +586,8 @@ class DecodeServer:
         self._next_id += 1
         self.prompts[rid] = prompt
         self.outputs[rid] = []
+        if self._blocks:
+            self.fixed_at[rid] = []
         self._pending.append((rid, prompt, max_new_tokens))
         self._admit_as_prefill(time.perf_counter())
         return rid
@@ -546,6 +627,13 @@ class DecodeServer:
             **({"final": final} if self._hybrid else {}))
         return logits
 
+    def _prefilled(self, prompt: list) -> int:
+        """Prompt tokens the prefill programs write: all of them, or
+        under a block-causal mask the prompt's whole blocks (the
+        remainder is seated in the row's first block, so no real
+        query sees a key that a pad wrote)."""
+        return len(prompt) // self._L * self._L
+
     def _run_prefill(self, prompt: list, slot: int):
         """Prefill one slot with a whole prompt; returns the
         last-real-token logits.
@@ -559,14 +647,16 @@ class DecodeServer:
         (padded to the chunk) carries the logits; a causal forward
         makes chunked and single-shot prefill the same computation
         (same argument as :func:`~.generate.prefill_chunked`)."""
-        ck = self._prefill_chunk
-        if ck is None or len(prompt) <= ck:
-            return self._prefill_segment(slot, prompt, 0,
-                                         self._bucket(len(prompt)), True)
-        for start in range(0, len(prompt), ck):
+        ck, n = self._prefill_chunk, self._prefilled(prompt)
+        if not n:
+            return None         # shorter than a block: nothing to write
+        if ck is None or n <= ck:
+            return self._prefill_segment(slot, prompt[:n], 0,
+                                         self._bucket(n), True)
+        for start in range(0, n, ck):
             logits = self._prefill_segment(
-                slot, prompt[start:start + ck], start, ck,
-                start + ck >= len(prompt))
+                slot, prompt[start:min(start + ck, n)], start, ck,
+                start + ck >= n)
         return logits
 
     def _admit_pending(self) -> None:
@@ -594,7 +684,7 @@ class DecodeServer:
                 # chunk.
                 self._prefilling[slot] = [rid, prompt, budget, 0]
                 continue
-            self.prefill_tokens_total += len(prompt)
+            self.prefill_tokens_total += self._prefilled(prompt)
             self._start_stream(slot, rid, prompt, budget,
                                self._run_prefill(prompt, slot))
 
@@ -602,7 +692,10 @@ class DecodeServer:
                       budget: int, last_logits) -> None:
         """The end of an admission: sample the stream's first token
         from the prompt's last logits, then finish the request or
-        activate its slot."""
+        activate its slot.  A block server samples nothing here: it
+        seats the prompt's remainder in the row's first block."""
+        if self._blocks:
+            return self._seat_block(slot, rid, prompt, budget)
         tok = int(_sample(last_logits[None], self._temperature,
                           self._sample_key(), self._top_k,
                           self._top_p)[0])
@@ -616,6 +709,21 @@ class DecodeServer:
             self._budget[rid] = budget - 1
             self._run[slot] = [len(prompt), budget - 1]
             self._active = self._active.at[slot].set(True)
+
+    def _seat_block(self, slot: int, rid: int, prompt: list[int],
+                    budget: int) -> None:
+        """A block server's end of an admission: the row's first block
+        is the prompt's remainder (fixed) followed by masks, at the
+        position its whole blocks end."""
+        at = self._prefilled(prompt)
+        self._block = sdar.seat_block(self._block, slot, prompt[at:],
+                                      self._cfg)
+        self._lens = self._lens.at[slot].set(at)
+        self._slot_req[slot] = rid
+        self._budget[rid] = budget
+        opened = self._L - (len(prompt) - at)
+        self._run[slot] = [at, budget, opened, opened]
+        self._active = self._active.at[slot].set(True)
 
     def _finish(self, slot: int, rid: int) -> None:
         """Free the slot and its pages.  A step in flight may still
@@ -643,12 +751,13 @@ class DecodeServer:
         slot, st = next(iter(self._prefilling.items()))
         rid, prompt, budget, written = st
         ck = self._prefill_chunk
-        seg = prompt[written:written + ck]
+        end = self._prefilled(prompt)
+        seg = prompt[written:min(written + ck, end)]
         logits = self._prefill_segment(slot, seg, written, ck,
-                                       written + ck >= len(prompt))
+                                       written + ck >= end)
         self.prefill_tokens_total += len(seg)
         st[3] = written + len(seg)
-        if st[3] < len(prompt):
+        if st[3] < end:
             return
         del self._prefilling[slot]
         self._start_stream(slot, rid, prompt, budget, logits)
@@ -728,6 +837,8 @@ class DecodeServer:
         step's tokens are known."""
         rows = {slot: self._slot_req[slot] for slot in self._run}
         kv_bytes = self._step_kv_read_bytes()
+        if self._blocks:
+            return self._dispatch_pass(rows, kv_bytes)
         self._cache, self._lens, self._last, load = self._step_fn(
             self._params, self._cache, self._paged.device_table(),
             self._lens, self._last, self._active, self._sample_key())
@@ -742,16 +853,48 @@ class DecodeServer:
                 self._active = self._active.at[slot].set(False)
         return _InFlight(self._last, load, rows, kv_bytes)
 
+    def _dispatch_pass(self, rows: dict, kv_bytes: tuple) -> _InFlight:
+        """A block server's :meth:`_dispatch_step`: one pass over the
+        rows of :attr:`_run`.  What each row's pass is follows from the
+        schedule alone: a row with open positions fixes
+        ``fixed_per_pass`` of them, a row with none commits its block,
+        and a commit that ends the budget takes the row out."""
+        self._cache, self._lens, self._block, out, load = self._step_fn(
+            self._params, self._cache, self._paged.device_table(),
+            self._lens, self._block, self._active, self._key)
+        fetched = (out["tokens"], out["when"])
+        for a in (*fetched, load):
+            a.copy_to_host_async()
+        L, per_pass = self._L, self._cfg.fixed_per_pass
+        commits, fixed = {}, 0
+        for slot, st in list(self._run.items()):
+            if st[2]:                           # a denoising pass
+                n = min(per_pass, st[2])
+                st[2] -= n
+                fixed += n
+                continue
+            commits[slot] = L - st[3]           # the commit
+            st[0] += L
+            st[1] -= st[3]
+            st[2] = st[3] = L
+            if st[1] <= 0:
+                del self._run[slot]
+                self._active = self._active.at[slot].set(False)
+        return _InFlight(fetched, load, rows, kv_bytes, commits, fixed)
+
     def _step_kv_read_bytes(self) -> tuple:
         """Bytes of K and V pages the next decode step's attention
         fetches, a count a kind of K/V over the layers that read it:
         for every row the step runs, the pages from the window's first
-        to the one its new token lands in."""
+        to the one its new token lands in (a block's last), once a
+        row."""
         bt = self._paged.block_tokens
+        last = self._L - 1      # a block's queries share its last key
         return tuple(
             kind.page_bytes * sum(
-                pos // bt - self._first_live_page(kind, pos) + 1
-                for pos, _ in self._run.values())
+                (st[0] + last) // bt
+                - self._first_live_page(kind, st[0] + last) + 1
+                for st in self._run.values())
             for kind in self._kinds)
 
     def _emit_step(self, step: _InFlight, toks, load) -> dict:
@@ -769,9 +912,31 @@ class DecodeServer:
             self.moe_load[0] += touched
             self.moe_load[1] = max(self.moe_load[1], most)
             self.moe_load[2] += rows
+        if self._blocks:
+            return self._emit_pass(step, *toks)
         return {rid: self._emit(slot, rid, [int(toks[slot])])
                 for slot, rid in step.rows.items()
                 if self._slot_req.get(slot) == rid}
+
+    def _emit_pass(self, step: _InFlight, tokens, when) -> dict:
+        """Count a fetched pass and emit the blocks it committed: the
+        new tokens of a row's block (a prompt's remainder is not
+        output), each with the pass that fixed it."""
+        self.commit_passes_total += len(step.commits)
+        self.denoise_passes_total += len(step.rows) - len(step.commits)
+        self.tokens_fixed_total += step.fixed
+        emitted = {}
+        for slot, first in step.commits.items():
+            rid = step.rows[slot]
+            if self._slot_req.get(slot) != rid:
+                continue
+            self.blocks_committed_total += 1
+            toks = self._emit(slot, rid,
+                              [int(t) for t in tokens[slot][first:]])
+            self.fixed_at[rid].extend(
+                int(w) for w in when[slot][first:first + len(toks)])
+            emitted[rid] = toks
+        return emitted
 
     def _emit(self, slot: int, rid: int, toks: list[int]) -> list[int]:
         """Budget-then-EOS truncation + bookkeeping for an emission —
@@ -806,6 +971,7 @@ class DecodeServer:
         if rid not in self.outputs:
             raise KeyError(f"unknown or already-released request {rid}")
         toks = self.outputs.pop(rid)
+        self.fixed_at.pop(rid, None)
         self.prompts.pop(rid, None)
         self._finished.discard(rid)
         return toks
@@ -863,6 +1029,10 @@ class DecodeServer:
                 "state": self.state_bytes_total,
                 "xdec": self.cross_decoder_runs_total,
                 "xkeys": self.cross_decoder_keys_total,
+                "dn:passes": self.denoise_passes_total,
+                "dn:commits": self.commit_passes_total,
+                "dn:blocks": self.blocks_committed_total,
+                "dn:fixed": self.tokens_fixed_total,
                 **{"kv:" + k.name: n for k, n in
                    zip(self._kinds, self.kv_read_bytes_by_kind)},
                 **{"ph:" + k: v for k, v in self.phase_s.items()}}
@@ -886,7 +1056,12 @@ class DecodeServer:
         ``st``: bytes of per-row state the decode steps read and
         wrote, and the steps; ``xdec``: chunk programs that ran the
         layers past the shared K/V, the chunk programs, and the shared
-        layer's keys the former attended.  A decode step counts, in
+        layer's keys the former attended.  A block server adds
+        ``dn``: its rows' denoising passes, their commit passes, the
+        blocks committed to a request and the positions fixed (a
+        step is then a pass over every row's block: ``kvr`` counts a
+        row's pages once a pass, ``dc`` the tokens that left at a
+        commit).  A decode step counts, in
         all of these, when its tokens are fetched: the step in flight
         at the call is the next account's."""
         now = self._totals()
@@ -901,6 +1076,9 @@ class DecodeServer:
         if self._hybrid:
             account.update(kvk=kv, st=[d["state"], d["steps"]],
                            xdec=[d["xdec"], d["chunks"], d["xkeys"]])
+        if self._blocks:
+            account["dn"] = [d["dn:passes"], d["dn:commits"],
+                             d["dn:blocks"], d["dn:fixed"]]
         if self._routed:
             account["moe"] = [round(v, 3) for v in self.moe_load]
             self.moe_load = [0.0, 0.0, 0.0]

@@ -75,10 +75,15 @@ class TransformerConfig:
     # (quantized heads are the inference configuration; training
     # wants the dense head).
     ce_chunk: int | None = None
+    # A head's width.  None (every preset): ``d_model // n_heads``,
+    # filled in at construction; a model whose projections are wider or
+    # narrower than the residual stream states its own.
+    head_dim: int | None = None
 
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.n_heads
+    def __post_init__(self):
+        if self.head_dim is None:
+            object.__setattr__(self, "head_dim",
+                               self.d_model // self.n_heads)
 
     def num_params(self) -> int:
         emb = self.vocab_size * self.d_model

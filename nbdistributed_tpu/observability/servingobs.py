@@ -81,11 +81,15 @@ TICK_RING = 64
 # steps), rows routed a layer (likewise); ``serve_emit`` frames the
 # worker sent and its ``step()`` calls that emitted; tokens a frame
 # delivered before its tick's reply, tokens applied (each carried by
-# one push, and a row gets one token a step: the steps the pushes
-# carried), and pushes to clients.
+# one push: where a row gets one token a step, the steps the pushes
+# carried; a block server's row gets a block at a commit), and pushes
+# to clients; a block server's row-passes: its rows' denoising passes,
+# their commit passes, and the blocks committed to a request; bytes of
+# K and V pages the decode steps' attention fetched (``kvr``'s).
 TICK_TOTALS = ("steps", "dc", "pf", "chunks", "state_bytes",
                "moe_touched", "moe_rows", "frames", "steps_emitting",
-               "pushed_early", "pushed", "pushes")
+               "pushed_early", "pushed", "pushes",
+               "passes", "commits", "blocks", "kv_bytes")
 SLOW_TICKS = 8
 SLOW_FACTOR = 3.0
 SLOW_ABS_S = 1.0
@@ -465,7 +469,11 @@ class ServingObservatory:
         ran the layers past the shared K/V, chunk programs, the shared
         layer's keys the former attended]``.  ``fr`` = ``[serve_emit
         frames it sent, step() calls that emitted]`` (absent from a
-        worker that answers once a tick).  ``pushed`` is the
+        worker that answers once a tick).  ``dn`` = ``[denoising
+        row-passes, commit row-passes, blocks committed, positions
+        fixed]`` from a block server (a step is then a pass over every
+        row's block, and ``dc`` the tokens that left at the commits).
+        ``pushed`` is the
         gateway's own count for this rank since the tick before:
         ``[tokens a frame delivered before its tick's reply, tokens
         applied, pushes to clients]`` (None: no token was applied).  Returns the tick's record
@@ -499,6 +507,7 @@ class ServingObservatory:
             "kvk": {k: int(v) for k, v in (tick.get("kvk") or {}).items()},
             "st": [int(v) for v in tick.get("st") or (0, 0)],
             "xdec": [int(v) for v in tick.get("xdec") or (0, 0, 0)],
+            "dn": [int(v) for v in tick.get("dn") or (0, 0, 0, 0)],
             "turnaround": (None if turnaround is None
                            else max(0.0, float(turnaround))),
             "handler": handler,
@@ -522,6 +531,9 @@ class ServingObservatory:
                          ("moe_rows", moe and moe[2]),
                          ("frames", frames),
                          ("steps_emitting", emitting),
+                         *zip(("passes", "commits", "blocks"),
+                              rec["dn"]),
+                         ("kv_bytes", kv_bytes),
                          *zip(("pushed_early", "pushed", "pushes"),
                               rec["pushed"])):
                 self._totals[k] += float(v or 0)
@@ -598,10 +610,11 @@ class ServingObservatory:
         if pushes:
             # of the tokens applied, the share a frame delivered
             # before its tick's reply (0 where the worker answers once
-            # a tick); the steps' tokens a push to a client carried
-            # (the tick's steps on that path, 1 where a stream hears
-            # every step); frames the workers sent and their step()
-            # calls that emitted
+            # a tick); the tokens a push to a client carried (where a
+            # row gets a token a step, the steps a push carried: 1
+            # where a stream hears every step; a block server's push
+            # carries a block or more); frames the workers sent and
+            # their step() calls that emitted
             out["pushed_share"] = round(
                 sum(t["pushed"][0] for t in ticks)
                 / max(1, sum(t["pushed"][1] for t in ticks)), 4)
@@ -609,6 +622,17 @@ class ServingObservatory:
                 sum(t["pushed"][1] for t in ticks) / pushes, 3)
             out["frames"] = [sum(t["fr"][0] for t in ticks),
                              sum(t["fr"][1] for t in ticks)]
+        blocks = sum(t["dn"][2] for t in ticks)
+        if blocks:
+            # a block server: row-passes (denoising and commit) a
+            # block committed, and positions fixed a denoising pass
+            out["denoise"] = {
+                "passes_per_block": round(
+                    sum(t["dn"][0] + t["dn"][1] for t in ticks)
+                    / blocks, 3),
+                "tokens_per_pass": round(
+                    sum(t["dn"][3] for t in ticks)
+                    / max(1, sum(t["dn"][0] for t in ticks)), 3)}
         routed = [t for t in ticks if t["moe"] is not None]
         if routed:
             # a decode step's routing load: means over the steps, the

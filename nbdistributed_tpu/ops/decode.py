@@ -534,7 +534,13 @@ def paged_decode_attention(q, k_pool, v_pool, layer, table, pos, *,
     same mathematics as :func:`flash_decode_attention`, over keys that
     lie in table-selected physical blocks instead of a dense row.
 
-    q: (S, H, D) — this step's queries, one per slot;
+    q: (S, H, D) — this step's queries, one per slot — or (S, L, H, D):
+    a block of ``L`` queries a slot that share the one bound ``pos``
+    (a block-causal model's step: ``pos`` is the block's last position,
+    and every query of the block attends keys ``<= pos``, its own block
+    in both directions).  They fold into the kernel's query-group axis,
+    ``L`` times the queries a KV head, so a slot's pages are read once
+    a slot and not once a query;
     k_pool/v_pool: (L, NB+1, Hkv, bt, D) — the whole physical pool
     (:func:`~..models.paged_kv.make_paged_pool`), of which only layer
     ``layer`` (a traced int32 scalar) is read;
@@ -548,9 +554,24 @@ def paged_decode_attention(q, k_pool, v_pool, layer, table, pos, *,
     (they ride the kernel's copies in interpret mode; on the chip Mosaic
     refuses the copy of a page of them, one lane wide, so a served int8
     pool gathers: :func:`~..models.paged_kv.reads_in_place`).
-    Returns (S, H, D).  Only the pages of a slot's live range are
-    copied out of the pool: the step's traffic goes with the tokens
-    held, not with ``max_len``."""
+    Returns (S, H, D), or (S, L, H, D).  Only the pages of a slot's
+    live range are copied out of the pool: the step's traffic goes with
+    the tokens held, not with ``max_len``."""
+    if q.ndim == 4:
+        S, L, H, D = q.shape
+        Hkv = k_pool.shape[2]
+        if H % Hkv:
+            raise ValueError(f"n_heads {H} not divisible by n_kv_heads "
+                             f"{Hkv}")
+        g = H // Hkv
+        # (S, L, Hkv, g, D) -> (S, Hkv*L*g, D): a KV head's L*g queries
+        folded = (q.reshape(S, L, Hkv, g, D).transpose(0, 2, 1, 3, 4)
+                  .reshape(S, Hkv * L * g, D))
+        out = paged_decode_attention(
+            folded, k_pool, v_pool, layer, table, pos, active=active,
+            scale=scale, window=window, k_s=k_s, v_s=v_s)
+        return (out.reshape(S, Hkv, L, g, D).transpose(0, 2, 1, 3, 4)
+                .reshape(S, L, H, D))
     S, H, D = q.shape
     Hkv = k_pool.shape[2]
     if H % Hkv:
@@ -608,11 +629,13 @@ def paged_prefill_attention(q, k_pool, v_pool, layer, table, start,
                             length=None, *, scale: float,
                             window: int | None = None,
                             v_width: int | None = None,
-                            k_s=None, v_s=None):
+                            k_s=None, v_s=None, block: int = 1):
     """Attention of a chunk of new tokens over the paged pool:
     :func:`paged_decode_attention` with ``S`` queries a row, causal
     among themselves.  The chunk's own keys must already be in the
-    pool.
+    pool.  ``block`` > 1 makes the mask block-causal: the query at
+    position ``p`` attends every key up to the last position of its
+    block, ``(p // block + 1) * block - 1`` (1: the causal mask).
 
     q: (B, S, H, D) — token ``i`` of row ``b`` sits at position
     ``start[b] + i``; ``length`` (B,) the real tokens of each row's
@@ -671,6 +694,8 @@ def paged_prefill_attention(q, k_pool, v_pool, layer, table, start,
     def row(b):
         end = start[b] + length[b]
         qpos = (start[b] + tok)[:, None]                # (R, 1)
+        # the last key a query attends: itself, or its block's last
+        bound = qpos if block == 1 else (qpos // block + 1) * block - 1
         last = jnp.maximum(jnp.minimum(start[b] + S, end) - 1, 0)
         first = (jnp.maximum(start[b] + 1 - window, 0)
                  if window is not None else 0)
@@ -688,7 +713,7 @@ def paged_prefill_attention(q, k_pool, v_pool, layer, table, start,
             if k_s is not None:
                 k, v = k * tile(k_s, ids), v * tile(v_s, ids)
             ki = t * keys + t_idx                       # (keys,)
-            keep = (ki[None] <= qpos) & (ki[None] < end)
+            keep = (ki[None] <= bound) & (ki[None] < end)
             if window is not None:
                 keep &= ki[None] > qpos - window
             v = jnp.where((ki < end)[None, :, None], v, 0.0)

@@ -642,3 +642,31 @@ def shared_routed_ffn(x, params: dict, *, top_k: int,
         y = y + EXPERT_FORMS[expert](xt, params["shared"])
     return (y.reshape(orig_shape),
             routing_load(expert_idx, count, mask_t))
+
+
+def softmax_routed_ffn(x, params: dict, *, top_k: int, token_mask=None):
+    """Fine-grained SwiGLU experts chosen by a softmax router, no
+    shared expert (the Qwen3-MoE layer): ``y = sum_{i in top_k} g_i
+    E_i(x)`` with ``p = softmax(x W_r)`` over all of
+    ``params["router"]``'s experts in float32 and the ``top_k`` largest
+    ``p`` renormalised to sum 1 (:func:`top_k_routing`).  The experts
+    run as dropless grouped-matmul segments exactly as
+    :func:`shared_routed_ffn`'s do (:func:`_dropless_ffn`: a token's
+    result depends on no other token and on no shape), and
+    ``token_mask`` means what it means there.  x: (..., D) -> (same
+    shape, :func:`routing_load`)."""
+    orig_shape = x.shape
+    xt = x.reshape(-1, orig_shape[-1])
+    E = params["router"].shape[-1]
+    mask_t = None if token_mask is None else token_mask.reshape(-1)
+    logits = jnp.matmul(xt.astype(jnp.float32),
+                        params["router"].astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    gates, expert_idx, _ = top_k_routing(logits, top_k)
+    from ..ops.grouped import xla_tiles_narrow
+    with jax.named_scope("experts"):
+        y = _dropless_ffn(xt, params, gates, expert_idx, E,
+                          token_mask=mask_t,
+                          kernel=xla_tiles_narrow(
+                              xt.shape[-1], _rows(params["w_down"])))
+    return y.reshape(orig_shape), routing_load(expert_idx, E, mask_t)

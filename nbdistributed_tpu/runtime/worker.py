@@ -1447,9 +1447,14 @@ class DistributedWorker:
         if st.t_reply is not None:
             tick["turnaround"] = round(t_in - st.t_reply, 6)
         st.t_reply = t_out
+        # A block server's record of a finished stream, whole: the
+        # pass of its block at which each token was fixed.
+        passes = {rid: list(srv.fixed_at[st.rids[rid]]) for rid in finished
+                  if st.rids[rid] in srv.fixed_at}
         return msg.reply(
             data={"status": "ok", "emitted": emitted,
                   "finished": finished, "errors": errors,
+                  **({"passes": passes} if passes else {}),
                   "active": srv.n_active,
                   "slots": srv._B,
                   "pending": len(srv._pending),

@@ -494,13 +494,15 @@ def test_tick_totals_outlast_the_ring_and_two_readings_give_a_slice():
     assert first == {**zero,
                      "steps": 560.0, "dc": 7000.0, "pf": 49000.0,
                      "chunks": 140.0, "state_bytes": 70000.0,
-                     "moe_touched": 35000.0, "moe_rows": 210000.0}
+                     "moe_touched": 35000.0, "moe_rows": 210000.0,
+                     "kv_bytes": 21000.0}
     obs.note_tick(70, 0, {}, {**tick, "moe": None})
     second = obs.ticks_summary()["totals"]
     assert {k: second[k] - first[k] for k in first} == {
         **zero,
         "steps": 8.0, "dc": 100.0, "pf": 700.0, "chunks": 2.0,
-        "state_bytes": 1000.0, "moe_touched": 0.0, "moe_rows": 0.0}
+        "state_bytes": 1000.0, "moe_touched": 0.0, "moe_rows": 0.0,
+        "kv_bytes": 300.0}
 
 
 # ----------------------------------------------------------------------

@@ -655,8 +655,8 @@ def test_push_counters_are_absent_without_a_push_and_sum_over_ranks():
 
 
 @pytest.mark.parametrize("frames, want", [
-    (True, "pushed early 67%, 1 steps/push"),
-    (False, "pushed early 0%, 3 steps/push")])
+    (True, "pushed early 67%, 1 tokens/push"),
+    (False, "pushed early 0%, 3 tokens/push")])
 def test_serve_status_line_shows_the_push_counters(tmp_path, capsys,
                                                    frames, want):
     from nbdistributed_tpu.magics.magic import DistributedMagics
