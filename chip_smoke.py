@@ -266,6 +266,11 @@ class Smoke:
     def kernels(self):
         k = self.cell(_KERNELS_CELL, ranks="[0]")[0]
         self.facts["kernels"] = k["checks"]
+        for c in k["checks"]:
+            if "ms" in c:
+                print(f"{c['name']}: {c['ms']} ms a call, XLA's ragged_dot "
+                      f"{c['xla_ms']} ms; err {c['err']:.4g} (tol "
+                      f"{c['tol']:.4g})")
         bad = [c for c in k["checks"]
                if not (c["mosaic"] >= 1 and c["err"] <= c["tol"])]
         if bad:
@@ -622,6 +627,38 @@ _check("decode_int8",
            _dequantize_kv(v8, v_s).astype(jnp.bfloat16), pos),
        qd, k8, v8, pos, k_s, v_s)
 del q, k, v, do, qd, kc, vc, k8, v8, k_s, v_s
+# The grouped matmul at SDAR-30B-A3B's call: 512 token rows x 8 choices
+# sorted over 128 experts of 2048 x 768, uneven groups that keep 3,871
+# of the 4,096 rows; the rows the groups cover against XLA's ragged_dot,
+# and both kernels' time a call.
+import time
+import numpy as np
+from nbdistributed_tpu.ops.grouped import grouped_matmul
+M, E, KEPT = 4096, 128, 3871
+_rng = np.random.default_rng(7)
+gs = jnp.asarray(_rng.multinomial(KEPT, _rng.dirichlet(np.full(E, 4.0))),
+                 jnp.int32)
+def _ms(fn, *args, n=50):
+    jf = jax.jit(fn)
+    jf(*args).block_until_ready()
+    t0 = time.perf_counter()
+    for _ in range(n):
+        out = jf(*args)
+    out.block_until_ready()
+    return round((time.perf_counter() - t0) / n * 1e3, 4)
+_gm = lambda x, w, g: grouped_matmul(x, w, g)[:KEPT]
+_rd = lambda x, w, g: jax.lax.ragged_dot(x, w, g)[:KEPT]
+for name, kk, nn in (("gmm_sdar_up", 2048, 768), ("gmm_sdar_down", 768, 2048)):
+    xg = jax.random.normal(_ks[0], (M, kk), jnp.bfloat16)
+    wg = (jax.random.normal(_ks[1], (E, kk, nn), jnp.float32)
+          * kk ** -0.5).astype(jnp.bfloat16)
+    _check(name, _gm, _rd, xg, wg, gs)
+    checks[-1].update(ms=_ms(_gm, xg, wg, gs), xla_ms=_ms(_rd, xg, wg, gs))
+# a training step differentiates it: the derivative is ragged_dot's
+_dg = lambda f: jax.grad(
+    lambda x, w, g: (f(x, w, g).astype(jnp.float32) ** 2).sum(), argnums=(0, 1))
+_check("gmm_sdar_grad", _dg(_gm), _dg(_rd), xg, wg, gs)
+del xg, wg
 _emit(checks=checks)
 '''
 

@@ -10,26 +10,41 @@ read is 0.8 ms (seen on the chip: 9.5% of the read roofline, 87% of the
 busy time).  Here a tile spans the whole contraction and as many
 output columns as ``BLOCK_BYTES`` of weights hold: a call is a few
 hundred grid steps, each one block of an expert's matrix read once.
+
+On the TPU this is the repo's grouped matmul at every width
+(:func:`ragged_dot`, which ``parallel/expert.py`` calls for every
+unquantized expert layer): XLA's kernel read SDAR's and JoyAI's
+``2048 x 768`` experts, whole 256s both ways, at 31.7% to 49% of the
+peak, where a tile here *is* one expert's 3 MiB matrix (82%).  Off the
+TPU the interpreter would walk every test's experts tile by tile, and
+``jax.lax.ragged_dot`` stays.
 """
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-from ._common import use_interpret as _use_interpret
+from . import decode
 
 BLOCK_BYTES = 4 << 20       # of one weight tile (two are in flight)
 ROW_TILE = 128
 
 
-def xla_tiles_narrow(k: int, n: int) -> bool:
-    """Whether experts ``k`` wide in and ``n`` wide inside should take
-    :func:`grouped_matmul`: on the TPU, where a width that is not whole
-    256s leaves XLA's kernel tiles of 128 (the module docstring).
-    Elsewhere, and for widths that are whole 256s (``2048 x 768`` reads
-    its weights at half the peak under XLA's kernel, five times what
-    ``2688 x 1856`` reached), ``jax.lax.ragged_dot`` stays."""
-    return not _use_interpret() and bool(k % 256 or n % 256)
+def _use_interpret():
+    # One switch for a program's Pallas kernels: what forces the decode
+    # kernels compiled (a sandbox compile for the TPU,
+    # benchmarks/tests/compile_sizes.py) forces this one.
+    return decode._use_interpret()
+
+
+def ragged_dot(x, w, group_sizes):
+    """``jax.lax.ragged_dot(x, w, group_sizes)``, on the TPU under
+    :func:`grouped_matmul`: there the rows past the groups' total are
+    undefined, not zeros."""
+    if _use_interpret():
+        return jax.lax.ragged_dot(x, w.astype(x.dtype), group_sizes)
+    return grouped_matmul(x, w, group_sizes)
 
 
 def _column_tile(k: int, n: int, itemsize: int) -> int:
@@ -41,12 +56,14 @@ def _column_tile(k: int, n: int, itemsize: int) -> int:
     return max(fits) if fits else n
 
 
+@jax.custom_vjp
 def grouped_matmul(x, w, group_sizes):
     """``x[rows of group g] @ w[g]`` for every group.  x (M, K), its
     rows sorted by group; w (G, K, N); group_sizes (G,) int32, summing
     to at most M -> (M, N) in x's dtype.  Rows past the groups' total
     are not computed: what they hold is undefined, and the caller masks
-    them."""
+    them.  A Mosaic call: operands sharded over a mesh need a
+    ``shard_map`` around it.  Its derivative is ``ragged_dot``'s."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
     m, k = x.shape
     n = w.shape[2]
@@ -58,3 +75,19 @@ def grouped_matmul(x, w, group_sizes):
               tiling=(ROW_TILE, k, _column_tile(k, n, x.dtype.itemsize)),
               interpret=_use_interpret())
     return out[:m] if pad else out
+
+
+def _forward(x, w, group_sizes):
+    return grouped_matmul(x, w, group_sizes), (x, w, group_sizes)
+
+
+def _backward(saved, g):
+    # XLA's transposes of the ragged product: the rows past the total
+    # give and take nothing there either
+    x, w, group_sizes = saved
+    _, vjp = jax.vjp(lambda x, w: jax.lax.ragged_dot(
+        x, w.astype(x.dtype), group_sizes), x, w)
+    return (*vjp(g), None)
+
+
+grouped_matmul.defvjp(_forward, _backward)

@@ -189,36 +189,30 @@ def _route_sort(expert_idx, E: int, token_mask=None):
     return order, e_sorted, (order % T).astype(jnp.int32), counts
 
 
-def _ragged_expert_linear(xs, w, group_sizes, e_sorted,
-                          kernel: bool = False):
-    """``ragged_dot`` over expert segments, supporting int8 weight-only
-    quantized leaves: the per-(expert, output-channel) scales become a
-    per-ROW rescale gathered by each row's expert id (constant along
-    the contraction dim, so the grouped dot still reads raw int8).
-    ``kernel``: the Pallas grouped matmul with tiles from the shapes
-    (:func:`~..ops.grouped.grouped_matmul`) in the place of XLA's, for
-    experts whose widths leave XLA's kernel tiles of 128."""
+def _ragged_expert_linear(xs, w, group_sizes, e_sorted):
+    """``ragged_dot`` over expert segments (on the TPU the Pallas
+    grouped matmul: :func:`~..ops.grouped.ragged_dot`), supporting int8
+    weight-only quantized leaves: the per-(expert, output-channel)
+    scales become a per-ROW rescale gathered by each row's expert id
+    (constant along the contraction dim, so the grouped dot still reads
+    raw int8)."""
     from ..models.transformer import is_quantized
+    from ..ops.grouped import ragged_dot
     if is_quantized(w):
         y = jax.lax.ragged_dot(xs, w["q8"].astype(xs.dtype),
                                group_sizes)
         s_rows = w["s"][jnp.clip(e_sorted, 0, w["s"].shape[0] - 1), 0]
         return (y.astype(jnp.float32) * s_rows).astype(xs.dtype)
-    if kernel:
-        from ..ops.grouped import grouped_matmul
-        return grouped_matmul(xs, w, group_sizes)
-    return jax.lax.ragged_dot(xs, w.astype(xs.dtype), group_sizes)
+    return ragged_dot(xs, w, group_sizes)
 
 
 def _dropless_ffn(xt, params, gates, expert_idx, E: int,
-                  token_mask=None, expert: str = "swiglu",
-                  kernel: bool = False):
+                  token_mask=None, expert: str = "swiglu"):
     """MegaBlocks-style dropless expert compute: sort the (token,
     choice) pairs by expert and run the experts (``expert``: one of
     :data:`EXPERT_FORMS`) as grouped matmuls over the variable-size
-    segments (``jax.lax.ragged_dot``, or with ``kernel`` the Pallas
-    grouped matmul: :func:`_ragged_expert_linear`) — every routed token
-    is computed, no capacity buffer exists, and compute is exactly
+    segments (:func:`_ragged_expert_linear`) — every routed token is
+    computed, no capacity buffer exists, and compute is exactly
     sum_e n_e GEMM rows (what the MXU would do with perfect per-expert
     batching).
 
@@ -236,7 +230,7 @@ def _dropless_ffn(xt, params, gates, expert_idx, E: int,
 
     xs = jnp.where(keep[:, None], xt[tok], 0)     # (kT, D)
     grouped = lambda x, w: _ragged_expert_linear(x, w, group_sizes,
-                                                 e_sorted, kernel)
+                                                 e_sorted)
     rows = EXPERT_FORMS[expert](xs, params, grouped)    # (kT, D)
     # The rows past the covered total are zeros only in XLA's own
     # ragged_dot; the TPU's grouped-matmul kernel leaves them unwritten,
@@ -607,9 +601,8 @@ def shared_routed_ffn(x, params: dict, *, top_k: int,
     two).  The routed experts run as dropless grouped-matmul segments
     (:func:`_dropless_ffn`: no capacity, so the result of a token
     depends on no other token and on no shape — bucketed, chunked and
-    batched calls compute the same thing), XLA's kernel or the Pallas
-    one by the experts' widths (:func:`~..ops.grouped.xla_tiles_narrow`);
-    every token passes through ``params["shared"]``.
+    batched calls compute the same thing); every token passes through
+    ``params["shared"]``.
 
     ``token_mask`` (bool, ``x.shape[:-1]``): masked tokens (pad
     positions, idle slots) route nowhere and touch no expert's
@@ -632,12 +625,9 @@ def shared_routed_ffn(x, params: dict, *, top_k: int,
         local = expert_idx - first
         expert_idx = jnp.where((local >= 0) & (local < count), local,
                                count)
-    from ..ops.grouped import xla_tiles_narrow
     with jax.named_scope("experts"):
         y = _dropless_ffn(xt, params, gates, expert_idx, count,
-                          token_mask=mask_t, expert=expert,
-                          kernel=xla_tiles_narrow(
-                              xt.shape[-1], _rows(params["w_down"])))
+                          token_mask=mask_t, expert=expert)
     with jax.named_scope("shared_expert"):
         y = y + EXPERT_FORMS[expert](xt, params["shared"])
     return (y.reshape(orig_shape),
@@ -663,10 +653,7 @@ def softmax_routed_ffn(x, params: dict, *, top_k: int, token_mask=None):
                         params["router"].astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
     gates, expert_idx, _ = top_k_routing(logits, top_k)
-    from ..ops.grouped import xla_tiles_narrow
     with jax.named_scope("experts"):
         y = _dropless_ffn(xt, params, gates, expert_idx, E,
-                          token_mask=mask_t,
-                          kernel=xla_tiles_narrow(
-                              xt.shape[-1], _rows(params["w_down"])))
+                          token_mask=mask_t)
     return y.reshape(orig_shape), routing_load(expert_idx, E, mask_t)

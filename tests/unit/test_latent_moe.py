@@ -23,16 +23,18 @@ from nbdistributed_tpu.models import (DecodeServer, config_from_hf_json,
                                       forward_with_cache, generate,
                                       init_kv_cache, init_latent_moe_model,
                                       init_moe_model, init_params,
-                                      latent_moe_forward,
+                                      init_sdar_model, latent_moe_forward,
                                       latent_moe_shardings, tiny_config,
                                       tiny_latent_moe_config,
-                                      tiny_moe_config)
+                                      tiny_moe_config, tiny_sdar_config)
 from nbdistributed_tpu.models.paged_kv import make_paged_pool
 from nbdistributed_tpu.observability.servingobs import ServingObservatory
+from nbdistributed_tpu.ops import grouped
 from nbdistributed_tpu.ops.decode import paged_latent_decode_attention
 from nbdistributed_tpu.parallel.expert import (routing_load,
                                                shared_routed_ffn,
-                                               sigmoid_bias_routing)
+                                               sigmoid_bias_routing,
+                                               softmax_routed_ffn)
 
 pytestmark = [pytest.mark.unit, pytest.mark.serve]
 
@@ -398,19 +400,26 @@ def test_init_and_shardings_have_the_tree_the_weights_module_makes(model):
     assert isinstance(tiny_latent_moe_config(), type(cfg))
 
 
+@pytest.mark.parametrize("layer", ["shared", "softmax"])
 def test_rows_the_grouped_matmul_leaves_unwritten_reach_no_output(
-        model, monkeypatch):
-    """The TPU's grouped-matmul kernel does not write the rows past the
-    covered total (XLA's own does, with zeros): whatever they hold, a
-    masked token's output stays finite and a live one's unchanged."""
-    cfg, params = model
-    moe = params["layers"][0]["moe"]
+        model, monkeypatch, layer):
+    """The TPU's grouped-matmul kernels do not write the rows past the
+    covered total (XLA's own on the CPU does, with zeros): whatever they
+    hold, a masked token's output stays finite and a live one's
+    unchanged, in JoyAI's layer and in SDAR's."""
+    if layer == "shared":
+        cfg, params = model
+        ffn, moe = shared_routed_ffn, params["layers"][0]["moe"]
+        kw = dict(top_k=cfg.top_k, routed_scale=cfg.routed_scale)
+    else:
+        cfg = tiny_sdar_config(dtype=jnp.float32)
+        moe = init_sdar_model(jax.random.PRNGKey(3), cfg)["layers"][0]["moe"]
+        ffn, kw = softmax_routed_ffn, dict(top_k=cfg.top_k)
     x = jnp.asarray(np.random.default_rng(4).normal(
         size=(5, cfg.d_model)), jnp.float32)
-    mask = jnp.asarray([True, False, True, False, True])
-    kw = dict(top_k=cfg.top_k, routed_scale=cfg.routed_scale,
-              token_mask=mask)
-    want, _ = shared_routed_ffn(x, moe, **kw)
+    kw["token_mask"] = jnp.asarray([True, False, True, False, True])
+    want, _ = ffn(x, moe, **kw)
+    assert bool(jnp.any(want[0] != 0))
     real = jax.lax.ragged_dot
 
     def unwritten_tail(lhs, rhs, group_sizes, **k):
@@ -419,6 +428,39 @@ def test_rows_the_grouped_matmul_leaves_unwritten_reach_no_output(
         return jnp.where(covered[:, None], out, jnp.nan)
 
     monkeypatch.setattr(jax.lax, "ragged_dot", unwritten_tail)
-    got, _ = shared_routed_ffn(x, moe, **kw)
+    got, _ = ffn(x, moe, **kw)
     assert bool(jnp.isfinite(got).all())
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("compiled", [True, False])
+def test_which_grouped_matmul_the_expert_layers_run(monkeypatch, compiled):
+    """One rule for every expert layer, in ``ops/grouped.py::ragged_dot``:
+    the Pallas kernel where it runs compiled (JoyAI's and SDAR's widths
+    are whole 256s and take it too), ``jax.lax.ragged_dot`` on the CPU.
+    Traced over shapes: no weight is made."""
+    if compiled:
+        monkeypatch.setattr(grouped, "_use_interpret", lambda: False)
+    seen = []
+
+    def spy(name):
+        def dot(x, w, group_sizes):
+            seen.append(name)
+            return jnp.zeros((x.shape[0], w.shape[2]), x.dtype)
+        return dot
+
+    monkeypatch.setattr(grouped, "grouped_matmul", spy("pallas"))
+    monkeypatch.setattr(jax.lax, "ragged_dot", spy("xla"))
+    E, d, f = 4, 2048, 768
+    mat = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    moe = {"router": jax.ShapeDtypeStruct((d, E), jnp.float32),
+           "w_gate": mat(E, d, f), "w_up": mat(E, d, f),
+           "w_down": mat(E, f, d)}
+    shared = {**moe, "bias": jax.ShapeDtypeStruct((E,), jnp.float32),
+              "shared": {"w_gate": mat(d, f), "w_up": mat(d, f),
+                         "w_down": mat(f, d)}}
+    jax.eval_shape(lambda x, p: softmax_routed_ffn(x, p, top_k=2),
+                   mat(8, d), moe)
+    jax.eval_shape(lambda x, p: shared_routed_ffn(
+        x, p, top_k=2, routed_scale=2.5), mat(8, d), shared)
+    assert seen == ["pallas" if compiled else "xla"] * 6

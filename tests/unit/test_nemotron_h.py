@@ -298,16 +298,23 @@ def test_the_whole_share_in_swiglu_form_is_the_layer_joyai_called():
         np.testing.assert_array_equal(load, want_load)
 
 
-@pytest.mark.parametrize("m, sizes", [(256, [100, 0, 56, 30]),
-                                      (74, [0, 9, 40, 25]),
-                                      (128, [0, 0, 0, 0])])
+@pytest.mark.parametrize("m, sizes", [
+    (256, [100, 0, 56, 30]),
+    (74, [0, 9, 40, 25]),
+    (128, [0, 0, 0, 0]),
+    # SDAR's call in small: many groups inside one row tile, empty
+    # ones among them, one across the tile's edge (rows 111-140), a
+    # total (219) under m
+    (256, [20, 0, 13, 31, 7, 0, 40, 30, 9, 16, 0, 25, 12, 8, 5, 3]),
+    # four groups share every tile exactly, and the total is m
+    (512, [32] * 16)])
 def test_grouped_matmul_is_ragged_dot_over_the_rows_in_a_group(m, sizes):
     """The Pallas grouped matmul (interpreted here) against XLA's
     ``ragged_dot`` on the rows the groups cover; rows past their total
     are undefined.  A row count that is not whole tiles is padded."""
     from nbdistributed_tpu.ops.grouped import _column_tile, grouped_matmul
     x = jax.random.normal(jax.random.PRNGKey(0), (m, 64))
-    w = jax.random.normal(jax.random.PRNGKey(1), (4, 64, 256)) / 8
+    w = jax.random.normal(jax.random.PRNGKey(1), (len(sizes), 64, 256)) / 8
     gs = jnp.asarray(sizes, jnp.int32)
     got = grouped_matmul(x, w, gs)
     want = jax.lax.ragged_dot(x, w, gs)
@@ -319,6 +326,29 @@ def test_grouped_matmul_is_ragged_dot_over_the_rows_in_a_group(m, sizes):
     assert _column_tile(2688, 1920, 2) == 640
     assert _column_tile(1856, 2688, 2) == 896
     assert _column_tile(2048, 768, 2) == 768 and _column_tile(64, 32, 4) == 32
+    # SDAR's: a tile is one expert's whole matrix, 3 MiB
+    assert _column_tile(768, 2048, 2) == 2048
+
+
+@pytest.mark.parametrize("m, sizes", [(256, [100, 0, 56, 30]),
+                                      (128, [32] * 4)])
+def test_grouped_matmul_has_ragged_dots_derivative(m, sizes):
+    """A training step differentiates the expert layer: the kernel's
+    derivative is ``ragged_dot``'s, in ``x`` and in ``w``, and the rows
+    past the groups' total give and take nothing."""
+    from nbdistributed_tpu.ops.grouped import grouped_matmul
+    x = jax.random.normal(jax.random.PRNGKey(0), (m, 64))
+    w = jax.random.normal(jax.random.PRNGKey(1), (len(sizes), 64, 256)) / 8
+    gs = jnp.asarray(sizes, jnp.int32)
+    covered = (jnp.arange(m) < sum(sizes))[:, None]
+    weigh = jax.random.normal(jax.random.PRNGKey(2), (m, 256))
+    loss = lambda dot: lambda x, w: jnp.sum(
+        jnp.where(covered, dot(x, w, gs), 0) * weigh)
+    got = jax.grad(loss(grouped_matmul), (0, 1))(x, w)
+    want = jax.grad(loss(jax.lax.ragged_dot), (0, 1))(x, w)
+    for g, r in zip(got, want):
+        np.testing.assert_allclose(g, r, rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got[0])[sum(sizes):].any()
 
 
 def test_routing_load_counts_over_the_experts_held():

@@ -171,6 +171,53 @@ def test_prefill_then_passes_are_the_references_forward(params, lens):
         block[:len(seqs)] = MASK
 
 
+def test_a_pass_under_the_pallas_grouped_matmul_is_the_ragged_dots(
+        params, monkeypatch):
+    """What the chip runs (the experts under ``ops/grouped.py``'s
+    kernel, interpreted here) against what the CPU runs, on
+    one server's state mid-block with two of four rows idle: the gmm
+    leaves the rows past the routed total unwritten, and the idle rows'
+    choices sort there.  The live rows' logits agree and are finite,
+    and the server's streams under the kernel are the reference's."""
+    from nbdistributed_tpu.ops import grouped
+    srv = server(params, max_batch=4)
+    reqs = list(zip(prompts((18, 5)), (9, 7)))
+    for p, m in reqs:
+        srv.submit(p, m)
+    for _ in range(6):                          # prefilled, into a block
+        srv.step()
+    live = np.asarray(srv._active)
+    assert live.sum() == 2 and not live.all()
+    cfg, calls = program_config(), []
+
+    def a_pass():
+        # a fresh function a path: nothing traced under the other one
+        return jax.jit(lambda pool, block, lens, table: forward_with_cache(
+            params, block, pool, lens, cfg, row_mask=srv._active,
+            block_table=table, with_moe_load=True))(
+            srv._cache, srv._block["tokens"], srv._lens,
+            srv._paged.device_table())
+
+    want, _, want_load = a_pass()
+    monkeypatch.setattr(
+        grouped, "ragged_dot",
+        lambda *a: calls.append(1) or grouped.grouped_matmul(*a))
+    got, _, load = a_pass()
+    assert len(calls) == 3 * cfg.n_layers
+    assert bool(jnp.isfinite(got[live]).all())
+    np.testing.assert_allclose(np.asarray(got[live]),
+                               np.asarray(want[live]), **TOL)
+    np.testing.assert_array_equal(load, want_load)
+    # and a server whose passes all run under the kernel
+    srv = server(params, max_batch=4)
+    rids = [srv.submit(p, m) for p, m in reqs]
+    out = srv.run_until_done(300)
+    assert len(calls) > 3 * cfg.n_layers
+    for (prompt, max_new), rid in zip(reqs, rids):
+        want_toks, want_when = reference(prompt, max_new)
+        assert out[rid] == want_toks and srv.fixed_at[rid] == want_when
+
+
 # ----------------------------------------------------------------------
 # the server against the published loop
 
