@@ -255,12 +255,12 @@ class Smoke:
         f0 = facts[0]
         self.facts = {"versions": f0["versions"],
                       "transport": {"fleet": self.DM._comm.transport},
-                      "tuned_blocks": f0["tuned_blocks"],
+                      "flash_tiles": f0["flash_tiles"],
                       "compile_cache": f0["compile_cache"],
                       "hbm_limit_gb": limit_gb}
         print(f"device {self.device} · {f0['versions']} · transport "
-              f"{self.DM._comm.transport} · tuned block table "
-              f"{'loaded' if f0['tuned_blocks'] else 'absent'} · compile "
+              f"{self.DM._comm.transport} · flash tiles at S=4096 "
+              f"{f0['flash_tiles']} · compile "
               f"cache {f0['compile_cache']} · depth {self.layers}")
 
     def kernels(self):
@@ -571,13 +571,17 @@ cfg = mistral_7b_config(n_layers={layers})
 
 _FACTS_CELL = _EMIT + '''
 import jaxlib, libtpu
-from nbdistributed_tpu.ops import attention as _att, decode as _dec
+from nbdistributed_tpu.ops import attention as _att
 _d = jax.local_devices()[0]
 _emit(versions=dict(jax=jax.__version__, jaxlib=jaxlib.__version__,
                     libtpu=libtpu.__version__),
       coords=list(_d.coords),
       all_reduce=float(all_reduce(jnp.float32(rank + 1))),
-      tuned_blocks=bool(_att.TUNED_BLOCKS or _dec.DECODE_TUNED_BLOCKS),
+      # (block_q, block_k) each flash kernel derives for the training
+      # shape; the `kernels` phase checks the gradients at them
+      flash_tiles={k: list(_att._block_sizes(
+          None, None, 4096, 4096, 128, 4, interpret=False, kernel=k))
+          for k in ("fwd", "dq", "dkv")},
       compile_cache=jax.config.jax_compilation_cache_dir)
 '''
 

@@ -405,8 +405,8 @@ def _flash_on_mesh(q, k, v, window, segment_ids):
     see ``ring_attention``), every other axis replicated.  Axes an
     enclosing shard_map already made manual are local as they are.
     """
-    # block sizes None -> TUNED_BLOCKS table (tune_flash.py) with the
-    # 128x128 fallback.
+    # block sizes None -> each kernel's tile from these shapes
+    # (ops/attention.py::_block_sizes).
     def local(q, k, v, seg=None):
         return flash_attention(q, k, v, True, None, None, None, window,
                                seg)

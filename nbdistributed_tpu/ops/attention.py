@@ -141,16 +141,22 @@ def _window_last_q_block(k_idx, q_off, k_off, block_q, block_k,
 
 def _keep_mask(q_idx, kb, *, block_q, block_k, q_off, k_off,
                seq_k_valid, causal, seq_q_valid=None, window=None,
-               qseg=None, kseg=None):
+               qseg=None, kseg=None, keys_first: bool = False):
     """(block_q, block_k) bool: which score entries are real — inside
     the valid key range, (optionally) inside the valid query range,
     at-or-below the offset causal diagonal, (optionally) within the
     sliding window, and (optionally) in the same packed-document
-    segment (``qseg`` (block_q, 1) vs ``kseg`` (1, block_k))."""
-    qi = (q_idx * block_q
-          + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0))
-    ki = (kb * block_k
-          + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1))
+    segment (``qseg`` (block_q, 1) vs ``kseg`` (1, block_k)).
+
+    ``keys_first``: the same mask for a transposed score tile,
+    (block_k, block_q), with ``qseg`` (1, block_q) and ``kseg``
+    (block_k, 1) — the dK/dV kernel's orientation."""
+    shape = (block_k, block_q) if keys_first else (block_q, block_k)
+    q_dim = 1 if keys_first else 0
+    qi = q_idx * block_q + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                    q_dim)
+    ki = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, shape,
+                                                 1 - q_dim)
     keep = ki < seq_k_valid
     if seq_q_valid is not None:
         keep = keep & (qi < seq_q_valid)
@@ -267,6 +273,10 @@ def _flash_kernel(offs_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
         lse_ref[0, g] = (ms[g] + jnp.log(l_safe))[:, 0]
 
 
+def _round_up(n: int, unit: int) -> int:
+    return -(-n // unit) * unit
+
+
 def _fold_heads(x, S_pad):
     """(B, S, Hkv, D) → (B*Hkv, S_pad, D), zero-padding the seq axis.
     The per-(batch, kv-head) layout gives every kernel program
@@ -358,8 +368,8 @@ def _flash_forward(q, k, v, *, causal: bool, scale: float,
     # Pad both sequence axes to block multiples; padded keys are masked
     # inside the kernel (dynamic-slice clamping would otherwise re-read
     # earlier rows), padded query rows are sliced off below.
-    Sq_pad = -(-Sq // block_q) * block_q
-    Sk_pad = -(-Sk // block_k) * block_k
+    Sq_pad = _round_up(Sq, block_q)
+    Sk_pad = _round_up(Sk, block_k)
 
     qt = _fold_q_gqa(q, Hkv, Sq_pad)      # (B*Hkv, G, Sq_pad, D)
     kt = _fold_heads(k, Sk_pad)           # (B*Hkv, Sk_pad, D)
@@ -420,6 +430,9 @@ def _flash_forward(q, k, v, *, causal: bool, scale: float,
             ],
         ),
         interpret=interpret,
+        compiler_params=_mosaic_params("fwd", block_q, block_k, Sq_pad,
+                                       Sk_pad, D, group,
+                                       q.dtype.itemsize),
         name="nbd_flash_fwd",
     )(_offsets_array(offsets), *args)
     return _unfold_q_gqa(out, B, Hkv, Sq), lse
@@ -511,11 +524,18 @@ def _flash_bwd_dkv_kernel(offs_ref, k_ref, v_ref, q_ref, do_ref, lse_ref,
                           block_k: int, group: int,
                           window: int | None = None,
                           qseg_ref=None, kseg_ref=None):
-    """dK/dV for one k-block.  The GQA group rides the *grid* (innermost
-    dim, sequential on-core): each step stages only one head's
-    (Sq_pad, D) q/dO plane — the same per-program VMEM footprint as an
-    MHA kernel — and accumulates this k-block's dk/dv across the group
-    in fp32 scratch, writing out on the last head."""
+    """dK/dV for one k-block and one query head.  The GQA group rides
+    the *grid* (innermost dim, sequential on-core): each step stages
+    one head's (Sq_pad, D) q/dO planes — the same per-program VMEM
+    footprint as an MHA kernel — and accumulates this k-block's dk/dv
+    across the group in fp32 scratch, writing out on the last head.
+
+    The score tile is computed transposed, keys on the sublanes and
+    queries on the lanes: lse and delta are then rows ``(1, Bq)`` as
+    the forward wrote them (a staged plane is Sq_pad floats; as a
+    column it is Sq_pad 128-lane tiles, 2 MiB at 4096 rows, and was
+    most of what a grid step moved), and all four products are plain
+    or transposed-rhs matmuls, none with a transposed lhs."""
     from jax.experimental import pallas as pl
 
     k_blk = k_ref[0].astype(jnp.float32)              # (Bk, D)
@@ -543,43 +563,39 @@ def _flash_bwd_dkv_kernel(offs_ref, k_ref, v_ref, q_ref, do_ref, lse_ref,
         first_block = 0
 
     has_seg = qseg_ref is not None
-    kseg_blk = kseg_ref[0] if has_seg else None       # (1, Bk)
+    kseg_blk = kseg_ref[0] if has_seg else None       # (Bk, 1)
+    nt = (((1,), (1,)), ((), ()))                     # a @ b.T
+    nn = (((1,), (0,)), ((), ()))
 
     def body(qb, carry):
         dk_acc, dv_acc = carry
-        q_blk = (q_ref[0, 0, pl.ds(qb * block_q, block_q)]
-                 .astype(jnp.float32) * scale)        # (Bq, D)
-        do_blk = do_ref[0, 0, pl.ds(qb * block_q, block_q)].astype(
-            jnp.float32)
-        # lse/delta arrive with a trailing unit dim (see the caller:
-        # Mosaic requires the last two block dims be (8k, 128k) or
-        # equal to the array dims — (1, Sq_pad) with group > 1 is
-        # neither, (Sq_pad, 1) matching the array is).
-        lse = lse_ref[0, 0, pl.ds(qb * block_q, block_q)]   # (Bq, 1)
-        delta = dta_ref[0, 0, pl.ds(qb * block_q, block_q)]
-        qseg_blk = (qseg_ref[0, pl.ds(qb * block_q, block_q)]
-                    if has_seg else None)             # (Bq, 1)
+        rows = pl.ds(pl.multiple_of(qb * block_q, block_q), block_q)
+        q_blk = q_ref[0, 0, rows].astype(jnp.float32) * scale  # (Bq, D)
+        do_blk = do_ref[0, 0, rows].astype(jnp.float32)
+        lse = lse_ref[0, 0, :, rows]                  # (1, Bq)
+        delta = dta_ref[0, 0, :, rows]
+        qseg_blk = qseg_ref[0, :, rows] if has_seg else None  # (1, Bq)
         s = jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (Bq, Bk)
+            k_blk, q_blk, nt,
+            preferred_element_type=jnp.float32)       # (Bk, Bq)
         # seq_q_valid: padded q rows carry a meaningless lse — mask
         # them here so they contribute nothing to dk/dv.
         keep = _keep_mask(qb, k_idx, block_q=block_q, block_k=block_k,
                           q_off=q_off, k_off=k_off,
                           seq_k_valid=seq_k_valid, causal=causal,
                           seq_q_valid=seq_q_valid, window=window,
-                          qseg=qseg_blk, kseg=kseg_blk)
+                          qseg=qseg_blk, kseg=kseg_blk, keys_first=True)
         s = jnp.where(keep, s, _NEG_INF)
-        p = jnp.exp(s - lse)                          # (Bq, Bk)
+        p = jnp.exp(s - lse)                          # (Bk, Bq)
         dv_new = dv_acc + jax.lax.dot_general(
-            p, do_blk, (((0,), (0,)), ((), ())),
+            p, do_blk, nn,
             preferred_element_type=jnp.float32)       # (Bk, D)
         dp = jax.lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)       # (Bq, Bk)
+            v_blk, do_blk, nt,
+            preferred_element_type=jnp.float32)       # (Bk, Bq)
         ds = p * (dp - delta)
         dk_new = dk_acc + jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
+            ds, q_blk, nn,
             preferred_element_type=jnp.float32)       # (Bk, D)
         return dk_new, dv_new
 
@@ -602,7 +618,7 @@ def _flash_bwd_prep(q, o, g, block_q: int, Hkv: int):
     kernel layout plus delta_i = rowsum(dO * O) (one elementwise pass
     XLA fuses; padded rows give 0).  Split out so ring attention can
     hoist this out of its per-hop loop instead of redoing it n times."""
-    Sq_pad = -(-q.shape[1] // block_q) * block_q
+    Sq_pad = _round_up(q.shape[1], block_q)
     qt = _fold_q_gqa(q, Hkv, Sq_pad)      # (B*Hkv, G, Sq_pad, D)
     got = _fold_q_gqa(g, Hkv, Sq_pad)
     ot = _fold_q_gqa(o, Hkv, Sq_pad)
@@ -614,32 +630,45 @@ def _flash_bwd_prep(q, o, g, block_q: int, Hkv: int):
 def _flash_backward(q, k, v, o, lse, g, *, causal: bool, scale: float,
                     block_q: int, block_k: int, interpret: bool,
                     offsets=None, window: int | None = None,
-                    segment_ids=None, kv_segment_ids=None):
+                    segment_ids=None, kv_segment_ids=None,
+                    dkv_blocks=None):
     qt, got, delta = _flash_bwd_prep(q, o, g, block_q, k.shape[2])
     return _flash_backward_folded(
         qt, got, delta, lse, k, v, B=q.shape[0], Sq=q.shape[1],
         q_dtype=q.dtype, causal=causal, scale=scale,
         block_q=block_q, block_k=block_k, interpret=interpret,
         offsets=offsets, window=window, segment_ids=segment_ids,
-        kv_segment_ids=kv_segment_ids)
+        kv_segment_ids=kv_segment_ids, dkv_blocks=dkv_blocks)
 
 
 def _flash_backward_folded(qt, got, delta, lse, k, v, *, B: int, Sq: int,
                            q_dtype, causal: bool, scale: float,
                            block_q: int, block_k: int, interpret: bool,
                            offsets=None, window: int | None = None,
-                           segment_ids=None, kv_segment_ids=None):
+                           segment_ids=None, kv_segment_ids=None,
+                           dkv_blocks=None):
     """The two backward pallas_calls over pre-folded q/dO/delta (see
     :func:`_flash_bwd_prep`); k/v arrive raw (B, Sk, Hkv, D) and stay
     at Hkv heads throughout — the dK/dV kernel's contractions sum the
-    GQA group inside the matmul."""
+    GQA group inside the matmul.  ``block_q``/``block_k`` are the dQ
+    kernel's tile; ``dkv_blocks`` the dK/dV kernel's own pair (the
+    same where none is given).  Its rows must pad the queries to the
+    length the folded operands already have."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     _, Sk, Hkv, D = k.shape
     group = qt.shape[1]
     Sq_pad = qt.shape[2]
-    Sk_pad = -(-Sk // block_k) * block_k
+    Sk_pad = _round_up(Sk, block_k)
+    kv_bq, kv_bk = dkv_blocks or (block_q, block_k)
+    if (Sq_pad % kv_bq or Sq_pad % block_q or lse.shape[-1] != Sq_pad
+            or _round_up(Sk, kv_bk) != Sk_pad):
+        raise ValueError(
+            f"backward tiles {(block_q, block_k)} and {(kv_bq, kv_bk)} do "
+            f"not pad {Sq} queries and {Sk} keys to {Sq_pad} (the saved "
+            f"logsumexp has {lse.shape[-1]}) and {Sk_pad}")
+    itemsize = qt.dtype.itemsize
 
     kt = _fold_heads(k, Sk_pad)           # (B*Hkv, Sk_pad, D)
     vt = _fold_heads(v, Sk_pad)
@@ -698,13 +727,15 @@ def _flash_backward_folded(qt, got, delta, lse, k, v, *, B: int, Sq: int,
                                    lambda bh, qb, offs: (bh, 0, qb, 0)),
         ),
         interpret=interpret,
+        compiler_params=_mosaic_params("dq", block_q, block_k, Sq_pad,
+                                       Sk_pad, D, group, itemsize),
         name="nbd_flash_bwd_dq",
     )(offs, *dq_args)
 
     dkv_base = functools.partial(
-        _flash_bwd_dkv_kernel, block_q=block_q, seq_q=Sq_pad,
+        _flash_bwd_dkv_kernel, block_q=kv_bq, seq_q=Sq_pad,
         seq_q_valid=Sq, seq_k_valid=Sk, causal=causal, scale=scale,
-        block_k=block_k, group=group, window=window)
+        block_k=kv_bk, group=group, window=window)
 
     def dkv_kernel(offs_ref, *refs):
         if has_seg:
@@ -720,32 +751,34 @@ def _flash_backward_folded(qt, got, delta, lse, k, v, *, B: int, Sq: int,
                      dk_r, dv_r, dk_s, dv_s)
 
     dkv_in_specs = [
-        pl.BlockSpec((1, block_k, D),
+        pl.BlockSpec((1, kv_bk, D),
                      lambda bh, kb, g, offs: (bh, kb, 0)),   # k
-        pl.BlockSpec((1, block_k, D),
+        pl.BlockSpec((1, kv_bk, D),
                      lambda bh, kb, g, offs: (bh, kb, 0)),   # v
         pl.BlockSpec((1, 1, Sq_pad, D),
                      lambda bh, kb, g, offs: (bh, g, 0, 0)),  # q
         pl.BlockSpec((1, 1, Sq_pad, D),
                      lambda bh, kb, g, offs: (bh, g, 0, 0)),  # dO
-        # lse/delta get a trailing unit dim so the last two
-        # block dims (Sq_pad, 1) equal the array dims — the
-        # (1, 1, Sq_pad) layout fails Mosaic's block-shape
-        # rule whenever group is not 1 or a multiple of 8.
-        pl.BlockSpec((1, 1, Sq_pad, 1),
+        # lse/delta get a unit axis before the rows: the last two
+        # block dims (1, Sq_pad) then equal the array's — Mosaic's
+        # block-shape rule, which (1, 1, Sq_pad) over (.., group,
+        # Sq_pad) fails unless group is 1 or a multiple of 8 — and
+        # the rows lie on the lanes.
+        pl.BlockSpec((1, 1, 1, Sq_pad),
                      lambda bh, kb, g, offs: (bh, g, 0, 0)),  # lse
-        pl.BlockSpec((1, 1, Sq_pad, 1),
+        pl.BlockSpec((1, 1, 1, Sq_pad),
                      lambda bh, kb, g, offs: (bh, g, 0, 0)),  # dta
     ]
-    dkv_args = [kt, vt, qt, got, lse[..., None], delta[..., None]]
+    dkv_args = [kt, vt, qt, got, lse[:, :, None], delta[:, :, None]]
     if has_seg:
+        # transposed tile: query segments a row, key segments a column
         dkv_in_specs += [
-            pl.BlockSpec((1, Sq_pad, 1),
+            pl.BlockSpec((1, 1, Sq_pad),
                          lambda bh, kb, g, offs: (bh // Hkv, 0, 0)),
-            pl.BlockSpec((1, 1, block_k),
-                         lambda bh, kb, g, offs: (bh // Hkv, 0, kb)),
+            pl.BlockSpec((1, kv_bk, 1),
+                         lambda bh, kb, g, offs: (bh // Hkv, kb, 0)),
         ]
-        dkv_args += [qseg, kseg]
+        dkv_args += [qseg.transpose(0, 2, 1), kseg.transpose(0, 2, 1)]
     dk, dv = pl.pallas_call(
         dkv_kernel,
         out_shape=[
@@ -757,20 +790,22 @@ def _flash_backward_folded(qt, got, delta, lse, k, v, *, B: int, Sq: int,
             # Group innermost: sequential on-core, so the fp32 scratch
             # accumulators carry this k-block's dk/dv across the
             # group's heads; q/dO stage one (Sq_pad, D) plane at a time.
-            grid=(B * Hkv, Sk_pad // block_k, group),
+            grid=(B * Hkv, Sk_pad // kv_bk, group),
             in_specs=dkv_in_specs,
             out_specs=[
-                pl.BlockSpec((1, block_k, D),
+                pl.BlockSpec((1, kv_bk, D),
                              lambda bh, kb, g, offs: (bh, kb, 0)),
-                pl.BlockSpec((1, block_k, D),
+                pl.BlockSpec((1, kv_bk, D),
                              lambda bh, kb, g, offs: (bh, kb, 0)),
             ],
             scratch_shapes=[
-                pltpu.VMEM((block_k, D), jnp.float32),   # dk
-                pltpu.VMEM((block_k, D), jnp.float32),   # dv
+                pltpu.VMEM((kv_bk, D), jnp.float32),   # dk
+                pltpu.VMEM((kv_bk, D), jnp.float32),   # dv
             ],
         ),
         interpret=interpret,
+        compiler_params=_mosaic_params("dkv", kv_bq, kv_bk, Sq_pad,
+                                       Sk_pad, D, group, itemsize),
         name="nbd_flash_bwd_dkv",
     )(offs, *dkv_args)
 
@@ -799,11 +834,11 @@ def flash_attention(q, k, v, causal: bool = True,
     attend only keys in the same segment (requires Sq == Sk; compose
     with causal for the standard packed-pretraining mask).  Both
     backward kernels apply the identical mask.
-    ``block_q``/``block_k`` default to the per-shape tuned table
-    (:data:`TUNED_BLOCKS`, measured by ``tune_flash.py`` on a live
-    chip) falling back to 128.  On non-TPU backends the Pallas kernel
-    runs in interpreter mode (slow but exact), so tests exercise the
-    same code path everywhere.
+    ``block_q``/``block_k`` default to tiles each of the three kernels
+    derives from the call's shapes (:func:`_block_sizes`); given, they
+    hold for all three.  On non-TPU backends the Pallas kernel runs in
+    interpreter mode (slow but exact), so tests exercise the same code
+    path everywhere.
     """
     return _flash_fwd(q, k, v, causal, scale, block_q, block_k,
                       window, segment_ids)[0]
@@ -813,18 +848,27 @@ def _resolved_scale(scale, D):
     return scale if scale is not None else 1.0 / np.sqrt(D)
 
 
-# (Sq, Sk, head_dim, gqa_group) -> (block_q, block_k), measured on a
-# chip by tune_flash.py's chained-timing sweep.  The group (H // Hkv)
-# is part of the key because it sets the q-block's batch extent inside
-# the kernel —
-# MHA (group 1) and GQA (group > 1) tune differently at the same S/D.
-# Consulted only when the caller passes no explicit block sizes; empty
-# entries fall back to 128x128.  Seeded from ops/tuned_blocks.json
-# (written by tune_flash.py, absent until then — see ops/_tuned.py).
-from ._tuned import load as _load_tuned
+# ----------------------------------------------------------------------
+# Tiles from shapes
+#
+# Each of the three kernels takes the tile its call's shapes allow: the
+# largest multiple of 128 rows and of 128 keys, dividing the padded
+# lengths, whose VMEM by that kernel's :func:`_vmem_need` stays under
+# :data:`_VMEM_BUDGET`, up to where the chip's sweep at the training
+# shape stopped gaining (:data:`_TILE_CAP`).  No table, no file, no
+# environment: as ``ops/decode.py::_pages_per_tile`` and
+# ``ops/grouped.py::_column_tile`` choose theirs.
 
-TUNED_BLOCKS: dict = _load_tuned()[0]
-_DEFAULT_BLOCK = 128
+_TILE_UNIT = 128            # rows and keys of the smallest tile Mosaic takes
+_VMEM_BUDGET = 48 << 20     # what a derived tile may need, by _vmem_need
+_VMEM_DEFAULT = 16 << 20    # Mosaic's scoped VMEM when a call states none
+_VMEM_MOST = 100 << 20      # the most a call asks for, of a v5e's 128 MiB
+# (block_q, block_k) past which none of the three kernels gained at
+# S = 4096, D = 128, group 4: each was fastest at 512 x 512 and read
+# 0.5-5% slower at 1024 either way (the chip's sweep: PERF.md section
+# 6, PR 45).  A causal kernel also wastes half of every tile on the
+# diagonal, which grows with the tile.
+_TILE_CAP = (512, 512)
 
 
 def _mosaic_block(block: int) -> int:
@@ -838,24 +882,93 @@ def _mosaic_block(block: int) -> int:
     padded query rows).  Interpret mode checks none of this: a 64-row
     block over 256 rows, or an 89-row sequence as its own block, passes
     every CPU test and is refused by the compiler on the chip."""
-    return -(-block // 128) * 128
+    return _round_up(block, 128)
 
 
-def _block_sizes(block_q, block_k, Sq, Sk, D=None, group=None, *,
-                 interpret: bool):
-    """Resolve block sizes: explicit args win; None consults the tuned
-    per-shape table, then the 128 default.  Compiled kernels get the
-    nearest sizes Mosaic accepts (:func:`_mosaic_block`); interpret
-    mode takes them as given, clamped to the array, so CPU tests can
-    drive the multi-block logic with small blocks."""
-    if block_q is None or block_k is None:
-        tq, tk = TUNED_BLOCKS.get((Sq, Sk, D, group),
-                                  (_DEFAULT_BLOCK, _DEFAULT_BLOCK))
-        block_q = tq if block_q is None else block_q
-        block_k = tk if block_k is None else block_k
+def _vmem_need(kernel, bq, bk, sq_pad, sk_pad, D, group, itemsize) -> int:
+    """VMEM bytes a kernel's grid step needs at a (bq, bk) tile, from
+    the shapes alone: the blocks its specs stage (twice each: Pallas
+    double-buffers), the float32 score tiles and accumulators of one
+    group, and minor dimensions padded to whole 128-lane tiles as VMEM
+    holds them.  What the tile choice is held to and what the call's
+    limit is stated from."""
+    Dl = _round_up(D, 128)
+    if kernel == "dkv":
+        staged = (4 * bk * Dl * itemsize          # k, v in; dk, dv out
+                  + 2 * sq_pad * Dl * itemsize    # one head's q, dO planes
+                  + 2 * 8 * sq_pad * 4)     # lse, delta rows, 8 sublanes
+        live = (6 * bk * Dl * 4     # k, v in float32; scratch; accumulators
+                + 2 * bq * Dl * 4   # one q and dO tile in float32
+                + 4 * bq * bk * 4)  # s/p, dp/ds and the mask's iotas
+        return 2 * staged + live
+    grads = 2 if kernel == "dq" else 1            # dq stages dO beside q
+    staged = ((grads + 1) * group * bq * Dl * itemsize   # q (dO), out
+              + 2 * sk_pad * Dl * itemsize        # the K and V planes
+              + 2 * 8 * bq * 4)                   # lse (and delta) rows
+    live = ((grads + 1) * group * bq * Dl * 4     # float32 q (dO), acc
+            + 2 * group * bq * 128 * 4      # m, l (lse, delta) columns
+            + 2 * bk * Dl * 4                     # one K and V tile
+            + (2 * group + 2) * bq * bk * 4)      # s, p a head; the mask
+    return 2 * staged + live
+
+
+def _mosaic_params(kernel, bq, bk, sq_pad, sk_pad, D, group, itemsize):
+    """The call's compiler parameters: its VMEM limit stated from the
+    tile's own arithmetic, twice the need (Mosaic's internal scratch
+    and spills are not in it), at least the default and at most
+    :data:`_VMEM_MOST`."""
+    from jax.experimental.pallas import tpu as pltpu
+    need = _vmem_need(kernel, bq, bk, sq_pad, sk_pad, D, group, itemsize)
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=min(max(2 * need, _VMEM_DEFAULT), _VMEM_MOST))
+
+
+def _block_sizes(block_q, block_k, Sq, Sk, D=128, group=1, *,
+                 interpret: bool, kernel: str = "fwd", itemsize: int = 2):
+    """The (block_q, block_k) one kernel (``"fwd"``, ``"dq"`` or
+    ``"dkv"``) runs at.  Explicit sizes win: compiled kernels get the
+    nearest Mosaic accepts (:func:`_mosaic_block`), interpret mode
+    takes them as given, clamped to the array, so CPU tests can drive
+    the multi-block logic with small blocks.  ``None`` is derived from
+    the shapes: the largest tile of whole :data:`_TILE_UNIT`s that
+    divides the length as the unit pads it (so a sequence is never
+    padded further than a 128-row tile pads it, the three kernels pad
+    alike whatever their tiles, and the forward's saved logsumexp fits
+    both backward kernels), needs at most :data:`_VMEM_BUDGET` by
+    :func:`_vmem_need`, and is no larger than :data:`_TILE_CAP`."""
     if interpret:
-        return min(block_q, Sq), min(block_k, Sk)
-    return _mosaic_block(block_q), _mosaic_block(block_k)
+        unit_q, unit_k = min(_TILE_UNIT, Sq), min(_TILE_UNIT, Sk)
+        given = min
+    else:
+        unit_q = unit_k = _TILE_UNIT
+        given = lambda block, _S: _mosaic_block(block)
+    cap_q, cap_k = _TILE_CAP
+
+    def choices(block, S, unit, cap):
+        if block is not None:
+            return [given(block, S)]
+        padded = _round_up(S, unit)
+        return [t for t in range(unit, max(cap, unit) + 1, unit)
+                if padded % t == 0]
+
+    qs = choices(block_q, Sq, unit_q, cap_q)
+    ks = choices(block_k, Sk, unit_k, cap_k)
+    fits = [(bq, bk) for bq in qs for bk in ks
+            if _vmem_need(kernel, bq, bk, _round_up(Sq, bq),
+                          _round_up(Sk, bk), D, group,
+                          itemsize) <= _VMEM_BUDGET]
+    # the largest score tile, the wider in keys of two alike
+    return max(fits, key=lambda t: (t[0] * t[1], t[1]),
+               default=(qs[0], ks[0]))
+
+
+def _tile_of(q, k, block_q, block_k, interpret):
+    """:func:`_block_sizes` bound to one call's shapes: ``kernel`` in,
+    that kernel's (block_q, block_k) out."""
+    return functools.partial(
+        _block_sizes, block_q, block_k, q.shape[1], k.shape[1],
+        q.shape[-1], q.shape[2] // k.shape[2], interpret=interpret,
+        itemsize=q.dtype.itemsize)
 
 
 def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None,
@@ -866,8 +979,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, window=None,
                          "self-attention)")
     D = q.shape[-1]
     interpret = _use_interpret()
-    bq, bk = _block_sizes(block_q, block_k, q.shape[1], k.shape[1], D,
-                          q.shape[2] // k.shape[2], interpret=interpret)
+    bq, bk = _tile_of(q, k, block_q, block_k, interpret)(kernel="fwd")
     out, lse = _flash_forward(q, k, v, causal=causal,
                               scale=_resolved_scale(scale, D),
                               block_q=bq, block_k=bk,
@@ -883,13 +995,12 @@ def _flash_bwd(causal, scale, block_q, block_k, window, residuals, g):
     either."""
     q, k, v, out, lse, segment_ids = residuals
     interpret = _use_interpret()
-    bq, bk = _block_sizes(block_q, block_k, q.shape[1], k.shape[1],
-                          q.shape[-1], q.shape[2] // k.shape[2],
-                          interpret=interpret)
+    tile = _tile_of(q, k, block_q, block_k, interpret)
+    bq, bk = tile(kernel="dq")
     dq, dk, dv = _flash_backward(
         q, k, v, out, lse, g, causal=causal,
         scale=_resolved_scale(scale, q.shape[-1]),
-        block_q=bq, block_k=bk,
+        block_q=bq, block_k=bk, dkv_blocks=tile(kernel="dkv"),
         interpret=interpret, window=window,
         segment_ids=segment_ids, kv_segment_ids=segment_ids)
     if segment_ids is None:

@@ -469,7 +469,9 @@ def _make_ring_flash(axis: str, n: int, causal: bool, scale: float,
         Sk, Hkv = k.shape[1], k.shape[2]
         interp = _use_interpret()
         bq, bk = _block_sizes(block_q, block_k, Sq, Sk, D, H // Hkv,
-                              interpret=interp)
+                              interpret=interp, kernel="dq")
+        dkv_blocks = _block_sizes(block_q, block_k, Sq, Sk, D, H // Hkv,
+                                  interpret=interp, kernel="dkv")
         my = jax.lax.axis_index(axis)
         # Hop-invariant work — the q/dO folds and the delta reduction —
         # happens once, not n times (only k/v change per hop).
@@ -490,7 +492,8 @@ def _make_ring_flash(axis: str, n: int, causal: bool, scale: float,
             dq_j, dk_j, dv_j = _flash_backward_folded(
                 qt, got, delta, L, k_cur, v_cur, B=B, Sq=Sq,
                 q_dtype=q.dtype, causal=causal, scale=scale,
-                block_q=bq, block_k=bk, interpret=interp,
+                block_q=bq, block_k=bk, dkv_blocks=dkv_blocks,
+                interpret=interp,
                 offsets=(my * Sq, src * Sk), window=window,
                 segment_ids=seg, kv_segment_ids=kseg_cur)
             rest = (dk_cur + dk_j.astype(dk_cur.dtype),
@@ -601,7 +604,9 @@ def _make_ring_flash_zigzag(axis: str, n: int, scale: float,
         C = Sq // 2
         interp = _use_interpret()
         bq, bk = _block_sizes(block_q, block_k, C, C, D, H // Hkv,
-                              interpret=interp)
+                              interpret=interp, kernel="dq")
+        dkv_blocks = _block_sizes(block_q, block_k, C, C, D, H // Hkv,
+                                  interpret=interp, kernel="dkv")
         my = jax.lax.axis_index(axis)
         q_offs = _offs(my, C)
         Ls = (La, Lb)
@@ -633,7 +638,7 @@ def _make_ring_flash_zigzag(axis: str, n: int, scale: float,
                         v_cur[:, ki * C:(ki + 1) * C],
                         B=B, Sq=C, q_dtype=q.dtype, causal=True,
                         scale=scale, block_q=bq, block_k=bk,
-                        interpret=interp,
+                        dkv_blocks=dkv_blocks, interpret=interp,
                         offsets=(q_offs[qi], k_offs[ki]),
                         window=window,
                         segment_ids=qsegh[qi],

@@ -229,45 +229,92 @@ class TestSlidingWindow:
             attention_reference(q, q, q, causal=False, window=16)
 
 
-def test_flash_tuned_block_table_consulted():
-    """block_q/block_k=None resolve through TUNED_BLOCKS[(Sq, Sk, D,
-    group)] with a 128 fallback; a tuned entry must change nothing
-    numerically (forward and gradients)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
+# (Sq, Sk, D, group): the training cell's call, an 89-row sequence, a
+# ring hop's chunk against a longer K/V, and plain multi-head.
+DERIVED_SHAPES = [(4096, 4096, 128, 4), (89, 89, 64, 2),
+                  (1024, 2048, 128, 4), (2048, 2048, 128, 1)]
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("Sq,Sk,D,group", DERIVED_SHAPES)
+def test_flash_derived_tiles(Sq, Sk, D, group, kernel):
+    """block_q/block_k=None: each kernel's tile comes from the call's
+    shapes alone — whole 128s, never padding a sequence further than a
+    128-row tile pads it (so the three kernels pad alike and the saved
+    logsumexp fits the backward), within the VMEM budget by the
+    function's own arithmetic, no larger than the sweep's caps."""
     from nbdistributed_tpu.ops import attention as att
 
-    B, S, H, Hkv, D = 1, 64, 4, 2, 16
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (B, S, H, D))
-    k = jax.random.normal(ks[1], (B, S, Hkv, D))
-    v = jax.random.normal(ks[2], (B, S, Hkv, D))
-    loss = lambda q_: jnp.sum(att.flash_attention(q_, k, v, True) ** 2)
-    default, g_default = jax.value_and_grad(loss)(q)
-    key = (S, S, D, H // Hkv)
+    bq, bk = att._block_sizes(None, None, Sq, Sk, D, group,
+                              interpret=False, kernel=kernel)
+    assert bq % 128 == 0 and bk % 128 == 0
+    pad = att._round_up
+    assert pad(Sq, bq) == pad(Sq, 128) and pad(Sk, bk) == pad(Sk, 128)
+    need = att._vmem_need(kernel, bq, bk, pad(Sq, bq), pad(Sk, bk), D,
+                          group, 2)
+    assert need <= att._VMEM_BUDGET < att._VMEM_MOST
+    cap_q, cap_k = att._TILE_CAP
+    assert bq <= cap_q and bk <= cap_k
+    # an 89-row sequence is one 128-row tile; the cell's takes more
+    # than the old 128 x 128 fallback
+    if Sq == 89:
+        assert (bq, bk) == (128, 128)
+    if Sq == 4096:
+        assert bq * bk > 128 * 128
+    # explicit sizes still win, rounded to what Mosaic accepts
+    assert att._block_sizes(200, 64, Sq, Sk, D, group, interpret=False,
+                            kernel=kernel) == (256, 128)
 
-    class _Recording(dict):
-        keys_seen: list = []
 
-        def get(self, k_, d=None):
-            _Recording.keys_seen.append(k_)
-            return super().get(k_, d)
+OWN_TILES = {"fwd": (32, 64), "dq": (32, 32), "dkv": (64, 32)}
 
-    orig = att.TUNED_BLOCKS
-    att.TUNED_BLOCKS = _Recording({key: (32, 32)})
-    try:
-        tuned, g_tuned = jax.value_and_grad(loss)(q)
-    finally:
-        att.TUNED_BLOCKS = orig
-    # The lookup must have fired with the exact (Sq, Sk, D, group) key
-    # (numerics alone cannot prove it: a missed lookup falls back to
-    # the same 128 default).
-    assert key in _Recording.keys_seen, _Recording.keys_seen
-    np.testing.assert_allclose(float(tuned), float(default), rtol=1e-6)
-    np.testing.assert_allclose(np.asarray(g_tuned),
-                               np.asarray(g_default), atol=1e-5,
-                               rtol=1e-5)
+
+@pytest.mark.parametrize("window", [None, 48])
+@pytest.mark.parametrize("packed", [False, True])
+@pytest.mark.parametrize("S", [128, 100])
+def test_flash_kernels_at_their_own_tiles(S, packed, window):
+    """The three kernels at three different rectangular tiles, as far
+    as they may differ (each pads the sequence to the same length, so
+    the forward's logsumexp fits both backward kernels): at a length
+    all of them divide and at one they pad, forward and all three
+    gradients agree with the reference."""
+    from nbdistributed_tpu.ops import attention as att
+
+    B, H, Hkv, D = 1, 4, 2, 16
+    q = rand((B, S, H, D), 31)
+    k, v = (rand((B, S, Hkv, D), 32 + i) for i in range(2))
+    w = rand((B, S, H, D), 34)
+    seg = None
+    if packed:
+        seg = jnp.asarray([[0] * 40 + [1] * 37 + [2] * (S - 77)])
+    common = dict(causal=True, scale=D ** -0.5, interpret=True,
+                  window=window, segment_ids=seg, kv_segment_ids=seg)
+    (bq, bk), (dq_bq, dq_bk) = OWN_TILES["fwd"], OWN_TILES["dq"]
+    out, lse = att._flash_forward(q, k, v, block_q=bq, block_k=bk,
+                                  **common)
+    grads = att._flash_backward(q, k, v, out, lse, w, block_q=dq_bq,
+                                block_k=dq_bk,
+                                dkv_blocks=OWN_TILES["dkv"], **common)
+    want, vjp = jax.vjp(lambda q, k, v: attention_reference(
+        q, k, v, causal=True, window=window, segment_ids=seg), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+    for got, ref in zip(grads, vjp(w)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+
+def test_flash_backward_refuses_tiles_that_pad_apart():
+    """A dK/dV tile that pads the queries to another length than the
+    saved logsumexp has is refused, not run on a misaligned plane."""
+    from nbdistributed_tpu.ops import attention as att
+
+    q = rand((1, 96, 2, 16), 41)
+    common = dict(causal=True, scale=0.25, interpret=True)
+    out, lse = att._flash_forward(q, q, q, block_q=32, block_k=32, **common)
+    with pytest.raises(ValueError, match="do not pad"):
+        att._flash_backward(q, q, q, out, lse, q, block_q=32, block_k=32,
+                            dkv_blocks=(64, 32), **common)
 
 
 class TestSegmentIds:

@@ -5,14 +5,17 @@ same parent as a worker fleet); takes ~5-10 min of compiles:
 
     python tune_flash.py
 
-Sweeps (block_q, block_k) for the flash kernel on the bench shapes,
-timing with the chained-dependency pattern of ``ops/timing.py`` (each
-scan step's q depends on the previous output; per-call time =
-(long-short chain)/delta with a host fetch at the end).
+Sweeps explicit (block_q, block_k) for the flash kernels on the bench
+shapes, timing with the chained-dependency pattern of ``ops/timing.py``
+(each scan step's q depends on the previous output; per-call time =
+(long-short chain)/delta with a host fetch at the end), beside the
+tiles the kernels derive from the shapes themselves
+(``ops/attention.py::_block_sizes``): a sweep that beats the derived
+tiles is a reason to change that function, not a table to load.
 
-Prints per-config timings and the tuned-vs-XLA speedup, and **writes
-the tuned tables to ``nbdistributed_tpu/ops/tuned_blocks.json``** (see
-``ops/_tuned.py``) so every later process picks them up automatically.
+Prints per-config timings and the best-vs-XLA speedup.  The decode
+kernel still reads a table: its sweep **writes
+``nbdistributed_tpu/ops/tuned_blocks.json``** (see ``ops/_tuned.py``).
 
 ``NBD_TUNE_CPU_SMOKE=1`` shrinks the sweep to one tiny shape, lifts
 the TPU gate, and writes the table to /tmp — an end-to-end harness
@@ -32,7 +35,7 @@ import jax
 import jax.numpy as jnp
 
 from nbdistributed_tpu.ops import attention_reference
-from nbdistributed_tpu.ops.attention import flash_attention
+from nbdistributed_tpu.ops.attention import _block_sizes, flash_attention
 
 SMOKE = bool(os.environ.get("NBD_TUNE_CPU_SMOKE"))
 
@@ -82,7 +85,6 @@ def main() -> int:
               f"(backend={jax.default_backend()})", file=sys.stderr)
         return 1
     results = {}
-    flash_tbl: dict = {}
     decode_tbl: dict = {}
 
     def checkpoint_tables():
@@ -91,13 +93,12 @@ def main() -> int:
         the existing on-disk table — an early checkpoint must never
         gut a previous complete table down to the one shape measured
         so far (save() replaces the whole file)."""
-        if flash_tbl or decode_tbl:
+        if decode_tbl:
             from nbdistributed_tpu.ops import _tuned
             path = "/tmp/tuned_blocks_smoke.json" if SMOKE else None
             old_flash, old_decode = _tuned.load(path)
             p = _tuned.save(
-                {**old_flash, **flash_tbl},
-                {**old_decode, **decode_tbl},
+                old_flash, {**old_decode, **decode_tbl},
                 meta={"measured_at": time.strftime(
                           "%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
                       "device": jax.devices()[0].device_kind},
@@ -192,14 +193,13 @@ def main() -> int:
                 round(ref_fb / best["fwd_bwd_ms"], 3)
                 if valid(ref_fb) and valid(best.get("fwd_bwd_ms"))
                 else None),
-            # TUNED_BLOCKS key: (Sq, Sk, head_dim, gqa_group).
-            "tuned_entry": {f"({S}, {S}, {D}, {H // Hkv})":
-                            f"({best['bq']}, {best['bk']})"},
+            # what the kernels choose for this shape on their own
+            "derived": {kern: list(_block_sizes(
+                None, None, S, S, D, H // Hkv, interpret=False,
+                kernel=kern)) for kern in ("fwd", "dq", "dkv")},
         }
-        flash_tbl[(S, S, D, H // Hkv)] = (best["bq"], best["bk"])
-        print(f"[{name}] best flash bq={best['bq']} bk={best['bk']}",
-              file=sys.stderr)
-        checkpoint_tables()
+        print(f"[{name}] best flash bq={best['bq']} bk={best['bk']}; "
+              f"derived {results[name]['derived']}", file=sys.stderr)
     # ---- decode kernel sweep: block_k over realistic cache shapes.
     from nbdistributed_tpu.ops.decode import flash_decode_attention
 
