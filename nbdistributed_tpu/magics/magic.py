@@ -1890,11 +1890,14 @@ class DistributedMagics(Magics):
                   + (f" · pushed early {tk['pushed_share']:.0%}, "
                      f"{tk['steps_per_push']:g} tokens/push"
                      if "pushed_share" in tk else "")
-                  # a block server: row-passes a block committed, and
-                  # positions fixed a denoising pass
+                  # a block server: row-passes a block, positions
+                  # fixed a denoising pass, and the share of the
+                  # commits that rode a lane of another block's pass
                   + (f" · {tk['denoise']['passes_per_block']:g} "
                      f"passes/block, "
-                     f"{tk['denoise']['tokens_per_pass']:g} fixed/pass"
+                     f"{tk['denoise']['tokens_per_pass']:g} fixed/pass, "
+                     f"{tk['denoise']['fused_share']:.0%} of "
+                     f"commits fused"
                      if "denoise" in tk else ""))
         print(f"   accepted {st.get('accepted', 0)} · completed "
               f"{st.get('completed', 0)} · shed {st.get('shed', 0)} · "

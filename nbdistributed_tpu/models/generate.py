@@ -431,7 +431,7 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
                        mesh=None, ep_axis: str = "ep", row_mask=None,
                        token_mask=None, block_table=None,
                        with_moe_load: bool = False, slot=None,
-                       final: bool = True):
+                       final: bool = True, head_rows: int | None = None):
     """Run ``tokens`` (B, S) through the model, reading/writing the KV
     cache at offset ``cache_len`` (traced scalar ok, or a per-row
     ``(B,)`` vector when the streams in the batch sit at different
@@ -501,7 +501,9 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
     block, and a decode step (``row_mask`` given) carries
     ``block_length`` tokens a row at ``cache_len .. cache_len +
     block_length - 1``, written to the row's pages and attended in
-    both directions, whose logits all come back.
+    both directions, whose logits all come back: those of the first
+    ``head_rows`` rows where that is given (the rows after them are
+    there for what they write: :func:`~.sdar.with_lanes`).
     """
     from .hybrid import StatefulConfig, hybrid_forward_with_cache
     from .mla import LatentMoEConfig, MLAMixer
@@ -603,6 +605,8 @@ def forward_with_cache(params: dict, tokens, cache: dict, cache_len,
             idx, (B, 1, x.shape[-1])), axis=1)         # (B, 1, D)
     elif last_only:
         x = x[:, -1:]
+    if head_rows is not None:
+        x = x[:head_rows]
     x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = qlinear(x, params["lm_head"]).astype(jnp.float32)
     if with_moe_load:

@@ -119,12 +119,12 @@ def write_token(pool, layer, new, table, pos, active):
     ``new`` leaves ``(S, Hkv, n, D)`` (the pool's leaves for the
     step's tokens; ``n`` is 1, or the block length of a block-causal
     model, whose blocks start at multiples of ``n`` and so lie inside
-    one page where ``n`` divides the page), ``pos`` (S,) the position
-    of the first.  Physical block
-    ``table[b, pos // bt]``, offset ``pos % bt``, all heads.  Inactive
-    slots are redirected to the trash block — their frozen-position
-    write must never land in a block that may have been reallocated to
-    another request."""
+    one page where ``n`` divides the page: :func:`_write_blocks`
+    stages those), ``pos`` (S,) the position of the first.  Physical
+    block ``table[b, pos // bt]``, offset ``pos % bt``, all heads.
+    Inactive slots are redirected to the trash block — their
+    frozen-position write must never land in a block that may have
+    been reallocated to another request."""
     first = jax.tree_util.tree_leaves(pool)[0]
     trash, bt = first.shape[1] - 1, first.shape[3]
     blk = jnp.minimum(pos // bt, table.shape[1] - 1)
@@ -132,6 +132,8 @@ def write_token(pool, layer, new, table, pos, active):
     if active is not None:
         phys = jnp.where(active, phys, trash)
     off = pos % bt
+    if jax.tree_util.tree_leaves(new)[0].shape[2] > 1:
+        return _write_blocks(pool, layer, new, phys, off)
 
     def one(c, n):
         # One slice update a slot, not one scatter: a slice update
@@ -142,6 +144,46 @@ def write_token(pool, layer, new, table, pos, active):
         for b in range(n.shape[0]):
             c = jax.lax.dynamic_update_slice(
                 c, n[b][None, None], (layer, phys[b], 0, off[b], 0))
+        return c
+    return jax.tree_util.tree_map(one, pool, new)
+
+
+def _write_blocks(pool, layer, new, phys, off):
+    """:func:`write_token`'s slice updates for a block a row, staged
+    with under half the operations: a row's page and offset are taken
+    out of their vectors and wrapped once for all leaves, by ``lax``
+    and not by indexing, and an update wraps no index again.  A
+    block server's pass is unrolled over its layers and runs a hundred
+    rows and more, so these updates are most of what tracing and
+    lowering it cost; one token a row keeps the form above, whose text
+    the dense family's tests pin.
+
+    Every index still goes through the wrap once (a select that
+    changes nothing): the TPU compiler keeps a select's scalar result
+    in scalar memory, where an update finds it, and leaves a bare
+    element of a vector (or its clamp by ``max``) in HBM, from where
+    every update fetches it: 1.8 us an update for 0.67, at 2,240
+    updates a pass (PERF.md, PR 46)."""
+    def scalar(x, size):
+        return jax.lax.select(jax.lax.lt(x, np.int32(0)),
+                              jax.lax.add(x, np.int32(size)), x)
+
+    first = jax.tree_util.tree_leaves(pool)[0]
+    rows = range(phys.shape[0])
+    layer = scalar(jnp.asarray(layer), first.shape[0])
+    at = [(scalar(jax.lax.index_in_dim(phys, b, keepdims=False),
+                  first.shape[1]),
+           scalar(jax.lax.index_in_dim(off, b, keepdims=False),
+                  first.shape[3]))
+          for b in rows]
+
+    def one(c, n):
+        n = n.astype(c.dtype)                 # (S, Hkv, n, D)
+        for b, (page, start) in zip(rows, at):
+            c = jax.lax.dynamic_update_slice(
+                c, jax.lax.expand_dims(jax.lax.slice_in_dim(n, b, b + 1),
+                                       (0,)),
+                (layer, page, 0, start, 0), allow_negative_indices=False)
         return c
     return jax.tree_util.tree_map(one, pool, new)
 

@@ -36,17 +36,26 @@ What makes it SDAR is the mask and the loop.
   positions, the most confident first (:func:`denoise`;
   ``low_confidence_static``: ``denoise_steps`` passes of ``L /
   denoise_steps`` positions each).  A pass writes the block's K/V to
-  the row's pages at ``lens .. lens + L - 1`` and attends keys below
-  ``lens + L``; only a **commit** (one more pass, over the finished
-  block) moves ``lens``, so nothing uncommitted is attended by another
-  block or row.  :class:`~.serving.DecodeServer` runs one such pass a
-  step and emits a block at its commit.
+  the row's pages and attends keys up to the block's last position;
+  what it wrote is not committed: the next pass writes it again.
+* **A finished block is committed by a lane of a later pass.**  Its
+  final tokens' K/V must lie in the row's pages before a later block
+  attends them, and that is all a commit is.  A pass therefore runs
+  ``rows + lanes`` rows of ``L`` tokens (:func:`with_lanes`): the open
+  blocks, and :func:`lanes` *commit lanes*, each the finished block of
+  the row the host names, at that block's own position and under its
+  own bound on the keys.  A layer writes every row's K/V first and
+  attends second, so the open block of the same row, ``L`` further,
+  attends the lane's fresh K/V in that very layer: the pass that opens
+  a row's next block commits the one before it, ``lens`` moves there,
+  and a block costs ``denoise_steps`` passes and no pass of its own
+  for the commit.  A lane's logits are never computed: the head runs
+  over the open blocks.  :class:`~.serving.DecodeServer` runs one such
+  pass a step and emits a block at its last denoising pass.
 
 Not here: thresholds on the confidence (``low_confidence_dynamic``:
 how many positions a pass fixes is then data the host learns a pass
-late), sampling with a temperature inside the block, and a commit
-fused with the next block's first pass (the same mathematics in
-``denoise_steps`` passes a block).
+late) and sampling with a temperature inside the block.
 """
 
 from __future__ import annotations
@@ -152,17 +161,31 @@ def sdar_mlp_block(x, layer, cfg: SDARConfig, token_mask):
 OPEN = -2   # ``when`` of a position no pass has fixed yet
 
 
+def lanes(cfg: SDARConfig, rows: int) -> int:
+    """Commit lanes a pass over ``rows`` open blocks carries: a row
+    finishes a block every ``denoise_steps`` passes and needs a lane
+    once for it, so ``rows / denoise_steps`` is the mean demand.  Rows
+    that ask for more in one pass wait a pass, which staggers them."""
+    return -(-rows // cfg.denoise_steps)
+
+
 def fresh_block(cfg: SDARConfig, rows: int) -> dict:
-    """The state a block server keeps a row: the block's tokens (all
-    ``[MASK]``), ``when`` each was fixed (the pass of its block, -1 for
-    a prompt's remainder, :data:`OPEN` while it is open) and the pass
-    the row's block is at.  Which positions are open is this state and
-    never a comparison with the mask id: a token a pass chose may be
-    that id."""
+    """The state a block server keeps a row: the open block's tokens
+    (all ``[MASK]``), ``when`` each was fixed (the pass of its block,
+    -1 for a prompt's remainder, :data:`OPEN` while it is open), the
+    pass the row's block is at, and ``closed``: the block the row
+    finished last, kept until a lane commits it.  Which positions are
+    open is this state and never a comparison with the mask id: a token
+    a pass chose may be that id.  ``lane`` is the host's word for one
+    pass and no state: the row each commit lane serves (``rows``:
+    none)."""
     L = cfg.block_length
-    return {"tokens": jnp.full((rows, L), cfg.mask_token_id, jnp.int32),
+    masks = jnp.full((rows, L), cfg.mask_token_id, jnp.int32)
+    return {"tokens": masks,
             "when": jnp.full((rows, L), OPEN, jnp.int32),
-            "at": jnp.zeros((rows,), jnp.int32)}
+            "at": jnp.zeros((rows,), jnp.int32),
+            "closed": masks,
+            "lane": jnp.full((lanes(cfg, rows),), rows, jnp.int32)}
 
 
 def seat_block(block: dict, slot: int, remainder: list[int],
@@ -172,27 +195,49 @@ def seat_block(block: dict, slot: int, remainder: list[int],
     L, r = cfg.block_length, len(remainder)
     toks = list(remainder) + [cfg.mask_token_id] * (L - r)
     when = [-1] * r + [OPEN] * (L - r)
-    return {"tokens": block["tokens"].at[slot].set(
+    return {**block,
+            "tokens": block["tokens"].at[slot].set(
                 jnp.asarray(toks, jnp.int32)),
             "when": block["when"].at[slot].set(
                 jnp.asarray(when, jnp.int32)),
             "at": block["at"].at[slot].set(0)}
 
 
-def denoise(logits, block: dict, lens, active, cfg: SDARConfig):
-    """One pass's bookkeeping, after the forward over the block:
-    ``logits`` (B, L, V) float32.  A row with an open position fixes
-    ``fixed_per_pass`` of them (all that are open, if fewer), the most
-    confident first and the lower index on a tie, to ``argmax
-    logits``; a row with none commits: its block goes out
-    (``out``), ``lens`` moves past it and its next block is all masks.
-    -> (block, lens, out): ``out`` holds every row's tokens and
-    ``when`` as the pass left them and ``done``, the rows that
-    committed."""
-    L = cfg.block_length
+def with_lanes(block: dict, lens, table, active, cfg: SDARConfig):
+    """The rows one pass runs, the open blocks first and the commit
+    lanes after them -> (tokens, the position of each row's first
+    token, block table, the rows that take part, ``lens`` as the pass
+    leaves it).  Lane ``j`` is row ``block["lane"][j]``'s finished
+    block at the position ``lens`` still stands at, under that row's
+    table; the row's open block then sits a block further, and
+    ``lens`` moves past the block the lane writes."""
+    rows = active.shape[0]
+    lane = block["lane"]
+    on = lane < rows
+    of = jnp.minimum(lane, rows - 1)
+    carried = jnp.any(on[None, :] & (lane[None, :]
+                                     == jnp.arange(rows)[:, None]), axis=1)
+    step = cfg.block_length * carried.astype(lens.dtype)
+    return (jnp.concatenate([block["tokens"], block["closed"][of]]),
+            jnp.concatenate([lens + step, lens[of]]),
+            jnp.concatenate([table, table[of]]),
+            jnp.concatenate([active, on]),
+            lens + step)
+
+
+def denoise(logits, block: dict, active, cfg: SDARConfig):
+    """One pass's bookkeeping, after the forward over the open blocks:
+    ``logits`` (B, L, V) float32.  An active row fixes
+    ``fixed_per_pass`` of its open positions (all that are open, if
+    fewer), the most confident first and the lower index on a tie, to
+    ``argmax logits``.  A row that has none open after that has
+    finished its block: the block goes out (``out``) and into
+    ``closed``, where a lane of a later pass finds it, and the row's
+    next block is all masks.
+    -> (block, out): ``out`` holds every row's tokens and ``when`` as
+    the pass left them and ``done``, the rows that finished a block."""
     with jax.named_scope("denoise"):
         is_open = block["when"] == OPEN                     # (B, L)
-        commit = active & ~jnp.any(is_open, axis=-1)
         with jax.named_scope("sample"):
             x0 = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             # max softmax = exp(max - logsumexp): its logarithm orders
@@ -206,12 +251,14 @@ def denoise(logits, block: dict, lens, active, cfg: SDARConfig):
         fix = is_open & (rank < cfg.fixed_per_pass) & active[:, None]
         tokens = jnp.where(fix, x0, block["tokens"])
         when = jnp.where(fix, block["at"][:, None], block["when"])
-        out = {"tokens": tokens, "when": when, "done": commit}
+        done = active & ~jnp.any(when == OPEN, axis=-1)
+        out = {"tokens": tokens, "when": when, "done": done}
         fresh = fresh_block(cfg, tokens.shape[0])
         at = jnp.where(active, block["at"] + 1, block["at"])
-        block = {"tokens": jnp.where(commit[:, None], fresh["tokens"],
-                                     tokens),
-                 "when": jnp.where(commit[:, None], fresh["when"], when),
-                 "at": jnp.where(commit, 0, at)}
-        lens = lens + L * commit.astype(lens.dtype)
-    return block, lens, out
+        roll = done[:, None]
+        block = {**block,
+                 "tokens": jnp.where(roll, fresh["tokens"], tokens),
+                 "when": jnp.where(roll, fresh["when"], when),
+                 "at": jnp.where(done, 0, at),
+                 "closed": jnp.where(roll, tokens, block["closed"])}
+    return block, out

@@ -55,15 +55,22 @@ the KV-cache machinery was built to support.  Design:
 
 * **A block server** (a config with ``block_length`` > 1,
   :mod:`.sdar`) runs a *pass* a step where the others run a token: a
-  row carries a block of ``L`` tokens and which of them are open, a
+  row carries a block of ``L`` tokens and which of them are open, and a
   denoising pass fixes the scheduled number of open positions and
-  advances no position, and a pass that finds none open commits the
-  block (``lens`` moves, the block is emitted, the next is all masks).
-  The schedule is static, so the host knows without a fetch which pass
-  commits and whose budget ends, and one pass stays in flight exactly
-  as a step does.  A prompt's whole blocks are prefilled (no logits:
-  nothing is sampled from them) and its remainder is seated in the
-  row's first block.
+  advances no position.  The pass that fixes a block's last open
+  position finishes it: the block is emitted at that pass's fetch and
+  the row's next block is all masks.  The finished block's K/V are
+  committed by a *lane* of the pass that opens the next block
+  (:func:`~.sdar.with_lanes`: one more row of that pass, whose logits
+  nobody computes; ``lens`` moves there), so a commit takes no pass of
+  its own.  A pass has :func:`~.sdar.lanes` of them; a row that finds
+  none free sits that pass out and takes the next, and a request's
+  last block is never committed, since no block attends it.  The
+  schedule is static, so the host knows without a fetch which pass
+  finishes a block, which rows need a lane and whose budget ends, and
+  one pass stays in flight exactly as a step does.  A prompt's whole
+  blocks are prefilled (no logits: nothing is sampled from them) and
+  its remainder is seated in the row's first block.
 
 Greedy serving reproduces a standalone :func:`~.generate.generate`
 call per request: admission order, batch occupancy, and other
@@ -146,10 +153,13 @@ class _InFlight(NamedTuple):
     rows: dict[int, int]        # {slot: request id} it ran
     kv_bytes: tuple             # K and V page bytes its attention
     #                             reads, one count a :class:`_KVKind`
-    commits: dict | None = None  # a block server's pass: {slot: index
+    blocks: dict | None = None  # a block server's pass: {slot: index
     #                             of the block's first new token} of
-    #                             the rows whose block it commits
-    fixed: int = 0              # ... and positions it fixes, all rows
+    #                             the rows whose block it finishes,
+    fixed: int = 0              # the positions it fixes, all rows,
+    fused: int = 0              # the commits its lanes carry
+    waits: int = 0              # and the rows it left out for want of
+    #                             a lane
 
 
 class DecodeServer:
@@ -342,9 +352,12 @@ class DecodeServer:
         # left to dispatch].  ``_active[slot]`` on the device is true
         # exactly for these.  A budget's end is known here, at
         # dispatch; an EOS only at the fetch, one step late.  A block
-        # server's row: slot -> [position of its block, tokens left
-        # to dispatch, open positions left in the block, tokens the
-        # block yields (its length less a prompt's remainder)].
+        # server's row: slot -> [position of its open block, tokens
+        # left to dispatch, open positions left in the block, tokens
+        # the block yields (its length less a prompt's remainder), 0
+        # or, while the block before it awaits a lane, 1 + the passes
+        # the row has sat out for one].  ``_active`` is not kept for
+        # such a server: a pass's rows are made from this.
         self._run: dict[int, list[int]] = {}
         # The one decode step in flight (dispatched, not fetched).
         self._flying: _InFlight | None = None
@@ -391,14 +404,16 @@ class DecodeServer:
         self.prefill_chunks_total = 0
         self.cross_decoder_runs_total = 0
         self.cross_decoder_keys_total = 0
-        # a block server's row-passes: a row's denoising passes, its
-        # commit passes, the blocks that reached their request, the
-        # positions the denoising passes fixed (counted, like a step,
-        # when the pass is fetched);
+        # a block server's row-passes: a row's denoising passes, the
+        # blocks that reached their request, the positions the
+        # denoising passes fixed, the commits that rode a lane of
+        # another block's pass, and the row-passes sat out for want of
+        # a lane (counted, like a step, when the pass is fetched);
         self.denoise_passes_total = 0
-        self.commit_passes_total = 0
-        self.blocks_committed_total = 0
+        self.blocks_emitted_total = 0
         self.tokens_fixed_total = 0
+        self.fused_commits_total = 0
+        self.lane_waits_total = 0
         # seconds per phase of step() (and of submit()'s admission,
         # which is prefill), on this process's perf_counter;
         self.phase_s = dict.fromkeys(STEP_PHASES, 0.0)
@@ -522,19 +537,21 @@ class DecodeServer:
         if self._blocks:
             def nbd_denoise_step_paged(params, pool, table, lens, block,
                                        active, key):
-                """One pass over every row's block -> (pool, lens, the
-                rows' blocks, what the pass left of them for the host,
-                the routing load): the forward over the ``L`` tokens a
-                row at ``lens .. lens + L - 1``, whose K/V land in the
-                row's pages on every pass, then
-                :func:`~.sdar.denoise`.  ``key`` is unused: a block
-                server is greedy."""
+                """One pass over every row's open block and the commit
+                lanes ``block["lane"]`` names -> (pool, lens, the rows'
+                blocks, what the pass left of them for the host, the
+                routing load): one forward over the ``L`` tokens of
+                each (:func:`~.sdar.with_lanes`), whose K/V land in
+                their rows' pages, the head over the open blocks
+                alone, then :func:`~.sdar.denoise`.  ``key`` is
+                unused: a block server is greedy."""
+                tokens, at, rows, taking, lens = sdar.with_lanes(
+                    block, lens, table, active, cfg)
                 logits, pool, load = forward_with_cache(
-                    params, block["tokens"], pool, lens, cfg,
-                    row_mask=active, block_table=table,
-                    with_moe_load=True)
-                block, lens, out = sdar.denoise(logits, block, lens,
-                                                active, cfg)
+                    params, tokens, pool, at, cfg, row_mask=taking,
+                    block_table=rows, with_moe_load=True,
+                    head_rows=active.shape[0])
+                block, out = sdar.denoise(logits, block, active, cfg)
                 return pool, lens, block, out, load
 
             return jax.jit(nbd_denoise_step_paged, donate_argnums=(1,))
@@ -722,8 +739,7 @@ class DecodeServer:
         self._slot_req[slot] = rid
         self._budget[rid] = budget
         opened = self._L - (len(prompt) - at)
-        self._run[slot] = [at, budget, opened, opened]
-        self._active = self._active.at[slot].set(True)
+        self._run[slot] = [at, budget, opened, opened, 0]
 
     def _finish(self, slot: int, rid: int) -> None:
         """Free the slot and its pages.  A step in flight may still
@@ -734,7 +750,7 @@ class DecodeServer:
         self._finished.add(rid)
         self._slot_req.pop(slot, None)
         self._budget.pop(rid, None)
-        if self._run.pop(slot, None) is not None:
+        if self._run.pop(slot, None) is not None and not self._blocks:
             self._active = self._active.at[slot].set(False)
         self._free.append(slot)
         self._paged.free(slot)
@@ -835,10 +851,10 @@ class DecodeServer:
         does not queue behind programs dispatched later.  A row whose
         budget this step ends leaves :attr:`_run` here, before the
         step's tokens are known."""
+        if self._blocks:
+            return self._dispatch_pass()
         rows = {slot: self._slot_req[slot] for slot in self._run}
         kv_bytes = self._step_kv_read_bytes()
-        if self._blocks:
-            return self._dispatch_pass(rows, kv_bytes)
         self._cache, self._lens, self._last, load = self._step_fn(
             self._params, self._cache, self._paged.device_table(),
             self._lens, self._last, self._active, self._sample_key())
@@ -853,48 +869,75 @@ class DecodeServer:
                 self._active = self._active.at[slot].set(False)
         return _InFlight(self._last, load, rows, kv_bytes)
 
-    def _dispatch_pass(self, rows: dict, kv_bytes: tuple) -> _InFlight:
+    def _dispatch_pass(self) -> _InFlight:
         """A block server's :meth:`_dispatch_step`: one pass over the
         rows of :attr:`_run`.  What each row's pass is follows from the
-        schedule alone: a row with open positions fixes
-        ``fixed_per_pass`` of them, a row with none commits its block,
-        and a commit that ends the budget takes the row out."""
+        schedule alone.  A row whose block before this one awaits its
+        commit books a lane, the longest wait first; with none free it
+        sits the pass out: nothing of it runs, so its schedule stands.
+        Every other row fixes ``fixed_per_pass`` open positions, and
+        the pass that fixes a block's last finishes it: the block
+        leaves at that pass's fetch, and the row opens its next block
+        with a lane to book, or, its budget spent, leaves with nothing
+        to commit."""
+        L, per_pass, run = self._L, self._cfg.fixed_per_pass, self._run
+        lane = np.full(self._block["lane"].shape, self._B, np.int32)
+        asking = sorted((slot for slot, st in run.items() if st[4]),
+                        key=lambda slot: -run[slot][4])
+        booked, sitting = asking[:lane.size], set(asking[lane.size:])
+        lane[:len(booked)] = booked
+        for slot in sitting:
+            run[slot][4] += 1
+        rows = {slot: self._slot_req[slot] for slot in run
+                if slot not in sitting}
+        active = np.zeros((self._B,), bool)
+        active[list(rows)] = True
+        # an open block's queries share its last key, a lane's the last
+        # key of the block before the open one
+        kv_bytes = self._step_kv_read_bytes(
+            [run[slot][0] + L - 1 for slot in rows]
+            + [run[slot][0] - 1 for slot in booked])
         self._cache, self._lens, self._block, out, load = self._step_fn(
             self._params, self._cache, self._paged.device_table(),
-            self._lens, self._block, self._active, self._key)
+            self._lens, {**self._block, "lane": lane}, active, self._key)
         fetched = (out["tokens"], out["when"])
         for a in (*fetched, load):
             a.copy_to_host_async()
-        L, per_pass = self._L, self._cfg.fixed_per_pass
-        commits, fixed = {}, 0
-        for slot, st in list(self._run.items()):
-            if st[2]:                           # a denoising pass
-                n = min(per_pass, st[2])
-                st[2] -= n
-                fixed += n
+        blocks, fixed = {}, 0
+        for slot in rows:
+            st = run[slot]
+            n = min(per_pass, st[2])
+            st[2] -= n
+            st[4] = 0
+            fixed += n
+            if st[2]:
                 continue
-            commits[slot] = L - st[3]           # the commit
-            st[0] += L
+            blocks[slot] = L - st[3]            # the block's last pass
             st[1] -= st[3]
-            st[2] = st[3] = L
             if st[1] <= 0:
-                del self._run[slot]
-                self._active = self._active.at[slot].set(False)
-        return _InFlight(fetched, load, rows, kv_bytes, commits, fixed)
+                del run[slot]
+            else:
+                st[0] += L
+                st[2] = st[3] = L
+                st[4] = 1
+        return _InFlight(fetched, load, rows, kv_bytes, blocks, fixed,
+                         len(booked), len(sitting))
 
-    def _step_kv_read_bytes(self) -> tuple:
+    def _step_kv_read_bytes(self, last_keys=None) -> tuple:
         """Bytes of K and V pages the next decode step's attention
         fetches, a count a kind of K/V over the layers that read it:
-        for every row the step runs, the pages from the window's first
-        to the one its new token lands in (a block's last), once a
-        row."""
+        for every row the kernel runs, the pages from the window's
+        first to the one its last key lies in, once a row.
+        ``last_keys``: the last position each kernel row attends (the
+        rows of :attr:`_run`, each at its new token, where not
+        given)."""
         bt = self._paged.block_tokens
-        last = self._L - 1      # a block's queries share its last key
+        if last_keys is None:
+            last_keys = [st[0] for st in self._run.values()]
         return tuple(
             kind.page_bytes * sum(
-                (st[0] + last) // bt
-                - self._first_live_page(kind, st[0] + last) + 1
-                for st in self._run.values())
+                pos // bt - self._first_live_page(kind, pos) + 1
+                for pos in last_keys)
             for kind in self._kinds)
 
     def _emit_step(self, step: _InFlight, toks, load) -> dict:
@@ -919,18 +962,20 @@ class DecodeServer:
                 if self._slot_req.get(slot) == rid}
 
     def _emit_pass(self, step: _InFlight, tokens, when) -> dict:
-        """Count a fetched pass and emit the blocks it committed: the
+        """Count a fetched pass and emit the blocks it finished: the
         new tokens of a row's block (a prompt's remainder is not
-        output), each with the pass that fixed it."""
-        self.commit_passes_total += len(step.commits)
-        self.denoise_passes_total += len(step.rows) - len(step.commits)
+        output), each with the pass that fixed it.  Their tokens are
+        final here, a pass before a lane commits them."""
+        self.denoise_passes_total += len(step.rows)
         self.tokens_fixed_total += step.fixed
+        self.fused_commits_total += step.fused
+        self.lane_waits_total += step.waits
         emitted = {}
-        for slot, first in step.commits.items():
+        for slot, first in step.blocks.items():
             rid = step.rows[slot]
             if self._slot_req.get(slot) != rid:
                 continue
-            self.blocks_committed_total += 1
+            self.blocks_emitted_total += 1
             toks = self._emit(slot, rid,
                               [int(t) for t in tokens[slot][first:]])
             self.fixed_at[rid].extend(
@@ -1030,9 +1075,10 @@ class DecodeServer:
                 "xdec": self.cross_decoder_runs_total,
                 "xkeys": self.cross_decoder_keys_total,
                 "dn:passes": self.denoise_passes_total,
-                "dn:commits": self.commit_passes_total,
-                "dn:blocks": self.blocks_committed_total,
+                "dn:blocks": self.blocks_emitted_total,
                 "dn:fixed": self.tokens_fixed_total,
+                "dn:fused": self.fused_commits_total,
+                "dn:waits": self.lane_waits_total,
                 **{"kv:" + k.name: n for k, n in
                    zip(self._kinds, self.kv_read_bytes_by_kind)},
                 **{"ph:" + k: v for k, v in self.phase_s.items()}}
@@ -1057,11 +1103,15 @@ class DecodeServer:
         wrote, and the steps; ``xdec``: chunk programs that ran the
         layers past the shared K/V, the chunk programs, and the shared
         layer's keys the former attended.  A block server adds
-        ``dn``: its rows' denoising passes, their commit passes, the
-        blocks committed to a request and the positions fixed (a
-        step is then a pass over every row's block: ``kvr`` counts a
-        row's pages once a pass, ``dc`` the tokens that left at a
-        commit).  A decode step counts, in
+        ``dn``: its rows' denoising passes, the commit passes that
+        took a row-pass of their own (0: a commit rides a lane of
+        another block's pass), the blocks that reached a request, the
+        positions fixed, the commits the lanes carried and the
+        row-passes sat out for want of a lane (a step is then a pass
+        over every row's block: ``kvr`` counts a row's pages once a
+        pass and once more for its lane, ``moe`` the lanes' routed
+        rows too, ``dc`` the tokens that left with a finished block).
+        A decode step counts, in
         all of these, when its tokens are fetched: the step in flight
         at the call is the next account's."""
         now = self._totals()
@@ -1077,8 +1127,8 @@ class DecodeServer:
             account.update(kvk=kv, st=[d["state"], d["steps"]],
                            xdec=[d["xdec"], d["chunks"], d["xkeys"]])
         if self._blocks:
-            account["dn"] = [d["dn:passes"], d["dn:commits"],
-                             d["dn:blocks"], d["dn:fixed"]]
+            account["dn"] = [d["dn:passes"], 0, d["dn:blocks"],
+                             d["dn:fixed"], d["dn:fused"], d["dn:waits"]]
         if self._routed:
             account["moe"] = [round(v, 3) for v in self.moe_load]
             self.moe_load = [0.0, 0.0, 0.0]

@@ -82,14 +82,21 @@ TICK_RING = 64
 # worker sent and its ``step()`` calls that emitted; tokens a frame
 # delivered before its tick's reply, tokens applied (each carried by
 # one push: where a row gets one token a step, the steps the pushes
-# carried; a block server's row gets a block at a commit), and pushes
+# carried; a block server's row gets a block as it finishes), and pushes
 # to clients; a block server's row-passes: its rows' denoising passes,
-# their commit passes, and the blocks committed to a request; bytes of
-# K and V pages the decode steps' attention fetched (``kvr``'s).
+# the commit passes that took a row-pass of their own, and the blocks
+# that reached a request; bytes of K and V pages the decode steps'
+# attention fetched (``kvr``'s); a block server's commits that rode a
+# lane of another block's pass, and its row-passes sat out for want of
+# a lane.
 TICK_TOTALS = ("steps", "dc", "pf", "chunks", "state_bytes",
                "moe_touched", "moe_rows", "frames", "steps_emitting",
                "pushed_early", "pushed", "pushes",
-               "passes", "commits", "blocks", "kv_bytes")
+               "passes", "commits", "blocks", "kv_bytes",
+               "fused", "lane_waits")
+# A block server's ``dn``, in the worker's order; a shorter one (a
+# worker that knows no lanes) reads 0 for the rest.
+_DN = ("passes", "commits", "blocks", "fixed", "fused", "lane_waits")
 SLOW_TICKS = 8
 SLOW_FACTOR = 3.0
 SLOW_ABS_S = 1.0
@@ -470,9 +477,11 @@ class ServingObservatory:
         layer's keys the former attended]``.  ``fr`` = ``[serve_emit
         frames it sent, step() calls that emitted]`` (absent from a
         worker that answers once a tick).  ``dn`` = ``[denoising
-        row-passes, commit row-passes, blocks committed, positions
-        fixed]`` from a block server (a step is then a pass over every
-        row's block, and ``dc`` the tokens that left at the commits).
+        row-passes, commit row-passes of their own, blocks that
+        reached a request, positions fixed, commits a lane of another
+        block's pass carried, row-passes sat out for want of a lane]``
+        from a block server (a step is then a pass over every row's
+        block, and ``dc`` the tokens that left with finished blocks).
         ``pushed`` is the
         gateway's own count for this rank since the tick before:
         ``[tokens a frame delivered before its tick's reply, tokens
@@ -507,7 +516,8 @@ class ServingObservatory:
             "kvk": {k: int(v) for k, v in (tick.get("kvk") or {}).items()},
             "st": [int(v) for v in tick.get("st") or (0, 0)],
             "xdec": [int(v) for v in tick.get("xdec") or (0, 0, 0)],
-            "dn": [int(v) for v in tick.get("dn") or (0, 0, 0, 0)],
+            "dn": dict(zip(_DN, [int(v) for v in tick.get("dn") or ()]
+                           + [0] * len(_DN))),
             "turnaround": (None if turnaround is None
                            else max(0.0, float(turnaround))),
             "handler": handler,
@@ -531,8 +541,8 @@ class ServingObservatory:
                          ("moe_rows", moe and moe[2]),
                          ("frames", frames),
                          ("steps_emitting", emitting),
-                         *zip(("passes", "commits", "blocks"),
-                              rec["dn"]),
+                         *((k, rec["dn"][k]) for k in _DN
+                           if k in TICK_TOTALS),
                          ("kv_bytes", kv_bytes),
                          *zip(("pushed_early", "pushed", "pushes"),
                               rec["pushed"])):
@@ -622,17 +632,19 @@ class ServingObservatory:
                 sum(t["pushed"][1] for t in ticks) / pushes, 3)
             out["frames"] = [sum(t["fr"][0] for t in ticks),
                              sum(t["fr"][1] for t in ticks)]
-        blocks = sum(t["dn"][2] for t in ticks)
-        if blocks:
-            # a block server: row-passes (denoising and commit) a
-            # block committed, and positions fixed a denoising pass
+        dn = {k: sum(t["dn"][k] for t in ticks) for k in _DN}
+        if dn["blocks"]:
+            # a block server: row-passes (denoising, and commits that
+            # took one of their own) a block that reached its request,
+            # positions fixed a denoising pass, and of the commits the
+            # share that rode a lane of another block's pass
             out["denoise"] = {
                 "passes_per_block": round(
-                    sum(t["dn"][0] + t["dn"][1] for t in ticks)
-                    / blocks, 3),
+                    (dn["passes"] + dn["commits"]) / dn["blocks"], 3),
                 "tokens_per_pass": round(
-                    sum(t["dn"][3] for t in ticks)
-                    / max(1, sum(t["dn"][0] for t in ticks)), 3)}
+                    dn["fixed"] / max(1, dn["passes"]), 3),
+                "fused_share": round(
+                    dn["fused"] / max(1, dn["fused"] + dn["commits"]), 4)}
         routed = [t for t in ticks if t["moe"] is not None]
         if routed:
             # a decode step's routing load: means over the steps, the

@@ -7,10 +7,13 @@ the reference's full forward under the block-causal mask, with rows of
 unequal length, an idle row, a prompt shorter than a block, remainders
 of 0 to 3 and a prompt of several chunks; ``DecodeServer`` serves the
 reference's own loop, pass for pass, at 4, 2 and 1 passes a block, with
-budgets and an EOS that end inside a block; what no committed token
-wrote reaches no stream; a cancelled row leaves nothing behind; which
-positions are open is state; and the dense family's programs are the
-parent's, text for text.
+budgets and an EOS that end inside a block; a finished block is
+committed by a lane of the pass that opens the row's next one (ISSUE
+46): the pages hold what a commit writes, rows wait where lanes are
+short, a request's last block takes none, and a lane in flight at a
+cancel leaves nothing behind; what no committed token wrote reaches no
+stream; which positions are open is state; and the dense family's
+programs are the parent's, text for text.
 
 Tolerance: logits are O(1) and both sides are float32 at ``highest``
 precision, differing in the order of sums (online softmax over pages,
@@ -186,14 +189,14 @@ def test_a_pass_under_the_pallas_grouped_matmul_is_the_ragged_dots(
         srv.submit(p, m)
     for _ in range(6):                          # prefilled, into a block
         srv.step()
-    live = np.asarray(srv._active)
+    live = np.isin(np.arange(4), list(srv._run))
     assert live.sum() == 2 and not live.all()
     cfg, calls = program_config(), []
 
     def a_pass():
         # a fresh function a path: nothing traced under the other one
         return jax.jit(lambda pool, block, lens, table: forward_with_cache(
-            params, block, pool, lens, cfg, row_mask=srv._active,
+            params, block, pool, lens, cfg, row_mask=jnp.asarray(live),
             block_table=table, with_moe_load=True))(
             srv._cache, srv._block["tokens"], srv._lens,
             srv._paged.device_table())
@@ -261,34 +264,67 @@ def test_fewer_passes_a_block_fix_more_positions_a_pass(params, steps):
         requests.append((prompt, out[rid], srv.fixed_at[rid]))
     assert R.schedule_faults(requests, L, steps) == 0
     acct = srv.take_account()["dn"]
-    # the rows' passes follow the schedule: L / steps positions a pass
-    assert acct[3] <= acct[0] * (L // steps) and acct[1] == acct[2]
+    # the rows' passes follow the schedule: L / steps positions a pass;
+    # no commit takes a pass, and a request's last block takes no lane
+    assert acct[3] <= acct[0] * (L // steps) and acct[1] == 0
+    assert acct[4] == acct[2] - len(reqs)
 
 
 def test_the_account_counts_row_passes_and_the_observatory_reads_it(served):
     srv, requests = served
     acct = srv.take_account()
-    passes, commits, blocks, fixed = acct["dn"]
+    passes, commits, blocks, fixed, fused, waits = acct["dn"]
     new = sum(len(t) for _, t, _ in requests)
-    assert acct["dc"] == new and commits == blocks
+    # no commit takes a row-pass of its own: every block but a
+    # request's last is committed by a lane of the row's next pass
+    assert acct["dc"] == new and commits == 0
+    assert fused == blocks - len(requests) > 0
     # every token that left was fixed by a denoising pass (a budget's
     # end leaves a few fixed and not emitted), one position a pass
     assert passes == fixed >= new
+    assert (passes + commits) / new < 1.1
     assert acct["kvr"][1] == srv.decode_steps_total > 0
     obs = ServingObservatory()
     obs.note_tick(1, 0, {"roundtrip": 0.1}, {**acct, "seq": 1},
                   pushed=(0, new, 5))
     ticks = obs.ticks_summary()
     assert ticks["denoise"] == {
-        "passes_per_block": round((passes + commits) / blocks, 3),
-        "tokens_per_pass": 1.0}
-    assert {"passes", "commits", "blocks"} <= set(TICK_TOTALS)
+        "passes_per_block": round(passes / blocks, 3),
+        "tokens_per_pass": 1.0, "fused_share": 1.0}
+    assert {"passes", "commits", "blocks", "fused",
+            "lane_waits"} <= set(TICK_TOTALS)
     assert ticks["totals"]["passes"] == passes
     assert ticks["totals"]["blocks"] == blocks
+    assert ticks["totals"]["commits"] == 0
+    assert ticks["totals"]["fused"] == fused
+    assert ticks["totals"]["lane_waits"] == waits
+    # a worker that knows no lanes sends four counts: its commits took
+    # passes of their own
+    old = ServingObservatory()
+    old.note_tick(1, 0, {}, {"dc": 8, "dn": [8, 2, 2, 8], "seq": 1})
+    assert old.ticks_summary()["denoise"] == {
+        "passes_per_block": 5.0, "tokens_per_pass": 1.0,
+        "fused_share": 0.0}
     # a server of another family reports no such block
     plain = ServingObservatory()
     plain.note_tick(1, 0, {}, {"dc": 3, "kvr": [10, 3], "seq": 1})
     assert "denoise" not in plain.ticks_summary()
+
+
+def test_the_status_line_shows_the_share_of_commits_fused(tmp_path, capsys):
+    from nbdistributed_tpu.magics.magic import DistributedMagics
+    obs = ServingObservatory()
+    obs.note_tick(1, 0, {"roundtrip": 0.1},
+                  {"dc": 8, "dn": [8, 0, 2, 8, 1, 3], "seq": 1},
+                  pushed=(0, 8, 2))
+    mgr, _, _ = make_mgr(tmp_path, FakeComm(num_workers=1))
+    st = mgr.describe()
+    st.setdefault("lat", {}).setdefault("summary", {})["ticks"] = \
+        obs.ticks_summary()
+    DistributedMagics._render_serve_status(st)
+    mgr.stop()
+    assert "4 passes/block, 1 fixed/pass, 100% of commits fused" \
+        in capsys.readouterr().out
 
 
 def test_what_no_committed_token_wrote_reaches_no_stream(params, served):
@@ -336,6 +372,209 @@ def test_cancel_mid_block_frees_the_row_and_leaves_nothing_behind(
     assert srv.run_until_done(300)[nxt] == requests[1][1]
 
 
+# ----------------------------------------------------------------------
+# the commit rides a lane of the next block's first pass (ISSUE 46)
+
+
+@pytest.mark.parametrize("steps", [4, 2, 1])
+def test_more_rows_than_lanes_serve_the_references_streams(params, steps):
+    """Five rows over ``ceil(5 / steps)`` lanes, seven requests with
+    remainders 0 to 3 and budgets that end inside a block: the streams
+    and the pass that fixed each token are the reference's, whichever
+    rows waited for a lane."""
+    srv = server(params, steps, max_batch=5)
+    assert srv._block["lane"].shape == (-(-5 // steps),)
+    lens, budgets = (8, 9, 10, 11, 4, 21, 12), (9, 12, 7, 10, 13, 6, 8)
+    reqs = list(zip(prompts(lens, seed=3), budgets))
+    rids = [srv.submit(p, m) for p, m in reqs]
+    out = srv.run_until_done(500)
+    requests = []
+    for (prompt, max_new), rid in zip(reqs, rids):
+        want, want_when = reference(prompt, max_new, steps)
+        assert out[rid] == want and srv.fixed_at[rid] == want_when
+        requests.append((prompt, out[rid], srv.fixed_at[rid]))
+    assert R.schedule_faults(requests, L, steps) == 0
+    passes, commits, blocks, _, fused, _ = srv.take_account()["dn"]
+    assert commits == 0 and fused == blocks - len(reqs)
+    assert srv.done() and not srv._run and srv._paged.used_blocks == 0
+
+
+def test_a_lane_leaves_in_the_pages_what_a_commit_writes(params):
+    """After a request's blocks but the one it is in, the row's pages
+    hold the K/V of the served tokens under the block-causal mask: what
+    a pass over the finished block alone (a commit) writes, and what a
+    prefill of those tokens writes."""
+    cfg = program_config()
+    srv = server(params, max_batch=2)
+    prompt = prompts((10,), seed=5)[0]
+    rid = srv.submit(prompt, 30)
+    while len(srv.outputs[rid]) < 14:
+        srv.step()
+    assert srv._run and srv._flying.fused == 1
+    held = int(srv._lens[0])                    # waits for the pass
+    seq = prompt + srv.outputs[rid]
+    # the pass in flight carries the lane of the block emitted last
+    assert held == len(seq) // L * L == 24
+    table = srv._paged.device_table()
+    pool = make_paged_pool(cfg, srv._cache["k"].shape[1] - 1, BT)
+    for at in range(0, held, CHUNK):
+        seg = seq[at:min(at + CHUNK, held)]
+        toks = np.zeros((1, CHUNK), np.int32)
+        toks[0, :len(seg)] = seg
+        _, pool = forward_with_cache(
+            params, jnp.asarray(toks), pool, jnp.int32(at), cfg,
+            token_mask=jnp.arange(CHUNK)[None] < len(seg),
+            block_table=table[:1], final=False)
+    pages = np.asarray(table[0, :held // BT])
+    for name in ("k", "v"):
+        got = np.asarray(srv._cache[name])[:, pages]
+        np.testing.assert_allclose(got, np.asarray(pool[name])[:, pages],
+                                   **TOL)
+        assert np.abs(got).max() > 0.1
+
+
+def test_rows_that_finish_together_wait_for_the_one_lane(params, served):
+    """Three rows seated together finish their blocks in the same pass
+    and there is one lane: two wait, then one, and from there the rows
+    are a pass apart.  A row that waits runs nothing, so no pass of its
+    block is off the schedule, and the streams are unchanged."""
+    srv = server(params)
+    assert srv._block["lane"].shape == (1,)
+    reqs = list(zip(prompts((8, 12, 16), seed=9), (12, 12, 12)))
+    rids = [srv.submit(p, m) for p, m in reqs]
+    assert len(srv._run) == 3
+    out = srv.run_until_done(300)
+    requests = []
+    for (prompt, max_new), rid in zip(reqs, rids):
+        want, want_when = reference(prompt, max_new)
+        assert out[rid] == want and srv.fixed_at[rid] == want_when
+        assert set(srv.fixed_at[rid]) <= set(range(4))
+        requests.append((prompt, out[rid], srv.fixed_at[rid]))
+    assert R.schedule_faults(requests, L, 4) == 0
+    passes, commits, blocks, fixed, fused, waits = srv.take_account()["dn"]
+    # the first finish: two wait, then one; staggered, nobody waits
+    assert waits == 3 and commits == 0 and fused == 6 and blocks == 9
+    assert passes == fixed == 36
+
+
+@pytest.mark.parametrize("budget, lanes_used", [(3, 0), (4, 0), (5, 1),
+                                                (12, 2)])
+def test_a_requests_last_block_takes_no_lane(params, budget, lanes_used):
+    """No block attends a request's last: the row leaves at that
+    block's last denoising pass, and the passes a request takes are its
+    blocks' denoising passes alone."""
+    srv = server(params, max_batch=1)
+    prompt = prompts((8,), seed=budget)[0]
+    rid = srv.submit(prompt, budget)
+    steps = 0
+    while not srv.done():
+        srv.step()
+        steps += 1
+    want, want_when = reference(prompt, budget)
+    assert srv.outputs[rid] == want and srv.fixed_at[rid] == want_when
+    passes, commits, blocks, _, fused, waits = srv.take_account()["dn"]
+    assert (commits, fused, waits) == (0, lanes_used, 0)
+    assert passes == 4 * blocks == 4 * -(-budget // L)
+    assert steps == passes + 1                  # and one call to drain
+
+
+def test_a_cancel_under_a_lane_in_flight_leaves_nothing_behind(
+        params, served):
+    _, requests = served
+    srv = server(params, max_batch=1)
+    rid = srv.submit(requests[3][0], 24)
+    while not (srv._flying and srv._flying.fused):
+        srv.step()
+    assert srv._run and len(srv.outputs[rid]) >= 3
+    assert srv.cancel(rid) and not srv._run
+    assert srv._paged.used_blocks == 0
+    # the slot's next request: no lane of the old one, no block of it
+    nxt = srv.submit(requests[1][0], 9)
+    assert srv.run_until_done(300)[nxt] == requests[1][1]
+    assert srv.fixed_at[nxt] == requests[1][2]
+    # and an EOS learned with the next block's lane already dispatched
+    prompt, toks, when = requests[4]            # two whole blocks
+    srv = server(params, max_batch=1, eos_id=toks[2])
+    first = srv.submit(prompt, 8)
+    second = srv.submit(requests[0][0], 7)
+    out = srv.run_until_done(300)
+    assert out[first] == toks[:toks.index(toks[2]) + 1]
+    assert out[second] == requests[0][1]
+    assert srv.fixed_at[second] == requests[0][2]
+
+
+def test_the_pass_is_one_forward_whose_head_skips_the_lanes(
+        params, monkeypatch):
+    """The lowered denoise program: one paged-decode call a layer over
+    rows + lanes kernel rows, three grouped matmuls a layer, and a head
+    whose matmul has rows x L rows: a lane's logits are not computed."""
+    from nbdistributed_tpu.ops import decode, grouped
+    cfg, rows = program_config(), 6
+    srv = server(params, max_batch=rows)
+    lanes = sdar_mod.lanes(cfg, rows)
+    assert lanes == 2
+    calls = {"decode": [], "gmm": 0}
+    plain_decode, plain_dot = decode.paged_decode_attention, \
+        grouped.ragged_dot
+
+    def counted_decode(q, *a, **kw):
+        calls["decode"].append(q.shape)
+        return plain_decode(q, *a, **kw)
+
+    def counted_dot(*a):
+        calls["gmm"] += 1
+        return plain_dot(*a)
+
+    monkeypatch.setattr(decode, "paged_decode_attention", counted_decode)
+    monkeypatch.setattr(grouped, "ragged_dot", counted_dot)
+    text = srv._step_fn.lower(
+        params, srv._cache, srv._paged.device_table(), srv._lens,
+        srv._block, srv._active, srv._key).as_text()
+    # the folded call recurses once: the outer one carries the block
+    outer = [sh for sh in calls["decode"] if len(sh) == 4]
+    assert outer == [(rows + lanes, L, cfg.n_heads, cfg.head_dim)] \
+        * cfg.n_layers
+    assert calls["gmm"] == 3 * cfg.n_layers
+    head = f"tensor<{rows}x{L}x{cfg.vocab_size}xf32>"
+    assert head in text
+    assert f"tensor<{rows + lanes}x{L}x{cfg.vocab_size}xf32>" not in text
+    assert f"tensor<{(rows + lanes) * L}x{cfg.vocab_size}xf32>" not in text
+
+
+def test_a_rows_block_lands_where_its_tokens_go_and_is_staged_lean():
+    """``write_token`` with a block a row: each row's ``L`` tokens at
+    its page and offset, an idle row's in the trash block, every other
+    entry untouched; and three operations a row a leaf (slice, reshape,
+    update) beside ten a row for its two wrapped scalars, not the
+    seventeen a row a leaf of one token a row: at 160 rows of 7
+    unrolled layers they are what tracing the pass costs."""
+    from nbdistributed_tpu.models.paged_kv import write_token
+    rng = np.random.default_rng(2)
+    pool = {k: jnp.asarray(rng.normal(size=(2, 6, 2, BT, 4)), jnp.float32)
+            for k in ("k", "v")}
+    new = {k: jnp.asarray(rng.normal(size=(3, 2, L, 4)), jnp.float32)
+           for k in ("k", "v")}
+    table = jnp.asarray([[0, 1], [2, 3], [4, 4]], jnp.int32)
+    pos = jnp.asarray([4, 8, 0], jnp.int32)
+    active = jnp.asarray([True, True, False])
+    write = lambda pool, new: write_token(pool, jnp.int32(1), new, table,
+                                          pos, active)
+    got = write(pool, new)
+    for k in ("k", "v"):
+        want = np.array(pool[k])
+        want[1, 0, :, 4:8] = new[k][0]          # row 0: page 0, offset 4
+        want[1, 3, :, 0:4] = new[k][1]          # row 1: its second page
+        want[1, 5, :, 0:4] = new[k][2]          # idle: the trash block
+        np.testing.assert_array_equal(np.asarray(got[k]), want)
+    eqns = jax.make_jaxpr(write)(pool, new).eqns
+    per_row = [e for e in eqns if e.primitive.name in (
+        "slice", "squeeze", "reshape", "broadcast_in_dim", "select_n",
+        "lt", "add", "dynamic_update_slice")]
+    assert sum(e.primitive.name == "dynamic_update_slice"
+               for e in eqns) == 3 * 2
+    assert len(per_row) <= 3 * (2 * 3 + 10) + 8 < 3 * 2 * 17
+
+
 def test_a_fixed_token_that_equals_the_mask_id_stays_fixed():
     """Which positions are open is state: a pass that chooses the mask
     id fixes it, and the next pass fixes another position."""
@@ -346,27 +585,41 @@ def test_a_fixed_token_that_equals_the_mask_id_stays_fixed():
     logits[0, 0, 7] = 5.0
     logits[0, 1, 8] = 3.0
     logits[0, 3, 9] = 1.0
-    active, lens = jnp.asarray([True, False]), jnp.zeros((2,), jnp.int32)
+    active = jnp.asarray([True, False])
     seen = []
-    for _ in range(cfg.denoise_steps):
-        block, lens, out = sdar_mod.denoise(jnp.asarray(logits), block,
-                                            lens, active, cfg)
+    for s in range(cfg.denoise_steps):
+        block, out = sdar_mod.denoise(jnp.asarray(logits), block, active,
+                                      cfg)
         seen.append(np.asarray(out["when"][0]).tolist())
-        assert not bool(out["done"][0]) and int(lens[0]) == 0
+        # the pass that fixes the last open position finishes the block
+        assert np.asarray(out["done"]).tolist() \
+            == [s == cfg.denoise_steps - 1, False]
     assert seen[0] == [sdar_mod.OPEN, sdar_mod.OPEN, 0, sdar_mod.OPEN]
     assert seen[-1] == [1, 2, 0, 3]
-    assert np.asarray(out["tokens"][0]).tolist() == [7, 8,
-                                                     cfg.mask_token_id, 9]
-    # the pass that finds nothing open commits: the block goes out, the
-    # row moves on and its next block is all masks; the idle row stays
-    block, lens, out = sdar_mod.denoise(jnp.asarray(logits), block, lens,
-                                        active, cfg)
-    assert np.asarray(out["done"]).tolist() == [True, False]
-    assert np.asarray(lens).tolist() == [cfg.block_length, 0]
-    assert np.asarray(out["tokens"][0]).tolist() == [7, 8,
-                                                     cfg.mask_token_id, 9]
+    final = [7, 8, cfg.mask_token_id, 9]
+    assert np.asarray(out["tokens"][0]).tolist() == final
+    # the block went out and waits for a lane; the row's next block is
+    # all masks at pass 0; the idle row stays
+    assert np.asarray(block["closed"][0]).tolist() == final
+    assert (np.asarray(block["tokens"]) == cfg.mask_token_id).all()
     assert (np.asarray(block["when"]) == sdar_mod.OPEN).all()
     assert np.asarray(block["at"]).tolist() == [0, 0]
+    # a lane is the finished block at the position ``lens`` stands at;
+    # the row's open block sits a block further and ``lens`` moves
+    lens = jnp.asarray([8, 4], jnp.int32)
+    table = jnp.arange(6, dtype=jnp.int32).reshape(2, 3)
+    toks, at, rows, taking, moved = sdar_mod.with_lanes(
+        {**block, "lane": jnp.asarray([0], jnp.int32)}, lens, table,
+        active, cfg)
+    assert np.asarray(toks[2]).tolist() == final
+    assert np.asarray(at).tolist() == [12, 4, 8]
+    assert np.asarray(rows).tolist() == [[0, 1, 2], [3, 4, 5], [0, 1, 2]]
+    assert np.asarray(taking).tolist() == [True, False, True]
+    assert np.asarray(moved).tolist() == [12, 4]
+    # no row named: the lane takes no part and nothing moves
+    *_, taking, moved = sdar_mod.with_lanes(block, lens, table, active, cfg)
+    assert np.asarray(taking).tolist() == [True, False, False]
+    assert np.asarray(moved).tolist() == [8, 4]
 
 
 # ----------------------------------------------------------------------
@@ -433,8 +686,9 @@ def test_a_finished_streams_passes_ride_the_reply_to_the_result(
     prompt, toks, when = requests[0]
     w = _worker(server(params))
     d = _step(w, 1, admit=[{"rid": "a", "prompt": prompt, "max_new": 7}],
-              steps=8)
+              steps=4)
     assert "passes" not in d and d["tick"]["dn"][0] > 0
+    assert len(d["tick"]["dn"]) == 6
     while "a" not in d["finished"]:
         d = _step(w, 2, steps=8)
     assert d["passes"] == {"a": when}
