@@ -8,10 +8,9 @@ the three :mod:`concur` concurrency passes (lock-order graph,
 blocking-call-under-lock, callback-reentrancy):
 
 1. **env-knob registry** (:func:`check_env_knobs`): every ``NBD_*``
-   string in the product tree (``nbdistributed_tpu/``, ``tools/``,
-   ``bench.py``) must be declared in ``utils/knobs.py`` and
-   documented in README's configuration reference.  Undocumented
-   knobs fail CI.
+   string in the product tree (``nbdistributed_tpu/``, ``tools/``)
+   must be declared in ``utils/knobs.py`` and documented in README's
+   configuration reference.  Undocumented knobs fail CI.
 
 2. **codec wire-extension registry** (:func:`check_codec_headers`):
    the optional frame-header keys ``encode``/``decode`` handle and
@@ -64,7 +63,6 @@ _NBD_FULL = re.compile(r"^NBD_[A-Z][A-Z0-9_]*$")
 # SET knobs (monkeypatch, notebook parametrization) but only the
 # product tree READS them — declarations cover readers.
 _PRODUCT_DIRS = ("nbdistributed_tpu", "tools")
-_PRODUCT_FILES = ("bench.py",)
 
 # Container-constructor names recognized when classifying ``__init__``
 # attributes for the thread pass.
@@ -128,10 +126,6 @@ def _iter_product_files(root: str):
             for name in sorted(filenames):
                 if name.endswith(".py"):
                     yield os.path.join(dirpath, name)
-    for f in _PRODUCT_FILES:
-        path = os.path.join(root, f)
-        if os.path.exists(path):
-            yield path
 
 
 def _parse(path: str) -> ast.Module | None:

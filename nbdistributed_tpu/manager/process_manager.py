@@ -36,7 +36,7 @@ def wait_until_ready(comm, pm, timeout_s: float, *, poll_s: float = 2.0,
     the dead child's stdio) instead of a timeout; raises TimeoutError
     at the deadline.  ``on_wait()`` runs after each poll interval
     (progress display).  The one bring-up loop shared by the magic
-    layer, bench, selftest, and the integration tests.
+    layer, selftest, and the integration tests.
     """
     t0 = time.time()
     deadline = t0 + timeout_s

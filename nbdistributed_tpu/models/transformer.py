@@ -99,8 +99,7 @@ class TransformerConfig:
 # BASELINE.json ("8-rank Llama-2-7B forward"); tiny is the test/demo
 # scale (SmolLM2-135M-like role in the reference's notebook).
 # Caller kwargs OVERRIDE the preset's defaults (so e.g.
-# smol_135m_config(max_seq_len=8192) works — the bench's long-context
-# row does exactly that).
+# smol_135m_config(max_seq_len=8192) works).
 def _preset(kw: dict, cls=None, **defaults):
     """Build a preset config with caller kwargs overriding the
     defaults.  ``cls`` lets subclass factories (MoEConfig) share the
@@ -121,9 +120,8 @@ def smol_135m_config(**kw) -> TransformerConfig:
 
 def tinyllama_1b_config(**kw) -> TransformerConfig:
     """TinyLlama-1.1B dims (Zhang et al. 2024): the ~1B scale where
-    d_model=2048 matmuls feed the MXU properly — the bench's
-    MFU-at-meaningful-scale config (a 135M model's d=576 GEMMs cannot
-    reach competitive MFU on a v5e)."""
+    d_model=2048 matmuls feed the MXU properly (a 135M model's d=576
+    GEMMs cannot reach competitive MFU on a v5e)."""
     return _preset(kw, vocab_size=32000, d_model=2048, n_layers=22,
                    n_heads=32, n_kv_heads=4, d_ff=5632,
                    max_seq_len=2048)

@@ -42,9 +42,9 @@ end of the file (a write cut mid-record by a kill or truncation).
 
 The append path is the hot path (it runs on every control-plane
 dispatch): one compact-JSON encode, one CRC, one ``memoryview`` splice
-into the mmap under a lock — low single-digit microseconds, measured by
-``bench.py`` against control-plane echo latency (< 5 % is the
-acceptance bar; the socket round-trip is ~100× slower).  Recording is
+into the mmap under a lock — low single-digit microseconds against a
+control-plane echo of hundreds (< 5 % is the acceptance bar; the
+socket round-trip is ~100× slower).  Recording is
 **on by default** (``NBD_FLIGHT=0`` is the escape hatch) and every
 failure mode degrades to a silent no-op: a black box must never crash
 the plane.

@@ -38,12 +38,12 @@ header is emitted unless the observatory is enabled**
 Completed records feed per-stage log-scale histograms
 (``nbd_stage_seconds{stage=…}``, :data:`~.metrics.LATENCY_BUCKETS`)
 plus a bounded ring of raw records (``NBD_LAT_RING``) that backs
-``%dist_lat`` (per-stage p50/p95/p99 table, ``--last N`` waterfall),
-``GET /latency.json`` on the scrape endpoint (:mod:`.httpd`), and the
-``bench.py`` ``extra.latency_stages`` snapshot.  While a
-``%dist_trace`` session is active, each record is also mirrored into
-the trace as ``stage/<name>`` child spans of the request's send span,
-so the Perfetto view shows the same decomposition inline.
+``%dist_lat`` (per-stage p50/p95/p99 table, ``--last N`` waterfall)
+and ``GET /latency.json`` on the scrape endpoint (:mod:`.httpd`).
+While a ``%dist_trace`` session is active, each record is also
+mirrored into the trace as ``stage/<name>`` child spans of the
+request's send span, so the Perfetto view shows the same
+decomposition inline.
 """
 
 from __future__ import annotations

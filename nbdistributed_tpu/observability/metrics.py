@@ -7,7 +7,7 @@ collective durations, fault injections, supervisor transitions.
 Exported two ways:
 
 - :meth:`MetricsRegistry.to_json` — the payload of the worker
-  ``metrics`` handler and ``%dist_metrics`` (and the bench snapshot);
+  ``metrics`` handler and ``%dist_metrics``;
 - :meth:`MetricsRegistry.prometheus_text` — standard Prometheus
   exposition text, so a deployment can be scraped with nothing but a
   file/HTTP shim.

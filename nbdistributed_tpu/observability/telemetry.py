@@ -305,8 +305,8 @@ def hbm_totals(snapshot: dict | None) -> dict | None:
 
 
 def peak_hbm(snapshots) -> dict:
-    """Summarize a sequence of snapshots into per-device peak HBM bytes
-    (the ``bench.py`` trajectory summary)."""
+    """Summarize a sequence of snapshots into per-device peak HBM
+    bytes."""
     peaks: dict[str, int] = {}
     for snap in snapshots:
         for dev in (snap or {}).get("hbm", ()):

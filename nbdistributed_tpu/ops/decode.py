@@ -457,15 +457,9 @@ def _paged_decode_call(q, k_pool, v_pool, layer, table, pos, *,
     )(layer.reshape(1), table, pos, q, *pools)
 
 
-# (T, head_dim, gqa_group) -> block_k, measured on a live chip by
-# tune_flash.py's decode sweep.  Consulted when the caller passes no
-# explicit block_k; empty entries fall back to 128.  Decode is
-# HBM-streaming-bound, so the block size mostly trades grid overhead
-# against VMEM residency of the (block_k, D) cache window.  Seeded
-# from ops/tuned_blocks.json (see ops/_tuned.py).
-from ._tuned import load as _load_tuned
-
-DECODE_TUNED_BLOCKS: dict = _load_tuned()[1]
+# Keys a grid step when the caller passes no block_k.  Decode streams K
+# and V from HBM, and 128 keys a block is what every run of this round
+# used.
 _DEFAULT_BLOCK_K = 128
 
 
@@ -509,10 +503,7 @@ def flash_decode_attention(q, kc, vc, pos, *, scale: float | None = None,
         raise ValueError("pass both k_s and v_s, or neither")
     group = H // Hkv
     scale = scale if scale is not None else float(1.0 / np.sqrt(D))
-    if block_k is None:
-        block_k = DECODE_TUNED_BLOCKS.get((T, D, group),
-                                          _DEFAULT_BLOCK_K)
-    block_k = min(block_k, T)
+    block_k = min(_DEFAULT_BLOCK_K if block_k is None else block_k, T)
     qg = q.reshape(B, Hkv, group, D)
     if window is not None and window < 1:
         raise ValueError(f"window must be >= 1, got {window}")

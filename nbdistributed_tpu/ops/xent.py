@@ -4,7 +4,7 @@ The standard next-token loss computes ``logits = x @ W`` at (N, V)
 then ``log_softmax`` over V — two (N, V) fp32 buffers that dominate
 training memory at LM scale (B8 S2048 V32000: ~2.1 GB each, doubled
 again in the backward).  At 1B scale on a 16 G chip this is the wall
-that caps the train batch (bench.py's MFU ladder).
+that caps the train batch.
 
 This module computes the same loss with the vocabulary processed in
 chunks inside a ``lax.scan`` whose body is ``jax.checkpoint``-ed:
@@ -57,8 +57,7 @@ def chunked_softmax_xent(x, W, targets, valid=None, chunk: int = 8192):
     # Zero-pad only when chunk does not divide V: dynamic_slice CLAMPS
     # an out-of-range start (the last ragged chunk would silently read
     # overlapping columns), so the ragged case pays one W-sized copy.
-    # Callers wanting zero-copy pick a chunk that divides V (bench.py
-    # uses vocab_size // 4).
+    # Callers wanting zero-copy pick a chunk that divides V.
     Wp = jnp.pad(W, ((0, 0), (0, pad))) if pad else W
     targets = targets.astype(jnp.int32)
 

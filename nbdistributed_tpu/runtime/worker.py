@@ -632,9 +632,9 @@ class DistributedWorker:
         # Publish the cell's target ranks for the duration of the cell:
         # the eager world-collectives consult them at CALL time and
         # raise on a strict subset instead of deadlocking (see
-        # runtime/collective_guard.py).  Raw-string requests (bench
-        # cells, direct control-plane callers) carry no targets — the
-        # subset check stays inactive for them.
+        # runtime/collective_guard.py).  Raw-string requests (direct
+        # control-plane callers) carry no targets — the subset check
+        # stays inactive for them.
         targets = (None if isinstance(msg.data, str)
                    else msg.data.get("target_ranks"))
         repeat = until = None

@@ -16,7 +16,7 @@ the worker-side decode server, and the load harness:
   in-process ``TenantClient``), SLO scoring against the PR 12
   TTFT/TPOT histograms, and a machine-readable report with a pinned
   schema.  ``tools/nbd_loadgen.py`` is a thin CLI over this module so
-  bench and the unit tests drive the exact code the CLI runs.
+  the unit tests drive the exact code the CLI runs.
 """
 
 from .loadgen import (LoadConfig, run_load, score_slo, synth_schedule,

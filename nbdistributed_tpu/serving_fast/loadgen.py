@@ -5,8 +5,8 @@ can actually falsify them: offer load at a configured rate, watch
 every request to a TERMINAL verdict, and score the observed latency
 distributions against explicit targets.  This module is that harness'
 core — deliberately dependency-free (stdlib only, no jax) so the unit
-tests, ``bench.py``'s ``extra.serving`` row, the CI smoke, and the
-``tools/nbd_loadgen.py`` CLI all drive the exact same code.
+tests, the CI smoke, and the ``tools/nbd_loadgen.py`` CLI all drive
+the exact same code.
 
 Three pieces:
 
@@ -27,7 +27,7 @@ Three pieces:
 * :func:`score_slo` / :func:`validate_report` — pass/fail against
   p99 targets (client-observed percentiles, with the server's PR 12
   histogram summary attached for cross-checking) and the pinned
-  machine-readable report schema CI and bench consume.
+  machine-readable report schema CI consumes.
 """
 
 from __future__ import annotations
@@ -40,8 +40,8 @@ import urllib.request
 
 REPORT_SCHEMA_VERSION = 1
 
-# The pinned report surface: consumers (CI smoke, bench.py, dashboards)
-# key on these.  Adding a field is fine; removing or renaming one is a
+# The pinned report surface: consumers (CI smoke, dashboards) key on
+# these.  Adding a field is fine; removing or renaming one is a
 # breaking change the schema unit test is meant to catch.
 REPORT_REQUIRED_KEYS = frozenset({
     "schema", "config", "offered", "accepted", "rejected", "shed",
@@ -199,8 +199,8 @@ class HTTPTransport:
 
 class ClientTransport:
     """In-process transport over a connected
-    :class:`~..gateway.client.TenantClient` — what bench and the CI
-    smoke use (no HTTP server needed; same verdict surface)."""
+    :class:`~..gateway.client.TenantClient` — what the CI smoke uses
+    (no HTTP server needed; same verdict surface)."""
 
     def __init__(self, client):
         self.client = client

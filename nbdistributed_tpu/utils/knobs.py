@@ -1,8 +1,7 @@
 """The NBD_* environment-knob registry — every env knob in one table.
 
-Every ``NBD_*`` variable the framework (or its tools/bench harness)
-reads MUST be declared here.  The declaration is load-bearing three
-ways:
+Every ``NBD_*`` variable the framework (or its tools) reads MUST be
+declared here.  The declaration is load-bearing three ways:
 
 - the accessors below are the one choke point for env reads, so a
   typo'd knob name fails fast instead of silently reading nothing;
@@ -355,7 +354,7 @@ _ALL = (
     _k("NBD_LINT", "warn", "str",
        "Default pre-dispatch cell-vetting mode: warn (annotate), "
        "strict (block cells with error findings), off.", "lint"),
-    # --- selftest / bench / tools ---------------------------------------
+    # --- selftest / tools -----------------------------------------------
     _k("NBD_SELFTEST_FAULTS", None, "bool",
        "nbd-selftest: also run the fault-injection smoke section.",
        "harness"),
@@ -365,14 +364,6 @@ _ALL = (
     _k("NBD_SELFTEST_SERVE", None, "bool",
        "nbd-selftest: also run the serving smoke section (2-rank "
        "pool, 3 requests, one injected rank kill).", "harness"),
-    _k("NBD_BENCH_ONLY", None, "str",
-       "bench.py: comma-separated benchmark families to run.",
-       "harness"),
-    _k("NBD_BENCH_WORLD", None, "int",
-       "bench.py: world size override for multi-process rows.",
-       "harness"),
-    _k("NBD_BENCH_FAMILY_BUDGET_S", None, "float",
-       "bench.py: per-family wall-clock budget.", "harness"),
 )
 
 KNOBS: dict[str, Knob] = {k.name: k for k in _ALL}

@@ -58,9 +58,3 @@ def test_chip_smoke_verdict_line_has_exactly_ok_and_device(capsys,
     last = capsys.readouterr().out.strip().splitlines()[-1]
     assert json.loads(last) == {"ok": False, "device": smoke.device}
 
-
-def test_bench_without_a_chip_prints_no_row():
-    proc = _run("bench.py")
-    assert proc.returncode != 0
-    assert proc.stdout.strip() == ""           # the one JSON row: absent
-    assert "--backend tpu" in proc.stderr

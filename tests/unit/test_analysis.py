@@ -488,6 +488,30 @@ def test_env_knob_pass_catches_undeclared_knob(tmp_path):
                for f in findings)
 
 
+@pytest.mark.parametrize("module", ["attention", "decode", "grouped",
+                                    "xent"])
+def test_no_kernel_module_loads_a_table(module):
+    """A kernel chooses its tiles from the call's shapes or from a
+    constant in its source: no kernel module imports ``json``, opens a
+    file, or keeps a name with ``TUNED`` in it for a file to fill."""
+    path = os.path.join(REPO, "nbdistributed_tpu", "ops", module + ".py")
+    with open(path, encoding="utf-8") as f:
+        tree = ast.parse(f.read(), path)
+    names = set()
+    for node in ast.walk(tree):
+        if isinstance(node, (ast.Import, ast.ImportFrom)):
+            names.add(getattr(node, "module", None) or "")
+            for a in node.names:
+                names |= {a.name, a.asname or ""}
+        elif isinstance(node, ast.Name):
+            names.add(node.id)
+        elif isinstance(node, ast.Attribute):
+            names.add(node.attr)
+    names = {part for n in names for part in n.split(".")}
+    assert not names & {"json", "open"}
+    assert not [n for n in names if "TUNED" in n.upper()]
+
+
 def _thread_findings(src, exempt=None):
     tree = ast.parse(src)
     cls = tree.body[0]

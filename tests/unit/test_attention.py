@@ -444,25 +444,3 @@ class TestSegmentIds:
         np.testing.assert_allclose(np.asarray(g), np.asarray(gr),
                                    atol=1e-4, rtol=1e-4)
 
-
-def test_tuned_blocks_file_roundtrip(tmp_path):
-    """tune_flash.py persists its tables via ops._tuned; the kernels
-    load them at import.  Save/load must round-trip tuple keys, and a
-    corrupt or missing file must degrade to empty tables."""
-    from nbdistributed_tpu.ops import _tuned
-
-    p = str(tmp_path / "tuned.json")
-    flash = {(2048, 2048, 128, 4): (256, 512)}
-    decode = {(2048, 128, 4): 256}
-    _tuned.save(flash, decode, meta={"device": "test"}, path=p)
-    f, d = _tuned.load(p)
-    assert f == flash and d == decode
-    assert _tuned.load(str(tmp_path / "absent.json")) == ({}, {})
-    (tmp_path / "bad.json").write_text("{not json")
-    assert _tuned.load(str(tmp_path / "bad.json")) == ({}, {})
-    # Valid JSON, wrong schema: top level or sub-tables not dicts —
-    # must degrade to defaults, never crash import of ops.attention.
-    for bad in ('["a list"]', '{"flash": [1,2]}', '{"decode": 7}',
-                '{"flash": {"x": 1}}', '{"flash": {"1,2": null}}'):
-        (tmp_path / "schema.json").write_text(bad)
-        assert _tuned.load(str(tmp_path / "schema.json")) == ({}, {})

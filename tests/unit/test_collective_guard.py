@@ -29,7 +29,7 @@ def test_full_mesh_cell_passes_and_counts():
 
 
 def test_unknown_targets_pass():
-    """Raw-string execute requests (bench cells, direct callers)
+    """Raw-string execute requests (direct callers)
     carry no target info: the guard must not fire."""
     cg.begin_cell(None, world=4)
     cg.check("all_reduce")

@@ -26,19 +26,34 @@ def reference(q, kc, vc, pos):
     return o.reshape(B, H, D).astype(q.dtype)
 
 
-@pytest.mark.parametrize("T,pos", [(40, [10, 25]), (128, [0, 127]),
-                                   (37, [36, 5]),
-                                   # overlapping final block: T > 128,
-                                   # not a block multiple (the old gcd
-                                   # fallback collapsed these to 1-wide
-                                   # blocks)
-                                   (129, [128, 60]), (200, [199, 130]),
-                                   # T = block_k + 1 with pos at both
-                                   # extremes: first slot only, and the
-                                   # lone slot owned by the final block
-                                   (129, [0, 128])])
-def test_decode_matches_reference(T, pos):
-    B, H, Hkv, D = 2, 8, 4, 16
+# (n_heads, n_kv_heads, head_dim) of the models the tree serves, one
+# for each size of query group: the one default block_k (128 keys)
+# has to be right for every one of them.
+_SERVED_HEADS = [(4, 4, 128),     # group 1: Llama-2-7B's ratio
+                 (4, 2, 64),      # group 2: Phi-4-mini-flash
+                 (8, 2, 128),     # group 4: Mistral-7B
+                 (8, 1, 64),      # group 8: TinyLlama
+                 (8, 1, 128),     # group 8: SDAR-30B-A3B
+                 (16, 1, 128)]    # group 16: Nemotron-3-Nano
+
+
+_TOY_HEADS = (8, 4, 16)
+
+
+@pytest.mark.parametrize("T,pos,heads", [
+    (40, [10, 25], _TOY_HEADS), (128, [0, 127], _TOY_HEADS),
+    (37, [36, 5], _TOY_HEADS),
+    # overlapping final block: T > 128, not a block multiple (the old
+    # gcd fallback collapsed these to 1-wide blocks)
+    (129, [128, 60], _TOY_HEADS), (200, [199, 130], _TOY_HEADS),
+    # T = block_k + 1 with pos at both extremes: first slot only, and
+    # the lone slot owned by the final block
+    (129, [0, 128], _TOY_HEADS),
+    # the served head shapes over a cache that is no multiple of 128
+    *[(200, [199, 130], heads) for heads in _SERVED_HEADS]])
+def test_decode_matches_reference(T, pos, heads):
+    B = 2
+    H, Hkv, D = heads
     kc = jax.random.normal(jax.random.PRNGKey(0), (B, Hkv, T, D))
     vc = jax.random.normal(jax.random.PRNGKey(1), (B, Hkv, T, D))
     q = jax.random.normal(jax.random.PRNGKey(2), (B, H, D))
@@ -232,46 +247,19 @@ def test_decode_kernel_int8_requires_both_scales():
                                k_s=s)
 
 
-class _RecordingTable(dict):
-    """dict that records .get keys — proves the lookup actually fired
-    with the expected key (numerics alone cannot: a silently-missed
-    lookup falls back to the same default)."""
-
-    def __init__(self, *a):
-        super().__init__(*a)
-        self.keys_seen = []
-
-    def get(self, k, default=None):
-        self.keys_seen.append(k)
-        return super().get(k, default)
-
-
-def test_decode_tuned_block_table_consulted():
-    """block_k=None resolves through DECODE_TUNED_BLOCKS[(T, D, group)]
-    with a 128 fallback; the lookup must fire with that exact key, and
-    a tuned entry must change nothing numerically."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from nbdistributed_tpu.ops import decode as dec
-
+@pytest.mark.parametrize("block_k", [16, 32, 64])
+def test_explicit_block_k_changes_nothing(block_k):
+    """An explicit block_k wins over the default of 128 keys a block,
+    and the block size is a schedule, not a result."""
     B, T, H, Hkv, D = 1, 64, 4, 2, 16
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (B, H, D))
     kc = jax.random.normal(ks[1], (B, Hkv, T, D))
     vc = jax.random.normal(ks[2], (B, Hkv, T, D))
     pos = jnp.full((B,), T - 1, jnp.int32)
-    default = dec.flash_decode_attention(q, kc, vc, pos)
-    key = (T, D, H // Hkv)
-    orig = dec.DECODE_TUNED_BLOCKS
-    table = _RecordingTable({key: 32})
-    dec.DECODE_TUNED_BLOCKS = table
-    try:
-        tuned = dec.flash_decode_attention(q, kc, vc, pos)
-    finally:
-        dec.DECODE_TUNED_BLOCKS = orig
-    assert key in table.keys_seen, table.keys_seen
-    np.testing.assert_allclose(np.asarray(tuned), np.asarray(default),
+    default = flash_decode_attention(q, kc, vc, pos)
+    explicit = flash_decode_attention(q, kc, vc, pos, block_k=block_k)
+    np.testing.assert_allclose(np.asarray(explicit), np.asarray(default),
                                atol=2e-5, rtol=2e-5)
 
 

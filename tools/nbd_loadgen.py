@@ -4,7 +4,7 @@
 Drives the serving fast path at a configured request rate and scores
 SLO pass/fail from what the CLIENT observed, emitting the pinned
 machine-readable report (:mod:`nbdistributed_tpu.serving_fast.loadgen`
-— bench.py, CI, and the unit tests run the same core).  Two transports:
+— CI and the unit tests run the same core).  Two transports:
 
     # against the HTTP shim (tools/nbd_serve.py):
     python tools/nbd_loadgen.py --url http://localhost:8080 \\
